@@ -3,19 +3,39 @@
 Reference: python/paddle/distributed/launch.py:59,140,214 (parse ips/ports
 -> Cluster/Pod -> start_local_trainers sets PADDLE_* env, spawns children,
 watches and tears all down on failure) and fleet/launch.py (fleetrun, adds
---servers/--workers PS mode).  TPU differences: no per-GPU device
-assignment — each process drives its local chips; cross-process rendezvous
-is jax.distributed's coordinator (PADDLE_COORDINATOR = first trainer
+--servers/--workers PS mode).  Cross-process rendezvous is
+jax.distributed's coordinator (PADDLE_COORDINATOR = first trainer
 endpoint) instead of the NCCL-id TCP dance.
+
+Chips. A TPU chip belongs to one process at a time, and a jax process
+reaches for every chip of its host unless told otherwise. The supported
+shape on one host is therefore ONE process driving all local chips over
+a multi-device mesh (parallel.hybrid.make_hybrid_mesh). On a host that
+HAS TPU chips (their device files are there: /dev/accel* or
+/dev/vfio/<n>), and unless JAX_PLATFORMS names the cpu, this launcher
+(which itself never initialises a jax backend):
+
+  * refuses to start more than one trainer/worker process on the node —
+    they would fight over the same chips;
+  * gives each `--serving_replicas` child one chip of its own
+    (TPU_VISIBLE_CHIPS=<i> and 1x1x1 process bounds, set before the
+    child imports jax): replicas are independent one-chip engines with
+    no collective between them. A replica numbered past the host's
+    chips fails at jax start-up with libtpu's own message.
+
+On a host without chips (CPU-only, GPU) nothing is refused or pinned.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
 import sys
 import time
+
+from ..utils.compile_cache import cache_dir
 
 __all__ = ["launch", "main", "get_cluster_env"]
 
@@ -205,9 +225,60 @@ def get_cluster_env(rank, endpoints, role="TRAINER", servers="",
     return env
 
 
+def _host_has_tpu() -> bool:
+    """TPU chips show up as /dev/accel<n> (v4 and before, v5p) or as
+    vfio groups /dev/vfio/<n> (v5e, v6e: the chip machine here has
+    /dev/vfio/1 and no /dev/accel*)."""
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _on_cpu(env_over) -> bool:
+    """True when the child will not touch a chip: the host has none, or
+    the child's JAX_PLATFORMS names the cpu."""
+    plat = env_over.get("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    return not _host_has_tpu() \
+        or plat.split(",")[0].strip().lower() == "cpu"
+
+
+def _assign_chips(specs) -> str | None:
+    """One process per chip (module docstring): pin each serving
+    replica to its own chip through its environment; return an error
+    message when several trainer/worker children would share the
+    node's chips. Children that run on the cpu, and an operator who
+    set TPU_VISIBLE_CHIPS themselves, are left alone."""
+    if "TPU_VISIBLE_CHIPS" in os.environ:
+        return None
+    chip_users = [(name, env) for name, env, _argv in specs
+                  if name.startswith(("trainer.", "worker."))
+                  and not _on_cpu(env)]
+    if len(chip_users) > 1:
+        return (f"{len(chip_users)} trainer processes on one node would "
+                f"all reach for the same TPU chips, and a chip belongs to "
+                f"one process. Start ONE process and give it a "
+                f"multi-device mesh (parallel.hybrid.make_hybrid_mesh "
+                f"takes every local chip), or set JAX_PLATFORMS=cpu for a "
+                f"host-only run.")
+    replicas = [env for name, env, _argv in specs
+                if name.startswith("replica.") and not _on_cpu(env)]
+    if len(replicas) > 1:
+        for i, env in enumerate(replicas):
+            env.update({"TPU_VISIBLE_CHIPS": str(i),
+                        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                        "TPU_PROCESS_BOUNDS": "1,1,1"})
+        sys.stderr.write(
+            f"[launch] {len(replicas)} serving replicas pinned to chips "
+            f"0..{len(replicas) - 1} (TPU_VISIBLE_CHIPS); a replica "
+            f"numbered past this host's chips fails at jax start-up\n")
+    return None
+
+
 def _spawn_one(name, env_over, argv, log_dir):
     env = dict(os.environ)
     env.update(env_over)
+    if not _on_cpu(env_over):
+        # chip children find one compile cache, the same place on every
+        # run (XLA:CPU gains little from it and warns on every reload)
+        env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir())
     if log_dir:
         fh = open(os.path.join(log_dir, f"{name}.log"), "a")
         stdout = stderr = fh
@@ -513,6 +584,10 @@ def launch(argv=None):
             rank = base + i
             specs.append((f"trainer.{rank}",
                           get_cluster_env(rank, endpoints), script))
+    err = _assign_chips(specs)
+    if err:
+        sys.stderr.write(f"[launch] {err}\n")
+        return 1
     if args.metrics_dir:
         os.makedirs(args.metrics_dir, exist_ok=True)
         for _name, env, _argv in specs:

@@ -1,4 +1,4 @@
-"""Error taxonomy + enforce helpers (reference paddle/fluid/platform/
+"""Error classes + enforce helpers (reference paddle/fluid/platform/
 enforce.h + errors.h error codes, and operator.cc's exception re-wrap
 that attaches the failing op to the message).
 

@@ -25,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["GPTConfig", "init_gpt_params", "gpt_param_specs", "gpt_forward",
            "gpt_loss", "gpt_block_fn", "decoder_tail", "GPTForCausalLM"]
@@ -143,7 +144,6 @@ def gpt_param_specs(pp_stacked: bool = False, moe: bool = False) -> dict:
     dim when stacked per-stage; expert banks shard E over "ep"). Axes not
     present in the mesh are dropped by ShardingRules._restrict-like
     resolution in hybrid.py."""
-    from jax.sharding import PartitionSpec as P
 
     def blk(*entries):
         return P(*(("pp",) if pp_stacked else ()), None, *entries)
@@ -214,11 +214,23 @@ def _causal_attention(q, k, v, n_heads, impl="xla"):
     if impl == "flash":
         from ..ops.flash_attention import _flash_wins
         from ..ops.pallas_attention import flash_attention
+        from ..parallel.sharding import (_restrict, current_kernel_mesh,
+                                         per_shard)
         qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        attend = partial(flash_attention, causal=True)
+        local = qh
+        mesh = current_kernel_mesh()
+        if mesh is not None:
+            # the kernel on each device's shard: batch over dp, heads
+            # over tp, the layout gpt_param_specs already gives q/k/v
+            spec = _restrict(P("dp", "tp", None, None), mesh)
+            attend = per_shard(attend, mesh, spec, 3)
+            local = jax.ShapeDtypeStruct(
+                NamedSharding(mesh, spec).shard_shape(qh.shape), qh.dtype)
         # same measure-once gate as the fused_attention op: the Pallas
         # kernel only keeps the hot path on shapes where it beats XLA
-        if _flash_wins(qh, kh, vh, None, None, True):
-            o = flash_attention(qh, kh, vh, causal=True)
+        if _flash_wins(local, local, local, None, None, True):
+            o = attend(qh, kh, vh)
             return o.transpose(0, 2, 1, 3).reshape(B, T, D)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
     mask = jnp.tril(jnp.ones((T, T), bool))

@@ -20,7 +20,9 @@ Three pieces, all views over the one metrics registry:
   peak bf16 FLOP/s from ``jax.devices()[0].device_kind`` (bench.py
   delegates here, so live gauges and bench reports share one peak
   table by construction) and :func:`mfu` converts achieved FLOP/s to
-  model-flops-utilisation.
+  model-flops-utilisation. A device kind that is not in the table (the
+  CPU backend, a new chip) has NO peak: no MFU and no roofline rows
+  are reported there, rather than numbers against a guessed chip.
 
 :func:`snapshot` serialises the whole plane (costs, breakdowns, kernel
 margins, HBM stats) into the schema-versioned dict ``perfwatch record``
@@ -28,6 +30,7 @@ writes and ``perfwatch compare`` diffs.
 """
 from __future__ import annotations
 
+import logging
 import math
 import os
 import threading
@@ -67,6 +70,8 @@ __all__ = [
 
 SNAPSHOT_SCHEMA = "paddle_tpu.perf/1"
 
+logger = logging.getLogger("paddle_tpu.perf")
+
 # ---------------------------------------------------------------------------
 # Peak tables.  bench.py's chip_peak_flops() delegates here so the live
 # MFU gauges and the bench reports can never disagree on the peak.
@@ -85,7 +90,6 @@ _PEAKS = [
     ("v3", 123e12),
     ("v2", 45e12),
 ]
-_DEFAULT_PEAK = 275e12
 
 # (device_kind substring, HBM bandwidth bytes/s) — for the roofline
 # ridge point.  Same shape as _PEAKS; override with TPU_PEAK_GBPS.
@@ -100,61 +104,52 @@ _BWS = [
     ("v3", 900e9),
     ("v2", 700e9),
 ]
-_DEFAULT_BW = 1228e9
 
 
 def _device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return str(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
+    return str(jax.devices()[0].device_kind)
 
 
-def chip_peak_flops() -> tuple[float, str]:
-    """(peak bf16 FLOP/s, device kind) for one chip.
-
-    ``TPU_PEAK_TFLOPS_BF16`` overrides the table (e.g. for new chips or
-    int8 serving); on CPU the TPU-class default keeps MFU numbers
-    comparable across hosts rather than meaningful in absolute terms.
-    """
+def _peak(table, env_name: str, env_scale: float) -> tuple[float | None, str]:
     kind = _device_kind()
-    env = os.environ.get("TPU_PEAK_TFLOPS_BF16")
+    env = os.environ.get(env_name)
     if env:
         try:
-            return float(env) * 1e12, kind
+            return float(env) * env_scale, kind
         except ValueError:
             pass
     low = kind.lower()
-    for sub, peak in _PEAKS:
+    for sub, peak in table:
         if sub in low:
             return peak, kind
-    return _DEFAULT_PEAK, kind
+    return None, kind
 
 
-def chip_peak_bytes_per_s() -> tuple[float, str]:
-    """(HBM bandwidth bytes/s, device kind); ``TPU_PEAK_GBPS`` overrides."""
-    kind = _device_kind()
-    env = os.environ.get("TPU_PEAK_GBPS")
-    if env:
-        try:
-            return float(env) * 1e9, kind
-        except ValueError:
-            pass
-    low = kind.lower()
-    for sub, bw in _BWS:
-        if sub in low:
-            return bw, kind
-    return _DEFAULT_BW, kind
+def chip_peak_flops() -> tuple[float | None, str]:
+    """(peak bf16 FLOP/s, device kind) for one chip; the peak is None
+    for a device kind the table does not know.
+
+    ``TPU_PEAK_TFLOPS_BF16`` overrides the table (e.g. for new chips or
+    int8 serving).
+    """
+    return _peak(_PEAKS, "TPU_PEAK_TFLOPS_BF16", 1e12)
+
+
+def chip_peak_bytes_per_s() -> tuple[float | None, str]:
+    """(HBM bandwidth bytes/s or None, device kind); ``TPU_PEAK_GBPS``
+    overrides."""
+    return _peak(_BWS, "TPU_PEAK_GBPS", 1e9)
 
 
 def mfu(flops: float, seconds: float) -> float:
-    """Model-flops-utilisation of `flops` model FLOPs in `seconds`."""
+    """Model-flops-utilisation of `flops` model FLOPs in `seconds`;
+    0.0 where the device has no known peak (nothing to report)."""
     if seconds <= 0 or flops <= 0:
         return 0.0
     peak, _ = chip_peak_flops()
-    return float(flops) / seconds / peak
+    return float(flops) / seconds / peak if peak else 0.0
 
 
 def analytic_gpt_flops(cfg, tokens: int, ctx: int) -> float:
@@ -298,13 +293,18 @@ def register_jit_cost(name: str, key: str, jitfn, *args,
     Lowering is abstract (shapes only — safe with donated buffers) but
     not free, so call this once per compile bucket, on the same path
     that pays the compile.  Falls back to `analytic_flops` when the
-    backend reports nothing; never raises.
+    backend reports nothing.  Never raises, but a failed lowering is
+    logged with its traceback: for an engine bucket this is the FIRST
+    trace of the program, and the error it hit is the one the real
+    call is about to hit again.
     """
     fl = by = None
     if costs_enabled():
         try:
             fl, by = _cost_from_analysis(jitfn.lower(*args).cost_analysis())
         except Exception:
+            logger.warning("lowering %s[%s] for cost analysis failed",
+                           name, key, exc_info=True)
             fl = by = None
     if fl is not None:
         return register_cost(name, key, fl, by, source="xla")
@@ -321,10 +321,13 @@ def roofline() -> list[dict]:
 
     `bound` says whether the op sits left (memory-bound) or right
     (compute-bound) of the chip's ridge point peak_flops/peak_bw.
+    No rows on a device without known peaks.
     """
     peak, _ = chip_peak_flops()
     bw, _ = chip_peak_bytes_per_s()
-    ridge = peak / bw if bw else float("inf")
+    if not (peak and bw):
+        return []
+    ridge = peak / bw
     rows = []
     for (name, key), c in sorted(costs().items()):
         fl, by = c.get("flops"), c.get("bytes")
@@ -430,9 +433,14 @@ def note_compile_seconds(site: str, seconds: float) -> None:
 # Kernel margins (autobench feeds this)
 # ---------------------------------------------------------------------------
 
-def note_kernel(key: str, winner: str, timings_ms: dict[str, float]) -> None:
+def note_kernel(key: str, winner: str, timings_ms: dict[str, float],
+                errors: dict[str, str] | None = None,
+                source: str = "measured") -> None:
     """Record an autobench decision: all measured candidate times, the
-    winner, and the winner's margin over the best loser."""
+    winner, the winner's margin over the best loser (below 1 where the
+    gate kept its default on a tie), the error of every candidate that
+    failed to run, and whether it was measured in this process or
+    adopted from the persistent tuning cache."""
     ts = {c: float(v) for c, v in timings_ms.items() if math.isfinite(v)}
     margin = None
     win_ms = ts.get(winner)
@@ -441,7 +449,8 @@ def note_kernel(key: str, winner: str, timings_ms: dict[str, float]) -> None:
         margin = min(losers) / win_ms  # >1: winner is margin× faster
     with _LOCK:
         _KERNELS[key] = {"winner": winner, "candidates_ms": ts,
-                         "margin": margin}
+                         "margin": margin, "errors": dict(errors or {}),
+                         "source": source}
 
 
 def kernels() -> dict[str, dict]:

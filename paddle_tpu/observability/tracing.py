@@ -16,10 +16,8 @@ numbers; this holds the *timeline*):
     JSON (Perfetto / chrome://tracing), one complete event per span
     with trace/span ids in ``args``;
   * XPlane bridge — every recorded span also enters
-    ``jax.profiler.TraceAnnotation`` when available, so host spans line
-    up with device traces inside a ``jax.profiler.start_trace`` window.
-    Older jax without the attr degrades to a silent no-op (the same
-    guard utils/profiler.py uses).
+    ``jax.profiler.TraceAnnotation``, so host spans line up with device
+    traces inside a ``jax.profiler.start_trace`` window.
 
 ``PADDLE_TPU_TRACE=0`` disables recording (ids still propagate so
 downstream tiers keep correlating); ``PADDLE_TPU_TRACE_BRIDGE=0``
@@ -52,24 +50,6 @@ _HIGH_WATER = _obs.gauge(
 
 def new_trace_id() -> str:
     return os.urandom(8).hex()
-
-
-def _jax_trace_annotation():
-    """jax.profiler.TraceAnnotation, or None when jax/the attr is
-    missing (older jax) — the graceful-no-op contract."""
-    global _TA
-    if _TA is _UNSET:
-        try:
-            import jax
-            _TA = getattr(getattr(jax, "profiler", None),
-                          "TraceAnnotation", None)
-        except Exception:
-            _TA = None
-    return _TA
-
-
-_UNSET = object()
-_TA = _UNSET
 
 
 class Span:
@@ -160,22 +140,15 @@ class Tracer:
         stack.append(sp)
         ann = None
         if self.enabled and self.bridge_jax:
-            ta = _jax_trace_annotation()
-            if ta is not None:
-                try:
-                    ann = ta(name)
-                    ann.__enter__()
-                except Exception:
-                    ann = None
+            import jax
+            ann = jax.profiler.TraceAnnotation(name)
+            ann.__enter__()
         try:
             yield sp
         finally:
             sp.end = time.monotonic()
             if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
+                ann.__exit__(None, None, None)
             stack.pop()
             if self.enabled:
                 with self._lock:

@@ -8,12 +8,53 @@ shape, not hold it by construction. This module provides the gate:
   winner = prefer(key, {"pallas": fn_a, "xla": fn_b}, make_args)
 
 On first call for `key` (a hashable shape/dtype signature) each
-candidate is jitted and timed on freshly made concrete inputs; the
-fastest name is cached for the life of the process and every later
-call for the same key returns instantly. The gate is invoked at
-trace/first-call time from op kernels — Python side effects during a
-jax trace run exactly once per compilation, so the measurement cost is
-paid once per shape bucket, never per step.
+candidate is jitted, compiled and timed on freshly made concrete device
+arrays; the winner is cached for the life of the process and every
+later call for the same key returns instantly.
+
+What the clock reads. One dispatched call with a sync costs about a
+millisecond on the chip whatever the kernel does, so a sample is a
+BATCH of back-to-back calls under one `block_until_ready`, sized to
+last about `_SAMPLE_SECONDS`; a candidate's time is the median sample
+divided by the batch, its spread the range of its samples. A floor
+remains: one execution still costs 0.19 ms with one output buffer and
+0.38 with two on the v5e host (chip run, PR 21), and candidates faster
+than that read alike. (A `fori_loop` of calls inside one jit would pay the dispatch
+once, but its inputs are loop-invariant: XLA:CPU was seen to hoist the
+call out of the loop, through an `optimization_barrier` too, and a
+perturbed input costs the Pallas side a pass the XLA side fuses away.)
+So `default` holds the slot unless a challenger beats it by more than
+the measured spread and by more than `_MIN_MARGIN`: a difference the
+clock cannot resolve must not flip between two runs, because a flipped
+kernel changes every program it sits in — different numbers run to
+run, and a compile-cache miss for each. The gate times the candidate
+alone, forward only; it does not see what XLA would have fused around
+the reference.
+
+The gate is invoked at trace time from inside jitted bodies
+(models/gpt.py decoder_tail, the paged-attention scan body, a
+shard_map stage of the trainer), where an ambient trace would turn
+make_args() into tracers and the "timing" into a timing of tracing. So
+the measuring round runs on a THREAD OF ITS OWN and refuses tracer
+arguments: jax's trace state — the ambient trace, a shard_map's axis
+environment, the context mesh — is thread-local, and a new thread
+starts at top level. (``jax.ensure_compile_time_eval()`` is not
+enough: it evaluates a Pallas kernel body's ``program_id`` eagerly
+while jit traces the candidate, and every Pallas candidate then fails.
+``jax.core.eval_context()`` leaves a shard_map's axis environment in
+place, and with it every call of the jitted candidate misses jit's
+fast path: inside the four-chip trainer's stages both flash-attention
+candidates read 5.3-5.6 ms per call at a shape that takes under one
+— chip run, PR 21.)
+Python side effects during a jax trace run exactly once per
+compilation, so the measurement cost is paid once per shape bucket,
+never per step.
+
+A candidate that raises (a kernel Mosaic refuses, an out-of-memory
+shape) never wins, and its error is KEPT: `perf.kernels()` holds, per
+key, the winner, every candidate's milliseconds and every candidate's
+error (it is part of the perf snapshot); the same goes to the
+persistent cache record and a warning on the module logger.
 
 Persistent tuning cache (PR 7, TPP-style portable primitives): set
 ``PADDLE_TPU_AUTOBENCH_CACHE=/path/to/autobench.json`` and every
@@ -61,18 +102,21 @@ import os
 import threading
 import time
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
+
+import jax
 
 from ..observability import perf as _perf, registry as _obs
 
-__all__ = ["prefer", "decisions", "clear", "stats", "register_warmer",
-           "warm", "list_entries", "invalidate", "KERNEL_VERSION",
-           "PRESETS"]
+__all__ = ["prefer", "decisions", "clear", "stats",
+           "register_warmer", "warm", "list_entries", "invalidate",
+           "KERNEL_VERSION", "PRESETS"]
 
 # Bump when any gated Pallas kernel's implementation changes materially:
 # cached winners were measured against the OLD kernel and must not
 # survive it. (The jax version is stamped independently.)
-KERNEL_VERSION = 2
+KERNEL_VERSION = 3
 
 _FORMAT = "paddle-tpu-autobench-v1"
 
@@ -80,8 +124,9 @@ _CACHE: dict = {}
 _LOCK = threading.Lock()
 _DISK: dict | None = None      # (key_str, device) -> record, lazy-loaded
 _DISK_PATH: str | None = None  # path _DISK was loaded from
-_STATS = {"measures": 0, "cache_hits": 0, "cache_misses": 0,
-          "cache_stale": 0, "cache_corrupt": 0, "publishes": 0}
+_STATS = {"measures": 0, "candidate_errors": 0, "cache_hits": 0,
+          "cache_misses": 0, "cache_stale": 0, "cache_corrupt": 0,
+          "publishes": 0}
 _WARNED_FORCE: set = set()
 
 logger = logging.getLogger("paddle_tpu.autobench")
@@ -125,40 +170,108 @@ def _verbose_logging():
         logger.addHandler(h)
 
 
+# one timing sample: back-to-back calls lasting about this long in all
+# (at most _MAX_CALLS of them), so the ~1 ms a sync costs is a few
+# percent of the sample and not the whole of it
+_SAMPLE_SECONDS = 0.1
+_MAX_CALLS = 100
+# a challenger must beat `default` by this share of default's time (and
+# by the measured spread) to take the slot
+_MIN_MARGIN = 0.05
+
+
 def _record_decision(key, winner: str, timings: dict[str, float],
+                     errors: dict[str, str] | None = None,
                      source: str = "measured"):
+    """`timings`: seconds per candidate that ran; `errors`: message per
+    candidate that raised."""
     skey = str(key)
+    errors = dict(errors or {})
     for name, t in timings.items():
         _CANDIDATE_MS.labels(key=skey, candidate=name).set(
-            round(t * 1e3, 4) if t < float("inf") else float("inf"))
+            round(t * 1e3, 4))
+    for name in (*timings, *errors):
         _WINNER.labels(key=skey, candidate=name).set(
             1.0 if name == winner else 0.0)
+    ms = {k: round(v * 1e3, 4) for k, v in timings.items()}
     # the perf plane keeps the full per-candidate table so `top` can
     # show Pallas-vs-XLA margins, not just the winner name
-    _perf.note_kernel(skey, winner,
-                      {n: t * 1e3 for n, t in timings.items()})
+    _perf.note_kernel(skey, winner, ms, errors, source)
     _verbose_logging()
-    ms = {k: round(v * 1e3, 3) for k, v in timings.items()}
     logger.info("%s -> %s %s (%s)", skey, winner, ms, source)
+    for name, err in errors.items():
+        logger.warning("%s: candidate %r failed and cannot win: %s",
+                       skey, name, err)
 
 
-def _measure(fn: Callable, make_args: Callable, reps: int) -> float:
-    """Median wall time of `fn(*make_args())` jitted, after one warmup
-    call that also pays compilation. Separated out so tests can inject
-    deterministic timings."""
-    import jax
-
+def _measure(fn: Callable, make_args: Callable,
+             reps: int) -> tuple[float, float]:
+    """(median, spread) of the per-call wall time of `fn(*make_args())`
+    jitted, over `reps` samples of back-to-back calls (module
+    docstring), after one warm-up call that also pays compilation.
+    Separated out so tests can inject deterministic timings."""
     args = make_args()
+    if any(isinstance(a, jax.core.Tracer)
+           for a in jax.tree_util.tree_leaves(args)):
+        raise RuntimeError(
+            "autobench candidates must be timed on concrete arrays; "
+            "make_args() returned tracers (measuring under a trace "
+            "would time the tracing)")
     jfn = jax.jit(fn)
-    out = jax.block_until_ready(jfn(*args))
-    del out
-    times = []
+    jax.block_until_ready(jfn(*args))
+    t0 = time.perf_counter()
+    jax.block_until_ready(jfn(*args))
+    one = time.perf_counter() - t0
+    calls = max(1, min(_MAX_CALLS, int(_SAMPLE_SECONDS / max(one, 1e-9))))
+    samples = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        jax.block_until_ready(jfn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2]
+        for _ in range(calls):
+            out = jfn(*args)
+        jax.block_until_ready(out)
+        samples.append((time.perf_counter() - t0) / calls)
+    samples.sort()
+    return samples[len(samples) // 2], samples[-1] - samples[0]
+
+
+def _measure_all(key, candidates, make_args, reps):
+    """One measuring round: (seconds, spread, error message) per
+    candidate name."""
+    timings, spreads, errors = {}, {}, {}
+    cost_args = None
+    for name, fn in candidates.items():
+        try:
+            timings[name], spreads[name] = _measure(fn, make_args, reps)
+        except Exception as e:  # a candidate that errors never wins
+            errors[name] = f"{type(e).__name__}: {e}"[:2000]
+            continue
+        # fused-block ops join the perf-plane cost registry on the same
+        # once-per-key measuring path (roofline rows per candidate); a
+        # failed cost observation must not void a successful timing
+        if _perf.costs_enabled():
+            try:
+                if cost_args is None:
+                    cost_args = make_args()
+                _perf.register_jit_cost(f"ops:{name}", str(key),
+                                        jax.jit(fn), *cost_args)
+            except Exception:
+                logger.warning("%s: no cost analysis for candidate %r",
+                               key, name, exc_info=True)
+    return timings, spreads, errors
+
+
+def _pick(timings: dict, spreads: dict, default: str) -> str:
+    """The fastest candidate — unless `default` ran and the fastest does
+    not beat it by more than the clock resolves (module docstring)."""
+    if not timings:
+        return default
+    best = min(timings, key=timings.get)
+    if best == default or default not in timings:
+        return best
+    lead = timings[default] - timings[best]
+    noise = max(spreads[default], spreads[best],
+                _MIN_MARGIN * timings[default])
+    return best if lead > noise else default
 
 
 # ---------------------------------------------------------------------------
@@ -171,19 +284,11 @@ def cache_path() -> str | None:
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        return str(jax.devices()[0].device_kind)
-    except Exception:  # pragma: no cover - no backend at all
-        return "unknown"
+    return str(jax.devices()[0].device_kind)
 
 
 def _jax_version() -> str:
-    try:
-        import jax
-        return str(jax.__version__)
-    except Exception:  # pragma: no cover
-        return "unknown"
+    return str(jax.__version__)
 
 
 def _rec_crc(rec: dict) -> int:
@@ -303,11 +408,13 @@ def _disk_lookup(key, candidates) -> str | None:
     with _LOCK:
         _STATS["cache_hits"] += 1
     _CACHE_HITS.inc()
-    # null timing = the candidate errored when measured (inf serialized
-    # as JSON null) — adopt it as inf, never crash the gate on it
-    timings = {n: (float(t) / 1e3 if t is not None else float("inf"))
-               for n, t in (rec.get("timings_ms") or {}).items()}
-    _record_decision(key, rec["winner"], timings, source="cache")
+    # null timing = the candidate errored when measured; its message
+    # rides the record's "errors"
+    timings = {n: float(t) / 1e3
+               for n, t in (rec.get("timings_ms") or {}).items()
+               if t is not None}
+    _record_decision(key, rec["winner"], timings, rec.get("errors"),
+                     source="cache")
     return rec["winner"]
 
 
@@ -317,7 +424,8 @@ def _disk_lookup(key, candidates) -> str | None:
 
 def prefer(key, candidates: dict[str, Callable], make_args: Callable,
            default: str | None = None, reps: int = 3) -> str:
-    """Return the name of the fastest candidate for `key`, measuring at
+    """Return the name of the candidate that holds `key`'s slot (the
+    fastest; `default` on a tie the clock cannot resolve), measuring at
     most once per key per process — and, with
     PADDLE_TPU_AUTOBENCH_CACHE set, at most once per key per cache
     lifetime across processes.
@@ -325,7 +433,8 @@ def prefer(key, candidates: dict[str, Callable], make_args: Callable,
     candidates: name -> nullary-composable fn taking make_args() outputs.
     make_args:  () -> tuple of concrete device arrays (built lazily, only
                 on the measuring call).
-    default:    winner when benchmarking is disabled (first name if None).
+    default:    winner when benchmarking is disabled, on a tie, and when
+                every candidate raised (first name if None).
     """
     forced = os.environ.get("PADDLE_TPU_AUTOBENCH_FORCE")
     if forced:
@@ -355,37 +464,22 @@ def prefer(key, candidates: dict[str, Callable], make_args: Callable,
     if disk_winner is not None:
         with _LOCK:
             return _CACHE.setdefault(key, disk_winner)
-    timings = {}
     with _LOCK:
         _STATS["measures"] += 1
     _MEASURES.inc()
-    cost_args = None
-    for name, fn in candidates.items():
-        try:
-            timings[name] = _measure(fn, make_args, reps)
-        except Exception:  # a candidate that errors never wins
-            timings[name] = float("inf")
-            continue
-        # fused-block ops join the perf-plane cost registry on the same
-        # once-per-key measuring path (roofline rows per candidate); a
-        # failed cost observation must not void a successful timing
-        if _perf.costs_enabled():
-            try:
-                import jax
-                if cost_args is None:
-                    cost_args = make_args()
-                _perf.register_jit_cost(f"ops:{name}", str(key),
-                                        jax.jit(fn), *cost_args)
-            except Exception:
-                pass
-    winner = min(timings, key=timings.get)
-    if not (timings[winner] < float("inf")):
-        winner = default
+    # on a thread of its own: top-level trace state whatever jit / scan /
+    # shard_map trace this thread is in (see module docstring)
+    with ThreadPoolExecutor(max_workers=1,
+                            thread_name_prefix="autobench") as pool:
+        timings, spreads, errors = pool.submit(
+            _measure_all, key, candidates, make_args, reps).result()
+    winner = _pick(timings, spreads, default)
     with _LOCK:
+        _STATS["candidate_errors"] += len(errors)
         # a racing thread may have decided already; first one wins so the
         # process is consistent
         winner = _CACHE.setdefault(key, winner)
-    _record_decision(key, winner, timings)
+    _record_decision(key, winner, timings, errors)
     path = cache_path()
     if path is not None:
         try:
@@ -393,9 +487,10 @@ def prefer(key, candidates: dict[str, Callable], make_args: Callable,
                 "key": str(key), "device": _device_kind(),
                 "winner": winner, "jax": _jax_version(),
                 "kernels": KERNEL_VERSION,
-                "timings_ms": {n: (round(t * 1e3, 4)
-                                   if t < float("inf") else None)
-                               for n, t in timings.items()},
+                "timings_ms": {n: (round(timings[n] * 1e3, 4)
+                                   if n in timings else None)
+                               for n in candidates},
+                "errors": errors,
                 "ts": round(time.time(), 3)})
         except OSError as e:  # unwritable cache never blocks the gate
             logger.warning("autobench cache publish to %s failed: %s",
@@ -410,8 +505,9 @@ def decisions() -> dict:
 
 
 def stats() -> dict:
-    """Process-local counters: measures, cache_hits/misses/stale/
-    corrupt, publishes (tests + bench assert against these)."""
+    """Process-local counters: measures, candidate_errors, cache_hits/
+    misses/stale/corrupt, publishes (tests + bench assert against
+    these)."""
     with _LOCK:
         return dict(_STATS)
 
@@ -466,6 +562,18 @@ PRESETS: dict[str, list[dict]] = {
         {"kernel": "fused_ffn_block", "m": 8192, "h": 1024, "i": 4096,
          "act": "gelu_tanh", "norm": "none"},
         {"kernel": "fused_layer_norm", "rows": 8192, "cols": 1024},
+    ],
+    # what chip_smoke.py's serving phases reach: decoder_tail at the
+    # decode batch and every prefill / prefill_tail bucket, and paged
+    # attention at the decode batch and the two tail buckets
+    "gpt_1p3b_serve": [
+        *({"kernel": "fused_out_ln", "m": m, "din": 2048, "dout": 2048}
+          for m in (8, 16, 64, 256, 512, 1024, 2048)),
+        *({"kernel": "fused_ffn_block", "m": m, "h": 2048, "i": 8192,
+           "act": "gelu_tanh", "norm": "none"}
+          for m in (8, 16, 64, 256, 512, 1024, 2048)),
+        *({"kernel": "paged_attention", "s": s, "h": 16, "d": 128,
+           "p": 1025, "ps": 16, "m": 128} for s in (8, 16, 64)),
     ],
     "bert_base_512": [
         {"kernel": "flash_attention", "b": 16, "h": 12, "s": 512,

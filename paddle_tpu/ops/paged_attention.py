@@ -10,8 +10,7 @@ implementations behind one function:
     the portable default;
   * a Pallas TPU kernel: grid (slot, page), page indices scalar-prefetched
     so each program DMAs exactly one page from HBM, online-softmax
-    accumulation in VMEM scratch — the TPU-native shape of the kernel
-    (same design as the stock ragged-paged-attention kernels).
+    accumulation in VMEM scratch, the per-head mat-vecs on the VPU.
 
 Selection runs through ops/autobench.prefer — the same measure-once gate
 that arbitrates Pallas-vs-XLA flash attention — so the hand kernel only
@@ -33,11 +32,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..fluid.registry import register, same_shape_as
 from ..fluid.ops.common import x
@@ -84,24 +79,28 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    # one query token per head against one page: a batched mat-vec, done
+    # on the VPU with the head axis kept in place. (The MXU spelling,
+    # einsum("hd,phd->hp"), puts the batch dimension in the middle of the
+    # rhs and leaves the lhs no free dimension; Mosaic refuses it.)
+    # Scores stay [ps, H, 1] so that they broadcast over d without a
+    # relayout and reduce over the page axis into the [H, 1] scratch.
     q = q_ref[0].astype(jnp.float32)            # [H, d]
     k = k_ref[0].astype(jnp.float32)            # [ps, H, d]
-    scores = jnp.einsum("hd,phd->hp", q, k,
-                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
     idx = m * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)
-    scores = jnp.where(idx < len_ref[s], scores, _NEG)
+        jnp.int32, scores.shape, 0)
+    live = idx < len_ref[s]
+    scores = jnp.where(live, scores, _NEG)
 
     m_prev = m_ref[...]                          # [H, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, -1, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                  # [H, ps]
-    p = jnp.where(idx < len_ref[s], p, 0.0)      # kill exp(-NEG - -NEG)=1
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, -1, keepdims=True)
+    # masked again after exp: a dead page would give exp(_NEG - _NEG) = 1
+    p = jnp.where(live, jnp.exp(scores - m_new[None]), 0.0)   # [ps, H, 1]
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
     v = v_ref[0].astype(jnp.float32)             # [ps, H, d]
-    pv = jnp.einsum("hp,phd->hd", p, v,
-                    preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
     m_ref[...] = m_new
 
     @pl.when(m == n_pages - 1)
@@ -174,8 +173,7 @@ def _auto_impl(q, k_pages, page_table) -> str:
     """Measure-once arbitration (TPU only; everywhere else the gathered
     XLA path is the portable winner and interpret-mode timing would be
     meaningless)."""
-    if os.environ.get("PADDLE_TPU_DISABLE_PALLAS") or pltpu is None \
-            or not on_tpu():
+    if os.environ.get("PADDLE_TPU_DISABLE_PALLAS") or not on_tpu():
         return "xla"
     from . import autobench
     S, H, d = q.shape

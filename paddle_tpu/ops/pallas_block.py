@@ -47,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import autobench
 from .pallas_attention import on_tpu
-from .pallas_ffn import _ACTS, _CompilerParams, _vmem_budget
+from .pallas_ffn import _ACTS, _vmem_budget
 from .pallas_fused_residual import _ids, _keep
 
 __all__ = ["fused_out_ln", "can_use_fused_out_ln", "out_ln_wins",
@@ -203,7 +203,7 @@ def _out_ln_pallas(a2, w, b, res2, ln_s, ln_b, seed_arr, p, eps,
             jax.ShapeDtypeStruct((m_pad, dout), a2.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bm, dout), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(seed_arr, a2p, w, b.reshape(1, dout), resp,
@@ -383,7 +383,7 @@ def _ffn_ln_pallas(x2, w1, b1, w2, b2, res2, ln_s, ln_b, seed_arr, act,
         out_specs=pl.BlockSpec((bm, h), lambda mi, j: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, h), res2.dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(seed_arr, x2p, w1, b1.reshape(1, i), w2, b2.reshape(1, h), resp,

@@ -29,11 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_attention import on_tpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases;
-# accept either so the kernel runs on the toolchain actually installed
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 __all__ = ["fused_ffn", "can_use_fused_ffn"]
 
 
@@ -158,7 +153,7 @@ def _ffn_fwd_impl(x2, w1, b1, w2, b2, act_name, bm, bi):
         out_specs=pl.BlockSpec((bm, h), lambda mi, ji: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m, h), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, h), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(x2, w1, b1.reshape(1, i), w2, b2.reshape(1, h))

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["ShardedEmbedding", "sharded_embedding_lookup"]
 
@@ -47,7 +46,7 @@ def sharded_embedding_lookup(table, ids, mesh: Mesh, axis: str = "mp"):
         rows = jnp.where(hit[..., None], rows, 0)
         return jax.lax.psum(rows, axis)
 
-    return _shard_map(
+    return jax.shard_map(
         spmd, mesh=mesh, in_specs=(P(axis, None), P()), out_specs=P(),
         axis_names=frozenset({axis}), check_vma=False)(table, ids)
 

@@ -17,6 +17,9 @@ TP/PP-partitioned), donated every step.
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
+import logging
 from functools import partial
 from typing import Any
 
@@ -28,9 +31,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt as G
 from .pipeline import pipeline_apply
-from .sharding import _restrict
+from .sharding import _restrict, kernel_mesh
 
 __all__ = ["HybridParallelTrainStep", "make_hybrid_mesh"]
+
+logger = logging.getLogger("paddle_tpu.parallel.hybrid")
 
 _DECAY = {"wte", "wpe", "wq", "wk", "wv", "wo", "w_up", "w_down",
           "we_up", "we_down"}
@@ -41,7 +46,12 @@ def make_hybrid_mesh(dp: int = 1, pp: int = 1, tp: int = 1, sp: int = 1,
     """("pp","dp","sp","ep","tp") mesh — tp innermost so its collectives
     ride the fastest ICI links; ep next (MoE all_to_all dispatch); sp next
     (ring attention's ppermute hops); pp outermost (cheapest traffic: one
-    activation per microbatch tick)."""
+    activation per microbatch tick).
+
+    Devices are taken in jax.devices() id order. On the four-chip v5e
+    host (2x2, no wrap-around) ids 0..3 sit at (0,0) (1,0) (0,1) (1,1),
+    so with pp=2 x tp=2 each tp pair and each pp pair is a pair of
+    physical neighbours. Larger slices need a topology-aware order."""
     devs = np.array(devices if devices is not None else jax.devices())
     n = dp * pp * tp * sp * ep
     if devs.size < n:
@@ -95,8 +105,28 @@ class HybridParallelTrainStep:
                     "nested in the pp-manual region); the GPipe scan "
                     "has no per-stage function to host it")
             # sequence parallel => ring attention over the sp axis
-            import dataclasses as _dc
-            cfg = _dc.replace(cfg, attn_impl="ring")
+            cfg = dataclasses.replace(cfg, attn_impl="ring")
+        tp = mesh.shape.get("tp", 1)
+        if cfg.attn_impl == "flash" and cfg.num_heads % tp:
+            raise ValueError(
+                f"attn_impl='flash' runs the kernel on each tp shard's "
+                f"heads: num_heads={cfg.num_heads} must divide by tp={tp} "
+                f"(attn_impl='xla' has no such limit)")
+        if mesh.size > 1 and cfg.fused_blocks and cfg.num_experts == 0:
+            # jax does not partition a Mosaic kernel (see
+            # sharding.kernel_mesh). Flash attention runs per shard (the
+            # step is traced under kernel_mesh); the fused decoder-tail
+            # kernels cannot: under tp `wo` and `w_down` are row-parallel,
+            # so the sum they feed the fused LayerNorm / residual is
+            # partial on each shard. They are switched off here, where
+            # the mesh is known — the gate would time them on one device,
+            # let them win, and the step would then fail to lower.
+            logger.warning(
+                "fused_blocks is off on this %d-device mesh %s: the fused "
+                "decoder-tail kernels (ops/pallas_block.py) cannot be "
+                "partitioned; the composed XLA tail runs instead",
+                mesh.size, dict(mesh.shape))
+            cfg = dataclasses.replace(cfg, fused_blocks=False)
         self.cfg = cfg
         self.mesh = mesh
         self.n_micro = n_microbatches or max(2 * self.pp, 1)
@@ -172,13 +202,19 @@ class HybridParallelTrainStep:
         self._jit_step = self._build(mesh)
 
     # ------------------------------------------------------------------
-    def loss_fn(self, params, ids, key=None):
-        cfg, mesh = self.cfg, self.mesh
-        if cfg.num_experts > 0:
+    def _trace_contexts(self):
+        """What model code consults while this step is traced."""
+        stack = contextlib.ExitStack()
+        if self.mesh.size > 1:
+            stack.enter_context(kernel_mesh(self.mesh))
+        if self.cfg.num_experts > 0:
             from .moe import moe_context
-            with moe_context(mesh, "ep"):
-                return self._loss_inner(params, ids, key)
-        return self._loss_inner(params, ids, key)
+            stack.enter_context(moe_context(self.mesh, "ep"))
+        return stack
+
+    def loss_fn(self, params, ids, key=None):
+        with self._trace_contexts():
+            return self._loss_inner(params, ids, key)
 
     def _loss_inner(self, params, ids, key=None):
         cfg, mesh = self.cfg, self.mesh
@@ -232,8 +268,7 @@ class HybridParallelTrainStep:
             # dropped (also a partitioner trigger on this combo) — the
             # 1F1B engine already remats at stage granularity, so only
             # the within-B-tick residual footprint grows
-            import dataclasses as _dc
-            cfg = _dc.replace(cfg, remat=False)
+            cfg = dataclasses.replace(cfg, remat=False)
             params = dict(params)
             params["wte"] = jax.lax.with_sharding_constraint(
                 params["wte"], NamedSharding(mesh, P()))
@@ -282,7 +317,6 @@ class HybridParallelTrainStep:
         shared = {"wte": params["wte"], "lnf_s": params["lnf_s"],
                   "lnf_b": params["lnf_b"]}
         aux_w = cfg.moe_aux_weight if cfg.num_experts > 0 else 0.0
-        import contextlib
         ring_cm = contextlib.nullcontext()
         if self.sp > 1:
             # sp x pp: the sequence stays a GSPMD ("auto") axis inside
@@ -315,11 +349,8 @@ class HybridParallelTrainStep:
         use_1f1b = self.pp > 1 and self._schedule == "1F1B"
 
         def grads_1f1b(params, ids, key):
-            if self.cfg.num_experts > 0:
-                from .moe import moe_context
-                with moe_context(mesh, "ep"):
-                    return self._loss_and_grads_1f1b(params, ids, key)
-            return self._loss_and_grads_1f1b(params, ids, key)
+            with self._trace_contexts():
+                return self._loss_and_grads_1f1b(params, ids, key)
 
         def apply_update(params, opt_state, pows, grads, lr):
             if clip:

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["pipeline_apply", "num_ticks"]
 
@@ -94,7 +93,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, mb_inputs,
         # caller's slice of [-1] compile to a plain shard read
         return outbuf[None]
 
-    stacked = _shard_map(
+    stacked = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(axis_name),

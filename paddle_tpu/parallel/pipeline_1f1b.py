@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["simulate_1f1b", "pipeline_1f1b_grads"]
 
@@ -412,7 +411,7 @@ def pipeline_1f1b_grads(stage_fn: Callable, last_fn: Callable,
         gsh = jax.tree_util.tree_map(lambda g: g[None], carry["gsh"])
         return carry["loss"][None], gl, gsh, carry["dx0"][None]
 
-    loss, gl, gsh, dx0 = _shard_map(
+    loss, gl, gsh, dx0 = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis_name), P(), P(), P()),
         out_specs=(P(axis_name), P(axis_name), P(axis_name), P(axis_name)),

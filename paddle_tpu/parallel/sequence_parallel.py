@@ -22,9 +22,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P, get_abstract_mesh)
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["ring_attention", "ring_context", "current_ring"]
 
@@ -122,18 +122,10 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     # inner shard_map must be built on the CONTEXT abstract mesh — the
     # one where pp is already Manual — not the original device mesh
     use_mesh = mesh
-    try:  # AxisType/get_abstract_mesh only exist on newer jax; on the
-        # 0.4.x API nested manual regions resolve against the device
-        # mesh directly, so skipping the rebind is the correct fallback
-        from jax.sharding import AxisType, get_abstract_mesh
-        ctx_mesh = get_abstract_mesh()
-        if getattr(ctx_mesh, "axis_names", ()) and \
-                AxisType.Manual in tuple(getattr(ctx_mesh,
-                                                 "axis_types", ())):
-            use_mesh = ctx_mesh
-    except ImportError:
-        pass
-    return _shard_map(spmd, mesh=use_mesh, in_specs=(spec, spec, spec),
+    ctx_mesh = get_abstract_mesh()
+    if ctx_mesh.axis_names and AxisType.Manual in ctx_mesh.axis_types:
+        use_mesh = ctx_mesh
+    return jax.shard_map(spmd, mesh=use_mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names=frozenset({axis}),
                          check_vma=False)(q, k, v)
 

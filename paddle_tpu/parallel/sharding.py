@@ -9,13 +9,17 @@ model code runs unsharded, dp-only, or dp x tp by swapping the rule set.
 """
 from __future__ import annotations
 
+import contextlib
 import re
-from typing import Any, Sequence
+import threading
+from typing import Any, Callable, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          get_abstract_mesh)
 
-__all__ = ["ShardingRules", "shard_tree", "spec_for"]
+__all__ = ["ShardingRules", "shard_tree", "spec_for", "kernel_mesh",
+           "current_kernel_mesh", "per_shard"]
 
 
 class ShardingRules:
@@ -63,6 +67,44 @@ def _restrict(spec: P, mesh: Mesh) -> P:
             out.append(entry if entry in mesh.shape and
                        mesh.shape[entry] > 1 else None)
     return P(*out)
+
+
+_kernel_mesh = threading.local()
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh: Mesh):
+    """Marks the multi-device mesh the enclosed trace is partitioned
+    over. jax does not partition a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned", jax/_src/tpu_custom_call.py
+    `_tpu_custom_call_lowering`), so model code that reaches a Pallas
+    kernel under GSPMD asks `current_kernel_mesh()` and runs the kernel
+    on each device's shard through `per_shard`. Per thread: an engine
+    tracing a bucket on another thread is not on this mesh."""
+    prev = current_kernel_mesh()
+    _kernel_mesh.mesh = mesh
+    try:
+        yield
+    finally:
+        _kernel_mesh.mesh = prev
+
+
+def current_kernel_mesh() -> Mesh | None:
+    return getattr(_kernel_mesh, "mesh", None)
+
+
+def per_shard(fn: Callable, mesh: Mesh, spec: P, n_args: int) -> Callable:
+    """`fn` of `n_args` arrays laid out as `spec` (and returning one such
+    array), run on each device's local shard: a shard_map manual over
+    EVERY mesh axis, which is what the Mosaic lowering asks for. Inside
+    another manual region (the 1F1B engine, manual over "pp") the inner
+    map is built on the context mesh over the axes still automatic."""
+    ctx = get_abstract_mesh()
+    manual = frozenset(ctx.manual_axes) if ctx.axis_names else frozenset()
+    return jax.shard_map(
+        fn, mesh=ctx if manual else mesh, in_specs=(spec,) * n_args,
+        out_specs=spec, axis_names=frozenset(mesh.axis_names) - manual,
+        check_vma=False)
 
 
 def spec_for(tree_of_names: Any, rules: ShardingRules, mesh: Mesh):

@@ -4,11 +4,7 @@ TPU-native: jax.profiler (XPlane) traces device + host; op-phase markers come
 from the executor's jax.named_scope per op (replacing RecordEvent RAII at
 framework/operator.cc:984). View with TensorBoard or Perfetto.
 
-Version tolerance: older jax builds ship a ``jax.profiler`` missing
-``start_trace``/``stop_trace``/``TraceAnnotation`` (or no ``profiler``
-attr at all). Every wrapper here degrades to a graceful no-op in that
-case — the per-op host report still works, only the XPlane trace is
-skipped. ``RecordEvent`` now also records a host span into
+``RecordEvent`` also records a host span into
 ``paddle_tpu.observability.tracing`` (same bounded ring the serving/PS
 tiers write), so marker events land in the Chrome trace export next to
 the engine/rpc spans.
@@ -28,12 +24,6 @@ __all__ = ["Profiler", "profiler", "start_profiler", "stop_profiler",
 
 _trace_dir = None
 _trace_started = False
-
-
-def _prof_attr(name: str):
-    """jax.profiler.<name>, or None when jax/profiler lacks it (older
-    jax) — callers no-op instead of raising AttributeError."""
-    return getattr(getattr(jax, "profiler", None), name, None)
 
 
 # ---------------------------------------------------------------------------
@@ -99,17 +89,14 @@ def start_profiler(state="All", tracer_option="Default",
     _hook_tracer()
     _trace_dir = trace_dir
     os.makedirs(trace_dir, exist_ok=True)
-    start = _prof_attr("start_trace")
-    if start is not None:  # older jax: host-side report only
-        start(trace_dir)
-        _trace_started = True
+    jax.profiler.start_trace(trace_dir)
+    _trace_started = True
 
 
 def stop_profiler(sorted_key=None, profile_path=None):
     global _trace_dir, _trace_started
-    stop = _prof_attr("stop_trace")
-    if stop is not None and _trace_started:
-        stop()
+    if _trace_started:
+        jax.profiler.stop_trace()
     _trace_started = False
     out = _trace_dir
     _trace_dir = None
@@ -140,10 +127,8 @@ class RecordEvent:
     """Host event marker (reference platform/profiler.h:126).
 
     Backed by observability.tracing: records a host span (Chrome trace
-    export) AND enters jax.profiler.TraceAnnotation when this jax has
-    it, so the marker shows up in the XPlane device trace too. On older
-    jax without TraceAnnotation the span alone is recorded — no-op
-    degradation instead of AttributeError."""
+    export) AND enters jax.profiler.TraceAnnotation, so the marker
+    shows up in the XPlane device trace too."""
 
     def __init__(self, name: str):
         self.name = name

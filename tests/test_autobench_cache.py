@@ -35,6 +35,121 @@ def _mk():
     return (jnp.ones((16, 16), jnp.float32),)
 
 
+def test_gate_inside_jit_and_scan_times_concrete_arrays():
+    """The gate is reached at trace time from inside jitted bodies: its
+    candidates must get concrete device arrays (not the ambient trace's
+    tracers), and one that raises is kept with its error."""
+    import jax
+    from jax.sharding import PartitionSpec as P, get_abstract_mesh
+    autobench.clear()
+    made, seen = [], []
+
+    def mk():
+        made.append(_mk())
+        return made[-1]
+
+    def ok(x):
+        try:
+            jax.lax.axis_size("pp")
+            bound = True
+        except NameError:
+            bound = False
+        if (get_abstract_mesh().empty, bound) not in seen:
+            seen.append((get_abstract_mesh().empty, bound))
+        return x @ x
+
+    def refused(x):
+        raise ValueError("mosaic says no")
+
+    def gate():
+        return autobench.prefer(("trace_gate", 16),
+                                {"pallas": refused, "xla": ok}, mk,
+                                default="pallas", reps=1)
+
+    @jax.jit
+    def step(x):
+        winners = [gate()]
+
+        def body(c, _):
+            winners.append(gate())       # inside lax.scan inside jit
+            return c + 1.0, None
+
+        c, _ = jax.lax.scan(body, x, None, length=2)
+        assert winners == ["xla", "xla"]
+        return c
+
+    # reached first inside a shard_map stage inside jit, as in the
+    # trainer: there the measuring round must also be free of the
+    # stage's axis environment and context mesh
+    mesh = jax.make_mesh((1,), ("pp",))
+    staged = jax.shard_map(step, mesh=mesh, in_specs=P(), out_specs=P(),
+                           axis_names=frozenset({"pp"}), check_vma=False)
+    assert float(jax.jit(staged)(jnp.zeros(()))) == 2.0
+    assert float(step(jnp.zeros(()))) == 2.0
+    assert made and not any(isinstance(a, jax.core.Tracer)
+                            for args in made for a in args)
+    assert seen == [(True, False)]        # top level: no mesh, no axes
+    assert autobench.stats()["measures"] == 1
+    from paddle_tpu.observability import perf
+    row = perf.kernels()[str(("trace_gate", 16))]
+    assert row["winner"] == "xla" and row["source"] == "measured"
+    assert row["candidates_ms"]["xla"] > 0
+    assert "pallas" not in row["candidates_ms"]
+    assert row["errors"] == {"pallas": "ValueError: mosaic says no"}
+    assert autobench.stats()["candidate_errors"] == 1
+    # a make_args that does hand back tracers is refused, not "timed"
+    with pytest.raises(RuntimeError, match="concrete"):
+        jax.jit(lambda x: autobench._measure(ok, lambda: (x,), 1))(
+            jnp.ones((4, 4)))
+    autobench.clear()
+
+
+def test_default_keeps_the_slot_on_a_tie(monkeypatch):
+    """A lead the clock cannot resolve must not flip the decision from
+    run to run: the challenger has to beat `default` by more than the
+    measured spread and by more than _MIN_MARGIN."""
+    cands = {"pallas": "pallas", "xla": "xla"}
+
+    def decide(key, timed, default):
+        monkeypatch.setattr(autobench, "_measure",
+                            lambda fn, make_args, reps: timed[fn])
+        return autobench.prefer(key, cands, tuple, default=default)
+
+    autobench.clear()
+    # 3% ahead with no spread: inside _MIN_MARGIN, default stays
+    assert decide(("tie", 1), {"pallas": (1.00e-3, 0.0),
+                               "xla": (0.97e-3, 0.0)}, "pallas") == "pallas"
+    # 20% ahead but the samples spread over 30%: default stays
+    assert decide(("tie", 2), {"pallas": (1.0e-3, 0.3e-3),
+                               "xla": (0.8e-3, 0.0)}, "pallas") == "pallas"
+    # 20% ahead, tight samples: the challenger takes the slot
+    assert decide(("tie", 3), {"pallas": (1.0e-3, 1e-5),
+                               "xla": (0.8e-3, 1e-5)}, "pallas") == "xla"
+    # the default is not handicapped when it is the faster one
+    assert decide(("tie", 4), {"pallas": (0.99e-3, 0.0),
+                               "xla": (1.0e-3, 0.0)}, "pallas") == "pallas"
+    assert decide(("tie", 5), {"pallas": (0.97e-3, 0.0),
+                               "xla": (1.0e-3, 0.0)}, "xla") == "xla"
+    autobench.clear()
+
+
+def test_measure_times_batches_of_calls(monkeypatch):
+    """One sample is many back-to-back calls under one sync."""
+    import jax
+    monkeypatch.setattr(autobench, "_MAX_CALLS", 5)
+    runs = []
+
+    def fn(x):
+        jax.debug.callback(lambda: runs.append(1))
+        return x + 1.0
+
+    med, spread = autobench._measure(fn, _mk, 2)
+    jax.effects_barrier()
+    assert med > 0 and spread >= 0
+    # the warm-up call, the sizing call, two samples of five calls
+    assert len(runs) == 1 + 1 + 2 * 5
+
+
 def _recrc(rec):
     body = {k: v for k, v in rec.items() if k != "crc"}
     return zlib.crc32(json.dumps(

@@ -156,7 +156,7 @@ def test_sync_batch_norm_convert_and_jit_semantics():
 
 def test_op_errors_carry_operator_context(fresh_programs):
     """Kernel failures surface with [operator < type >] context
-    (reference operator.cc catch-and-rethrow + errors.h taxonomy)."""
+    (reference operator.cc catch-and-rethrow + errors.h error classes)."""
     import paddle_tpu as paddle
     paddle.enable_static()
     from paddle_tpu.fluid import Executor, framework, layers, unique_name
@@ -180,7 +180,7 @@ def test_op_errors_carry_operator_context(fresh_programs):
     paddle.disable_static()
 
 
-def test_enforce_taxonomy():
+def test_enforce_error_classes():
     from paddle_tpu.fluid import errors
     with pytest.raises(errors.InvalidArgumentError):
         errors.enforce(False, "bad arg")

@@ -82,9 +82,32 @@ def test_hybrid_matches_single_device(dp, pp, tp, micro):
     assert float(s8(ids)) < l1
 
 
+@pytest.mark.parametrize("dp,pp,tp,micro", [(2, 2, 2, 4), (1, 1, 4, None)])
+def test_hybrid_flash_per_shard_matches_single_device(dp, pp, tp, micro):
+    """attn_impl="flash" on a mesh: the Pallas kernel on each device's
+    (batch over dp, heads over tp) shard, inside the pp-manual 1F1B
+    region too, against the composed attention on one device."""
+    ids = _ids(GPTConfig.tiny(), t=64)
+    s1 = HybridParallelTrainStep(GPTConfig.tiny(), seed=0,
+                                 devices=jax.devices()[:1])
+    s8 = HybridParallelTrainStep(GPTConfig.tiny(attn_impl="flash"), dp=dp,
+                                 pp=pp, tp=tp, n_microbatches=micro, seed=0)
+    for i in range(3):
+        l1, l8 = float(s1(ids)), float(s8(ids))
+        assert abs(l1 - l8) < 5e-4, f"step {i}: {l1} vs {l8}"
+    with pytest.raises(ValueError, match="num_heads"):
+        HybridParallelTrainStep(GPTConfig.tiny(attn_impl="flash"), tp=8)
+
+
 def test_hybrid_params_actually_sharded():
-    cfg = GPTConfig.tiny()
+    cfg = GPTConfig.tiny(attn_impl="flash")
     s = HybridParallelTrainStep(cfg, dp=2, pp=2, tp=2, n_microbatches=4)
+    # jax cannot partition a Mosaic kernel: on a multi-device mesh flash
+    # attention runs per shard and the fused decoder tail is off (said
+    # in a warning); one device keeps both
+    assert s.cfg.attn_impl == "flash" and not s.cfg.fused_blocks
+    one = HybridParallelTrainStep(cfg, devices=jax.devices()[:1])
+    assert one.cfg.attn_impl == "flash" and one.cfg.fused_blocks
     blk = s.params["blocks"]["w_up"]
     # [pp, L/pp, D, F]: dim0 over pp, dim3 over tp
     assert blk.sharding.spec == P("pp", None, None, "tp")

@@ -484,7 +484,8 @@ def test_autobench_records_structured_events(monkeypatch, caplog):
 
     monkeypatch.setattr(
         autobench, "_measure",
-        lambda fn, make_args, reps: {"fast": 0.001, "slow": 0.004}[fn])
+        lambda fn, make_args, reps: ({"fast": 0.001, "slow": 0.004}[fn],
+                                     0.0))
     monkeypatch.setenv("PADDLE_TPU_AUTOBENCH_VERBOSE", "1")
     key = ("obs_test_shape", 128)
     autobench.clear()

@@ -1,6 +1,5 @@
-"""First direct coverage for utils/profiler.py: start/stop wrappers,
-RecordEvent, and the graceful no-op path on older jax builds whose
-jax.profiler lacks start_trace/stop_trace/TraceAnnotation."""
+"""Direct coverage for utils/profiler.py: start/stop wrappers and
+RecordEvent."""
 import types
 
 import jax
@@ -23,7 +22,7 @@ def test_start_stop_profiler_round_trip(monkeypatch, tmp_path):
     fake = types.SimpleNamespace(
         start_trace=lambda d: calls.append(("start", d)),
         stop_trace=lambda: calls.append(("stop",)),
-        TraceAnnotation=getattr(jax.profiler, "TraceAnnotation", None))
+        TraceAnnotation=jax.profiler.TraceAnnotation)
     monkeypatch.setattr(jax, "profiler", fake)
     d = str(tmp_path / "trace")
     P.start_profiler(trace_dir=d)
@@ -33,28 +32,6 @@ def test_start_stop_profiler_round_trip(monkeypatch, tmp_path):
     # stop again: no second stop_trace (no dangling start)
     P.stop_profiler()
     assert calls == [("start", d), ("stop",)]
-
-
-def test_profiler_graceful_noop_on_old_jax(monkeypatch, tmp_path):
-    """jax.profiler missing every attr: wrappers must not raise."""
-    monkeypatch.setattr(jax, "profiler", types.SimpleNamespace())
-    d = str(tmp_path / "trace")
-    P.start_profiler(trace_dir=d)        # no start_trace -> no-op
-    assert P.stop_profiler() == d        # no stop_trace -> no-op
-    with P.RecordEvent("marker"):        # no TraceAnnotation -> span only
-        pass
-    ev = P.RecordEvent("begin_end")
-    ev.begin()
-    ev.end()
-
-
-def test_profiler_tolerates_missing_profiler_module(monkeypatch,
-                                                    tmp_path):
-    monkeypatch.delattr(jax, "profiler")
-    P.start_profiler(trace_dir=str(tmp_path / "t"))
-    P.stop_profiler()
-    with P.RecordEvent("no_profiler_at_all"):
-        pass
 
 
 def test_record_event_lands_in_trace_export():

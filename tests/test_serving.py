@@ -200,7 +200,7 @@ def test_autobench_measures_once_and_caches(monkeypatch):
 
     def fake_measure(fn, make_args, reps):
         calls.append(fn)
-        return fn()          # candidates below return their "time"
+        return fn(), 0.0     # candidates below return their "time"
 
     monkeypatch.setattr(autobench, "_measure", fake_measure)
     cands = {"pallas": lambda: 2.0, "xla": lambda: 1.0}
@@ -220,7 +220,7 @@ def test_autobench_env_knobs(monkeypatch):
     from paddle_tpu.ops import autobench
     autobench.clear()
     monkeypatch.setattr(autobench, "_measure",
-                        lambda fn, make_args, reps: fn())
+                        lambda fn, make_args, reps: (fn(), 0.0))
     cands = {"pallas": lambda: 2.0, "xla": lambda: 1.0}
     monkeypatch.setenv("PADDLE_TPU_AUTOBENCH_FORCE", "pallas")
     assert autobench.prefer(("e", 1), cands, tuple) == "pallas"
@@ -232,7 +232,7 @@ def test_autobench_env_knobs(monkeypatch):
     cands3 = {"pallas": lambda: 1 / 0, "xla": lambda: 1.0}
 
     def m3(fn, make_args, reps):
-        return fn()
+        return fn(), 0.0
 
     monkeypatch.setattr(autobench, "_measure", m3)
     # prefer() shields candidate exceptions itself
