@@ -75,6 +75,8 @@ def _span_dict(sp) -> dict:
     d = {"name": sp.name, "trace_id": sp.trace_id,
          "span_id": sp.span_id, "parent_id": sp.parent_id,
          "start": sp.start, "end": sp.end, "tid": sp.tid}
+    if sp.caused_by:
+        d["caused_by"] = sp.caused_by
     if sp.attrs:
         d["attrs"] = _redact_attrs(sp.attrs)
     return d

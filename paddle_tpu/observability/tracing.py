@@ -17,7 +17,13 @@ numbers; this holds the *timeline*):
     with trace/span ids in ``args``;
   * XPlane bridge — every recorded span also enters
     ``jax.profiler.TraceAnnotation``, so host spans line up with device
-    traces inside a ``jax.profiler.start_trace`` window.
+    traces inside a ``jax.profiler.start_trace`` window;
+  * ``record(name, start, end, ...)`` — a finished span whose two stamps
+    were taken elsewhere with ``TRACER.clock()`` (a queue wait starts in
+    one thread's ``submit`` and ends in another's ``admit``). Every
+    stamp of the tracer is ``TRACER.clock()``: a reader that wants the
+    spans on another clock reads both back to back and takes the
+    offset.
 
 ``PADDLE_TPU_TRACE=0`` disables recording (ids still propagate so
 downstream tiers keep correlating); ``PADDLE_TPU_TRACE_BRIDGE=0``
@@ -25,7 +31,7 @@ disables only the jax annotation bridge.
 """
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import threading
@@ -52,29 +58,104 @@ def new_trace_id() -> str:
     return os.urandom(8).hex()
 
 
+# span ids: a counter behind a prefix drawn once per process (a forked
+# child draws its own), so ids stay unique across the processes whose
+# spans the collector assembles into one waterfall, at no syscall a span
+_span_seq = itertools.count(1)
+_span_prefix = os.urandom(4).hex()
+
+
+def _reseed_span_ids():
+    global _span_prefix
+    _span_prefix = os.urandom(4).hex()
+
+
+os.register_at_fork(after_in_child=_reseed_span_ids)
+
+
+def _new_span_id() -> str:
+    return f"{_span_prefix}{next(_span_seq):08x}"
+
+
+_annotation = None
+
+
+def _trace_annotation(name):
+    """`jax.profiler.TraceAnnotation(name)`; jax is imported on the first
+    bridged span, not at import (the scheduler's policy layer stays
+    importable without it) and not once a span."""
+    global _annotation
+    if _annotation is None:
+        import jax
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation(name)
+
+
 class Span:
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "end", "tid", "attrs")
+    """One span; also its own context manager (`Tracer.span` returns it
+    unentered, `with` stamps it and links it to the ambient span)."""
+
+    __slots__ = ("name", "trace_id", "span_id", "parent_id", "caused_by",
+                 "start", "end", "tid", "attrs", "_tracer", "_ann")
 
     def __init__(self, name, trace_id, span_id, parent_id, start,
-                 tid, attrs):
+                 tid, attrs, caused_by=None):
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
+        # the ambient span when `trace_id=` re-rooted this one: a request's
+        # prefill is in the request's trace, and still names the step
+        # that ran it
+        self.caused_by = caused_by
         self.start = start
         self.end = None
         self.tid = tid
         self.attrs = attrs
+        self._tracer = None
+        self._ann = None
 
     def duration(self) -> float | None:
         return None if self.end is None else self.end - self.start
+
+    def __enter__(self) -> "Span":
+        tr = self._tracer
+        stack = tr._stack()
+        parent = stack[-1] if stack else None
+        if parent is None:
+            self.trace_id = self.trace_id or new_trace_id()
+        elif not self.trace_id or self.trace_id == parent.trace_id:
+            self.trace_id = parent.trace_id
+            self.parent_id = parent.span_id
+        else:
+            self.caused_by = parent.span_id
+        self.span_id = _new_span_id()
+        self.tid = threading.get_ident()
+        stack.append(self)
+        if tr.enabled and tr.bridge_jax:
+            self._ann = _trace_annotation(self.name)
+            self._ann.__enter__()
+        self.start = tr.clock()
+        return self
+
+    def __exit__(self, *exc):
+        tr = self._tracer
+        self.end = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        tr._stack().pop()
+        if tr.enabled:
+            tr._keep(self)
+        return False
 
     def to_event(self) -> dict:
         """One Chrome trace_event 'X' (complete) event."""
         args = {"trace_id": self.trace_id, "span_id": self.span_id}
         if self.parent_id:
             args["parent_id"] = self.parent_id
+        if self.caused_by:
+            args["caused_by"] = self.caused_by
         args.update(self.attrs)
         return {"name": self.name, "ph": "X", "cat": "paddle_tpu",
                 "ts": round(self.start * 1e6, 3),
@@ -95,6 +176,8 @@ class Tracer:
                 "PADDLE_TPU_TRACE_BRIDGE", "1") != "0"
         self.enabled = bool(enabled)
         self.bridge_jax = bool(bridge_jax)
+        # every stamp of a span is this clock's
+        self.clock = time.monotonic
         self._spans: deque[Span] = deque(maxlen=max_spans)
         self._lock = threading.Lock()
         self._tls = threading.local()
@@ -124,47 +207,48 @@ class Tracer:
         st = self._stack()
         return st[-1] if st else None
 
-    @contextlib.contextmanager
-    def span(self, name: str, trace_id: str | None = None, **attrs):
-        """Record one host span. ``trace_id`` re-roots the context (a
-        request id that arrived over the wire); otherwise the ambient
-        parent's id is inherited, else a fresh one is minted."""
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        tid = trace_id or (parent.trace_id if parent else None) \
-            or new_trace_id()
-        sp = Span(name, tid, new_trace_id(),
-                  parent.span_id if parent and parent.trace_id == tid
-                  else None,
-                  time.monotonic(), threading.get_ident(), attrs)
-        stack.append(sp)
-        ann = None
-        if self.enabled and self.bridge_jax:
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        try:
-            yield sp
-        finally:
-            sp.end = time.monotonic()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            stack.pop()
-            if self.enabled:
-                with self._lock:
-                    if len(self._spans) == self._spans.maxlen:
-                        _DROPPED.inc()
-                    self._spans.append(sp)
-                    n = len(self._spans)
-                    if n > self._high_water:
-                        self._high_water = n
-                        _HIGH_WATER.set(n)
-                sink = self._sink
-                if sink is not None:
-                    try:
-                        sink(sp)
-                    except Exception:
-                        pass
+    def span(self, name: str, trace_id: str | None = None,
+             **attrs) -> Span:
+        """Record one host span: `with tracer.span(...) as sp`.
+        ``trace_id`` re-roots the context (a request id that arrived
+        over the wire; the ambient span's id is kept in ``caused_by``);
+        otherwise the ambient parent's id is inherited, else a fresh one
+        is minted."""
+        sp = Span(name, trace_id, None, None, None, None, attrs)
+        sp._tracer = self
+        return sp
+
+    def record(self, name: str, start: float, end: float,
+               trace_id: str | None = None, parent_id: str | None = None,
+               **attrs) -> Span | None:
+        """A finished span whose stamps were taken elsewhere, both with
+        ``self.clock()``. Same ring, sink and drop accounting as
+        ``span()``; no profiler annotation (it cannot be backdated).
+        Returns None with recording off."""
+        if not self.enabled:
+            return None
+        sp = Span(name, trace_id or self.current_trace_id()
+                  or new_trace_id(), _new_span_id(), parent_id, start,
+                  threading.get_ident(), attrs)
+        sp.end = end
+        self._keep(sp)
+        return sp
+
+    def _keep(self, sp: Span):
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                _DROPPED.inc()
+            self._spans.append(sp)
+            n = len(self._spans)
+            if n > self._high_water:
+                self._high_water = n
+                _HIGH_WATER.set(n)
+        sink = self._sink
+        if sink is not None:
+            try:
+                sink(sp)
+            except Exception:
+                pass
 
     # -- inspection / export --------------------------------------------
     def spans(self) -> list[Span]:

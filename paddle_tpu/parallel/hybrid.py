@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt as G
+from ..observability import tracing as _tracing
 from .pipeline import pipeline_apply
 from .sharding import _restrict, kernel_mesh
 
@@ -425,14 +426,22 @@ class HybridParallelTrainStep:
 
     # ------------------------------------------------------------------
     def __call__(self, ids):
-        ids = jax.device_put(jnp.asarray(ids), self._batch_sharding)
-        lr = self._lr() if callable(self._lr) else float(self._lr)
+        """Hand one step to the device and return its loss unread.
+        `train.step` is the host's part of a step (the batch's transfer
+        in `train.put`, the jitted call in `train.dispatch`, the step's
+        key between them); when the step completes is the caller's to
+        observe."""
         self._step_no = getattr(self, "_step_no", 0) + 1
-        key = jax.random.fold_in(jax.random.PRNGKey(self._seed),
-                                 self._step_no)
-        loss, self.params, self.opt_state, self._pows = self._jit_step(
-            self.params, self.opt_state, self._pows, ids,
-            np.float32(lr), key)
+        with _tracing.span("train.step", step=self._step_no):
+            with _tracing.span("train.put"):
+                ids = jax.device_put(jnp.asarray(ids), self._batch_sharding)
+            lr = self._lr() if callable(self._lr) else float(self._lr)
+            key = jax.random.fold_in(jax.random.PRNGKey(self._seed),
+                                     self._step_no)
+            with _tracing.span("train.dispatch"):
+                loss, self.params, self.opt_state, self._pows = \
+                    self._jit_step(self.params, self.opt_state, self._pows,
+                                   ids, np.float32(lr), key)
         return loss
 
     def unstacked_params(self):

@@ -220,6 +220,7 @@ class Engine:
         weakref.finalize(self, _debug.unregister_requests_provider,
                          wd_name)
         self._lock = threading.Lock()    # step loop exclusivity
+        self._step_no = 0                # `step` of the engine.step span
         self._stats_lock = threading.Lock()  # deque append vs snapshot
         # published-version identity (PR 12): stamped by warm_start
         # under the step lock, so ping/stats can never report a version
@@ -487,12 +488,15 @@ class Engine:
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
-                           cached_tokens=start):
+                           cached_tokens=start) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
+            compiled = self._compiles.get(bucket, 0) > pre_compiles
+            if compiled:
+                sp.attrs["compiled"] = True
         dt = time.perf_counter() - t0
         self._m_prefill_h.observe(dt)
-        if self._compiles.get(bucket, 0) > pre_compiles:
+        if compiled:
             _perf.note_compile_seconds("engine.prefill", dt)
         self._note_flops(self._bucket_flops.get(bucket))
         _flight.record("serving", "prefill", trace_id=req.trace_id,
@@ -506,12 +510,34 @@ class Engine:
             self._note_done(req)
 
     def step(self) -> bool:
-        """One scheduler iteration; returns True if any work was done."""
-        import jax.numpy as jnp
+        """One scheduler iteration; returns True if any work was done.
+
+        The call is one `engine.step` span whose children are its phases
+        in order, one after the other with nothing between them:
+        `engine.admit` (deadlines, admission and the admitted requests'
+        prefills), `engine.build` (the numpy batch and its transfers),
+        `engine.decode` (`engine.dispatch`, then `engine.wait` for the
+        tokens) and `engine.emit` (metering and one `record_token` a
+        slot). An engine with nothing queued and nothing running records
+        nothing."""
         with self._lock:
+            if self.scheduler.idle:
+                return False
+            self._step_no += 1
+            with _tracing.span("engine.step", engine=self.engine_id,
+                               step=self._step_no,
+                               queue_depth=self.scheduler.queue_depth
+                               ) as st:
+                return self._step_phases(st)
+
+    def _step_phases(self, st) -> bool:
+        import jax.numpy as jnp
+        with _tracing.span("engine.admit") as sp:
             for r in self.scheduler.expire_deadlines():
                 self._note_done(r)
-            for req in self.scheduler.admit():
+            admitted = self.scheduler.admit()
+            sp.attrs["admitted"] = st.attrs["admitted"] = len(admitted)
+            for req in admitted:
                 try:
                     self._run_prefill(req)
                 except Exception as e:
@@ -523,8 +549,11 @@ class Engine:
                     self._recover_cache("failed prefill")
             active = [(i, r) for i, r in enumerate(self.scheduler.slots)
                       if r is not None]
-            if not active:
-                return bool(self.scheduler.queue_depth)
+        st.attrs["active"] = len(active)
+        if not active:
+            st.attrs["idle"] = True
+            return bool(self.scheduler.queue_depth)
+        with _tracing.span("engine.build"):
             sample = self._perf_sampler.tick()
             t_host0 = time.perf_counter()
             S = self.num_slots
@@ -537,7 +566,7 @@ class Engine:
             topps = np.ones((S,), np.float32)
             seeds = np.zeros((S, 2), np.uint32)
             steps = np.zeros((S,), np.int32)
-            sampled_n = 0
+            sampled_n = pages_live = 0
             for i, r in active:
                 # a bootstrap admission (whole prompt cached, prefill
                 # skipped) reaches its first decode with NOTHING
@@ -555,6 +584,12 @@ class Engine:
                 steps[i] = len(r.generated)
                 if r.temperature > 0:
                     sampled_n += 1
+                # pages that hold a token once this step has written its own
+                pages_live += r.position // self.page_size + 1
+            # what the pool has handed out (worst case of every admitted
+            # request) against what holds a token: ROADMAP Speed 5
+            st.attrs["pages_reserved"] = self.pool.used_pages
+            st.attrs["pages_live"] = pages_live
             # hang injection (chaos drills): PADDLE_PS_FAULT_STALL with
             # PADDLE_PS_FAULT_STALL_POINT=serving_decode wedges the
             # step thread here — inside the step lock, exactly like a
@@ -572,12 +607,13 @@ class Engine:
             if bucket not in self._compiles:
                 self._register_perf_cost(bucket, self._decode, targs,
                                          S, self.max_seq_len)
-            try:
-                t0 = time.perf_counter()
-                with _tracing.span("engine.decode",
-                                   engine=self.engine_id,
-                                   active=len(active)):
+        try:
+            t0 = time.perf_counter()
+            with _tracing.span("engine.decode", engine=self.engine_id,
+                               active=len(active)) as sp:
+                with _tracing.span("engine.dispatch"):
                     self.cache, device_toks = self._decode(*targs)
+                with _tracing.span("engine.wait"):
                     if sample:
                         # fenced phase boundaries: dispatch ends when
                         # the async jit call returns, device when the
@@ -590,19 +626,23 @@ class Engine:
                         t3 = time.perf_counter()
                     else:
                         next_toks = np.asarray(device_toks)
-                dt = time.perf_counter() - t0
-                self._m_decode_h.observe(dt)
-            except Exception as e:
-                # a decode-step failure poisons the whole slot batch (the
-                # cache buffer may be donated/invalid): fail the in-flight
-                # requests with their pages freed rather than wedging them
-                for _i, r in active:
-                    r.error = f"decode failed: {type(e).__name__}: {e}"
-                    self.scheduler.evict(r, "error")
-                    self._note_done(r)
-                self._recover_cache("failed decode")
-                raise
-            if self._compiles.get(bucket, 0) > pre_compiles:
+                compiled = self._compiles.get(bucket, 0) > pre_compiles
+                if compiled:
+                    sp.attrs["compiled"] = True
+            dt = time.perf_counter() - t0
+            self._m_decode_h.observe(dt)
+        except Exception as e:
+            # a decode-step failure poisons the whole slot batch (the
+            # cache buffer may be donated/invalid): fail the in-flight
+            # requests with their pages freed rather than wedging them
+            for _i, r in active:
+                r.error = f"decode failed: {type(e).__name__}: {e}"
+                self.scheduler.evict(r, "error")
+                self._note_done(r)
+            self._recover_cache("failed decode")
+            raise
+        with _tracing.span("engine.emit") as sp:
+            if compiled:
                 _perf.note_compile_seconds("engine.decode", dt)
             elif sample:
                 # host = batch building (token/position/table arrays);
@@ -619,12 +659,13 @@ class Engine:
                 self._m_sampling_tokens.inc(sampled_n)
             self._note_flops(self._bucket_flops.get(bucket))
             self._m_steps.inc()
-            _flight.record("serving", "step", engine=self.engine_id,
-                           active=len(active))
+            finished = 0
             for i, r in active:
                 if self.scheduler.record_token(r, int(next_toks[i])):
                     self._note_done(r)
-            return True
+                    finished += 1
+            sp.attrs["finished"] = finished
+        return True
 
     def _recover_cache(self, why: str):
         """After a failed jitted call on a DONATING backend the cache

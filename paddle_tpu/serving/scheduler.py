@@ -47,7 +47,7 @@ from collections import deque
 import numpy as np
 
 from ..observability import (flight as _flight, meter as _meter,
-                             registry as _obs)
+                             registry as _obs, tracing as _tracing)
 from .kv_cache import PagePool, PageTable, pages_needed
 
 __all__ = ["Request", "Scheduler", "QueueFull", "QuotaExceeded",
@@ -87,12 +87,18 @@ _QUOTA_REJECTED = _obs.counter(
     "submits rejected by a tenant token-bucket quota", ["inst"],
     always=True)
 
+_ADMIT_BLOCKED = _obs.counter(
+    "paddle_tpu_serving_admit_blocked_total",
+    "admit() passes that left the queue's head waiting, by reason "
+    "(counted where the admit_blocked flight event is recorded)",
+    ["inst", "reason"])
+
 _sched_ids = itertools.count()
 
 
 def _drop_sched_series(inst: str):
     for m in (_ADMITTED, _COMPLETED, _PREEMPTED, _REJECTED, _EVICTIONS,
-              _EXPIRED_QUEUE, _SHED, _QUOTA_REJECTED):
+              _EXPIRED_QUEUE, _SHED, _QUOTA_REJECTED, _ADMIT_BLOCKED):
         m.remove_matching(inst=inst)
 
 
@@ -197,6 +203,12 @@ class Request:
         # SLO surface (TTFT, inter-token latency) the load generator
         # reads (serving/loadgen.py)
         self._queued_at: float | None = None
+        # the same moment on the tracer's clock (the scheduler's can be a
+        # test's fake one): the start of the `scheduler.queue` span, None
+        # once the request has left the queue; and the admit() passes
+        # that found the pool full for it
+        self._queue_t0: float | None = None
+        self._blocked = 0
         self.first_token_at: float | None = None
         self.last_token_at: float | None = None
         self._finished = False       # set once, under the scheduler lock
@@ -311,6 +323,8 @@ class Scheduler:
         self._m_expired_queue = _EXPIRED_QUEUE.labels(inst=self.inst)
         self._m_shed = _SHED.labels(inst=self.inst)
         self._m_quota_rejected = _QUOTA_REJECTED.labels(inst=self.inst)
+        self._m_admit_blocked = _ADMIT_BLOCKED.labels(
+            inst=self.inst, reason="pool_full")
         # a dead scheduler's series leave the exposition
         weakref.finalize(self, _drop_sched_series, self.inst)
 
@@ -431,6 +445,7 @@ class Scheduler:
                 # cannot fail: available() was checked under this same
                 # lock and no other submit ran since
                 bucket.take(req.total_tokens)
+            req._queue_t0 = _tracing.TRACER.clock()
             self.queue.append(req)
         if victim is not None:
             self._m_shed.inc()
@@ -545,6 +560,8 @@ class Scheduler:
                     # the scheduler DECIDED to block admission: the
                     # reason belongs in the flight record, it is what a
                     # postmortem reader needs to explain a deep queue
+                    head._blocked += 1
+                    self._m_admit_blocked.inc()
                     _flight.record("serving", "admit_blocked",
                                    trace_id=head.trace_id,
                                    inst=self.inst, request=head.id,
@@ -562,6 +579,7 @@ class Scheduler:
                 head.started_at = self.now()
                 self.slots[i] = head
             self._m_admitted.inc()
+            self._left_queue(head, "admitted")
             _flight.record("serving", "admit", trace_id=head.trace_id,
                            inst=self.inst, request=head.id, slot=i,
                            pages=len(table.pages),
@@ -570,6 +588,20 @@ class Scheduler:
                            tier=head.priority, tenant=head.tenant)
             out.append(head)
         return out
+
+    def _left_queue(self, req: Request, outcome: str):
+        """The request's `scheduler.queue` span: from `submit` to the
+        moment it left the queue (into a slot, or expired, shed or
+        cancelled while waiting), in the request's trace."""
+        t0, req._queue_t0 = req._queue_t0, None
+        if t0 is None:
+            return
+        tracer = _tracing.TRACER
+        tracer.record("scheduler.queue", t0, tracer.clock(),
+                      trace_id=req.trace_id, request=req.id,
+                      outcome=outcome, slot=req.slot,
+                      prompt_len=int(req.prompt.size),
+                      blocked=req._blocked)
 
     def record_token(self, req: Request, token: int) -> bool:
         """Append a sampled token; returns True when the request is now
@@ -624,6 +656,9 @@ class Scheduler:
                 return False
             req._finished = True
         now = self.now()
+        if req._queue_t0 is not None:   # it never held a slot
+            self._left_queue(req, "expired" if reason == "expired_in_queue"
+                             else status)
         if req.prefix_cow is not None:
             # bootstrap admission that died before the engine's COW
             # copy: drop the pinned lookup ref on the source page

@@ -1,0 +1,336 @@
+"""The spans the program records about itself (ISSUE 24): the phases of
+`Engine.step`, the queue span of `Scheduler`, the trainer's dispatch
+spans, and what `Tracer` grew for them (`record`, `clock`, `caused_by`,
+counter span ids). The names and attributes below are a contract: the
+benchmark's readers (benchmark/readers/spans.py) and PERF.md read them."""
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from paddle_tpu.models.gpt import GPTConfig
+from paddle_tpu.observability import flight, registry, tracing
+from paddle_tpu.observability.tracing import TRACER, Tracer
+from paddle_tpu.serving import (Engine, GPTDecodeModel, PagePool, Request,
+                                Scheduler)
+
+PHASES = ["engine.admit", "engine.build", "engine.decode", "engine.emit"]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    model = GPTDecodeModel(GPTConfig.tiny(num_layers=1), seed=0)
+    eng = Engine(model, num_slots=4, num_pages=32, page_size=8,
+                 max_seq_len=64)
+    # compile the buckets the tests use, so that no test times a compile
+    for n in (5, 12):
+        eng.submit(np.arange(1, n + 1), max_new_tokens=2)
+    eng.run_until_idle()
+    return eng
+
+
+def _prompt(n, seed=0):
+    return np.random.RandomState(seed).randint(1, 100, size=n)
+
+
+def _run(eng, *requests):
+    """Submit, run until idle; returns (requests, the spans recorded)."""
+    TRACER.clear()
+    reqs = [eng.submit(p, max_new_tokens=n) for p, n in requests]
+    eng.run_until_idle()
+    return reqs, TRACER.spans()
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s.parent_id == parent.span_id),
+                  key=lambda s: s.start)
+
+
+# -- Engine.step ------------------------------------------------------------
+
+def test_phases_nest_under_the_step_in_order_and_cover_it(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 6), (_prompt(12, 1), 4))
+    steps = [s for s in spans if s.name == "engine.step"]
+    busy = [s for s in steps if not s.attrs.get("idle")]
+    assert len(busy) >= 5
+    for st in busy:
+        kids = _children(spans, st)
+        assert [k.name for k in kids] == PHASES
+        # one after the other, inside the step
+        assert st.start <= kids[0].start and kids[-1].end <= st.end
+        for a, b in zip(kids, kids[1:]):
+            assert a.end <= b.start
+        dec = _children(spans, kids[2])
+        assert [k.name for k in dec] == ["engine.dispatch", "engine.wait"]
+        assert kids[3].attrs["finished"] >= 0
+    # over the run the phases account for the steps (a single tiny CPU
+    # step can lose 5% to one slow line between two phases; the sum
+    # cannot)
+    covered = sum(k.duration() for st in busy for k in _children(spans, st))
+    assert covered >= 0.95 * sum(st.duration() for st in busy)
+    nos = [st.attrs["step"] for st in steps]
+    assert nos == list(range(nos[0], nos[0] + len(nos)))
+
+
+def test_step_attributes_say_what_the_step_held(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 6), (_prompt(12, 1), 4))
+    steps = [s for s in spans if s.name == "engine.step"]
+    first = steps[0]
+    assert first.attrs["queue_depth"] == 2 and first.attrs["admitted"] == 2
+    assert first.attrs["active"] == 2
+    # reserved: the worst case of both, ceil(11/8) + ceil(16/8) pages;
+    # live: the pages that hold a token, ceil(6/8) + ceil(13/8)
+    assert first.attrs["pages_reserved"] == 2 + 2
+    assert first.attrs["pages_live"] == 1 + 2
+    assert _children(spans, first)[0].attrs["admitted"] == 2
+    later = steps[2]
+    assert later.attrs["queue_depth"] == 0 and later.attrs["admitted"] == 0
+    assert later.attrs["pages_live"] <= later.attrs["pages_reserved"]
+
+
+def test_a_step_with_no_active_slot_is_idle_with_admit_alone(engine):
+    # the one token of this request is the prefill's: nothing to decode
+    _reqs, spans = _run(engine, (_prompt(5), 1))
+    steps = [s for s in spans if s.name == "engine.step"]
+    assert len(steps) == 1 and steps[0].attrs["idle"] is True
+    assert [k.name for k in _children(spans, steps[0])] == ["engine.admit"]
+    # and an engine with nothing queued or running records nothing
+    TRACER.clear()
+    assert engine.step() is False
+    assert TRACER.spans() == []
+
+
+def test_prefill_names_the_admit_that_ran_it(engine):
+    reqs, spans = _run(engine, (_prompt(5), 3), (_prompt(12, 1), 3))
+    admit = next(s for s in spans if s.name == "engine.admit")
+    step = next(s for s in spans if s.name == "engine.step")
+    assert admit.parent_id == step.span_id
+    prefills = [s for s in spans if s.name == "engine.prefill"]
+    assert len(prefills) == 2
+    for p, r in zip(prefills, reqs):
+        # the request's own trace, and still linked to the step
+        assert p.trace_id == r.trace_id != step.trace_id
+        assert p.parent_id is None and p.caused_by == admit.span_id
+        assert admit.start <= p.start and p.end <= admit.end
+        assert p.to_event()["args"]["caused_by"] == admit.span_id
+
+
+def test_at_most_ten_spans_a_decode_step(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 8))
+    steps = [s for s in spans if s.name == "engine.step"]
+    assert len(steps) == 7          # the prefill's token, then one a step
+    # a step that admits nothing: itself, four phases, dispatch and wait
+    assert len(spans) - 2 == 7 * len(steps)     # + one prefill, one queue
+    # the first step also holds the prefill, and ends the queue span
+    inside = [sum(1 for s in spans if st.start <= s.start and s.end <= st.end)
+              for st in steps]
+    assert inside == [8] + [7] * 6 and inside[0] + 1 <= 10
+
+
+def test_compiled_marks_the_call_that_compiled():
+    model = GPTDecodeModel(GPTConfig.tiny(num_layers=1), seed=0)
+    eng = Engine(model, num_slots=2, num_pages=16, page_size=8,
+                 max_seq_len=32)
+    _reqs, spans = _run(eng, (_prompt(5), 3))
+    _reqs, again = _run(eng, (_prompt(5, 1), 3))
+    for name in ("engine.prefill", "engine.decode"):
+        first = [s for s in spans if s.name == name]
+        assert first[0].attrs.get("compiled") is True
+        assert not any(s.attrs.get("compiled") for s in first[1:])
+        assert not any(s.attrs.get("compiled") for s in again
+                       if s.name == name)
+
+
+def test_the_step_flight_event_is_gone_and_the_request_events_stay(engine):
+    flight.RECORDER.clear()
+    _run(engine, (_prompt(5), 4))
+    kinds = [e.kind for e in flight.RECORDER.events("serving")]
+    assert "step" not in kinds
+    for kind in ("submit", "admit", "prefill", "evict"):
+        assert kinds.count(kind) == 1
+
+
+# -- Scheduler --------------------------------------------------------------
+
+def test_queue_span_lasts_from_submit_to_admit(engine):
+    TRACER.clear()
+    reqs = [engine.submit(_prompt(5, i), max_new_tokens=3) for i in range(6)]
+    time.sleep(0.02)                # they wait; four slots for six
+    engine.run_until_idle()
+    spans = [s for s in TRACER.spans() if s.name == "scheduler.queue"]
+    assert len(spans) == 6
+    by_req = {s.attrs["request"]: s for s in spans}
+    for r in reqs:
+        q = by_req[r.id]
+        assert q.trace_id == r.trace_id
+        assert q.attrs["outcome"] == "admitted"
+        assert q.attrs["prompt_len"] == 5 and q.attrs["blocked"] == 0
+        assert q.attrs["slot"] in range(4)
+        # the scheduler's clock is the real one here: the same wait
+        assert q.duration() == pytest.approx(
+            r.started_at - r._queued_at, abs=1e-3)
+        assert q.duration() >= 0.02
+    # the last two waited for a slot through whole decode steps
+    waits = sorted(s.duration() for s in spans)
+    assert waits[-1] > waits[0]
+
+
+def _sched(**kw):
+    pool = PagePool(num_pages=4, page_size=4)
+    return pool, Scheduler(pool, num_slots=2, max_seq_len=32, **kw)
+
+
+def _req(n=4, new=4, **kw):
+    r = Request(np.arange(1, n + 1), new, **kw)
+    r.trace_id = tracing.new_trace_id()
+    return r
+
+
+@pytest.mark.parametrize("outcome", ["admitted", "expired", "shed",
+                                     "cancelled"])
+def test_queue_span_carries_the_trace_id_with_every_outcome(outcome):
+    clock = [100.0]                 # a fake scheduler clock: not the span's
+    _pool, s = _sched(now=lambda: clock[0], max_queue=1)
+    TRACER.clear()
+    t0 = TRACER.clock()
+    r = s.submit(_req(priority=2, deadline=101.0))
+    if outcome == "admitted":
+        assert s.admit() == [r]
+    elif outcome == "expired":
+        clock[0] = 102.0
+        assert s.expire_deadlines() == [r]
+    elif outcome == "shed":
+        s.submit(_req(priority=0))  # a full queue sheds the lower tier
+        assert r.status == "shed"
+    else:
+        assert s.cancel(r)
+    q = [x for x in TRACER.spans() if x.name == "scheduler.queue"]
+    assert len(q) == 1 and q[0].attrs["outcome"] == outcome
+    assert q[0].trace_id == r.trace_id and q[0].attrs["request"] == r.id
+    assert t0 <= q[0].start <= q[0].end <= TRACER.clock()
+    assert q[0].end - q[0].start < 5.0      # not the fake clock's 100 s
+    assert r._queue_t0 is None
+    # finishing later records no second span
+    if outcome == "admitted":
+        s.evict(r, "done")
+    assert len([x for x in TRACER.spans()
+                if x.name == "scheduler.queue"]) == 1
+
+
+def test_blocked_admissions_are_counted_on_the_request_and_the_registry():
+    pool, s = _sched()
+    TRACER.clear()
+    a = s.submit(_req(8, 8))        # the whole pool
+    b = s.submit(_req(4, 4))
+    assert s.admit() == [a]         # and found no pages for b: one
+    assert s.admit() == [] and s.admit() == []
+    assert f'paddle_tpu_serving_admit_blocked_total{{inst="{s.inst}",' \
+           f'reason="pool_full"}} 3' in registry.prometheus_text()
+    s.evict(a, "done")
+    assert s.admit() == [b]
+    q = [x for x in TRACER.spans() if x.name == "scheduler.queue"]
+    assert [x.attrs["blocked"] for x in q] == [0, 3]
+    blocked = [e for e in flight.RECORDER.events("serving")
+               if e.kind == "admit_blocked" and e.trace_id == b.trace_id]
+    assert len(blocked) == 3        # beside each count, as before
+    assert pool.used_pages == 2
+
+
+# -- the trainer ------------------------------------------------------------
+
+def test_train_step_has_put_and_dispatch_as_children():
+    import jax
+    from paddle_tpu.parallel.hybrid import HybridParallelTrainStep
+    cfg = GPTConfig.tiny(num_layers=1)
+    step = HybridParallelTrainStep(cfg, seed=0, devices=jax.devices()[:1])
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(2, 16))
+    TRACER.clear()
+    for _ in range(3):
+        loss = step(ids)
+    jax.block_until_ready(loss)
+    spans = TRACER.spans()
+    steps = [s for s in spans if s.name == "train.step"]
+    assert [s.attrs["step"] for s in steps] == [1, 2, 3]
+    assert len(spans) == 9          # three a step
+    for st in steps:
+        assert [k.name for k in _children(spans, st)] == \
+            ["train.put", "train.dispatch"]
+
+
+# -- Tracer -----------------------------------------------------------------
+
+def test_record_obeys_the_ring_the_sink_and_the_switch():
+    t = Tracer(max_spans=4, enabled=True, bridge_jax=False)
+    seen = []
+    t.set_sink(seen.append)
+    dropped = tracing._DROPPED.value
+    for i in range(6):
+        sp = t.record("q", 1.0 + i, 2.0 + i, trace_id="abc", n=i)
+    assert sp.trace_id == "abc" and sp.parent_id is None
+    assert (sp.start, sp.end, sp.attrs) == (6.0, 7.0, {"n": 5})
+    assert [s.attrs["n"] for s in t.spans()] == [2, 3, 4, 5]
+    assert tracing._DROPPED.value - dropped == 2
+    assert len(seen) == 6
+    # inside a span: the ambient trace unless told otherwise, and a parent
+    # only when given one
+    with t.span("outer") as outer:
+        inner = t.record("q", 0.0, 1.0)
+        given = t.record("q", 0.0, 1.0, parent_id=outer.span_id)
+    assert inner.trace_id == outer.trace_id and inner.parent_id is None
+    assert given.parent_id == outer.span_id
+    t.enabled = False
+    assert t.record("q", 0.0, 1.0) is None
+    assert len(seen) == 9 and len(t.spans()) == 4
+
+
+def test_switched_off_spans_propagate_ids_and_record_nothing():
+    t = Tracer(enabled=False)
+    with t.span("a", trace_id="feed") as a:
+        assert t.current_trace_id() == "feed"
+        with t.span("b") as b:
+            assert b.trace_id == "feed" and b.parent_id == a.span_id
+    assert t.current_span() is None and t.spans() == []
+
+
+def test_every_stamp_is_the_tracers_clock():
+    t = Tracer(bridge_jax=False)
+    ticks = iter(range(10, 20))
+    t.clock = lambda: next(ticks)
+    with t.span("a") as a:
+        with t.span("b") as b:
+            pass
+    assert (a.start, b.start, b.end, a.end) == (10, 11, 12, 13)
+    assert Tracer().clock is time.monotonic
+
+
+def test_rerooted_span_keeps_the_ambient_span_in_caused_by():
+    t = Tracer(bridge_jax=False)
+    with t.span("step") as step:
+        with t.span("prefill", trace_id="req") as p:
+            with t.span("inner") as inner:
+                pass
+        with t.span("same", trace_id=step.trace_id) as same:
+            pass
+    assert p.trace_id == "req" and p.parent_id is None
+    assert p.caused_by == step.span_id
+    assert inner.parent_id == p.span_id and inner.caused_by is None
+    assert same.parent_id == step.span_id and same.caused_by is None
+    assert "caused_by" not in step.to_event()["args"]
+
+
+def test_span_ids_are_unique_across_threads():
+    t = Tracer(max_spans=8192, bridge_jax=False)
+
+    def work():
+        for _ in range(500):
+            with t.span("x"):
+                t.record("y", 0.0, 1.0)
+    threads = [threading.Thread(target=work) for _ in range(8)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    ids = [s.span_id for s in t.spans()]
+    assert len(ids) == 8000 and len(set(ids)) == 8000
+    assert all(len(i) == 16 for i in ids)
