@@ -264,7 +264,7 @@ class GPTDecodeModel:
             ck = ck.at[l, tail_pages].set(kp)
             cv = cv.at[l, tail_pages].set(vp)
             a = paged_attention_decode(
-                q.reshape(T, H, d), ck[l], cv[l], tables, ctx,
+                q.reshape(T, H, d), ck, cv, tables, ctx, layer=l,
                 scale=1.0 / math.sqrt(d), impl=self.attn_impl)
             x = decoder_tail(p, a.reshape(T, -1), x, cfg)
             return (x, ck, cv), None
@@ -316,7 +316,7 @@ class GPTDecodeModel:
             cv = cv.at[l, page_of, off].set(
                 v.reshape(S, H, d).astype(cv.dtype))
             a = paged_attention_decode(
-                q.reshape(S, H, d), ck[l], cv[l], tables, ctx,
+                q.reshape(S, H, d), ck, cv, tables, ctx, layer=l,
                 scale=1.0 / math.sqrt(d), impl=self.attn_impl)
             x = decoder_tail(p, a.reshape(S, -1), x, cfg)
             return (x, ck, cv), None

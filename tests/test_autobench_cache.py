@@ -8,6 +8,7 @@ import subprocess
 import sys
 import zlib
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -318,3 +319,87 @@ def test_warm_presets_are_registered():
         for spec in specs:
             assert spec["kernel"] in autobench._WARMERS, \
                 (name, spec["kernel"])
+
+
+# the gpt_1p3b_serve preset's decode key before PR 25, when the gate timed
+# rank-4 pools [P, ps, H, d]
+_PAGED_SPEC = {"kernel": "paged_attention", "s": 8, "h": 16, "d": 128,
+               "p": 1025, "ps": 16, "m": 128}
+_OLD_PAGED_KEY = ("paged_attention", 8, 16, 128, 1025, 16, 128, "bfloat16")
+
+
+def _paged_gate_calls(monkeypatch):
+    """Run the gate with `_measure` replaced; returns what it was handed:
+    [(candidate name, shapes of make_args())]."""
+    seen = []
+
+    def fake_measure(fn, make_args, reps):
+        name = "pallas" if "pallas" in fn.__name__ else "xla"
+        seen.append((name, [tuple(a.shape) for a in make_args()]))
+        return (1e-3 if name == "pallas" else 2e-3), 0.0
+
+    monkeypatch.setattr(autobench, "_measure", fake_measure)
+    monkeypatch.setattr(autobench._perf, "costs_enabled", lambda: False)
+    return seen
+
+
+@pytest.mark.parametrize("through", ["warmer", "gate"])
+def test_paged_gate_times_the_stacked_form_on_one_layer(
+        cache_file, monkeypatch, through):
+    """Both candidates are timed in the form the decode body runs: pools
+    [1, P, ps, H, d] and the layer as a sixth, traced, argument."""
+    from paddle_tpu.ops import paged_attention as pa
+    seen = _paged_gate_calls(monkeypatch)
+    if through == "warmer":
+        [(_spec, winner)] = autobench.warm([_PAGED_SPEC])
+    else:
+        key, cands, make_args = pa._gate_paged(8, 16, 128, 1025, 16, 128,
+                                               jnp.bfloat16)
+        winner = autobench.prefer(key, cands, make_args, default="xla")
+    assert winner == "pallas"
+    assert sorted(n for n, _ in seen) == ["pallas", "xla"]
+    for _name, shapes in seen:
+        assert shapes == [(8, 16, 128), (1, 1025, 16, 16, 128),
+                          (1, 1025, 16, 16, 128), (8, 128), (8,), ()]
+    [key] = autobench.decisions()
+    assert key[:2] == ("paged_attention", "stacked")
+    assert key[2:] == _OLD_PAGED_KEY[1:]
+
+
+def test_paged_gate_candidates_run_on_their_own_args():
+    """The candidates as the gate jits them, at a small size: the stacked
+    pool of one layer with a traced layer gives what the rank-4 form
+    gives (Pallas in interpret mode here: the gate's own candidate pins
+    interpret=False, for the chip)."""
+    from paddle_tpu.ops import paged_attention as pa
+    _key, cands, make_args = pa._gate_paged(4, 4, 16, 13, 8, 3, jnp.float32)
+    q, k, v, pt, ln, layer = make_args()
+    assert k.shape == (1, 13, 8, 4, 16) and layer.dtype == jnp.int32
+    want = pa.paged_attention_xla(q, k[0], v[0], pt, ln)
+    got = jax.jit(cands["xla"])(q, k, v, pt, ln, layer)
+    assert jnp.array_equal(got, want)
+    got = jax.jit(lambda *a: pa.paged_attention_pallas(
+        *a[:-1], interpret=True, layer=a[-1]))(q, k, v, pt, ln, layer)
+    assert jnp.allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+def test_paged_record_of_the_rank4_gate_does_not_answer(cache_file,
+                                                        monkeypatch):
+    """A decision a fleet cached before PR 25 was measured on other
+    kernels (rank-4 pools): it stays in the file and is never adopted."""
+    autobench._publish(cache_file, {
+        "key": str(_OLD_PAGED_KEY), "device": autobench._device_kind(),
+        "winner": "xla", "jax": autobench._jax_version(),
+        "kernels": autobench.KERNEL_VERSION,
+        "timings_ms": {"xla": 1.0, "pallas": 2.0}, "errors": {},
+        "ts": 0.0})
+    autobench.clear()
+    seen = _paged_gate_calls(monkeypatch)
+    [(_spec, winner)] = autobench.warm([_PAGED_SPEC])
+    assert winner == "pallas" and len(seen) == 2      # measured anew
+    st = autobench.stats()
+    assert st["cache_hits"] == 0 and st["cache_misses"] == 1
+    keys = {rec["key"]: rec["winner"]
+            for rec in autobench.list_entries(cache_file)}
+    assert keys[str(_OLD_PAGED_KEY)] == "xla"
+    assert sum("'stacked'" in k for k in keys) == 1

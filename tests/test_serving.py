@@ -4,6 +4,7 @@ continuous-batching acceptance test (ISSUE 2): >= 8 concurrent requests
 of different prompt/output lengths decode token-for-token identically
 to sequential batch-1 greedy decode, with at most one compile per
 (slots, pages) bucket and deadline preemption returning every page."""
+import functools
 import time
 
 import numpy as np
@@ -181,6 +182,113 @@ def test_paged_attention_pallas_interpret_matches_xla():
     b = paged_attention_pallas(q, k, v, pt, ln, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
                                rtol=1e-5)
+
+
+def _stacked_args(L=3, **kw):
+    """A pool stacked over L layers, each layer's pages different."""
+    q, k, v, pt, ln = _paged_args(**kw)
+    rng = np.random.RandomState(7)
+    ks = jnp.asarray(rng.randn(L, *k.shape).astype(np.float32))
+    vs = jnp.asarray(rng.randn(L, *v.shape).astype(np.float32))
+    return q, ks, vs, pt, ln
+
+
+def _paged_impl(impl):
+    from paddle_tpu.ops import paged_attention as pa
+    if impl == "xla":
+        return pa.paged_attention_xla
+    return functools.partial(pa.paged_attention_pallas, interpret=True)
+
+
+def _assert_same(impl, got, want):
+    """The XLA path gathers the same values whatever the pool's rank:
+    exact. The Pallas path to the tolerance it is held to against XLA."""
+    if impl == "xla":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_paged_attention_stacked_pool_equals_layer_slice(impl, layer):
+    """[L, P, ps, H, d] with `layer` is the rank-4 form on pool[layer]:
+    first, middle and last layer, the layer a python int."""
+    fn = _paged_impl(impl)
+    q, ks, vs, pt, ln = _stacked_args()
+    want = fn(q, ks[layer], vs[layer], pt, ln)
+    _assert_same(impl, fn(q, ks, vs, pt, ln, layer=layer), want)
+
+
+@pytest.mark.parametrize("how", ["jit", "scan"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_paged_attention_stacked_pool_traced_layer(impl, how):
+    """The layer as the decode body has it: a traced int32 scalar, an
+    argument of a jitted call or the index a lax.scan carries."""
+    fn = _paged_impl(impl)
+    q, ks, vs, pt, ln = _stacked_args()
+    L = ks.shape[0]
+    want = jnp.stack([fn(q, ks[l], vs[l], pt, ln) for l in range(L)])
+    if how == "jit":
+        f = jax.jit(lambda l: fn(q, ks, vs, pt, ln, layer=l))
+        got = jnp.stack([f(jnp.int32(l)) for l in range(L)])
+    else:
+        _, got = jax.lax.scan(
+            lambda c, l: (c, fn(q, ks, vs, pt, ln, layer=l)), 0,
+            jnp.arange(L))
+    _assert_same(impl, got, want)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_paged_attention_pool_rank_and_layer_must_agree(impl):
+    fn = _paged_impl(impl)
+    q, ks, vs, pt, ln = _stacked_args()
+    with pytest.raises(ValueError, match="needs layer"):
+        fn(q, ks, vs, pt, ln)
+    with pytest.raises(ValueError, match="rank 4"):
+        fn(q, ks[0], vs[0], pt, ln, layer=0)
+    with pytest.raises(ValueError, match="layer 3 of a pool of 3"):
+        fn(q, ks, vs, pt, ln, layer=3)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (scan and
+    pjit bodies, a Pallas kernel's body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill_tail"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_bodies_never_make_one_layers_pool(impl, entry):
+    """The structural guard of PR 25: no equation of the traced program
+    outputs an array of one layer's pool shape (P+1, ps, H, d). `ck[l]`
+    in front of paged attention did, and on the chip that was a copy of
+    201 MB for K and for V in each of 24 layers of every decode step."""
+    cfg = GPTConfig.tiny(num_layers=3)
+    model = GPTDecodeModel(cfg, seed=0, attn_impl=impl)
+    P, ps, S, M, T = 10, 8, 4, 3, 16
+    cache = model.init_cache(P, ps)
+    pool = cache["k"].shape[1:]
+    assert pool == (P + 1, ps, cfg.num_heads, model.head_dim)
+    if entry == "decode":
+        jaxpr = jax.make_jaxpr(model.decode)(
+            model.params, cache, jnp.zeros((S,), jnp.int32),
+            jnp.arange(S, dtype=jnp.int32),
+            jnp.full((S, M), P, jnp.int32))
+    else:
+        jaxpr = jax.make_jaxpr(model.prefill_tail)(
+            model.params, cache, jnp.zeros((T,), jnp.int32),
+            jnp.int32(ps), jnp.int32(T), jnp.arange(M + 1, dtype=jnp.int32))
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1          # the layer loop is what is walked
+    made = [(e.primitive.name, v.aval.shape)
+            for e in _eqns(scans[0].params["jaxpr"].jaxpr)
+            for v in e.outvars if getattr(v.aval, "shape", None) == pool]
+    assert not made, made
 
 
 def test_paged_attention_op_registered_with_infer_shape():
