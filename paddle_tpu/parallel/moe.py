@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["moe_capacity", "topk_gating", "moe_ffn", "moe_context",
-           "current_moe_mesh"]
+           "current_moe_mesh", "sigmoid_topk_route", "dropless_moe_ffn"]
 
 _moe_stack: list[tuple[Mesh, str]] = []
 
@@ -153,3 +153,171 @@ def moe_ffn(x, wg, we_up, be_up, we_down, be_down, *,
     out = pin(out, ("ep", None, None))
     y = jnp.einsum("nec,ecd->nd", combine.astype(x.dtype), out)
     return y.reshape(shape), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless layer: sigmoid scores, selection on score plus bias, every
+# token-expert pair computed. (The capacity-based layer above stays for
+# its callers; a token it drops cannot agree with a plain reference.)
+# ---------------------------------------------------------------------------
+
+def sigmoid_topk_route(h, wg, bias, top_k: int, norm_topk: bool = True,
+                       scale: float = 1.0):
+    """Router of the sigmoid-scored, bias-corrected kind (DeepSeek-V3's
+    auxiliary-loss-free balancing, as LFM2-MoE uses it): s = sigmoid(h wg)
+    in float32, the top_k of s + bias are CHOSEN, and the weights come
+    from s alone, normalised over the chosen (1e-6 in the denominator).
+
+    h [N, D], wg [D, E], bias [E] or None. Returns (sel [N, k] int32,
+    weights [N, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                               wg.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    biased = s if bias is None else s + bias.astype(jnp.float32)
+    _, sel = jax.lax.top_k(biased, top_k)
+    g = jnp.take_along_axis(s, sel, axis=-1)
+    if norm_topk:
+        g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6)
+    return sel.astype(jnp.int32), g * scale
+
+
+def _held_index(num_experts: int, experts_held):
+    """local[e] = row of expert e in the weights held here, or the count
+    held for an expert that lives elsewhere."""
+    if experts_held is None:
+        return None, num_experts
+    held = tuple(int(e) for e in experts_held)
+    local = [len(held)] * num_experts
+    for i, e in enumerate(held):
+        local[e] = i
+    return jnp.asarray(local, jnp.int32), len(held)
+
+
+def _sorted_swiglu(h, local, g, w1, w3, w2, mm):
+    """Sort the token-expert pairs by expert, one grouped product per
+    projection over the sorted rows (`mm(x, w, sizes)`), unsort, weighted
+    sum. Work is N*k rows whatever the routing. Rows move by gathers only
+    (the unsort reads through the inverse permutation): a scatter of N*k
+    rows of D costs more than the products on a TPU."""
+    N, k = local.shape
+    Eh = w1.shape[0]
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.bincount(flat, length=Eh + 1)[:Eh].astype(jnp.int32)
+    xs = h[order // k]                                       # [N*k, D]
+    gated = jax.nn.silu(mm(xs, w1, sizes)) * mm(xs, w3, sizes)
+    ys = mm(gated.astype(xs.dtype), w2, sizes)
+    # rows of pairs whose expert lives elsewhere belong to no group
+    ys = jnp.where((flat[order] < Eh)[:, None], ys, 0)
+    return jnp.einsum("nkd,nk->nd", ys[inverse].reshape(N, k, -1),
+                      g.astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+
+
+def _gmm_tiles(m: int, k: int, n: int):
+    """(tm, tk, tn) for `megablox.gmm`: whole rows of the contraction in
+    one tile and half the output's width, so that an expert's matrix is
+    read once a row tile in two blocks of a few MB (jax's default of
+    128 x 128 x 128 makes ~7,000 grid steps of one layer's products)."""
+    tm = next(t for t in (256, 128, 64, 32, 16, 8, m) if m % t == 0)
+    tn = n // 2 if n % 256 == 0 else n
+    return tm, k, tn
+
+
+def grouped_swiglu_gmm(h, local, g, w1, w3, w2, interpret=None):
+    """The sorted spelling over jax's own Pallas grouped matmul
+    (`pallas.ops.tpu.megablox.gmm`, the kernel `lax.ragged_dot` lowers to
+    on a TPU; `ragged_dot` itself runs it at jax's default tiles and lost
+    at every row count, docs/KERNELS.md) at tiles chosen for these shapes.
+    Off the TPU the kernel is interpreted."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    from ..ops.pallas_attention import on_tpu
+    if interpret is None:
+        interpret = not on_tpu()
+
+    def mm(x, w, sizes):
+        return gmm(x, w, sizes, preferred_element_type=x.dtype,
+                   tiling=_gmm_tiles(x.shape[0], *w.shape[1:]),
+                   interpret=interpret)
+    return _sorted_swiglu(h, local, g, w1, w3, w2, mm)
+
+
+def grouped_swiglu_dense(h, local, g, w1, w3, w2):
+    """Every expert held on every row, then a masked weighted sum: E/k
+    times the products and no sort. At decode (a few rows an expert) the
+    layer is bound by streaming the experts' weights either way."""
+    Eh = w1.shape[0]
+    a = jnp.einsum("nd,edf->enf", h, w1)
+    b = jnp.einsum("nd,edf->enf", h, w3)
+    y = jnp.einsum("enf,efd->end", (jax.nn.silu(a) * b).astype(h.dtype), w2)
+    hit = local[:, :, None] == jnp.arange(Eh, dtype=jnp.int32)  # [N, k, Eh]
+    wgt = jnp.sum(jnp.where(hit, g[:, :, None], 0.0), axis=1)   # [N, Eh]
+    return jnp.einsum("end,ne->nd", y, wgt,
+                      preferred_element_type=jnp.float32)
+
+
+_GROUPED = {"gmm": grouped_swiglu_gmm, "dense": grouped_swiglu_dense}
+
+
+def _gate_grouped(N, k, Eh, D, F, dtype):
+    """(key, candidates, make_args) for ops/autobench: the two spellings
+    of the grouped products that each win somewhere on the chip, on one
+    layer's shapes, random even routing."""
+    dtype = jnp.dtype(dtype)
+    # the candidates are part of the key: a decision between other
+    # spellings does not answer for these
+    key = ("moe_grouped_swiglu", "|".join(_GROUPED), N, k, Eh, D, F,
+           str(dtype))
+
+    def make_args():
+        import numpy as np
+        rng = np.random.RandomState(0)
+        mk = lambda *s: jnp.asarray(0.02 * rng.randn(*s), dtype)
+        local = jnp.asarray(np.argsort(rng.rand(N, Eh), axis=1)[:, :k],
+                            jnp.int32)
+        g = jnp.full((N, k), 1.0 / k, jnp.float32)
+        return (jnp.asarray(rng.randn(N, D), dtype), local, g,
+                mk(Eh, D, F), mk(Eh, D, F), mk(Eh, F, D))
+
+    return key, dict(_GROUPED), make_args
+
+
+def _auto_grouped(h, local, w1) -> str:
+    """Measure-once arbitration on a TPU (ops/autobench.prefer) between
+    the Pallas grouped matmul and every-expert-on-every-row; elsewhere
+    the latter, which is plain einsum."""
+    from ..ops.pallas_attention import on_tpu
+    if not on_tpu():
+        return "dense"
+    from ..ops import autobench
+    (N, k), (Eh, D, F) = local.shape, w1.shape
+    key, cands, make_args = _gate_grouped(N, k, Eh, D, F, h.dtype)
+    return autobench.prefer(key, cands, make_args, default="gmm")
+
+
+def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
+                     norm_topk: bool = True, scale: float = 1.0,
+                     experts_held=None, impl: str | None = None):
+    """Dropless routed SwiGLU layer over the experts held here.
+
+    h [N, D]; wg [D, E] and bias [E] are the WHOLE router (it routes over
+    all E experts); w1/w3 [Eh, D, F] and w2 [Eh, F, D] are the weights of
+    `experts_held` (global ids, in the order of the rows; default all E).
+    The result is the part of the layer's output that these experts give:
+    over the shares of a partition of the experts the parts add up to the
+    whole layer. No pair is dropped.
+
+    impl: None = auto (see `_auto_grouped`), "gmm" or "dense".
+    Returns (y [N, D] in h's dtype, sel [N, k] int32 global expert ids)."""
+    E = wg.shape[1]
+    sel, g = sigmoid_topk_route(h, wg, bias, top_k, norm_topk, scale)
+    table, Eh = _held_index(E, experts_held)
+    if w1.shape[0] != Eh:
+        raise ValueError(f"{w1.shape[0]} experts' weights for "
+                         f"{Eh} experts held")
+    local = sel if table is None else table[sel]
+    if impl is None:
+        impl = _auto_grouped(h, local, w1)
+    y = _GROUPED[impl](h, local, g, w1, w3, w2)
+    return y.astype(h.dtype), sel

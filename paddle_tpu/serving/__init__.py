@@ -23,6 +23,11 @@ Network mode (PS wire format, see serving/frontend.py):
     srv = ServingServer(engine).start()          # engine-owned thread
     out = ServingClient(srv.endpoint).generate([1, 2, 3], 16)
 
+Models whose layers keep other state than K/V (a convolution's last
+inputs, per slot) are served through the same Engine by
+`HybridDecodeModel` (models/lfm2.py); serving/model.py has the cache of
+parts that both decode models answer.
+
 Replicated fleet (serving/router.py, docs/SERVING.md): a Router fronts
 N replicas with least-loaded dispatch, session affinity, streaming
 token frames, exactly-once failover, draining, and elastic respawn
@@ -33,7 +38,7 @@ from .prefix_cache import PrefixCache, PrefixMatch
 from .sampling import SamplingParams, derive_seed
 from .scheduler import (QueueFull, QuotaExceeded, Request, Scheduler,
                         TokenBucket)
-from .model import GPTDecodeModel
+from .model import CacheOfParts, GPTDecodeModel, HybridDecodeModel
 from .engine import Engine
 from .frontend import ServingClient, ServingServer
 from .loadgen import (Arrival, LoadGenerator, LoadResult, TrafficConfig,
@@ -44,7 +49,7 @@ __all__ = [
     "PagePool", "PageTable", "pages_needed", "defrag_plan",
     "PrefixCache", "PrefixMatch", "SamplingParams", "derive_seed",
     "Request", "Scheduler", "QueueFull", "QuotaExceeded", "TokenBucket",
-    "GPTDecodeModel", "Engine", "ServingServer", "ServingClient",
+    "GPTDecodeModel", "HybridDecodeModel", "CacheOfParts", "Engine", "ServingServer", "ServingClient",
     "Arrival", "LoadGenerator", "LoadResult", "TrafficConfig",
     "slo_report",
     "Router", "ReplicaSpec", "Replica", "InProcessReplica",

@@ -1,4 +1,6 @@
-"""Serving engine: continuous-batching decode loop over a paged cache.
+"""Serving engine: continuous-batching decode loop over the cache of
+parts (serving/model.py: K/V in pages, and whatever else the model keeps
+per token, per slot or as a tally).
 
 One Engine = one model + one preallocated page pool + one fixed-shape
 slot batch. Each scheduler iteration (`step()`):
@@ -6,7 +8,8 @@ slot batch. Each scheduler iteration (`step()`):
   1. expire deadlines (queued + running; preempted requests free ALL
      their pages back to the pool immediately);
   2. admit queued requests into free slots (capacity-gated FIFO), run
-     one jitted PREFILL per admission (prompt KV -> pages, first token);
+     one jitted PREFILL per admission (prompt K/V -> pages, per-slot
+     state -> the request's slot, first token);
   3. run ONE jitted DECODE over the whole slot batch (inactive slots
      ride along pointed at the trash page) and record each slot's token,
      evicting on EOS / max_new_tokens.
@@ -89,6 +92,10 @@ _SAMPLING_TOKENS = _obs.counter(
     "paddle_tpu_sampling_tokens_total",
     "tokens drawn from the Philox sampler (temperature > 0)",
     ["engine"])
+_SLOT_STATE_BYTES = _obs.gauge(
+    "paddle_tpu_serving_slot_state_bytes",
+    "bytes of the cache's per-slot parts (state kept per sequence, not "
+    "per token); 0 for a model that keeps none", ["engine"])
 
 _engine_ids = itertools.count()
 
@@ -96,7 +103,7 @@ _engine_ids = itertools.count()
 def _drop_engine_series(eid: str):
     for m in (_REQS, _TOKENS, _STEPS, _COMPILES, _DECODE_H, _PREFILL_H,
               _LATENCY_H, _QUEUE_DEPTH, _OCCUPANCY, _SAMPLING_REQS,
-              _SAMPLING_TOKENS):
+              _SAMPLING_TOKENS, _SLOT_STATE_BYTES):
         m.remove_matching(engine=eid)
 
 
@@ -152,8 +159,13 @@ class Engine:
         self.scheduler = Scheduler(self.pool, num_slots, self.max_seq_len,
                                    max_queue=max_queue,
                                    inst=self.engine_id)
-        self.trash_page = num_pages      # model pools carry P+1 pages
-        self.cache = model.init_cache(num_pages, page_size)
+        if hasattr(model, "routing_of"):
+            # weakly: the engine owns the scheduler, not the other way
+            keep = weakref.WeakMethod(self._keep_routing)
+            self.scheduler.before_release = \
+                lambda req, status: keep()(req, status)
+        self.trash_page = num_pages      # paged parts carry P+1 pages
+        self.cache = model.init_cache(num_pages, page_size, num_slots)
         # shared-prefix KV reuse (serving/prefix_cache.py): 0 pages =
         # disabled (the default — an idle engine then provably holds no
         # pages, the PR-2 invariant tests pin that)
@@ -161,6 +173,13 @@ class Engine:
             prefix_cache_pages = int(os.environ.get(
                 "PADDLE_TPU_PREFIX_CACHE_PAGES", "0") or 0)
         self.prefix_cache = None
+        if prefix_cache_pages > 0 and getattr(model, "slot_state", False):
+            raise ValueError(
+                "prefix_cache_pages > 0 with a model that keeps per-slot "
+                "state: a cached prefix resumes a prompt at a page "
+                "boundary from K/V alone, and this model's per-slot state "
+                "(a convolution's or a recurrence's last inputs) at that "
+                "boundary is not in any page")
         if prefix_cache_pages > 0:
             self.prefix_cache = PrefixCache(
                 self.pool, budget_pages=min(prefix_cache_pages,
@@ -250,11 +269,11 @@ class Engine:
         # literal argmax path inside sample_tokens, and no sampling
         # value can ever force a recompile — the one-compile-per-bucket
         # contract is pinned with sampling enabled
-        def prefill(params, cache, tokens, true_len, page_row,
+        def prefill(params, cache, tokens, true_len, page_row, slot,
                     temps, topks, topps, seeds, steps):
             note_compile(f"prefill[{tokens.shape[0]}]")  # trace-time
             cache, logits = model.prefill(params, cache, tokens,
-                                          true_len, page_row)
+                                          true_len, page_row, slot)
             tok = sample_tokens(logits[None, :], temps, topks, topps,
                                 seeds, steps)
             return cache, tok[0]
@@ -294,7 +313,14 @@ class Engine:
         _perf.mfu_gauge(self._perf_name).set_function(
             lambda: (lambda e: e.perf_rates()["mfu"] if e else 0.0)(wr()))
         _perf.kv_cache_gauge(eid).set_function(
-            lambda: (lambda e: e._kv_cache_bytes() if e else 0.0)(wr()))
+            lambda: (lambda e: e._kv_cache_bytes()["paged"] if e else 0.0)(
+                wr()))
+        _SLOT_STATE_BYTES.labels(engine=eid).set_function(
+            lambda: (lambda e: e._kv_cache_bytes()["slot"] if e else 0.0)(
+                wr()))
+        # the experts' tallies as last read (stats() reports the change)
+        self._tally_seen: dict = {}
+        self._steps_seen = 0
         _perf.register_provider(self._perf_name,
                                 _perf.weak_provider(self, "perf_rates"))
         weakref.finalize(self, _perf.drop_instance, self._perf_name, eid)
@@ -305,7 +331,8 @@ class Engine:
                eos_id: int | None = None, priority: int = 1,
                tenant: str = "default", temperature: float = 0.0,
                top_k: int = 0, top_p: float = 1.0,
-               seed: int | None = None) -> Request:
+               seed: int | None = None,
+               return_routing: bool = False) -> Request:
         """Enqueue a request. `deadline` is RELATIVE seconds from now;
         raises QueueFull (backpressure) when the queue is at capacity
         and QuotaExceeded (a QueueFull) when `tenant` is over its
@@ -314,7 +341,14 @@ class Engine:
         greedy; > 0 samples via the replayable (seed, step) Philox
         stream (serving/sampling.py) — `seed` defaults to the request
         id, so an identical resubmission with an explicit seed (or the
-        same wire id through the frontend) replays token-for-token."""
+        same wire id through the frontend) replays token-for-token.
+        `return_routing` (a model with routed experts): when the request
+        finishes, `req.routing` holds the experts chosen at each position
+        it fed the model, [prompt + generated - 1, expert layers, k], read
+        once from the cache's `routing` part (routing replay)."""
+        if return_routing and not hasattr(self.model, "routing_of"):
+            raise ValueError("return_routing needs a model with routed "
+                             "experts")
         req = Request(prompt, max_new_tokens,
                       deadline=None if deadline is None
                       else time.monotonic() + deadline,
@@ -322,6 +356,7 @@ class Engine:
                       priority=priority, tenant=tenant,
                       temperature=temperature, top_k=top_k, top_p=top_p,
                       seed=seed)
+        req.return_routing = bool(return_routing)
         if req.temperature > 0:
             self._m_sampling_reqs.inc()
         # carry the caller's trace context (e.g. the frontend handler's
@@ -475,7 +510,7 @@ class Engine:
             bucket = f"prefill[{T}]"
             fn = self._prefill
             targs = (self.model.params, self.cache, jnp.asarray(toks),
-                     np.int32(tail.size), row, *samp)
+                     np.int32(tail.size), row, np.int32(req.slot), *samp)
         # read BEFORE the cost registration: lower() traces the fn and
         # seeds the jit cache, so the note_compile side effect fires
         # there, not on the timed first call
@@ -488,7 +523,7 @@ class Engine:
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
-                           cached_tokens=start) as sp:
+                           cached_tokens=start, slot=req.slot) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
             compiled = self._compiles.get(bucket, 0) > pre_compiles
@@ -508,6 +543,16 @@ class Engine:
             self._m_sampling_tokens.inc()
         if self.scheduler.record_token(req, tok):
             self._note_done(req)
+
+    def _keep_routing(self, req: Request, status: str):
+        """`Scheduler.before_release`: the routing of a flagged request,
+        read from its pages before they are freed, however it ends (not
+        after an error: the cache may be lost with it). The positions fed
+        to the model are the prompt and all but the last token made."""
+        if req.return_routing and req.generated and status != "error":
+            req.routing = self.model.routing_of(
+                self.cache, self._row(req),
+                int(req.prompt.size) + len(req.generated) - 1)
 
     def step(self) -> bool:
         """One scheduler iteration; returns True if any work was done.
@@ -669,8 +714,8 @@ class Engine:
 
     def _recover_cache(self, why: str):
         """After a failed jitted call on a DONATING backend the cache
-        buffer may already be consumed — rebuild it and fail whatever
-        in-flight KV it held (CPU never donates: old cache stays valid,
+        buffers may already be consumed — rebuild every part and fail
+        whatever in-flight state they held (CPU never donates: old cache stays valid,
         surviving requests keep decoding)."""
         if not self._donate:
             return
@@ -678,7 +723,9 @@ class Engine:
             r.error = f"kv cache lost to a {why} (donated buffer)"
             self.scheduler.evict(r, "error")
             self._note_done(r)
-        self.cache = self.model.init_cache(self.num_pages, self.page_size)
+        self.cache = self.model.init_cache(self.num_pages, self.page_size,
+                                           self.num_slots)
+        self._tally_seen = {}
 
     def drain(self) -> "Engine":
         """Graceful removal from a serving fleet: stop admitting new
@@ -789,12 +836,52 @@ class Engine:
             with self._stats_lock:
                 self._flops_window.append((time.monotonic(), flops))
 
-    def _kv_cache_bytes(self) -> float:
-        # the cache is whatever pytree the model keeps (dict of layers
-        # here); tree_leaves reaches the buffers regardless of shape
-        import jax
-        return float(sum(getattr(leaf, "nbytes", 0)
-                         for leaf in jax.tree_util.tree_leaves(self.cache)))
+    def _kv_cache_bytes(self) -> dict:
+        """Bytes of the cache by kind of part: {"paged", "slot",
+        "tally"} (serving/model.py::CacheOfParts)."""
+        sizes = getattr(self.model, "cache_bytes", None)
+        if sizes is None:
+            # a model that keeps some other pytree: every buffer is paged
+            import jax
+            return {"paged": float(sum(
+                getattr(leaf, "nbytes", 0)
+                for leaf in jax.tree_util.tree_leaves(self.cache))),
+                "slot": 0.0, "tally": 0.0}
+        return {k: float(v) for k, v in sizes(self.cache).items()}
+
+    def _expert_stats(self) -> dict:
+        """The experts' tallies, read from the device under the step lock
+        (the step donates the cache), and what changed since the last
+        read. {} for a model without routed experts, or while a step
+        holds the lock for long."""
+        if "tally" not in getattr(self.model, "cache_kinds", {}).values():
+            return {}
+        if not self._lock.acquire(timeout=2.0):
+            return {}
+        try:
+            now = {n: np.asarray(self.cache[n]).astype(np.int64)
+                   for n in self.model.parts_of("tally")}
+            steps = int(self._m_steps.value)
+        finally:
+            self._lock.release()
+        seen, self._tally_seen = self._tally_seen, now
+        d_steps, self._steps_seen = steps - self._steps_seen, steps
+        delta = {n: a - seen.get(n, 0) for n, a in now.items()}
+        pairs, touched = delta["expert_tokens"], delta["expert_touched"]
+        mean = pairs.mean(axis=1)
+        out = {"expert_tokens": now["expert_tokens"].tolist(),
+               "expert_touched": now["expert_touched"].tolist(),
+               "expert_load_max_over_mean": None,
+               "experts_touched_share": None}
+        if (mean > 0).all():
+            # the busiest expert of a layer over the layer's mean, mean
+            # over the layers: 1.0 is an even load
+            out["expert_load_max_over_mean"] = float(
+                (pairs.max(axis=1) / mean).mean())
+        if d_steps > 0:
+            out["experts_touched_share"] = float(
+                touched.sum() / (d_steps * touched.size))
+        return out
 
     def perf_rates(self) -> dict:
         """Cheap live rates for ping/stats and the perf snapshot: no
@@ -880,7 +967,11 @@ class Engine:
 
     def stats(self) -> dict:
         """/stats counters: queue depth, latency percentiles, tokens/sec,
-        page-pool occupancy, preemptions, compiles per bucket."""
+        page-pool occupancy, preemptions, compiles per bucket; with routed
+        experts also `expert_tokens` (pairs per layer and expert since the
+        engine began) and, over the time since the last call,
+        `expert_load_max_over_mean` and `experts_touched_share` (of the
+        experts, in a decode step)."""
         with self._stats_lock:  # the step thread appends concurrently
             lats = sorted(self._latencies)
             w = list(self._tok_window)
@@ -896,7 +987,7 @@ class Engine:
         if len(w) >= 2 and w[-1][0] > w[0][0]:
             tps = sum(n for _, n in w[1:]) / (w[-1][0] - w[0][0])
         rates = self.perf_rates()
-        return {**self.scheduler.stats(),
+        return {**self.scheduler.stats(), **self._expert_stats(),
                 "pool": self.pool.stats(),
                 "prefix_cache": self.prefix_cache.stats()
                 if self.prefix_cache is not None else None,

@@ -1,4 +1,30 @@
-"""Decode-model adapter: GPT functional core over a paged KV cache.
+"""Decode-model adapters: a functional core over the serving cache.
+
+The cache of parts (one interface for every decode model). A model's
+cache is a dict of device arrays, and `cache_kinds` says of each part what
+it is indexed by:
+
+  "paged"  [layers of that kind, P+1, ps, ...]  per TOKEN, in pages under
+           the one page table (attention K/V). `apply_defrag` and
+           `copy_pages` move these and nothing else.
+  "slot"   [layers of that kind, S, ...]  per SLOT, i.e. per sequence (a
+           convolution's or a recurrence's state). Prefill of a request
+           writes its slot's row whole, so a slot never reads its last
+           tenant's; decode's row i is slot i. A model with such a part
+           has `slot_state` true, and cannot resume a prompt at a page
+           boundary from K/V alone: the engine refuses the prefix cache.
+  "tally"  counters the programs add to and only `Engine.stats` reads.
+
+  init_cache(num_pages, page_size, num_slots) -> cache
+  prefill(params, cache, tokens [T], true_len, page_row [M], slot)
+      -> (cache', logits [V])
+  decode(params, cache, tokens [S], positions [S], tables [S, M])
+      -> (cache', logits [S, V])
+
+`GPTDecodeModel` (below) has two paged parts and no other;
+`HybridDecodeModel` has paged, slot and tally parts.
+
+GPT functional core over a paged KV cache.
 
 Bridges `models/gpt.py` (stacked-block functional GPT) to the serving
 engine's two jitted entry points:
@@ -36,17 +62,64 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..models import lfm2 as _lfm2
 from ..models.gpt import (GPTConfig, _causal_attention, _ln,
                           decoder_tail, init_gpt_params)
 from ..ops.paged_attention import paged_attention_decode
 
-__all__ = ["GPTDecodeModel"]
+__all__ = ["CacheOfParts", "GPTDecodeModel", "HybridDecodeModel"]
 
 
-class GPTDecodeModel:
+class CacheOfParts:
+    """What every decode model's cache answers, from `cache_kinds`."""
+
+    cache_kinds: dict[str, str] = {}
+
+    def parts_of(self, kind: str) -> tuple:
+        return tuple(n for n, k in self.cache_kinds.items() if k == kind)
+
+    @property
+    def slot_state(self) -> bool:
+        """Whether some state is kept per sequence and not per token."""
+        return bool(self.parts_of("slot"))
+
+    def cache_bytes(self, cache) -> dict:
+        """Bytes held, by kind of part."""
+        out = {"paged": 0, "slot": 0, "tally": 0}
+        for name, kind in self.cache_kinds.items():
+            out[kind] += int(cache[name].nbytes)
+        return out
+
+    def apply_defrag(self, cache, mapping: dict[int, int]):
+        """Move live pages per defrag_plan's old->new mapping (host-side
+        plan, one device gather per paged part; per-slot state stays
+        where it is: slots do not move)."""
+        if not mapping:
+            return cache
+        paged = self.parts_of("paged")
+        P = cache[paged[0]].shape[1]
+        perm = list(range(P))
+        for old, new in mapping.items():
+            perm[new] = old
+        perm = jnp.asarray(perm, jnp.int32)
+        return {**cache, **{n: cache[n][:, perm] for n in paged}}
+
+    def copy_pages(self, cache, src, dst):
+        """Copy page contents src[i] -> dst[i] in every paged part: the
+        copy-on-write step for a full-prompt bootstrap admission (one
+        small device gather/scatter per part, outside jit)."""
+        src = jnp.asarray(src, jnp.int32)
+        dst = jnp.asarray(dst, jnp.int32)
+        return {**cache, **{n: cache[n].at[:, dst].set(cache[n][:, src])
+                            for n in self.parts_of("paged")}}
+
+
+class GPTDecodeModel(CacheOfParts):
     """Serving adapter around the functional GPT core.
 
     The engine owns jit/donation/bucketing; everything here is pure."""
+
+    cache_kinds = {"k": "paged", "v": "paged"}
 
     def __init__(self, cfg: GPTConfig, params=None, seed: int = 0,
                  attn_impl: str | None = None):
@@ -146,25 +219,15 @@ class GPTDecodeModel:
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     # -- cache ---------------------------------------------------------
-    def init_cache(self, num_pages: int, page_size: int):
-        """[L, num_pages+1, ps, H, d] zero pools (last page = trash)."""
+    def init_cache(self, num_pages: int, page_size: int,
+                   num_slots: int = 0):
+        """[L, num_pages+1, ps, H, d] zero pools (last page = trash);
+        nothing is kept per slot."""
         cfg = self.cfg
         dt = jnp.dtype(cfg.amp_dtype) if cfg.amp_dtype else jnp.float32
         shape = (cfg.num_layers, num_pages + 1, page_size,
                  cfg.num_heads, self.head_dim)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-
-    def apply_defrag(self, cache, mapping: dict[int, int]):
-        """Move live pages per defrag_plan's old->new mapping (host-side
-        plan, one device gather per pool)."""
-        if not mapping:
-            return cache
-        P = cache["k"].shape[1]
-        perm = list(range(P))
-        for old, new in mapping.items():
-            perm[new] = old
-        perm = jnp.asarray(perm, jnp.int32)
-        return {"k": cache["k"][:, perm], "v": cache["v"][:, perm]}
 
     # -- layer math (mirrors models.gpt.gpt_block_fn) -------------------
     def _qkv(self, p, h):
@@ -179,9 +242,11 @@ class GPTDecodeModel:
     # Pallas sub-blocks where they win)
 
     # -- prefill -------------------------------------------------------
-    def prefill(self, params, cache, tokens, true_len, page_row):
+    def prefill(self, params, cache, tokens, true_len, page_row,
+                slot=None):
         """tokens [T] int32 (padded bucket), true_len scalar int32,
-        page_row [M] int32 (fill = trash). Returns (cache, logits [V])."""
+        page_row [M] int32 (fill = trash); `slot` is not used (no part is
+        per slot). Returns (cache, logits [V])."""
         cfg = self.cfg
         H, d = cfg.num_heads, self.head_dim
         T = tokens.shape[0]
@@ -281,15 +346,6 @@ class GPTDecodeModel:
             @ params["wte"].T.astype(jnp.float32)
         return {"k": ck, "v": cv}, logits
 
-    def copy_pages(self, cache, src, dst):
-        """Copy page contents src[i] -> dst[i] across every layer pool —
-        the copy-on-write step for a full-prompt bootstrap admission
-        (one small device gather/scatter per pool, outside jit)."""
-        src = jnp.asarray(src, jnp.int32)
-        dst = jnp.asarray(dst, jnp.int32)
-        return {"k": cache["k"].at[:, dst].set(cache["k"][:, src]),
-                "v": cache["v"].at[:, dst].set(cache["v"][:, src])}
-
     # -- decode --------------------------------------------------------
     def decode(self, params, cache, tokens, positions, tables):
         """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
@@ -329,3 +385,165 @@ class GPTDecodeModel:
         logits = x.astype(jnp.float32) \
             @ params["wte"].T.astype(jnp.float32)
         return {"k": ck, "v": cv}, logits
+
+
+class HybridDecodeModel(CacheOfParts):
+    """Serving adapter around `models/lfm2.py`: layers of two kinds, two
+    kinds of state. Attention layers keep K and V per token in ONE fused
+    paged part `kv` [attention layers, P+1, ps, Hkv, 2d] (K | V side by
+    side: a head of 64 alone is a poor minor dimension on the chip, see
+    ops/paged_attention.py). Convolution layers keep the last K-1 gated
+    inputs per slot in `conv` [conv layers, S, K-1, D]. `routing` is
+    paged too: the experts chosen for every cached token, [1, P+1, ps x
+    expert layers x k] (what `Engine.submit(return_routing=True)` hands
+    back; 48 bytes a token here; a page's tokens lie side by side in one
+    minor dimension of 768, because the chip turns a minor dimension of
+    48 round and then copies the whole part twice a step, PR 26).
+    `expert_tokens` and `expert_touched` are tallies [expert layers, E]:
+    token-expert pairs of real tokens, and decode steps in which a live
+    slot's token reached the expert.
+
+    The three bodies here are drivers over `lfm2.apply_layers`: they
+    differ in what attention does with its state, nothing else."""
+
+    cache_kinds = {"kv": "paged", "routing": "paged", "conv": "slot",
+                   "expert_tokens": "tally", "expert_touched": "tally"}
+
+    def __init__(self, cfg: "_lfm2.LFM2Config", params=None, seed: int = 0,
+                 attn_impl: str | None = None):
+        self.cfg = cfg
+        self.params = params if params is not None \
+            else _lfm2.init_params(cfg, seed)
+        self.params = jax.tree_util.tree_map(jnp.asarray, self.params)
+        self.head_dim = cfg.head_dim
+        self.attn_impl = attn_impl      # None = auto (ops/autobench gate)
+        # no position table to run past: RoPE; the config's own ceiling
+        self.max_positions = cfg.max_position_embeddings
+
+    # -- cache ---------------------------------------------------------
+    def init_cache(self, num_pages: int, page_size: int, num_slots: int):
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        La, Lc, Lm = (cfg.layers_of(_lfm2.ATTN), cfg.layers_of(_lfm2.CONV),
+                      cfg.num_moe_layers)
+        E, k = cfg.num_experts, cfg.num_experts_per_tok
+        return {
+            "kv": jnp.zeros((La, num_pages + 1, page_size,
+                             cfg.num_key_value_heads, 2 * cfg.head_dim),
+                            dt),
+            # the paged axis second, as in every paged part
+            "routing": jnp.zeros((1, num_pages + 1, page_size * Lm * k),
+                                 jnp.int8 if E <= 127 else jnp.int16),
+            "conv": jnp.zeros((Lc, num_slots, cfg.conv_L_cache - 1,
+                               cfg.hidden_size), dt),
+            "expert_tokens": jnp.zeros((Lm, E), jnp.int32),
+            "expert_touched": jnp.zeros((Lm, E), jnp.int32),
+        }
+
+    def routing_of(self, cache, pages, length: int):
+        """The experts chosen at the first `length` cached positions of a
+        request that holds `pages`: [length, expert layers, k] (numpy).
+        Give `pages` padded to one length (the engine's page row), so that
+        the gather is one program."""
+        import numpy as np
+        got = np.asarray(cache["routing"][0, jnp.asarray(pages, jnp.int32)])
+        k = self.cfg.num_experts_per_tok
+        return got.reshape(-1, self.cfg.num_moe_layers, k)[:length]
+
+    def _pairs(self, sel, live):
+        """Token-expert pairs [expert layers, E] of the tokens marked
+        `live` [N]; sel [expert layers, N, k]."""
+        hot = jax.nn.one_hot(sel, self.cfg.num_experts, dtype=jnp.int32)
+        return jnp.sum(hot * live[None, :, None, None].astype(jnp.int32),
+                       axis=(1, 2))
+
+    def _routes(self, sel, dtype):
+        """sel [expert layers, N, k] as rows of the `routing` part."""
+        return jnp.moveaxis(sel, 0, 1).reshape(sel.shape[1], -1) \
+            .astype(dtype)
+
+    # -- prefill -------------------------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row, slot):
+        """tokens [T] int32 (padded bucket), true_len and slot scalar
+        int32, page_row [M] int32 (fill = trash). Returns (cache,
+        logits [V]) of the last real position. The slot's convolution
+        state is written whole, from positions true_len-2 and true_len-1
+        (zeros before the prompt's start)."""
+        cfg = self.cfg
+        T = tokens.shape[0]
+        ps = cache["kv"].shape[2]
+        n_pages = T // ps
+        pages = page_row[:n_pages]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, D]
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, state):
+            pool, i = state
+            kv = jnp.concatenate([k, v], axis=-1)[0]            # [T, Hkv, 2d]
+            pool = pool.at[i, pages].set(
+                kv.reshape((n_pages, ps) + kv.shape[1:]).astype(pool.dtype))
+            return _lfm2.dense_causal_attention(q, k, v, scale), (pool, i)
+
+        x, conv, pool, sel = _lfm2.apply_layers(
+            cfg, params, x, positions,
+            _lfm2.zero_conv_state(cfg, 1, cache["conv"].dtype), attend,
+            cache["kv"], lengths=jnp.reshape(true_len, (1,)))
+        xlast = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, 0,
+                                             keepdims=False)
+        logits = _lfm2.head_logits(params, xlast, cfg)
+        real = jnp.arange(T, dtype=jnp.int32) < true_len
+        rt = cache["routing"]
+        return {
+            "kv": pool,
+            "routing": rt.at[0, pages].set(
+                self._routes(sel, rt.dtype).reshape(n_pages, -1)),
+            "conv": jax.lax.dynamic_update_slice_in_dim(
+                cache["conv"], conv, slot, axis=1),
+            "expert_tokens": cache["expert_tokens"]
+            + self._pairs(sel, real),
+            "expert_touched": cache["expert_touched"],
+        }, logits
+
+    # -- decode --------------------------------------------------------
+    def decode(self, params, cache, tokens, positions, tables):
+        """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
+        inactive slots = all-trash rows with position 0). Row i is slot
+        i. Returns (cache, logits [S, V])."""
+        cfg = self.cfg
+        S = tokens.shape[0]
+        ps, trash = cache["kv"].shape[2], cache["kv"].shape[1] - 1
+        x = jnp.take(params["embed"], tokens, axis=0)[:, None]  # [S, 1, D]
+        page_of = jnp.take_along_axis(
+            tables, (positions // ps)[:, None], axis=1)[:, 0]
+        off = positions % ps
+        ctx = positions + 1
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, state):
+            pool, i = state
+            kv = jnp.concatenate([k, v], axis=-1)[:, 0]         # [S, Hkv, 2d]
+            pool = pool.at[i, page_of, off].set(kv.astype(pool.dtype))
+            a = paged_attention_decode(
+                q[:, 0], pool, None, tables, ctx, layer=i, scale=scale,
+                impl=self.attn_impl)
+            return a.reshape(S, 1, -1), (pool, i)
+
+        x, conv, pool, sel = _lfm2.apply_layers(
+            cfg, params, x, positions[:, None], cache["conv"], attend,
+            cache["kv"])
+        logits = _lfm2.head_logits(params, x[:, 0], cfg)
+        live = page_of != trash
+        hit = self._pairs(sel, live)
+        rt = cache["routing"]
+        routes = self._routes(sel, rt.dtype)                    # [S, Lm k]
+        lanes = off[:, None] * routes.shape[1] \
+            + jnp.arange(routes.shape[1], dtype=jnp.int32)
+        return {
+            "kv": pool,
+            "routing": rt.at[0, page_of[:, None], lanes].set(routes),
+            "conv": conv,
+            "expert_tokens": cache["expert_tokens"] + hit,
+            "expert_touched": cache["expert_touched"]
+            + (hit > 0).astype(jnp.int32),
+        }, logits

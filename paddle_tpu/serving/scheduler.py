@@ -190,6 +190,10 @@ class Request:
         self.prefix_match = None
         self.prefix_cow: tuple[int, int] | None = None
         self.trace_id: str | None = None  # set by Engine.submit
+        # routing replay (Engine.submit(return_routing=True)): the experts
+        # chosen at each position fed to the model, set when it finishes
+        self.return_routing = False
+        self.routing = None
         self.generated: list[int] = []
         self.status = "queued"
         self.error: str | None = None
@@ -309,6 +313,10 @@ class Scheduler:
         # shared-prefix admission: installed by the Engine when
         # PADDLE_TPU_PREFIX_CACHE_PAGES > 0 (serving/prefix_cache.py)
         self.prefix_cache = None
+        # called as before_release(req, status) while a finishing request
+        # still holds its pages, however it ends (the Engine reads a
+        # flagged request's expert routing there)
+        self.before_release = None
         # graceful drain: True = admit nothing new, finish what's here
         # (the router stops routing to a draining replica; docs/SERVING.md)
         self.draining = False
@@ -666,6 +674,8 @@ class Scheduler:
             req.prefix_cow = None
         pages = 0
         if req.table is not None:
+            if self.before_release is not None:
+                self.before_release(req, status)
             if status == "done" and self.prefix_cache is not None:
                 # retirement insert: publish prompt+generated pages so
                 # a follow-up turn reuses this conversation's KV. The

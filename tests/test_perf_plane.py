@@ -79,7 +79,7 @@ def test_engine_stats_and_kv_gauge(perf_engine):
     st = eng.stats()
     assert st["mfu"] >= 0.0
     assert st["tokens_per_s_per_chip"] >= 0.0
-    assert eng._kv_cache_bytes() > 0
+    assert eng._kv_cache_bytes()["paged"] > 0
     # the registry-side gauge reads the same engine via weakref
     dump = {m["name"]: m for m in obs.to_dict()["metrics"]}
     kv = dump["paddle_tpu_perf_kv_cache_bytes"]

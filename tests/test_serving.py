@@ -210,6 +210,59 @@ def _assert_same(impl, got, want):
                                    atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("G,d", [(4, 64), (2, 16), (1, 16)])
+def test_paged_attention_grouped_query_matches_dense(impl, G, d, fused):
+    """G query heads a KV head (LFM2: 4 of 64) against a dense float32
+    attention, KV head j serving query heads G j .. G j + G - 1; separate
+    K and V pools, and the fused [K | V] pool a head under 128 lanes
+    wants. Tolerance: float32 sums in another order."""
+    S, Hkv, P, ps, M, L = 4, 2, 12, 8, 3, 2
+    H = G * Hkv
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(S, H, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(L, P + 1, ps, Hkv, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(L, P + 1, ps, Hkv, d).astype(np.float32))
+    pt = jnp.asarray(rng.randint(0, P, (S, M)), jnp.int32)
+    ln = jnp.asarray([1, 5, 17, 24], jnp.int32)
+    pools = (jnp.concatenate([k, v], -1), None) if fused else (k, v)
+    o = _paged_impl(impl)(q, *pools, pt, ln, layer=1)
+    assert o.shape == (S, H, d)
+    for s in range(S):
+        n = int(ln[s])
+        kk = np.asarray(k)[1][np.asarray(pt)[s]].reshape(-1, Hkv, d)[:n]
+        vv = np.asarray(v)[1][np.asarray(pt)[s]].reshape(-1, Hkv, d)[:n]
+        for h in range(H):
+            sc = kk[:, h // G] @ np.asarray(q)[s, h] / np.sqrt(d)
+            pr = np.exp(sc - sc.max())
+            pr /= pr.sum()
+            np.testing.assert_allclose(np.asarray(o)[s, h],
+                                       pr @ vv[:, h // G], atol=2e-5)
+
+
+def test_paged_gate_key_tells_grouped_query_and_fused_pools_apart():
+    from paddle_tpu.ops.paged_attention import _gate_paged
+    old = ("paged_attention", "stacked", 32, 16, 128, 3073, 16, 128,
+           "bfloat16")
+    # multi-head attention keeps the key it had: a cached decision stands
+    assert _gate_paged(32, 16, 128, 3073, 16, 128, "bfloat16")[0] == old
+    assert _gate_paged(32, 16, 128, 3073, 16, 128, "bfloat16",
+                       Hkv=16)[0] == old
+    gqa = _gate_paged(64, 32, 64, 16385, 16, 256, "bfloat16", Hkv=8)[0]
+    fused = _gate_paged(64, 32, 64, 16385, 16, 256, "bfloat16", Hkv=8,
+                        fused=True)[0]
+    assert gqa[-2:] == ("kv_heads", 8) and fused[-1] == "fused"
+    assert len({old, gqa, fused}) == 3
+
+
+def test_paged_attention_refuses_heads_that_do_not_divide():
+    from paddle_tpu.ops.paged_attention import paged_attention_xla
+    q, k, v, pt, ln = _paged_args(H=4)
+    with pytest.raises(ValueError, match="KV heads"):
+        paged_attention_xla(q[:, :3], k, v, pt, ln)
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_paged_attention_stacked_pool_equals_layer_slice(impl, layer):

@@ -334,3 +334,44 @@ def test_span_ids_are_unique_across_threads():
     ids = [s.span_id for s in t.spans()]
     assert len(ids) == 8000 and len(set(ids)) == 8000
     assert all(len(i) == 16 for i in ids)
+
+
+# -- a model with per-slot state and routed experts (ISSUE 26) ---------------
+
+def test_prefill_names_its_slot_and_stats_name_the_experts_tallies():
+    from paddle_tpu.models.lfm2 import LFM2Config
+    from paddle_tpu.serving import HybridDecodeModel
+    cfg = LFM2Config.tiny()
+    eng = Engine(HybridDecodeModel(cfg, seed=0), num_slots=2, num_pages=16,
+                 page_size=8, max_seq_len=32)
+    reqs, spans = _run(eng, (_prompt(5), 3), (_prompt(9, 1), 3),
+                       (_prompt(3, 2), 2))
+    prefills = [s for s in spans if s.name == "engine.prefill"]
+    assert len(prefills) == 3
+    # the third request takes the slot the first to finish left
+    assert [p.attrs["slot"] for p in prefills[:2]] == [0, 1]
+    assert prefills[2].attrs["slot"] in (0, 1)
+    for p in prefills:
+        assert {"engine", "request", "prompt_len", "bucket",
+                "cached_tokens", "slot"} <= set(p.attrs)
+    st = eng.stats()
+    for name in ("expert_tokens", "expert_touched",
+                 "expert_load_max_over_mean", "experts_touched_share"):
+        assert name in st
+    assert np.asarray(st["expert_tokens"]).shape == (
+        cfg.num_moe_layers, cfg.num_experts)
+    assert registry.REGISTRY.get(
+        "paddle_tpu_serving_slot_state_bytes") is not None
+    # nothing is recorded per token: the same spans a step as before
+    steps = [s for s in spans if s.name == "engine.step"
+             and not s.attrs.get("idle")]
+    for stp in steps:
+        assert [k.name for k in _children(spans, stp)] == PHASES
+
+
+def test_a_model_without_experts_reports_no_tally(engine):
+    st = engine.stats()
+    assert "expert_tokens" not in st and "experts_touched_share" not in st
+    _reqs, spans = _run(engine, (_prompt(5), 2))
+    assert next(s for s in spans
+                if s.name == "engine.prefill").attrs["slot"] == 0
