@@ -24,6 +24,12 @@ drill in tests/test_router.py pins it).
 `philox_uniform_host` is the numpy mirror of the device stream — the
 unit tests pin the two against each other so the device implementation
 can never drift silently.
+
+The draw needs each row sorted, and the sort carries the values: one
+stable `lax.sort` of (negated logits, indices) gives the sorted row and
+the order together. Nothing of `[S, V]` is gathered afterwards (on a TPU
+an element gather of that size cost ten times the sort itself, PERF.md
+section 6, PR 27); the one gather left picks `S` tokens out of the order.
 """
 from __future__ import annotations
 
@@ -158,8 +164,12 @@ def sample_tokens(logits, temps, topks, topps, seeds, steps):
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     V = logits.shape[-1]
     scaled = logits / jnp.where(temps > 0, temps, 1.0)[:, None]
-    order = jnp.argsort(-scaled, axis=-1)            # descending, stable
-    sl = jnp.take_along_axis(scaled, order, axis=-1)
+    # descending by the negated key, ties by index (stable): the sorted
+    # row is the first result negated back, not a gather through `order`
+    iota = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+    neg_sl, order = jax.lax.sort((-scaled, iota), dimension=1, num_keys=1,
+                                 is_stable=True)
+    sl = -neg_sl
     probs = jax.nn.softmax(sl, axis=-1)
     k_eff = jnp.where(topks > 0, jnp.clip(topks, 1, V), V)
     rank = jnp.arange(V, dtype=jnp.int32)[None, :]

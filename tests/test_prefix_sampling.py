@@ -25,8 +25,9 @@ from paddle_tpu.serving import (Engine, GPTDecodeModel, PagePool,
                                 ServingServer, TrafficConfig, defrag_plan,
                                 derive_seed)
 from paddle_tpu.serving.loadgen import LoadGenerator
-from paddle_tpu.serving.sampling import (_philox4, philox_uniform_host,
-                                         sample_tokens, seed_to_key)
+from paddle_tpu.serving.sampling import (_philox4, _uniform,
+                                         philox_uniform_host, sample_tokens,
+                                         seed_to_key)
 
 ENGINE_KW = dict(num_slots=4, num_pages=64, page_size=4, max_seq_len=48)
 
@@ -145,6 +146,103 @@ def test_sample_tokens_greedy_and_determinism():
     top4 = np.argsort(-np.asarray(logits), axis=-1)[:, :4]
     for s in range(S):
         assert int(out[s]) in top4[s]
+
+
+def _sample_tokens_parent(logits, temps, topks, topps, seeds, steps):
+    """`sample_tokens` as it stood before PR 27, body copied verbatim
+    (`argsort`, then the sorted rows fetched back by a `take_along_axis`
+    of S*V single elements): the reference the new spelling must match
+    token for token."""
+    import jax
+    import jax.numpy as jnp
+
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    V = logits.shape[-1]
+    scaled = logits / jnp.where(temps > 0, temps, 1.0)[:, None]
+    order = jnp.argsort(-scaled, axis=-1)            # descending, stable
+    sl = jnp.take_along_axis(scaled, order, axis=-1)
+    probs = jax.nn.softmax(sl, axis=-1)
+    k_eff = jnp.where(topks > 0, jnp.clip(topks, 1, V), V)
+    rank = jnp.arange(V, dtype=jnp.int32)[None, :]
+    csum = jnp.cumsum(probs, axis=-1)
+    # nucleus: keep while the mass BEFORE a token is < top_p, which
+    # always includes the crossing token (and rank 0)
+    keep = (rank < k_eff[:, None]) \
+        & ((csum - probs) < topps[:, None])
+    w = jnp.where(keep, probs, 0.0)
+    cdf = jnp.cumsum(w, axis=-1)
+    u = _uniform(jnp, seeds, steps)
+    target = u * cdf[:, -1]
+    pick = jnp.sum((cdf <= target[:, None]).astype(jnp.int32), axis=-1)
+    pick = jnp.clip(pick, 0, V - 1)   # u*total rounding up to total
+    sampled = jnp.take_along_axis(order, pick[:, None],
+                                  axis=-1)[:, 0].astype(jnp.int32)
+    return jnp.where(temps > 0, sampled, greedy)
+
+
+_EQ_TEMPS = np.asarray([0.0, 0.3, 0.8, 1.5], np.float32)
+
+
+@pytest.fixture(scope="module")
+def eq_jitted():
+    """New and old spelling, each jitted once over a column of steps: the
+    rows are sorted once a call and drawn from at every step."""
+    import jax
+    over_steps = (None,) * 5 + (0,)
+    return (jax.jit(jax.vmap(sample_tokens, over_steps)),
+            jax.jit(jax.vmap(_sample_tokens_parent, over_steps)))
+
+
+def _eq_rows(rng, V):
+    """Eight kinds of row: plain, spread, near flat, peaked, a block of
+    equal logits on top, an all-equal row, a coarse grid (ties all over),
+    and the negative zero beside the positive."""
+    rows = rng.standard_normal((8, V)).astype(np.float32)
+    rows[1] *= 4.0
+    rows[2] *= 1e-3
+    rows[3, rng.integers(V)] += 25.0
+    block = rng.choice(V, size=min(V, 37), replace=False)
+    rows[4, block] = rows[4].max() + 0.5
+    rows[5] = rows[5, 0]
+    rows[6] = np.round(rows[6] * 2.0) / 2.0
+    rows[7, ::2] = np.where(rows[7, ::2] > 0, 0.0, -0.0)
+    return rows
+
+
+@pytest.mark.parametrize("top_p", [0.1, 0.9, 1.0])
+@pytest.mark.parametrize("top_k", [0, 1, 5, "V", "V+9"])
+@pytest.mark.parametrize("V", [257, 4096, 50304])
+def test_sample_tokens_matches_the_gathering_spelling(eq_jitted, V, top_k,
+                                                      top_p):
+    """PR 27's contract: the sort that carries the values returns what
+    `argsort` + `take_along_axis` returned, token for token, greedy and
+    sampled, ties broken by index. A case is 52 steps: four batches of
+    the eight rows, 13 steps each, the four temperatures mixed in every
+    batch and moved on by one row from batch to batch."""
+    import jax.numpy as jnp
+
+    new_fn, old_fn = eq_jitted
+    k = {"V": V, "V+9": V + 9}.get(top_k, top_k)
+    rng = np.random.default_rng([V, k, int(top_p * 10)])
+    S, per_batch = 8, 13
+    topks = jnp.full((S,), k, jnp.int32)
+    topps = jnp.full((S,), top_p, jnp.float32)
+    picked = set()
+    for batch in range(4):
+        temps = np.roll(np.tile(_EQ_TEMPS, 2), batch)
+        seeds = np.stack([seed_to_key(int(x)) for x in
+                          rng.integers(0, 2 ** 63, size=S)])
+        steps = batch * per_batch + np.arange(per_batch, dtype=np.int32)
+        args = (jnp.asarray(_eq_rows(rng, V)), jnp.asarray(temps), topks,
+                topps, jnp.asarray(seeds),
+                jnp.asarray(np.repeat(steps[:, None], S, axis=1)))
+        new, old = np.asarray(new_fn(*args)), np.asarray(old_fn(*args))
+        assert new.shape == (per_batch, S)
+        np.testing.assert_array_equal(new, old, err_msg=f"batch {batch}")
+        picked.update(new[:, temps > 0].ravel().tolist())
+    # the draws moved: a case that kept returning one token shows nothing
+    if k != 1:
+        assert len(picked) > 8
 
 
 # ---------------------------------------------------------------------------
