@@ -44,6 +44,7 @@ from ..observability import (debug as _debug, flight as _flight,
                              registry as _obs, tracing as _tracing,
                              watchdog as _watchdog)
 from .kv_cache import PagePool, defrag_plan
+from .model import DecodeModel
 from .prefix_cache import PrefixCache
 from .sampling import sample_tokens, seed_to_key
 from .scheduler import QueueFull, Request, Scheduler
@@ -132,6 +133,10 @@ class Engine:
                  prefix_cache_pages: int | None = None):
         import jax
 
+        if not isinstance(model, DecodeModel):
+            raise TypeError(
+                f"Engine serves a paddle_tpu.serving.DecodeModel; got "
+                f"{type(model).__module__}.{type(model).__qualname__}")
         self.model = model
         self.eos_id = eos_id
         self.page_size = page_size
@@ -140,10 +145,8 @@ class Engine:
         # MODEL position limit) — without the model term a request could
         # decode past wpe and jnp.take would clip instead of erroring,
         # returning garbage tokens with status "done"
-        model_cap = getattr(model, "max_positions", None)
         cap = min(max_seq_len or num_pages * page_size,
-                  num_pages * page_size,
-                  model_cap if model_cap else num_pages * page_size)
+                  num_pages * page_size, model.max_positions)
         # floor to a page multiple: prefill buckets are page-aligned and
         # must never pad past the model's position table
         if cap < page_size:
@@ -159,7 +162,7 @@ class Engine:
         self.scheduler = Scheduler(self.pool, num_slots, self.max_seq_len,
                                    max_queue=max_queue,
                                    inst=self.engine_id)
-        if hasattr(model, "routing_of"):
+        if model.has_routing:
             # weakly: the engine owns the scheduler, not the other way
             keep = weakref.WeakMethod(self._keep_routing)
             self.scheduler.before_release = \
@@ -173,7 +176,7 @@ class Engine:
             prefix_cache_pages = int(os.environ.get(
                 "PADDLE_TPU_PREFIX_CACHE_PAGES", "0") or 0)
         self.prefix_cache = None
-        if prefix_cache_pages > 0 and getattr(model, "slot_state", False):
+        if prefix_cache_pages > 0 and not model.has_prefill_tail:
             raise ValueError(
                 "prefix_cache_pages > 0 with a model that keeps per-slot "
                 "state: a cached prefix resumes a prompt at a page "
@@ -346,7 +349,7 @@ class Engine:
         finishes, `req.routing` holds the experts chosen at each position
         it fed the model, [prompt + generated - 1, expert layers, k], read
         once from the cache's `routing` part (routing replay)."""
-        if return_routing and not hasattr(self.model, "routing_of"):
+        if return_routing and not self.model.has_routing:
             raise ValueError("return_routing needs a model with routed "
                              "experts")
         req = Request(prompt, max_new_tokens,
@@ -824,7 +827,7 @@ class Engine:
         under (serving:<eid>, bucket), analytic matmul FLOPs as the
         fallback when the backend reports no cost analysis."""
         analytic = _perf.analytic_gpt_flops(
-            getattr(self.model, "cfg", None), tokens, ctx) or None
+            self.model.cfg, tokens, ctx) or None
         fl = _perf.register_jit_cost(f"serving:{self.engine_id}", bucket,
                                      jitfn, *targs,
                                      analytic_flops=analytic)
@@ -838,23 +841,16 @@ class Engine:
 
     def _kv_cache_bytes(self) -> dict:
         """Bytes of the cache by kind of part: {"paged", "slot",
-        "tally"} (serving/model.py::CacheOfParts)."""
-        sizes = getattr(self.model, "cache_bytes", None)
-        if sizes is None:
-            # a model that keeps some other pytree: every buffer is paged
-            import jax
-            return {"paged": float(sum(
-                getattr(leaf, "nbytes", 0)
-                for leaf in jax.tree_util.tree_leaves(self.cache))),
-                "slot": 0.0, "tally": 0.0}
-        return {k: float(v) for k, v in sizes(self.cache).items()}
+        "tally"} (serving/model.py::DecodeModel)."""
+        return {k: float(v)
+                for k, v in self.model.cache_bytes(self.cache).items()}
 
     def _expert_stats(self) -> dict:
         """The experts' tallies, read from the device under the step lock
         (the step donates the cache), and what changed since the last
         read. {} for a model without routed experts, or while a step
         holds the lock for long."""
-        if "tally" not in getattr(self.model, "cache_kinds", {}).values():
+        if not self.model.parts_of("tally"):
             return {}
         if not self._lock.acquire(timeout=2.0):
             return {}

@@ -1,47 +1,10 @@
 """Decode-model adapters: a functional core over the serving cache.
 
-The cache of parts (one interface for every decode model). A model's
-cache is a dict of device arrays, and `cache_kinds` says of each part what
-it is indexed by:
-
-  "paged"  [layers of that kind, P+1, ps, ...]  per TOKEN, in pages under
-           the one page table (attention K/V). `apply_defrag` and
-           `copy_pages` move these and nothing else.
-  "slot"   [layers of that kind, S, ...]  per SLOT, i.e. per sequence (a
-           convolution's or a recurrence's state). Prefill of a request
-           writes its slot's row whole, so a slot never reads its last
-           tenant's; decode's row i is slot i. A model with such a part
-           has `slot_state` true, and cannot resume a prompt at a page
-           boundary from K/V alone: the engine refuses the prefix cache.
-  "tally"  counters the programs add to and only `Engine.stats` reads.
-
-  init_cache(num_pages, page_size, num_slots) -> cache
-  prefill(params, cache, tokens [T], true_len, page_row [M], slot)
-      -> (cache', logits [V])
-  decode(params, cache, tokens [S], positions [S], tables [S, M])
-      -> (cache', logits [S, V])
-
-`GPTDecodeModel` (below) has two paged parts and no other;
-`HybridDecodeModel` has paged, slot and tally parts.
-
-GPT functional core over a paged KV cache.
-
-Bridges `models/gpt.py` (stacked-block functional GPT) to the serving
-engine's two jitted entry points:
-
-  prefill(params, cache, tokens [T], true_len, page_row [M])
-      -> (cache', logits [V])
-    Dense causal forward over one padded prompt bucket; per-layer K/V of
-    every bucket position is scattered into the request's pages (padding
-    positions land in the pool's trash page — see below) and the logits
-    of the LAST REAL position come back for the first sampled token.
-
-  decode(params, cache, tokens [S], positions [S], tables [S, M])
-      -> (cache', logits [S, V])
-    One token for every slot of the fixed-shape slot batch: embed at
-    `positions`, per layer append K/V into the position's page, ragged
-    paged attention over each slot's own history
-    (ops/paged_attention.py), final LN + tied-embedding head.
+`DecodeModel` is what the engine asks of a model; `GPTDecodeModel` (K and V
+in two paged parts, nothing else) and `HybridDecodeModel` (paged, per-slot
+and tally parts) answer it. Each adapter's bodies are drivers over its
+architecture's layer loop (`GPTDecodeModel._layers`; `lfm2.apply_layers`):
+they say how tokens become `x`, where K/V land and what attends.
 
 Trash-page convention: the device pools carry ONE extra page at index
 `num_pages` that absorbs every masked write — padded page-table entries
@@ -51,9 +14,9 @@ garbage lands in the trash page, reads are masked by ctx_len before
 softmax). Page tables handed to these functions must therefore be
 padded with `fill=num_pages`.
 
-Numerical contract: bit-matches `models.gpt.gpt_forward` greedy decode
-when scale factors are exact binary fractions (head_dim a power of two)
-— the end-to-end parity test in tests/test_serving.py pins this.
+Numerical contract (GPT): bit-matches `models.gpt.gpt_forward` greedy
+decode when scale factors are exact binary fractions (head_dim a power of
+two) — the end-to-end parity test in tests/test_serving.py pins this.
 """
 from __future__ import annotations
 
@@ -63,17 +26,72 @@ import jax
 import jax.numpy as jnp
 
 from ..models import lfm2 as _lfm2
-from ..models.gpt import (GPTConfig, _causal_attention, _ln,
+from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
                           decoder_tail, init_gpt_params)
 from ..ops.paged_attention import paged_attention_decode
 
-__all__ = ["CacheOfParts", "GPTDecodeModel", "HybridDecodeModel"]
+__all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel"]
 
 
-class CacheOfParts:
-    """What every decode model's cache answers, from `cache_kinds`."""
+class DecodeModel:
+    """What `Engine` asks of a decode model: what it reads to serve is
+    declared here (`Engine.warm_start` besides wants `GPTDecodeModel`'s
+    checkpoint methods). The engine owns jit, donation and bucketing; the
+    bodies are pure.
+
+      cfg, params    the architecture's config, and the weight pytree handed
+                     to every body (swapped whole on a warm start)
+      max_positions  the longest sequence the model can address; the engine
+                     caps admission at it
+      init_cache(num_pages, page_size, num_slots) -> cache
+      prefill(params, cache, tokens [T], true_len, page_row [M], slot)
+          -> (cache', logits [V])   one padded prompt bucket: K/V of every
+          position into the request's pages, the logits of the LAST REAL
+          position for the first sampled token
+      decode(params, cache, tokens [S], positions [S], tables [S, M])
+          -> (cache', logits [S, V])   one token for every slot of the
+          fixed-shape slot batch (inactive slots: all-trash rows, position 0)
+      has_prefill_tail   the model can resume a prompt at a page boundary
+          from the pages alone: prefill_tail(params, cache, tokens [T], start,
+          true_len, page_row [M]) -> (cache', logits [V]). The prefix cache
+          needs it; a model with per-slot state cannot have it.
+      has_routing        the model records the experts chosen for every
+          cached token: routing_of(cache, pages, length) (`Engine.submit(
+          return_routing=True)`)
+
+    `cache'` has the keys, shapes and dtypes of `cache`: the engine donates
+    it. The cache is a dict of device arrays, and `cache_kinds` says of each
+    part what it is indexed by:
+
+      "paged"  [layers of that kind, P+1, ps, ...]  per TOKEN, in pages under
+               the one page table (attention K/V). `apply_defrag` and
+               `copy_pages` move these and nothing else.
+      "slot"   [layers of that kind, S, ...]  per SLOT, i.e. per sequence (a
+               convolution's or a recurrence's state). Prefill of a request
+               writes its slot's row whole, so a slot never reads its last
+               tenant's; decode's row i is slot i.
+      "tally"  counters the programs add to and only `Engine.stats` reads.
+    """
 
     cache_kinds: dict[str, str] = {}
+    has_prefill_tail = False
+    has_routing = False
+
+    def __init__(self, cfg, params, attn_impl: str | None = None):
+        self.cfg = cfg
+        self.params = jax.tree_util.tree_map(jnp.asarray, params)
+        self.attn_impl = attn_impl      # None = auto (ops/autobench gate)
+        # GPT: positions past wpe would silently clip under jnp.take
+        self.max_positions = cfg.max_position_embeddings
+
+    def init_cache(self, num_pages: int, page_size: int, num_slots: int = 0):
+        raise NotImplementedError
+
+    def prefill(self, params, cache, tokens, true_len, page_row, slot=None):
+        raise NotImplementedError
+
+    def decode(self, params, cache, tokens, positions, tables):
+        raise NotImplementedError
 
     def parts_of(self, kind: str) -> tuple:
         return tuple(n for n, k in self.cache_kinds.items() if k == kind)
@@ -114,24 +132,18 @@ class CacheOfParts:
                             for n in self.parts_of("paged")}}
 
 
-class GPTDecodeModel(CacheOfParts):
-    """Serving adapter around the functional GPT core.
-
-    The engine owns jit/donation/bucketing; everything here is pure."""
+class GPTDecodeModel(DecodeModel):
+    """Serving adapter around the functional GPT core (`models/gpt.py`):
+    `prefill`, `prefill_tail` and `decode` are drivers over `_layers`."""
 
     cache_kinds = {"k": "paged", "v": "paged"}
+    has_prefill_tail = True
 
     def __init__(self, cfg: GPTConfig, params=None, seed: int = 0,
                  attn_impl: str | None = None):
-        self.cfg = cfg
-        self.params = params if params is not None \
-            else init_gpt_params(cfg, seed)
-        self.params = jax.tree_util.tree_map(jnp.asarray, self.params)
+        super().__init__(cfg, params if params is not None
+                         else init_gpt_params(cfg, seed), attn_impl)
         self.head_dim = cfg.hidden_size // cfg.num_heads
-        self.attn_impl = attn_impl  # None = auto (ops/autobench gate)
-        # the engine caps admission at this (positions past wpe would
-        # silently clip under jnp.take)
-        self.max_positions = cfg.max_position_embeddings
 
     # -- checkpoint warm-start (paddle_tpu.checkpoint) ------------------
     def save_checkpoint(self, root: str, step: int | None = None) -> int:
@@ -229,60 +241,90 @@ class GPTDecodeModel(CacheOfParts):
                  cfg.num_heads, self.head_dim)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
-    # -- layer math (mirrors models.gpt.gpt_block_fn) -------------------
-    def _qkv(self, p, h):
-        q = h @ p["wq"] + p["bq"]
-        k = h @ p["wk"] + p["bk"]
-        v = h @ p["wv"] + p["bv"]
-        return q, k, v
-
-    # (the post-attention tail — out-projection + residual + LN2 + FFN —
-    # is models.gpt.decoder_tail: one source of truth with training, and
-    # the serving decode path reuses the same autobench-gated fused
-    # Pallas sub-blocks where they win)
-
-    # -- prefill -------------------------------------------------------
-    def prefill(self, params, cache, tokens, true_len, page_row,
-                slot=None):
-        """tokens [T] int32 (padded bucket), true_len scalar int32,
-        page_row [M] int32 (fill = trash); `slot` is not used (no part is
-        per slot). Returns (cache, logits [V])."""
+    # -- the layer loop ------------------------------------------------
+    def _layers(self, params, cache, x, write, attend):
+        """x [N, D] through every block, in one scan over the stacked
+        blocks. Per layer l: LN1, q/k/v, `write(ck, cv, l, k, v) -> (ck,
+        cv)` puts the layer's K and V [N, D] into the stacked pools,
+        `attend(q, k, v, ck, cv, l) -> [N, D]`, then models.gpt's
+        post-attention tail (out-projection + residual + LN2 + FFN: one
+        source of truth with training, gate-chosen fused sub-blocks
+        included). Returns (x, cache)."""
         cfg = self.cfg
-        H, d = cfg.num_heads, self.head_dim
-        T = tokens.shape[0]
-        ps = cache["k"].shape[2]
-        n_pages = T // ps
-        x = jnp.take(params["wte"], tokens, axis=0) \
-            + params["wpe"][:T]                               # [T, D]
 
         def body(carry, xs):
             x, ck, cv = carry
             p, l = xs
             h = _ln(x, p["ln1_s"], p["ln1_b"], cfg.layer_norm_eps)
-            q, k, v = self._qkv(p, h)
-            kp = k.reshape(n_pages, ps, H, d).astype(ck.dtype)
-            vp = v.reshape(n_pages, ps, H, d).astype(cv.dtype)
-            ck = ck.at[l, page_row[:n_pages]].set(kp)
-            cv = cv.at[l, page_row[:n_pages]].set(vp)
+            q = h @ p["wq"] + p["bq"]
+            k = h @ p["wk"] + p["bk"]
+            v = h @ p["wv"] + p["bv"]
+            ck, cv = write(ck, cv, l, k, v)
+            x = decoder_tail(p, attend(q, k, v, ck, cv, l), x, cfg)
+            return (x, ck, cv), None
+
+        (x, ck, cv), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["blocks"], jnp.arange(cfg.num_layers)))
+        return x, {"k": ck, "v": cv}
+
+    def _write_pages(self, cache, T, pages):
+        """`write` of a bucket of T positions into whole pages. `pages()`
+        [T // ps] names them and is asked inside the loop: that is where
+        `prefill` has always sliced its page row, and the lowered programs
+        are held byte for byte (scripts/lowered_serving_programs.py)."""
+        ps = cache["k"].shape[2]
+        shape = (T // ps, ps, self.cfg.num_heads, self.head_dim)
+
+        def write(ck, cv, l, k, v):
+            kp = k.reshape(shape).astype(ck.dtype)
+            vp = v.reshape(shape).astype(cv.dtype)
+            return ck.at[l, pages()].set(kp), cv.at[l, pages()].set(vp)
+        return write
+
+    def _paged(self, tables, ctx):
+        """`attend` of N single positions over their own cached history:
+        ragged paged attention (ops/paged_attention.py), tables [N, M], ctx
+        [N] tokens visible to each (its own K/V, just written, included)."""
+        H, d = self.cfg.num_heads, self.head_dim
+
+        def attend(q, k, v, ck, cv, l):
+            N = q.shape[0]
+            a = paged_attention_decode(
+                q.reshape(N, H, d), ck, cv, tables, ctx, layer=l,
+                scale=1.0 / math.sqrt(d), impl=self.attn_impl)
+            return a.reshape(N, -1)
+        return attend
+
+    def _last_logits(self, params, x, true_len):
+        """Logits [V] of a bucket's last real position."""
+        xlast = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
+                                             keepdims=False)
+        return _head(params, xlast, self.cfg)
+
+    # -- prefill -------------------------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row,
+                slot=None):
+        """tokens [T] int32 (padded bucket), true_len scalar int32,
+        page_row [M] int32 (fill = trash; padding positions land in the
+        trash page); `slot` is not used (no part is per slot). Dense
+        causal forward. Returns (cache, logits [V])."""
+        T = tokens.shape[0]
+        n_pages = T // cache["k"].shape[2]
+        x = jnp.take(params["wte"], tokens, axis=0) \
+            + params["wpe"][:T]                               # [T, D]
+
+        def attend(q, k, v, ck, cv, l):
             # ONE source of truth for the dense math: the serving parity
             # contract (prefill == models.gpt forward, bit-for-bit) holds
             # by construction, not by a hand-mirrored copy
-            a = _causal_attention(q[None], k[None], v[None], H,
-                                  impl="xla")[0]
-            x = decoder_tail(p, a, x, cfg)
-            return (x, ck, cv), None
+            return _causal_attention(q[None], k[None], v[None],
+                                     self.cfg.num_heads, impl="xla")[0]
 
-        L = cfg.num_layers
-        (x, ck, cv), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["blocks"], jnp.arange(L)))
-        xlast = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
-                                             keepdims=False)
-        xlast = _ln(xlast, params["lnf_s"], params["lnf_b"],
-                    cfg.layer_norm_eps)
-        logits = xlast.astype(jnp.float32) \
-            @ params["wte"].T.astype(jnp.float32)
-        return {"k": ck, "v": cv}, logits
+        x, cache = self._layers(
+            params, cache, x,
+            self._write_pages(cache, T, lambda: page_row[:n_pages]), attend)
+        return cache, self._last_logits(params, x, true_len)
 
     # -- tail prefill (shared-prefix admission) ------------------------
     def prefill_tail(self, params, cache, tokens, start, true_len,
@@ -301,12 +343,8 @@ class GPTDecodeModel(CacheOfParts):
         ctx = position + 1 — so the greedy-parity contract the decode
         path pins (bit-match vs the dense forward) carries over to
         shared-prefix admissions unchanged."""
-        import jax
-        cfg = self.cfg
-        H, d = cfg.num_heads, self.head_dim
         T = tokens.shape[0]
         ps = cache["k"].shape[2]
-        n_pages = T // ps
         positions = start + jnp.arange(T, dtype=jnp.int32)
         x = jnp.take(params["wte"], tokens, axis=0) \
             + jnp.take(params["wpe"], positions, axis=0)       # [T, D]
@@ -317,42 +355,21 @@ class GPTDecodeModel(CacheOfParts):
         ctx = positions + 1
         # the tail's own pages: page_row[start//ps : start//ps + T//ps]
         tail_pages = jax.lax.dynamic_slice_in_dim(
-            page_row, start // ps, n_pages)
-
-        def body(carry, xs):
-            x, ck, cv = carry
-            p, l = xs
-            h = _ln(x, p["ln1_s"], p["ln1_b"], cfg.layer_norm_eps)
-            q, k, v = self._qkv(p, h)
-            kp = k.reshape(n_pages, ps, H, d).astype(ck.dtype)
-            vp = v.reshape(n_pages, ps, H, d).astype(cv.dtype)
-            ck = ck.at[l, tail_pages].set(kp)
-            cv = cv.at[l, tail_pages].set(vp)
-            a = paged_attention_decode(
-                q.reshape(T, H, d), ck, cv, tables, ctx, layer=l,
-                scale=1.0 / math.sqrt(d), impl=self.attn_impl)
-            x = decoder_tail(p, a.reshape(T, -1), x, cfg)
-            return (x, ck, cv), None
-
-        L = cfg.num_layers
-        (x, ck, cv), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["blocks"], jnp.arange(L)))
-        xlast = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
-                                             keepdims=False)
-        xlast = _ln(xlast, params["lnf_s"], params["lnf_b"],
-                    cfg.layer_norm_eps)
-        logits = xlast.astype(jnp.float32) \
-            @ params["wte"].T.astype(jnp.float32)
-        return {"k": ck, "v": cv}, logits
+            page_row, start // ps, T // ps)
+        x, cache = self._layers(
+            params, cache, x,
+            self._write_pages(cache, T, lambda: tail_pages),
+            self._paged(tables, ctx))
+        return cache, self._last_logits(params, x, true_len)
 
     # -- decode --------------------------------------------------------
     def decode(self, params, cache, tokens, positions, tables):
         """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
-        inactive slots = all-trash rows with position 0). Returns
-        (cache, logits [S, V])."""
-        cfg = self.cfg
-        H, d = cfg.num_heads, self.head_dim
+        inactive slots = all-trash rows with position 0): embed at
+        `positions`, append each slot's K/V at its position's page and
+        offset, attend over its own history. Returns (cache, logits
+        [S, V])."""
+        H, d = self.cfg.num_heads, self.head_dim
         S = tokens.shape[0]
         ps = cache["k"].shape[2]
         x = jnp.take(params["wte"], tokens, axis=0) \
@@ -362,32 +379,19 @@ class GPTDecodeModel(CacheOfParts):
         off = positions % ps
         ctx = positions + 1
 
-        def body(carry, xs):
-            x, ck, cv = carry
-            p, l = xs
-            h = _ln(x, p["ln1_s"], p["ln1_b"], cfg.layer_norm_eps)
-            q, k, v = self._qkv(p, h)
+        def write(ck, cv, l, k, v):
             ck = ck.at[l, page_of, off].set(
                 k.reshape(S, H, d).astype(ck.dtype))
             cv = cv.at[l, page_of, off].set(
                 v.reshape(S, H, d).astype(cv.dtype))
-            a = paged_attention_decode(
-                q.reshape(S, H, d), ck, cv, tables, ctx, layer=l,
-                scale=1.0 / math.sqrt(d), impl=self.attn_impl)
-            x = decoder_tail(p, a.reshape(S, -1), x, cfg)
-            return (x, ck, cv), None
+            return ck, cv
 
-        L = cfg.num_layers
-        (x, ck, cv), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["blocks"], jnp.arange(L)))
-        x = _ln(x, params["lnf_s"], params["lnf_b"], cfg.layer_norm_eps)
-        logits = x.astype(jnp.float32) \
-            @ params["wte"].T.astype(jnp.float32)
-        return {"k": ck, "v": cv}, logits
+        x, cache = self._layers(params, cache, x, write,
+                                self._paged(tables, ctx))
+        return cache, _head(params, x, self.cfg)
 
 
-class HybridDecodeModel(CacheOfParts):
+class HybridDecodeModel(DecodeModel):
     """Serving adapter around `models/lfm2.py`: layers of two kinds, two
     kinds of state. Attention layers keep K and V per token in ONE fused
     paged part `kv` [attention layers, P+1, ps, Hkv, 2d] (K | V side by
@@ -408,17 +412,15 @@ class HybridDecodeModel(CacheOfParts):
 
     cache_kinds = {"kv": "paged", "routing": "paged", "conv": "slot",
                    "expert_tokens": "tally", "expert_touched": "tally"}
+    has_routing = True
 
     def __init__(self, cfg: "_lfm2.LFM2Config", params=None, seed: int = 0,
                  attn_impl: str | None = None):
-        self.cfg = cfg
-        self.params = params if params is not None \
-            else _lfm2.init_params(cfg, seed)
-        self.params = jax.tree_util.tree_map(jnp.asarray, self.params)
+        # no position table to run past: RoPE; max_positions is the
+        # config's own ceiling
+        super().__init__(cfg, params if params is not None
+                         else _lfm2.init_params(cfg, seed), attn_impl)
         self.head_dim = cfg.head_dim
-        self.attn_impl = attn_impl      # None = auto (ops/autobench gate)
-        # no position table to run past: RoPE; the config's own ceiling
-        self.max_positions = cfg.max_position_embeddings
 
     # -- cache ---------------------------------------------------------
     def init_cache(self, num_pages: int, page_size: int, num_slots: int):

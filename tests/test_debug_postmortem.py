@@ -387,30 +387,6 @@ def test_prefill_only_traffic_is_progress_not_a_stall(monkeypatch):
         eng.stop()
 
 
-class _WedgedModel:
-    """Model wrapper whose decode blocks until released — a wedged
-    jitted step, the serving tier's watchdog target."""
-
-    def __init__(self, inner):
-        self._inner = inner
-        self.release = threading.Event()
-        for a in ("params", "max_positions"):
-            if hasattr(inner, a):
-                setattr(self, a, getattr(inner, a))
-
-    def init_cache(self, *a, **k):
-        return self._inner.init_cache(*a, **k)
-
-    def prefill(self, *a, **k):
-        return self._inner.prefill(*a, **k)
-
-    def decode(self, *a, **k):
-        # block OUTSIDE the trace (fixture engines compile eagerly
-        # enough); a hung host callback models a wedged device step
-        self.release.wait()
-        return self._inner.decode(*a, **k)
-
-
 def test_wedged_engine_detected_with_trace_keyed_timeline(tmp_path,
                                                           monkeypatch):
     """Acceptance e2e: a wedged serving engine is detected by the
@@ -422,8 +398,19 @@ def test_wedged_engine_detected_with_trace_keyed_timeline(tmp_path,
     from paddle_tpu.serving import Engine, GPTDecodeModel
 
     monkeypatch.setenv("PADDLE_TPU_WATCHDOG_DEADLINE", "0.3")
-    inner = GPTDecodeModel(GPTConfig.tiny(num_layers=1), seed=0)
-    model = _WedgedModel(inner)
+
+    class Wedged(GPTDecodeModel):
+        """decode blocks until released — a wedged jitted step, the
+        serving tier's watchdog target."""
+        release = threading.Event()
+
+        def decode(self, *a, **k):
+            # block OUTSIDE the trace (fixture engines compile eagerly
+            # enough); a hung host callback models a wedged device step
+            self.release.wait()
+            return super().decode(*a, **k)
+
+    model = Wedged(GPTConfig.tiny(num_layers=1), seed=0)
     eng = Engine(model, num_slots=2, num_pages=16, page_size=4,
                  max_seq_len=32)
     token = f"serving.engine.{eng.engine_id}"

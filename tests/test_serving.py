@@ -314,6 +314,21 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
+def _gpt_body_jaxpr(model, entry, P=10, ps=8, S=4, M=3, T=16):
+    """The jaxpr of one of `GPTDecodeModel`'s three bodies at small
+    shapes: a pool of P pages of ps, S slots or a bucket of T tokens."""
+    head = (model.params, model.init_cache(P, ps))
+    toks, row = jnp.zeros((T,), jnp.int32), jnp.arange(M + 1, dtype=jnp.int32)
+    targs = {
+        "prefill": (*head, toks, jnp.int32(T - 3), row),
+        "prefill_tail": (*head, toks, jnp.int32(ps), jnp.int32(T), row),
+        "decode": (*head, jnp.zeros((S,), jnp.int32),
+                   jnp.arange(S, dtype=jnp.int32),
+                   jnp.full((S, M), P, jnp.int32)),
+    }[entry]
+    return jax.make_jaxpr(getattr(model, entry))(*targs)
+
+
 @pytest.mark.parametrize("entry", ["decode", "prefill_tail"])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_decode_bodies_never_make_one_layers_pool(impl, entry):
@@ -323,25 +338,41 @@ def test_decode_bodies_never_make_one_layers_pool(impl, entry):
     201 MB for K and for V in each of 24 layers of every decode step."""
     cfg = GPTConfig.tiny(num_layers=3)
     model = GPTDecodeModel(cfg, seed=0, attn_impl=impl)
-    P, ps, S, M, T = 10, 8, 4, 3, 16
-    cache = model.init_cache(P, ps)
-    pool = cache["k"].shape[1:]
-    assert pool == (P + 1, ps, cfg.num_heads, model.head_dim)
-    if entry == "decode":
-        jaxpr = jax.make_jaxpr(model.decode)(
-            model.params, cache, jnp.zeros((S,), jnp.int32),
-            jnp.arange(S, dtype=jnp.int32),
-            jnp.full((S, M), P, jnp.int32))
-    else:
-        jaxpr = jax.make_jaxpr(model.prefill_tail)(
-            model.params, cache, jnp.zeros((T,), jnp.int32),
-            jnp.int32(ps), jnp.int32(T), jnp.arange(M + 1, dtype=jnp.int32))
+    P, ps = 10, 8
+    pool = (P + 1, ps, cfg.num_heads, model.head_dim)
+    assert model.init_cache(P, ps)["k"].shape[1:] == pool
+    jaxpr = _gpt_body_jaxpr(model, entry, P=P, ps=ps)
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1          # the layer loop is what is walked
     made = [(e.primitive.name, v.aval.shape)
             for e in _eqns(scans[0].params["jaxpr"].jaxpr)
             for v in e.outvars if getattr(v.aval, "shape", None) == pool]
     assert not made, made
+
+
+@pytest.mark.parametrize("entry", ["prefill", "prefill_tail", "decode"])
+def test_gpt_bodies_are_drivers_over_one_layer_loop(entry, monkeypatch):
+    """`GPTDecodeModel` spells its layer once: every body's program holds
+    exactly one scan, of `num_layers` steps, whose body is `_layers`'; the
+    two paged bodies reach `paged_attention_decode` through the one
+    `attend` of `_paged`, and the dense one does not reach it."""
+    import sys
+    from paddle_tpu.serving import model as model_mod
+    callers = {"decoder_tail": [], "paged_attention_decode": []}
+    for name in callers:
+        def spy(*a, _name=name, _real=getattr(model_mod, name), **kw):
+            callers[_name].append(sys._getframe(1).f_code.co_qualname)
+            return _real(*a, **kw)
+        monkeypatch.setattr(model_mod, name, spy)
+    cfg = GPTConfig.tiny(num_layers=3)
+    jaxpr = _gpt_body_jaxpr(GPTDecodeModel(cfg, seed=0, attn_impl="xla"),
+                            entry)
+    scans = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [cfg.num_layers]
+    assert callers["decoder_tail"] == ["GPTDecodeModel._layers.<locals>.body"]
+    assert callers["paged_attention_decode"] == (
+        [] if entry == "prefill"
+        else ["GPTDecodeModel._paged.<locals>.attend"])
 
 
 def _engine_programs(family):
@@ -617,6 +648,23 @@ def test_engine_caps_sequence_at_model_positions():
         eng.submit([1] * 100, 40)              # 140 > 128
     with pytest.raises(ValueError, match="sequence ceiling"):
         Engine(model, num_slots=1, num_pages=4, page_size=256)
+
+
+def test_engine_refuses_what_is_not_a_decode_model():
+    """What `Engine` reads of a model is `DecodeModel`'s to declare: an
+    object with the right method names and no such base is refused when
+    the engine is built, by name, not at the first missing attribute."""
+    class Lookalike:
+        max_positions = 128
+        params = {}
+
+        def init_cache(self, *a):
+            return {}
+
+        prefill = decode = init_cache
+
+    with pytest.raises(TypeError, match="DecodeModel.*Lookalike"):
+        Engine(Lookalike(), num_slots=1, num_pages=4, page_size=4)
 
 
 def test_engine_poison_request_fails_alone(tiny_engine):

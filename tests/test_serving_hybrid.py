@@ -13,7 +13,7 @@ import pytest
 from benchmark.reference import lfm2_moe as ref
 from paddle_tpu.models import lfm2
 from paddle_tpu.models.gpt import GPTConfig
-from paddle_tpu.serving import (CacheOfParts, Engine, GPTDecodeModel,
+from paddle_tpu.serving import (DecodeModel, Engine, GPTDecodeModel,
                                 HybridDecodeModel)
 from tests.test_lfm2_model import sizes_of
 
@@ -155,21 +155,23 @@ def test_routing_is_handed_back_however_the_request_ends(served, ending):
     assert float(short.max()) < 1e-5
 
 
-def test_cache_bytes_of_a_model_that_keeps_another_pytree():
-    """A duck-typed model (tests wrap one) has no `cache_bytes`: the gauge
-    counts every buffer as paged, as before the cache had parts."""
-    inner = GPTDecodeModel(GPTConfig.tiny(num_layers=1))
+def test_cache_bytes_of_a_model_that_wraps_another():
+    """A model that wraps one (tests do) subclasses it, so it answers the
+    engine's questions as its parent does: the gauge counts its cache by
+    kind of part. (A duck-typed look-alike is refused when the engine is
+    built: tests/test_serving.py.)"""
+    class Wrapped(GPTDecodeModel):
+        def decode(self, *a):
+            return super().decode(*a)
 
-    class Wrapped:
-        cfg, params, head_dim = inner.cfg, inner.params, inner.head_dim
-        max_positions = inner.max_positions
-        init_cache, prefill, decode = (inner.init_cache, inner.prefill,
-                                       inner.decode)
-
-    eng = Engine(Wrapped(), num_slots=2, num_pages=8, page_size=8)
+    eng = Engine(Wrapped(GPTConfig.tiny(num_layers=1)), num_slots=2,
+                 num_pages=8, page_size=8)
     want = sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.cache))
     assert eng._kv_cache_bytes() == {"paged": float(want), "slot": 0.0,
                                      "tally": 0.0}
+    req = eng.submit([1, 2, 3], 2)
+    eng.run_until_idle()
+    assert req.status == "done" and len(req.generated) == 2
 
 
 def test_return_routing_needs_routed_experts():
@@ -210,25 +212,64 @@ def test_defrag_moves_the_pages_and_leaves_the_slots(served):
     assert run(defrag=True) == run(defrag=False)
 
 
-def test_both_decode_models_answer_one_cache_interface(served):
+@pytest.mark.parametrize("which", ["gpt", "hybrid"])
+def test_both_decode_models_answer_one_cache_interface(served, which):
+    """Everything `Engine` reads of a model, `DecodeModel` declares and
+    both models answer; the bodies hand back the cache they were given
+    (keys, shapes, dtypes), which donation relies on."""
     cfg, _sizes, params, _eng, _seen = served
-    gpt = GPTDecodeModel(GPTConfig.tiny(num_layers=1))
-    hyb = HybridDecodeModel(cfg, params=params)
-    for model, slot in ((gpt, False), (hyb, True)):
-        assert isinstance(model, CacheOfParts)
-        cache = model.init_cache(8, 4, 3)
-        assert set(cache) == set(model.cache_kinds)
-        assert model.slot_state is slot
-        size = model.cache_bytes(cache)
-        assert size["paged"] > 0 and (size["slot"] > 0) is slot
-        for name in model.parts_of("paged"):
-            assert cache[name].shape[1] == 9            # P+1 pages
-        for name in model.parts_of("slot"):
-            assert cache[name].shape[1] == 3            # one row a slot
-        copied = model.copy_pages(
-            {k: v + 1 if k in model.parts_of("paged") else v
-             for k, v in cache.items()}, [0], [5])
-        assert set(copied) == set(cache)
+    model = GPTDecodeModel(GPTConfig.tiny(num_layers=1)) if which == "gpt" \
+        else HybridDecodeModel(cfg, params=params)
+    slot = which == "hybrid"
+    assert isinstance(model, DecodeModel)
+    assert model.cfg is not None and model.params
+    assert model.max_positions == model.cfg.max_position_embeddings
+    # the two optional capabilities, and the methods behind them
+    assert model.has_prefill_tail is (not slot) \
+        is hasattr(model, "prefill_tail")
+    assert model.has_routing is slot is hasattr(model, "routing_of")
+    assert not (model.has_prefill_tail and model.slot_state)
+    cache = model.init_cache(8, 4, 3)
+    assert set(cache) == set(model.cache_kinds)
+    assert set(model.cache_kinds.values()) <= {"paged", "slot", "tally"}
+    assert model.slot_state is slot
+    size = model.cache_bytes(cache)
+    assert size["paged"] > 0 and (size["slot"] > 0) is slot
+    for name in model.parts_of("paged"):
+        assert cache[name].shape[1] == 9            # P+1 pages
+    for name in model.parts_of("slot"):
+        assert cache[name].shape[1] == 3            # one row a slot
+    copied = model.copy_pages(
+        {k: v + 1 if k in model.parts_of("paged") else v
+         for k, v in cache.items()}, [0], [5])
+    assert set(copied) == set(cache)
+
+    def like(tree):
+        return jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), tree)
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    V = model.cfg.vocab_size
+    bodies = {"prefill": (i32(8), i32(), i32(4), i32()),
+              "decode": (i32(3), i32(3), i32(3, 4))}
+    if model.has_prefill_tail:
+        bodies["prefill_tail"] = (i32(8), i32(), i32(), i32(4))
+    for name, targs in bodies.items():
+        out, logits = jax.eval_shape(getattr(model, name), model.params,
+                                     cache, *targs)
+        assert like(out) == like(cache), name
+        assert logits.shape == ((3, V) if name == "decode" else (V,))
+        assert logits.dtype == jnp.float32
+
+
+def test_the_protocol_base_answers_for_no_model():
+    base = DecodeModel(GPTConfig.tiny(), params={})
+    assert not base.has_prefill_tail and not base.has_routing
+    assert base.cache_kinds == {} and not base.slot_state
+    for call in (lambda: base.init_cache(8, 4, 3),
+                 lambda: base.prefill({}, {}, None, None, None, None),
+                 lambda: base.decode({}, {}, None, None, None)):
+        with pytest.raises(NotImplementedError):
+            call()
 
 
 def test_gauges_report_paged_and_per_slot_bytes_apart(served):
