@@ -164,10 +164,19 @@ class Span:
                 "pid": os.getpid(), "tid": self.tid, "args": args}
 
 
+# The ring's default size. A reader that finds a dropped span reads
+# nothing (benchmark/readers/spans.py), so the ring has to hold what a
+# serving process records between its start and a 40 s window's end: seven
+# spans a decode step and one a request. At the 12 ms steps of PR 29 that
+# is 23,000 spans (16,384, the size until then, dropped 3,578 of them);
+# 131,072 holds steps down to 2 ms, at about 0.5 KB a span when full.
+MAX_SPANS = 131072
+
+
 class Tracer:
     """Bounded span recorder + thread-local trace context."""
 
-    def __init__(self, max_spans: int = 16384, enabled: bool | None
+    def __init__(self, max_spans: int = MAX_SPANS, enabled: bool | None
                  = None, bridge_jax: bool | None = None):
         if enabled is None:
             enabled = os.environ.get("PADDLE_TPU_TRACE", "1") != "0"

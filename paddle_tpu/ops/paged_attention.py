@@ -8,10 +8,14 @@ implementations behind one function:
   * gather-based XLA: k_pages[layer, page_table] gathers each slot's
     pages into a [S, M*ps] context, masked past ctx_len — one fused XLA
     computation, the portable default;
-  * a Pallas TPU kernel: grid (slot, page), layer and page indices
-    scalar-prefetched so each program DMAs exactly one page from HBM,
-    online-softmax accumulation in VMEM scratch, the per-head mat-vecs
-    on the VPU.
+  * a Pallas TPU kernel: grid (slot,), one program a slot. The pools stay
+    in HBM; the layer, the page table and the contexts are
+    scalar-prefetched, and the program copies its slot's own
+    cdiv(ctx, ps) pages itself, B pages a block, the next block's copies
+    in flight while this block's pages go through an online softmax in a
+    loop (the per-head mat-vecs on the VPU). What a call costs follows the
+    contexts, not the table's width; a table entry past the context is
+    never read.
 
 Selection runs through ops/autobench.prefer — the same measure-once gate
 that arbitrates Pallas-vs-XLA flash attention — so the hand kernel only
@@ -33,6 +37,11 @@ across lanes (looked at with the chip's compiler, PR 26). Such a model
 keeps ONE fused pool `[L, P, ps, Hkv, 2d]`, K in the first d lanes of a
 head and V in the last d, and passes it as `k_pages` with `v_pages=None`:
 one gather, or one page DMA, brings both.
+
+On the chip the kernel's copies move whole 128-lane tiles, so there the
+minor dimension of a pool it is given is a multiple of 128 (d = 128, or a
+fused pool of heads of 64); Mosaic refuses another, the gate keeps the
+refusal with its decision and the XLA path runs.
 
 The two pool ranks are one algorithm: both implementations address
 (layer, page) in the pool they are given, and a rank-4 pool is a stacked
@@ -125,185 +134,203 @@ def _groups(q, k_pages) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (slot, page); page_table, ctx_lens and the layer are
-# scalar-prefetched so the k/v BlockSpec index_map can steer each program's
-# DMA at one page of one layer.
+# Pallas kernel: grid (slot,). One program a slot walks that slot's own
+# pages, so a call's work follows the contexts and not the table's width.
+# The pools stay in HBM (memory_space=pl.ANY); page_table, ctx_lens and the
+# layer are scalar-prefetched and steer the kernel's own page copies.
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size, scale):
-    s, m = pl.program_id(0), pl.program_id(1)
-    n_pages = pl.num_programs(1)
-
-    @pl.when(m == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # one query token per head against one page: a batched mat-vec, done
-    # on the VPU with the head axis kept in place. (The MXU spelling,
-    # einsum("hd,phd->hp"), puts the batch dimension in the middle of the
-    # rhs and leaves the lhs no free dimension; Mosaic refuses it.)
-    # Scores stay [ps, H, 1] so that they broadcast over d without a
-    # relayout and reduce over the page axis into the [H, 1] scratch.
-    q = q_ref[0].astype(jnp.float32)            # [H, d]
-    k = k_ref[0, 0].astype(jnp.float32)         # [ps, H, d]
-    scores = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
-    idx = m * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 0)
-    live = idx < len_ref[s]
-    scores = jnp.where(live, scores, _NEG)
-
-    m_prev = m_ref[...]                          # [H, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-    alpha = jnp.exp(m_prev - m_new)
-    # masked again after exp: a dead page would give exp(_NEG - _NEG) = 1
-    p = jnp.where(live, jnp.exp(scores - m_new[None]), 0.0)   # [ps, H, 1]
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
-    v = v_ref[0, 0].astype(jnp.float32)          # [ps, H, d]
-    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
-    m_ref[...] = m_new
-
-    @pl.when(m == n_pages - 1)
-    def _fin():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+# VMEM for the page copies: two buffers (one filled by the DMA engine while
+# the other is read) of one block of B pages of each pool. 2 MiB keeps a
+# MiB of copies in flight, enough to cover HBM's latency at its bandwidth;
+# the float32 working set beside them is one page at a time (64 KiB of K,
+# as much of V) and the whole stays far under Mosaic's 16 MiB of scoped VMEM.
+_PAGE_BUFFER_BYTES = 2 * 2 ** 20
 
 
-def _paged_kernel_gqa(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size,
-                      scale, groups, fused):
-    """The kernel above for G query heads a KV head: q and o blocks are
-    [1, G, Hkv, d] (group-major, the wrapper transposes), the scratch
-    carries G online softmaxes, and one page of K and V, read once, serves
-    all G of them. Each group's update is the multi-head kernel's.
+def _block_pages(page_bytes: int, n_pools: int, M: int) -> int:
+    """B, the pages of one block: what the buffers hold, at most the table.
+    GPT-1.3B (64 KiB a page, K and V pools): 8; LFM2 (32 KiB, fused): 32."""
+    return max(1, min(_PAGE_BUFFER_BYTES // (2 * n_pools * page_bytes), M))
 
-    `fused`: one pool whose heads are [K | V] over 2d lanes. q arrives
-    with zeros in the V lanes, so q . [K | V] is q . K; the accumulator
-    sums p [K | V] and the wrapper keeps its V lanes. No lane is sliced
-    in the kernel."""
-    if fused:
-        k_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        v_ref = k_ref
-    else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    s, m = pl.program_id(0), pl.program_id(1)
-    n_pages = pl.num_programs(1)
 
-    @pl.when(m == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+def _softmax_update(carry, q, k, v, live=None):
+    """One page into the running softmax of the G head groups that share
+    it. carry: per group (m [Hkv, 1], l [Hkv, 1], acc [Hkv, w]), float32;
+    q: per group [Hkv, w], scaled; k, v: [ps, Hkv, w] float32; live:
+    [ps, Hkv, 1] bool for a page the context ends in, None for a whole one.
 
-    # a page past the slot's context does no arithmetic (its table entry
-    # is the trash page, so consecutive dead pages are not fetched again)
-    @pl.when(m * page_size < len_ref[s])
-    def _page():
-        k = k_ref[0, 0].astype(jnp.float32)         # [ps, Hkv, d]
-        v = k if fused else v_ref[0, 0].astype(jnp.float32)
-        for g in range(groups):
-            q = q_ref[0, g].astype(jnp.float32)     # [Hkv, d]
-            scores = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
-            idx = m * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 0)
-            live = idx < len_ref[s]
+    One query token per head against one page is a batched mat-vec, done
+    on the VPU with the head axis kept in place. (The MXU spelling,
+    einsum("hd,phd->hp"), puts the batch dimension in the middle of the
+    rhs and leaves the lhs no free dimension; Mosaic refuses it.) Scores
+    stay [ps, Hkv, 1] so that they broadcast over w without a relayout
+    and reduce over the page axis into [Hkv, 1]."""
+    if live is not None:
+        v = jnp.where(live, v, 0.0)     # rows nobody wrote: 0 x NaN is NaN
+    out = []
+    for (m_prev, l_prev, acc), qg in zip(carry, q):
+        scores = jnp.sum(qg[None] * k, axis=-1, keepdims=True)
+        if live is not None:
             scores = jnp.where(live, scores, _NEG)
-            m_prev = m_ref[g]                        # [Hkv, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(live, jnp.exp(scores - m_new[None]), 0.0)
-            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=0)
-            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
-            m_ref[g] = m_new
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new[None])                     # [ps, Hkv, 1]
+        if live is not None:    # a page all dead: exp(_NEG - _NEG) = 1
+            p = jnp.where(live, p, 0.0)
+        out.append((m_new, alpha * l_prev + jnp.sum(p, axis=0),
+                    acc * alpha + jnp.sum(p * v, axis=0)))
+    return tuple(out)
 
-    @pl.when(m == n_pages - 1)
-    def _fin():
-        l = l_ref[...]
+
+def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
+                  groups, block):
+    """refs: the pools in HBM ([L, P, ps, Hkv, w]: K and V, or one fused
+    [K | V] pool), o_ref, a VMEM buffer [2, B, ps, Hkv, w] a pool, DMA
+    semaphores [pools, 2] (one a buffer) and, in SMEM, which buffer holds
+    the slot's first block.
+
+    A slot of n = cdiv(ctx, ps) live pages is cdiv(n, B) blocks. The
+    copies of block i + 1 (or, after the last, of the NEXT slot's first
+    block: the grid runs in order on one core and scratch outlives a
+    program) are started before block i is waited for and worked on, so
+    the arithmetic and the start of a slot hide behind copies in flight.
+    A table entry past n is never read, nor is the page it names.
+
+    q and o blocks are [1, H, d] (multi-head, G = 1) or group-major
+    [1, G, Hkv, w]. Fused pool: q arrives with zeros in the V lanes, so
+    q . [K | V] is q . K; the accumulator sums p [K | V] and the wrapper
+    keeps its V lanes. No lane is sliced in the kernel."""
+    ps, B, G = page_size, block, groups
+    n_pools = (len(refs) - 3) // 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, (sem, first_ref) = refs[n_pools + 1:-2], refs[-2:]
+    s, S = pl.program_id(0), pl.num_programs(0)
+    layer = ly_ref[0]
+
+    def n_pages(slot):      # ctx >= 1 is the contract; 0 reads as 1, dead
+        return jnp.maximum((len_ref[slot] + ps - 1) // ps, 1)
+
+    def copies(slot, i, b, act):
+        """start or wait for the copies of block i of `slot` into buffer
+        b: one a live page and pool."""
+        def page(j, _):
+            at = pt_ref[slot, i * B + j]
+            for p in range(n_pools):
+                act(pltpu.make_async_copy(pools[p].at[layer, at],
+                                          bufs[p].at[b, j], sem.at[p, b]))
+            return _
+        jax.lax.fori_loop(0, jnp.minimum(B, n_pages(slot) - i * B), page, 0)
+
+    def start(slot, i, b):
+        copies(slot, i, b, lambda c: c.start())
+
+    def page_of(b, j):
+        k = bufs[0][b, j].astype(jnp.float32)
+        return k, (k if n_pools == 1 else bufs[1][b, j].astype(jnp.float32))
+
+    @pl.when(s == 0)
+    def _first():
+        first_ref[0] = 0
+        start(0, 0, 0)
+
+    n, b0 = n_pages(s), first_ref[0]
+    n_blocks = (n + B - 1) // B
+    # the block of head group g in q_ref and o_ref: [1, H, d] is group 0
+    at = (lambda g: (0,)) if q_ref.ndim == 3 else (lambda g: (0, g))
+    q = [q_ref[at(g)].astype(jnp.float32) * scale for g in range(G)]
+    Hkv, w = q[0].shape
+
+    def one_block(i, carry):
+        b = (b0 + i) % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(s, i + 1, 1 - b)
+
+        @pl.when(jnp.logical_and(i + 1 == n_blocks, s + 1 < S))
+        def _next_slot():
+            start(s + 1, 0, 1 - b)
+
+        copies(s, i, b, lambda c: c.wait())
+        # every page but the slot's last is whole: no mask
+        return jax.lax.fori_loop(
+            0, jnp.minimum(B, n - 1 - i * B),
+            lambda j, c: _softmax_update(c, q, *page_of(b, j)), carry)
+
+    zero = jnp.zeros((Hkv, 1), jnp.float32)
+    carry = jax.lax.fori_loop(
+        0, n_blocks, one_block,
+        ((zero + _NEG, zero, jnp.zeros((Hkv, w), jnp.float32)),) * G)
+    # the page the context ends in, still in the last block's buffer
+    last = n_blocks - 1
+    idx = (n - 1) * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, Hkv, 1), 0)
+    carry = _softmax_update(
+        carry, q, *page_of((b0 + last) % 2, n - 1 - last * B),
+        live=idx < len_ref[s])
+    first_ref[0] = (b0 + n_blocks) % 2
+    for g, (_, l, acc) in enumerate(carry):
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[at(g)] = (acc / l).astype(o_ref.dtype)
 
 
-def _paged_attention_pallas_gqa(q, k_pages, v_pages, page_table, ctx_lens,
-                                scale, interpret, layer, G):
-    S, H, d = q.shape
-    ps, Hkv = k_pages.shape[2], k_pages.shape[3]
-    M = page_table.shape[1]
-    fused = v_pages is None
-    w = k_pages.shape[4]                # d, or 2d of a fused pool
-    page = pl.BlockSpec(
-        (1, 1, ps, Hkv, w),
-        lambda s, m, pt, ln, ly: (ly[0], pt[s, m], 0, 0, 0))
-    heads = pl.BlockSpec((1, G, Hkv, w),
-                         lambda s, m, pt, ln, ly: (s, 0, 0, 0))
-    pools = (k_pages,) if fused else (k_pages, v_pages)
+def _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer, G):
+    """The kernel over q [S, H, d] (G = 1) or group-major [S, G, Hkv, w];
+    the output has q's shape."""
+    ps, Hkv, w = pools[0].shape[2:]
+    B = _block_pages(ps * Hkv * w * pools[0].dtype.itemsize, len(pools),
+                     page_table.shape[1])
+    heads = pl.BlockSpec((1,) + q.shape[1:],
+                         lambda s, *_: (s,) + (0,) * (q.ndim - 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, M),
-        in_specs=[heads] + [page] * len(pools),
+        grid=(q.shape[0],),
+        in_specs=[heads] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=heads,
-        scratch_shapes=[
-            pltpu.VMEM((G, Hkv, w), jnp.float32),
-            pltpu.VMEM((G, Hkv, 1), jnp.float32),
-            pltpu.VMEM((G, Hkv, 1), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((2, B, ps, Hkv, w), p.dtype)
+                        for p in pools]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2)),
+           pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(_paged_kernel_gqa, page_size=ps,
-                               scale=float(scale), groups=G, fused=fused)
-    q = q.reshape(S, Hkv, G, d).transpose(0, 2, 1, 3)
-    if fused:
-        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
-    o = pl.pallas_call(
+    kernel = functools.partial(_paged_kernel, page_size=ps,
+                               scale=float(scale), groups=G, block=B)
+    return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, G, Hkv, w), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # in order on one core: a program starts its successor's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       layer.reshape(1), q, *pools)
+
+
+def _paged_attention_pallas_gqa(q, pools, page_table, ctx_lens, scale,
+                                interpret, layer, G):
+    """G query heads a KV head, or a fused pool: q goes in group-major
+    [S, G, Hkv, w] (zeros in a fused pool's V lanes) and the custom call's
+    output is bf16[S, G, Hkv, w]."""
+    S, H, d = q.shape
+    Hkv, w = pools[0].shape[3:]
+    q = q.reshape(S, Hkv, G, d).transpose(0, 2, 1, 3)
+    if w != d:
+        q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+    o = _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer,
+                    G)
     return o[..., w - d:].transpose(0, 2, 1, 3).reshape(S, H, d)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, ctx_lens,
                            scale=None, interpret=None, layer=None):
-    S, H, d = q.shape
+    d = q.shape[-1]
     k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
     G = _groups(q, k_pages)
-    if G > 1 or v_pages is None:
-        return _paged_attention_pallas_gqa(
-            q, k_pages, v_pages, page_table, ctx_lens,
-            scale if scale is not None else 1.0 / math.sqrt(d),
-            (not on_tpu()) if interpret is None else interpret, layer, G)
-    ps = k_pages.shape[2]
-    M = page_table.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = not on_tpu()
-    page = pl.BlockSpec(
-        (1, 1, ps, H, d),
-        lambda s, m, pt, ln, ly: (ly[0], pt[s, m], 0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, M),
-        in_specs=[
-            pl.BlockSpec((1, H, d), lambda s, m, pt, ln, ly: (s, 0, 0)),
-            page, page,
-        ],
-        out_specs=pl.BlockSpec((1, H, d),
-                               lambda s, m, pt, ln, ly: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, d), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, page_size=ps,
-                               scale=float(scale))
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, d), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      layer.reshape(1), q, k_pages, v_pages)
+    pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
+    call = _paged_attention_pallas_gqa if G > 1 or v_pages is None \
+        else _paged_call        # multi-head: the custom call is bf16[S, H, d]
+    return call(q, pools, page_table, ctx_lens,
+                scale if scale is not None else 1.0 / math.sqrt(d),
+                (not on_tpu()) if interpret is None else interpret, layer, G)
 
 
 def _gate_paged(S, H, d, P, ps, M, dtype, Hkv=None, fused=False):
@@ -314,11 +341,12 @@ def _gate_paged(S, H, d, P, ps, M, dtype, Hkv=None, fused=False):
     The candidates are timed in the form that runs, a stacked pool with
     the layer an argument of the jitted call, on a stack of ONE layer:
     neither candidate's time depends on L, and a serving engine's own
-    pool leaves no room for a second one beside it. "stacked" in the key
-    keeps a record measured on the rank-4 kernels of before from
-    answering for these."""
+    pool leaves no room for a second one beside it. "live_pages" in the
+    key (after "stacked", PR 25) keeps a record measured on the kernel
+    of before, whose time was the table's width whatever the contexts,
+    from answering for this one: trees share a machine's gate cache."""
     dtype = jnp.dtype(dtype)
-    key = ("paged_attention", "stacked", S, H, d, P, ps, M, str(dtype))
+    key = ("paged_attention", "live_pages", S, H, d, P, ps, M, str(dtype))
     Hkv = H if Hkv is None else Hkv
     if Hkv != H or fused:   # other kernels, a key of their own
         key += ("kv_heads", Hkv) + (("fused",) if fused else ())

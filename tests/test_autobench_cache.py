@@ -362,7 +362,9 @@ def test_paged_gate_times_the_stacked_form_on_one_layer(
         assert shapes == [(8, 16, 128), (1, 1025, 16, 16, 128),
                           (1, 1025, 16, 16, 128), (8, 128), (8,), ()]
     [key] = autobench.decisions()
-    assert key[:2] == ("paged_attention", "stacked")
+    # the marker names the kernel that was judged ("stacked" until the
+    # kernel walked live pages only, PR 29); the form timed is the same
+    assert key[:2] == ("paged_attention", "live_pages")
     assert key[2:] == _OLD_PAGED_KEY[1:]
 
 
@@ -383,12 +385,21 @@ def test_paged_gate_candidates_run_on_their_own_args():
     assert jnp.allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
+# the same key from PR 25 to PR 28, when the kernel behind `pallas` ran a
+# grid step for every table entry whatever the contexts held
+_GRID_PAGED_KEY = ("paged_attention", "stacked") + _OLD_PAGED_KEY[1:]
+
+
+@pytest.mark.parametrize("old_key", [_OLD_PAGED_KEY, _GRID_PAGED_KEY],
+                         ids=["rank4", "stacked_grid"])
 def test_paged_record_of_the_rank4_gate_does_not_answer(cache_file,
-                                                        monkeypatch):
-    """A decision a fleet cached before PR 25 was measured on other
-    kernels (rank-4 pools): it stays in the file and is never adopted."""
+                                                        monkeypatch,
+                                                        old_key):
+    """A decision a fleet cached before PR 25 (rank-4 pools), or before
+    PR 29 (the grid over every table entry), was measured on another
+    kernel: it stays in the file and is never adopted."""
     autobench._publish(cache_file, {
-        "key": str(_OLD_PAGED_KEY), "device": autobench._device_kind(),
+        "key": str(old_key), "device": autobench._device_kind(),
         "winner": "xla", "jax": autobench._jax_version(),
         "kernels": autobench.KERNEL_VERSION,
         "timings_ms": {"xla": 1.0, "pallas": 2.0}, "errors": {},
@@ -401,5 +412,5 @@ def test_paged_record_of_the_rank4_gate_does_not_answer(cache_file,
     assert st["cache_hits"] == 0 and st["cache_misses"] == 1
     keys = {rec["key"]: rec["winner"]
             for rec in autobench.list_entries(cache_file)}
-    assert keys[str(_OLD_PAGED_KEY)] == "xla"
-    assert sum("'stacked'" in k for k in keys) == 1
+    assert keys[str(old_key)] == "xla"
+    assert sum("'live_pages'" in k for k in keys) == 1

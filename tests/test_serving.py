@@ -243,17 +243,100 @@ def test_paged_attention_grouped_query_matches_dense(impl, G, d, fused):
 
 def test_paged_gate_key_tells_grouped_query_and_fused_pools_apart():
     from paddle_tpu.ops.paged_attention import _gate_paged
-    old = ("paged_attention", "stacked", 32, 16, 128, 3073, 16, 128,
+    # "live_pages": the candidate named `pallas` is the kernel that walks
+    # a slot's live pages (PR 29). A decision cached for the grid kernel,
+    # whose time was the table's width whatever the contexts, says nothing
+    # of this one, and parent and change share one gate cache on a
+    # machine: under the old key ("paged_attention", "stacked", 32, ...)
+    # the new kernel would have been judged by the old one's time.
+    mha = ("paged_attention", "live_pages", 32, 16, 128, 3073, 16, 128,
            "bfloat16")
-    # multi-head attention keeps the key it had: a cached decision stands
-    assert _gate_paged(32, 16, 128, 3073, 16, 128, "bfloat16")[0] == old
+    assert _gate_paged(32, 16, 128, 3073, 16, 128, "bfloat16")[0] == mha
     assert _gate_paged(32, 16, 128, 3073, 16, 128, "bfloat16",
-                       Hkv=16)[0] == old
+                       Hkv=16)[0] == mha
     gqa = _gate_paged(64, 32, 64, 16385, 16, 256, "bfloat16", Hkv=8)[0]
     fused = _gate_paged(64, 32, 64, 16385, 16, 256, "bfloat16", Hkv=8,
                         fused=True)[0]
     assert gqa[-2:] == ("kv_heads", 8) and fused[-1] == "fused"
-    assert len({old, gqa, fused}) == 3
+    assert len({mha, gqa, fused}) == 3
+
+
+# The kernel's blocks, at a size a test can hold: 16 KiB of page buffers is
+# B = 4 pages a block for both pool forms below (float32, ps 8, 2 KV heads
+# of 16: 1 KiB a page of K and of V, 2 KiB a fused one), a table of 10.
+_WALK = dict(S=4, Hkv=2, d=16, P=40, ps=8, M=10, L=2, B=4)
+
+
+def _walk_args(G, fused, lens, dead_page=None, seed=11):
+    """(q, pools, table, lens): each slot's live pages drawn from the
+    pool, the rest of its row `dead_page` (default: the trash page, P)."""
+    c = _WALK
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(c["S"], G * c["Hkv"], c["d"]), jnp.float32)
+    shape = (c["L"], c["P"] + 2, c["ps"], c["Hkv"], c["d"])
+    k = jnp.asarray(rng.randn(*shape), jnp.float32)
+    v = jnp.asarray(rng.randn(*shape), jnp.float32)
+    table = np.full((c["S"], c["M"]),
+                    c["P"] if dead_page is None else dead_page, np.int32)
+    for s, n in enumerate(-(-np.asarray(lens) // c["ps"])):
+        table[s, :n] = rng.permutation(c["P"])[:n]
+    pools = (jnp.concatenate([k, v], -1), None) if fused else (k, v)
+    return q, pools, jnp.asarray(table), jnp.asarray(lens, jnp.int32)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    from paddle_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(pa, "_PAGE_BUFFER_BYTES", 16 * 1024)
+    for n_pools, page in ((2, 1024), (1, 2048)):
+        assert pa._block_pages(page, n_pools, _WALK["M"]) == _WALK["B"]
+    return pa
+
+
+@pytest.mark.parametrize("G,fused", [(1, False), (2, True), (4, False),
+                                     (4, True)])
+@pytest.mark.parametrize("ctx", [1, 7, 8, 9, 32, 33, 64, 80])
+def test_paged_kernel_walks_each_slots_own_pages(small_blocks, ctx, G,
+                                                 fused):
+    """The body that runs on the chip, interpreted: manual page copies
+    under a loop whose trip count is the slot's. Contexts round a page's
+    edge (ps - 1, ps, ps + 1), exactly one block of B pages, one token
+    more, two blocks, the whole table; beside each, in the same batch, an
+    empty slot (one token, a table all trash) and a full one, so that the
+    buffer a slot starts in changes from slot to slot; layer 1 of a stack
+    of 2; multi-head, grouped heads, separate and fused pools."""
+    pa = small_blocks
+    q, pools, table, lens = _walk_args(G, fused, [ctx, 1, 80, ctx])
+    table = table.at[1].set(_WALK["P"])             # the empty slot
+    got = pa.paged_attention_pallas(q, *pools, table, lens, layer=1,
+                                    interpret=True)
+    want = pa.paged_attention_xla(q, *pools, table, lens, layer=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("G,fused", [(1, False), (4, True)])
+def test_paged_kernel_never_reads_a_dead_page(small_blocks, G, fused):
+    """Every table entry past cdiv(ctx, ps) names a page of NaN, and what
+    the kernel's buffers hold before a copy lands is NaN too: the output
+    is finite and is the XLA path's over a table whose dead entries are
+    clean. (The grid kernel of before read every entry of the table and
+    masked afterwards: 0 x NaN.)"""
+    from jax.experimental.pallas import tpu as pltpu
+    pa = small_blocks
+    nan_page = _WALK["P"] + 1
+    lens = [33, 1, 80, 9]
+    q, pools, table, lens = _walk_args(G, fused, lens, dead_page=nan_page)
+    pools = tuple(None if p is None else p.at[:, nan_page].set(jnp.nan)
+                  for p in pools)
+    got = pa.paged_attention_pallas(
+        q, *pools, table, lens, layer=1,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    assert np.isfinite(np.asarray(got)).all()
+    clean = jnp.where(table == nan_page, _WALK["P"], table)
+    want = pa.paged_attention_xla(q, *pools, clean, lens, layer=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 def test_paged_attention_refuses_heads_that_do_not_divide():

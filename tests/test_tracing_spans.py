@@ -284,6 +284,17 @@ def test_record_obeys_the_ring_the_sink_and_the_switch():
     assert len(seen) == 9 and len(t.spans()) == 4
 
 
+def test_default_ring_holds_a_benchmark_window_of_serving_spans():
+    """A span reader reads nothing once the ring has dropped a span, so
+    the process-wide ring holds a whole 40 s window of decode steps:
+    seven spans a step, one a request, the warm-up's before them. At the
+    12.4 ms steps of mixed_open since PR 29 that is 23,000 (16,384 spans
+    dropped 3,578 of them); held to steps of 5 ms, with half as much
+    room again."""
+    assert tracing.TRACER._spans.maxlen == tracing.MAX_SPANS
+    assert tracing.MAX_SPANS >= 1.5 * (7 * 40.0 / 5e-3 + 1000)
+
+
 def test_switched_off_spans_propagate_ids_and_record_nothing():
     t = Tracer(enabled=False)
     with t.span("a", trace_id="feed") as a:
