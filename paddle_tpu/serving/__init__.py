@@ -25,8 +25,10 @@ Network mode (PS wire format, see serving/frontend.py):
 
 Models whose layers keep other state than K/V (a convolution's last
 inputs, per slot) are served through the same Engine by
-`HybridDecodeModel` (models/lfm2.py); `DecodeModel` (serving/model.py) is
-what the engine asks of either.
+`HybridDecodeModel` (models/lfm2.py), and a looped decoder, whose layers
+run several times a token with K/V of every pass, by `LoopedDecodeModel`
+(models/ouro.py); `DecodeModel` (serving/model.py) is what the engine asks
+of each.
 
 Replicated fleet (serving/router.py, docs/SERVING.md): a Router fronts
 N replicas with least-loaded dispatch, session affinity, streaming
@@ -38,7 +40,8 @@ from .prefix_cache import PrefixCache, PrefixMatch
 from .sampling import SamplingParams, derive_seed
 from .scheduler import (QueueFull, QuotaExceeded, Request, Scheduler,
                         TokenBucket)
-from .model import DecodeModel, GPTDecodeModel, HybridDecodeModel
+from .model import (DecodeModel, GPTDecodeModel, HybridDecodeModel,
+                    LoopedDecodeModel)
 from .engine import Engine
 from .frontend import ServingClient, ServingServer
 from .loadgen import (Arrival, LoadGenerator, LoadResult, TrafficConfig,
@@ -49,7 +52,8 @@ __all__ = [
     "PagePool", "PageTable", "pages_needed", "defrag_plan",
     "PrefixCache", "PrefixMatch", "SamplingParams", "derive_seed",
     "Request", "Scheduler", "QueueFull", "QuotaExceeded", "TokenBucket",
-    "DecodeModel", "GPTDecodeModel", "HybridDecodeModel", "Engine",
+    "DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
+    "LoopedDecodeModel", "Engine",
     "ServingServer", "ServingClient",
     "Arrival", "LoadGenerator", "LoadResult", "TrafficConfig",
     "slo_report",

@@ -97,6 +97,10 @@ _SLOT_STATE_BYTES = _obs.gauge(
     "paddle_tpu_serving_slot_state_bytes",
     "bytes of the cache's per-slot parts (state kept per sequence, not "
     "per token); 0 for a model that keeps none", ["engine"])
+_PAGED_BYTES_PER_TOKEN = _obs.gauge(
+    "paddle_tpu_serving_paged_bytes_per_token",
+    "bytes of the cache's paged parts one cached token holds (every "
+    "layer, and every pass of a model that loops)", ["engine"])
 
 _engine_ids = itertools.count()
 
@@ -104,7 +108,7 @@ _engine_ids = itertools.count()
 def _drop_engine_series(eid: str):
     for m in (_REQS, _TOKENS, _STEPS, _COMPILES, _DECODE_H, _PREFILL_H,
               _LATENCY_H, _QUEUE_DEPTH, _OCCUPANCY, _SAMPLING_REQS,
-              _SAMPLING_TOKENS, _SLOT_STATE_BYTES):
+              _SAMPLING_TOKENS, _SLOT_STATE_BYTES, _PAGED_BYTES_PER_TOKEN):
         m.remove_matching(engine=eid)
 
 
@@ -178,11 +182,12 @@ class Engine:
         self.prefix_cache = None
         if prefix_cache_pages > 0 and not model.has_prefill_tail:
             raise ValueError(
-                "prefix_cache_pages > 0 with a model that keeps per-slot "
-                "state: a cached prefix resumes a prompt at a page "
-                "boundary from K/V alone, and this model's per-slot state "
-                "(a convolution's or a recurrence's last inputs) at that "
-                "boundary is not in any page")
+                "prefix_cache_pages > 0 with a model that has no "
+                "`prefill_tail`: a cached prefix resumes a prompt at a "
+                "page boundary from the pages alone. A model that keeps "
+                "per-slot state (a convolution's or a recurrence's last "
+                "inputs) cannot have one: that state at the boundary is "
+                "not in any page")
         if prefix_cache_pages > 0:
             self.prefix_cache = PrefixCache(
                 self.pool, budget_pages=min(prefix_cache_pages,
@@ -321,7 +326,11 @@ class Engine:
         _SLOT_STATE_BYTES.labels(engine=eid).set_function(
             lambda: (lambda e: e._kv_cache_bytes()["slot"] if e else 0.0)(
                 wr()))
-        # the experts' tallies as last read (stats() reports the change)
+        _PAGED_BYTES_PER_TOKEN.labels(engine=eid).set_function(
+            lambda: (lambda e: e._kv_cache_bytes()["paged"]
+                     / ((e.num_pages + 1) * e.page_size) if e else 0.0)(
+                wr()))
+        # the model's tallies as last read (stats() reports the change)
         self._tally_seen: dict = {}
         self._steps_seen = 0
         _perf.register_provider(self._perf_name,
@@ -526,7 +535,8 @@ class Engine:
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
-                           cached_tokens=start, slot=req.slot) as sp:
+                           cached_tokens=start, slot=req.slot,
+                           passes=self.model.passes) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
             compiled = self._compiles.get(bucket, 0) > pre_compiles
@@ -658,7 +668,8 @@ class Engine:
         try:
             t0 = time.perf_counter()
             with _tracing.span("engine.decode", engine=self.engine_id,
-                               active=len(active)) as sp:
+                               active=len(active),
+                               passes=self.model.passes) as sp:
                 with _tracing.span("engine.dispatch"):
                     self.cache, device_toks = self._decode(*targs)
                 with _tracing.span("engine.wait"):
@@ -845,39 +856,27 @@ class Engine:
         return {k: float(v)
                 for k, v in self.model.cache_bytes(self.cache).items()}
 
-    def _expert_stats(self) -> dict:
-        """The experts' tallies, read from the device under the step lock
-        (the step donates the cache), and what changed since the last
-        read. {} for a model without routed experts, or while a step
-        holds the lock for long."""
-        if not self.model.parts_of("tally"):
+    def _tally_stats(self) -> dict:
+        """The model's tallies, read from the device under the step lock
+        (the step donates the cache), and what `DecodeModel.tally_stats`
+        makes of them and of their change since the last read. {} for a
+        model without tallies, or while a step holds the lock for long."""
+        names = self.model.parts_of("tally")
+        if not names:
             return {}
         if not self._lock.acquire(timeout=2.0):
             return {}
         try:
-            now = {n: np.asarray(self.cache[n]).astype(np.int64)
-                   for n in self.model.parts_of("tally")}
+            read = [np.asarray(self.cache[n]) for n in names]
             steps = int(self._m_steps.value)
         finally:
             self._lock.release()
+        now = {n: a.astype(np.int64 if a.dtype.kind in "iu" else np.float64)
+               for n, a in zip(names, read)}
         seen, self._tally_seen = self._tally_seen, now
         d_steps, self._steps_seen = steps - self._steps_seen, steps
         delta = {n: a - seen.get(n, 0) for n, a in now.items()}
-        pairs, touched = delta["expert_tokens"], delta["expert_touched"]
-        mean = pairs.mean(axis=1)
-        out = {"expert_tokens": now["expert_tokens"].tolist(),
-               "expert_touched": now["expert_touched"].tolist(),
-               "expert_load_max_over_mean": None,
-               "experts_touched_share": None}
-        if (mean > 0).all():
-            # the busiest expert of a layer over the layer's mean, mean
-            # over the layers: 1.0 is an even load
-            out["expert_load_max_over_mean"] = float(
-                (pairs.max(axis=1) / mean).mean())
-        if d_steps > 0:
-            out["experts_touched_share"] = float(
-                touched.sum() / (d_steps * touched.size))
-        return out
+        return self.model.tally_stats(now, delta, d_steps)
 
     def perf_rates(self) -> dict:
         """Cheap live rates for ping/stats and the perf snapshot: no
@@ -963,11 +962,9 @@ class Engine:
 
     def stats(self) -> dict:
         """/stats counters: queue depth, latency percentiles, tokens/sec,
-        page-pool occupancy, preemptions, compiles per bucket; with routed
-        experts also `expert_tokens` (pairs per layer and expert since the
-        engine began) and, over the time since the last call,
-        `expert_load_max_over_mean` and `experts_touched_share` (of the
-        experts, in a decode step)."""
+        page-pool occupancy, preemptions, compiles per bucket; and what the
+        model makes of its tally parts (`DecodeModel.tally_stats`, its
+        keys as they are), ratios over the time since the last call."""
         with self._stats_lock:  # the step thread appends concurrently
             lats = sorted(self._latencies)
             w = list(self._tok_window)
@@ -983,7 +980,7 @@ class Engine:
         if len(w) >= 2 and w[-1][0] > w[0][0]:
             tps = sum(n for _, n in w[1:]) / (w[-1][0] - w[0][0])
         rates = self.perf_rates()
-        return {**self.scheduler.stats(), **self._expert_stats(),
+        return {**self.scheduler.stats(), **self._tally_stats(),
                 "pool": self.pool.stats(),
                 "prefix_cache": self.prefix_cache.stats()
                 if self.prefix_cache is not None else None,

@@ -1,10 +1,12 @@
 """Decode-model adapters: a functional core over the serving cache.
 
 `DecodeModel` is what the engine asks of a model; `GPTDecodeModel` (K and V
-in two paged parts, nothing else) and `HybridDecodeModel` (paged, per-slot
-and tally parts) answer it. Each adapter's bodies are drivers over its
-architecture's layer loop (`GPTDecodeModel._layers`; `lfm2.apply_layers`):
-they say how tokens become `x`, where K/V land and what attends.
+in two paged parts, nothing else), `HybridDecodeModel` (paged, per-slot
+and tally parts) and `LoopedDecodeModel` (K and V of every layer of every
+PASS in two paged parts, and tallies) answer it. Each adapter's bodies are
+drivers over its architecture's layer loop (`GPTDecodeModel._layers`;
+`lfm2.apply_layers`; `ouro.apply_passes`): they say how tokens become `x`,
+where K/V land and what attends.
 
 Trash-page convention: the device pools carry ONE extra page at index
 `num_pages` that absorbs every masked write — padded page-table entries
@@ -26,11 +28,13 @@ import jax
 import jax.numpy as jnp
 
 from ..models import lfm2 as _lfm2
+from ..models import ouro as _ouro
 from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
                           decoder_tail, init_gpt_params)
 from ..ops.paged_attention import paged_attention_decode
 
-__all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel"]
+__all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
+           "LoopedDecodeModel"]
 
 
 class DecodeModel:
@@ -59,6 +63,10 @@ class DecodeModel:
           cached token: routing_of(cache, pages, length) (`Engine.submit(
           return_routing=True)`)
 
+      passes             how many times a token runs the layers in one
+          program: 1 unless the model loops (an attribute of the engine's
+          prefill and decode spans)
+
     `cache'` has the keys, shapes and dtypes of `cache`: the engine donates
     it. The cache is a dict of device arrays, and `cache_kinds` says of each
     part what it is indexed by:
@@ -70,12 +78,14 @@ class DecodeModel:
                convolution's or a recurrence's state). Prefill of a request
                writes its slot's row whole, so a slot never reads its last
                tenant's; decode's row i is slot i.
-      "tally"  counters the programs add to and only `Engine.stats` reads.
+      "tally"  counters the programs add to and only `Engine.stats` reads,
+               through `tally_stats`: what a tally means is the model's.
     """
 
     cache_kinds: dict[str, str] = {}
     has_prefill_tail = False
     has_routing = False
+    passes = 1
 
     def __init__(self, cfg, params, attn_impl: str | None = None):
         self.cfg = cfg
@@ -100,6 +110,13 @@ class DecodeModel:
     def slot_state(self) -> bool:
         """Whether some state is kept per sequence and not per token."""
         return bool(self.parts_of("slot"))
+
+    def tally_stats(self, now: dict, delta: dict, steps: int) -> dict:
+        """What `Engine.stats()` reports of the tally parts: `now` holds
+        each part as read from the device (numpy, int64 or float64),
+        `delta` what was added since the last read, `steps` the decode
+        steps run since then. Keys go into `stats()` as they are."""
+        return {}
 
     def cache_bytes(self, cache) -> dict:
         """Bytes held, by kind of part."""
@@ -452,6 +469,25 @@ class HybridDecodeModel(DecodeModel):
         k = self.cfg.num_experts_per_tok
         return got.reshape(-1, self.cfg.num_moe_layers, k)[:length]
 
+    def tally_stats(self, now, delta, steps):
+        """`expert_tokens` / `expert_touched` since the engine began and,
+        over what was added since the last read, the busiest expert of a
+        layer over the layer's mean (mean over the layers; 1.0 is an even
+        load) and the share of the experts a decode step reached."""
+        pairs, touched = delta["expert_tokens"], delta["expert_touched"]
+        mean = pairs.mean(axis=1)
+        out = {"expert_tokens": now["expert_tokens"].tolist(),
+               "expert_touched": now["expert_touched"].tolist(),
+               "expert_load_max_over_mean": None,
+               "experts_touched_share": None}
+        if (mean > 0).all():
+            out["expert_load_max_over_mean"] = float(
+                (pairs.max(axis=1) / mean).mean())
+        if steps > 0:
+            out["experts_touched_share"] = float(
+                touched.sum() / (steps * touched.size))
+        return out
+
     def _pairs(self, sel, live):
         """Token-expert pairs [expert layers, E] of the tokens marked
         `live` [N]; sel [expert layers, N, k]."""
@@ -549,3 +585,126 @@ class HybridDecodeModel(DecodeModel):
             "expert_touched": cache["expert_touched"]
             + (hit > 0).astype(jnp.int32),
         }, logits
+
+
+class LoopedDecodeModel(DecodeModel):
+    """Serving adapter around `models/ouro.py`: ONE stack of layers run
+    `total_ut_steps` times a token. Pass t attends over pass t's K/V only,
+    so a token owns a K/V row for every layer of every pass: two paged
+    parts `k`, `v` [passes x layers, P+1, ps, Hkv, d], row `ouro.cache_row`
+    = t L + l, under the ONE page table (a page id means the same `ps`
+    positions in every pass and layer). At Ouro-2.6B's sizes that is 1.5
+    MiB a token: the pool, not the slots, sets what a chip holds, and a
+    copy of it does not fit, so it stays one donated buffer through both
+    of `ouro.apply_passes`'s loops. Tallies, by pass: `loop_passes` (tokens
+    of real prompt positions and live slots that ran the pass) and
+    `exit_mass` (the sum over those tokens of the exit distribution's
+    p_t). Nothing is kept per slot.
+
+    `prefill` and `decode` are drivers over `ouro.apply_passes`: they
+    differ in where a pass's K/V land and what its q attends over."""
+
+    cache_kinds = {"k": "paged", "v": "paged", "loop_passes": "tally",
+                   "exit_mass": "tally"}
+
+    def __init__(self, cfg: "_ouro.OuroConfig", params=None, seed: int = 0,
+                 attn_impl: str | None = None):
+        super().__init__(cfg, params if params is not None
+                         else _ouro.init_params(cfg, seed), attn_impl)
+        self.passes = cfg.total_ut_steps
+
+    def init_cache(self, num_pages: int, page_size: int,
+                   num_slots: int = 0):
+        cfg = self.cfg
+        shape = (cfg.cache_rows, num_pages + 1, page_size,
+                 cfg.num_key_value_heads, cfg.head_dim)
+        dt = jnp.dtype(cfg.dtype)
+        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
+                "loop_passes": jnp.zeros((cfg.total_ut_steps,), jnp.int32),
+                "exit_mass": jnp.zeros((cfg.total_ut_steps,), jnp.float32)}
+
+    def tally_stats(self, now, delta, steps):
+        """`loop_passes` since the engine began and, over what was added
+        since the last read: passes run a token (tokens that ran pass 0
+        are all the tokens fed) and each pass's share of the exit mass."""
+        ran, mass = delta["loop_passes"], delta["exit_mass"]
+        return {"loop_passes": now["loop_passes"].tolist(),
+                "loop_passes_per_token":
+                    float(ran.sum() / ran[0]) if ran[0] > 0 else None,
+                "exit_mass_share":
+                    (mass / mass.sum()).tolist() if mass.sum() > 0 else None}
+
+    def _tallied(self, cache, lam, live):
+        """The tally parts after a program whose loop gave lam [passes, N]
+        for N tokens of which `live` [N] are real."""
+        n = jnp.sum(live.astype(jnp.int32))
+        p = _ouro.exit_distribution(lam) * live[None, :].astype(jnp.float32)
+        return {"loop_passes": cache["loop_passes"]
+                + jnp.full((lam.shape[0],), n, jnp.int32),
+                "exit_mass": cache["exit_mass"] + jnp.sum(p, axis=1)}
+
+    # -- prefill -------------------------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row,
+                slot=None):
+        """tokens [T] int32 (padded bucket), true_len scalar int32,
+        page_row [M] int32 (fill = trash); `slot` is not used. Dense
+        causal attention within each pass; every pass's K/V of every
+        position into the request's pages. Returns (cache, logits [V]) of
+        the last real position."""
+        cfg = self.cfg
+        T = tokens.shape[0]
+        ps = cache["k"].shape[2]
+        pages = page_row[:T // ps]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, D]
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, state, row):
+            ck, cv = state
+            shape = (T // ps, ps) + k.shape[2:]
+            ck = ck.at[row, pages].set(k[0].reshape(shape).astype(ck.dtype))
+            cv = cv.at[row, pages].set(v[0].reshape(shape).astype(cv.dtype))
+            return _lfm2.dense_causal_attention(q, k, v, scale), (ck, cv)
+
+        x, lam, (ck, cv) = _ouro.apply_passes(
+            cfg, params, x, positions, attend, (cache["k"], cache["v"]))
+        xlast = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, 0,
+                                             keepdims=False)
+        real = jnp.arange(T, dtype=jnp.int32) < true_len
+        return {"k": ck, "v": cv, **self._tallied(cache, lam[:, 0], real)}, \
+            _ouro.head_logits(params, xlast)
+
+    # -- decode --------------------------------------------------------
+    def decode(self, params, cache, tokens, positions, tables):
+        """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
+        inactive slots = all-trash rows with position 0). In each pass and
+        layer: the slot's K/V to its position's page and offset in that
+        pass's row, then ragged paged attention over that row. Returns
+        (cache, logits [S, V])."""
+        cfg = self.cfg
+        S = tokens.shape[0]
+        ps, trash = cache["k"].shape[2], cache["k"].shape[1] - 1
+        # the slot batch as ONE row of S positions, each with its own
+        # position and history: the layers' products are then [S, D] x [D, .]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, S, D]
+        page_of = jnp.take_along_axis(
+            tables, (positions // ps)[:, None], axis=1)[:, 0]
+        off = positions % ps
+        ctx = positions + 1
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, state, row):
+            ck, cv = state
+            ck = ck.at[row, page_of, off].set(k[0].astype(ck.dtype))
+            cv = cv.at[row, page_of, off].set(v[0].astype(cv.dtype))
+            a = paged_attention_decode(
+                q[0], ck, cv, tables, ctx, layer=row, scale=scale,
+                impl=self.attn_impl)
+            return a.reshape(1, S, -1), (ck, cv)
+
+        x, lam, (ck, cv) = _ouro.apply_passes(
+            cfg, params, x, positions[None], attend,
+            (cache["k"], cache["v"]))
+        live = page_of != trash
+        return {"k": ck, "v": cv, **self._tallied(cache, lam[:, 0], live)}, \
+            _ouro.head_logits(params, x[0])

@@ -13,8 +13,9 @@ import pytest
 from benchmark.reference import lfm2_moe as ref
 from paddle_tpu.models import lfm2
 from paddle_tpu.models.gpt import GPTConfig
+from paddle_tpu.models.ouro import OuroConfig
 from paddle_tpu.serving import (DecodeModel, Engine, GPTDecodeModel,
-                                HybridDecodeModel)
+                                HybridDecodeModel, LoopedDecodeModel)
 from tests.test_lfm2_model import sizes_of
 
 LOG = []
@@ -212,23 +213,28 @@ def test_defrag_moves_the_pages_and_leaves_the_slots(served):
     assert run(defrag=True) == run(defrag=False)
 
 
-@pytest.mark.parametrize("which", ["gpt", "hybrid"])
+@pytest.mark.parametrize("which", ["gpt", "hybrid", "looped"])
 def test_both_decode_models_answer_one_cache_interface(served, which):
     """Everything `Engine` reads of a model, `DecodeModel` declares and
-    both models answer; the bodies hand back the cache they were given
+    every model answers; the bodies hand back the cache they were given
     (keys, shapes, dtypes), which donation relies on."""
     cfg, _sizes, params, _eng, _seen = served
-    model = GPTDecodeModel(GPTConfig.tiny(num_layers=1)) if which == "gpt" \
-        else HybridDecodeModel(cfg, params=params)
+    model = {"gpt": lambda: GPTDecodeModel(GPTConfig.tiny(num_layers=1)),
+             "hybrid": lambda: HybridDecodeModel(cfg, params=params),
+             "looped": lambda: LoopedDecodeModel(OuroConfig.tiny())}[which]()
     slot = which == "hybrid"
     assert isinstance(model, DecodeModel)
     assert model.cfg is not None and model.params
     assert model.max_positions == model.cfg.max_position_embeddings
+    assert model.passes == (4 if which == "looped" else 1)
     # the two optional capabilities, and the methods behind them
-    assert model.has_prefill_tail is (not slot) \
+    assert model.has_prefill_tail is (which == "gpt") \
         is hasattr(model, "prefill_tail")
     assert model.has_routing is slot is hasattr(model, "routing_of")
     assert not (model.has_prefill_tail and model.slot_state)
+    # a tally has a meaning only through the model
+    assert bool(model.parts_of("tally")) is (which != "gpt")
+    assert DecodeModel.tally_stats(model, {}, {}, 0) == {}
     cache = model.init_cache(8, 4, 3)
     assert set(cache) == set(model.cache_kinds)
     assert set(model.cache_kinds.values()) <= {"paged", "slot", "tally"}

@@ -386,3 +386,62 @@ def test_a_model_without_experts_reports_no_tally(engine):
     _reqs, spans = _run(engine, (_prompt(5), 2))
     assert next(s for s in spans
                 if s.name == "engine.prefill").attrs["slot"] == 0
+
+
+# -- a model whose layers run several times a token (ISSUE 30) ----------------
+
+def test_a_looped_model_names_its_passes_and_its_tallies():
+    from paddle_tpu.models.ouro import OuroConfig
+    from paddle_tpu.serving import LoopedDecodeModel
+    cfg = OuroConfig.tiny()
+    eng = Engine(LoopedDecodeModel(cfg, seed=0), num_slots=2, num_pages=16,
+                 page_size=8, max_seq_len=32)
+    reqs, spans = _run(eng, (_prompt(5), 3), (_prompt(9, 1), 3))
+    for name in ("engine.prefill", "engine.decode"):
+        got = [s.attrs["passes"] for s in spans if s.name == name]
+        assert got and set(got) == {cfg.total_ut_steps}
+    st = eng.stats()
+    fed = sum(int(r.prompt.size) + len(r.generated) - 1 for r in reqs)
+    assert st["loop_passes"] == [fed] * 4
+    assert st["loop_passes_per_token"] == 4.0
+    assert len(st["exit_mass_share"]) == 4
+    assert "expert_tokens" not in st
+    # K and V, 12 rows of 4 heads of 16, float32: bytes one token holds
+    gauge = registry.REGISTRY.get("paddle_tpu_serving_paged_bytes_per_token")
+    assert gauge.labels(engine=eng.engine_id).value == 2 * 12 * 4 * 16 * 4
+    # no span per pass: the loop is inside one program
+    steps = [s for s in spans if s.name == "engine.step"
+             and not s.attrs.get("idle")]
+    for stp in steps:
+        assert [k.name for k in _children(spans, stp)] == PHASES
+
+
+def test_a_model_that_runs_its_layers_once_says_one_pass(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 2))
+    assert {s.attrs["passes"] for s in spans
+            if s.name in ("engine.prefill", "engine.decode")} == {1}
+    st = engine.stats()
+    assert "loop_passes" not in st and "exit_mass_share" not in st
+
+
+def test_the_experts_stats_are_the_tallies_read_as_before():
+    """What a tally means moved from the engine to the model
+    (`DecodeModel.tally_stats`): the numbers are those the engine made."""
+    from paddle_tpu.models.lfm2 import LFM2Config
+    from paddle_tpu.serving import HybridDecodeModel
+    cfg = LFM2Config.tiny()
+    eng = Engine(HybridDecodeModel(cfg, seed=0), num_slots=2, num_pages=16,
+                 page_size=8, max_seq_len=32)
+    _run(eng, (_prompt(5), 3), (_prompt(9, 1), 4))
+    pairs = np.asarray(eng.cache["expert_tokens"]).astype(np.int64)
+    touched = np.asarray(eng.cache["expert_touched"]).astype(np.int64)
+    st = eng.stats()
+    assert st["expert_tokens"] == pairs.tolist()
+    assert st["expert_touched"] == touched.tolist()
+    mean = pairs.mean(axis=1)
+    want = float((pairs.max(axis=1) / mean).mean()) \
+        if (mean > 0).all() else None
+    assert st["expert_load_max_over_mean"] == want
+    assert st["experts_touched_share"] == pytest.approx(
+        touched.sum() / (st["steps"] * touched.size))
+    assert eng.stats()["experts_touched_share"] is None
