@@ -10,9 +10,12 @@ slot batch. Each scheduler iteration (`step()`):
   2. admit queued requests into free slots (capacity-gated FIFO), run
      one jitted PREFILL per admission (prompt K/V -> pages, per-slot
      state -> the request's slot, first token);
-  3. run ONE jitted DECODE over the whole slot batch (inactive slots
-     ride along pointed at the trash page) and record each slot's token,
-     evicting on EOS / max_new_tokens.
+  3. dispatch ONE jitted DECODE over the whole slot batch (inactive
+     slots ride along pointed at the trash page), then read the tokens
+     of the decode dispatched the step BEFORE and record each slot's
+     token, evicting on EOS / max_new_tokens. One decode is always in
+     flight while the host admits, builds and emits: a running slot's
+     input token is the previous decode's output, taken on the device.
 
 Compilation contract: decode is one program per (slots, pages) bucket —
 an Engine has exactly one such bucket, so one compile for its lifetime;
@@ -35,6 +38,7 @@ import threading
 import time
 import weakref
 from collections import defaultdict, deque
+from typing import NamedTuple
 
 import numpy as np
 
@@ -112,6 +116,17 @@ def _drop_engine_series(eid: str):
         m.remove_matching(engine=eid)
 
 
+# `tokens` entry of a slot whose input is the decode before's output
+_FROM_DEVICE = -1
+
+
+class _InFlight(NamedTuple):
+    """A dispatched decode whose tokens the host has not read."""
+    step: int           # `step` of the engine.step that dispatched it
+    tokens: object      # [S] int32, on the device
+    reqs: dict          # slot -> the request it makes a token for
+
+
 def _bucket_len(n: int, page_size: int) -> int:
     """Smallest page-aligned power-of-two-pages length >= n."""
     pages = max(1, math.ceil(n / page_size))
@@ -136,6 +151,7 @@ class Engine:
                  eos_id: int | None = None, max_queue: int = 256,
                  prefix_cache_pages: int | None = None):
         import jax
+        import jax.numpy as jnp
 
         if not isinstance(model, DecodeModel):
             raise TypeError(
@@ -296,9 +312,12 @@ class Engine:
                                 seeds, steps)
             return cache, tok[0]
 
-        def decode(params, cache, tokens, positions, tables,
+        def decode(params, cache, tokens, prev_tokens, positions, tables,
                    temps, topks, topps, seeds, steps):
             note_compile(f"decode[slots={S},pages={M}]")  # trace-time
+            # a slot still running from the decode before has no token on
+            # the host yet (_FROM_DEVICE): it is that decode's output
+            tokens = jnp.where(tokens == _FROM_DEVICE, prev_tokens, tokens)
             cache, logits = model.decode(params, cache, tokens,
                                          positions, tables)
             return cache, sample_tokens(logits, temps, topks, topps,
@@ -308,6 +327,12 @@ class Engine:
         self._prefill = jax.jit(prefill, **kw)
         self._prefill_tail = jax.jit(prefill_tail, **kw)
         self._decode = jax.jit(decode, **kw)
+        # the decode whose tokens the host has not read yet (None: none),
+        # and what a decode with none before it takes as `prev_tokens`
+        self._inflight: _InFlight | None = None
+        self._no_tokens = jnp.zeros((S,), jnp.int32)
+        self._decodes_ahead = 0
+        self._tokens_discarded = 0
 
         # perf plane: per-bucket FLOP costs land in _register_perf_cost
         # on each bucket's first (compiling) call; a bounded window of
@@ -488,7 +513,10 @@ class Engine:
             self.prefix_cache.insert(req.prompt[:n * self.page_size],
                                      req.table.pages[:n])
 
-    def _run_prefill(self, req: Request):
+    def _run_prefill(self, req: Request) -> bool:
+        """Prefill `req` into its slot and record its first token, read
+        from the device here; False for a bootstrap admission, which
+        runs no program and reads nothing."""
         import jax.numpy as jnp
         if req.prefix_cow is not None:
             self._apply_cow(req)
@@ -504,7 +532,7 @@ class Engine:
                            trace_id=req.trace_id, engine=self.engine_id,
                            request=req.id,
                            cached_tokens=m.tokens)
-            return
+            return False
         start = m.tokens if m is not None else 0
         tail = req.prompt[start:] if start else req.prompt
         T = _bucket_len(tail.size, self.page_size)
@@ -556,6 +584,7 @@ class Engine:
             self._m_sampling_tokens.inc()
         if self.scheduler.record_token(req, tok):
             self._note_done(req)
+        return True
 
     def _keep_routing(self, req: Request, status: str):
         """`Scheduler.before_release`: the routing of a flagged request,
@@ -574,10 +603,25 @@ class Engine:
         in order, one after the other with nothing between them:
         `engine.admit` (deadlines, admission and the admitted requests'
         prefills), `engine.build` (the numpy batch and its transfers),
-        `engine.decode` (`engine.dispatch`, then `engine.wait` for the
-        tokens) and `engine.emit` (metering and one `record_token` a
-        slot). An engine with nothing queued and nothing running records
-        nothing."""
+        `engine.decode` (`engine.dispatch` of this step's decode, then
+        `engine.wait` for the tokens of the decode dispatched the step
+        before) and `engine.emit` (metering and one `record_token` a slot
+        of that earlier decode). An engine with nothing queued and
+        nothing running records nothing.
+
+        A decode's tokens are read one call later, so the device runs
+        decode k while the host admits, builds and emits: a slot that is
+        still running takes its input token from decode k-1's output on
+        the device, and its position, sampler counter and pages come from
+        what has been dispatched for it. What a caller may assume: a
+        token appears in `generated` (through `Scheduler.record_token`)
+        no later than the call after the one that dispatched it; a
+        request that ends by `max_new_tokens` is left out of the decode
+        after its last; one that ends any other way (EOS, cancel,
+        deadline, error) while a decode holds its slot has that decode's
+        token discarded, so `generated` never holds a token past the end;
+        and `scheduler.idle` is False while a decode is unread, so a
+        loop that steps until idle has every token."""
         with self._lock:
             if self.scheduler.idle:
                 return False
@@ -589,15 +633,18 @@ class Engine:
                 return self._step_phases(st)
 
     def _step_phases(self, st) -> bool:
+        import jax
         import jax.numpy as jnp
+        prev = self._inflight
         with _tracing.span("engine.admit") as sp:
             for r in self.scheduler.expire_deadlines():
                 self._note_done(r)
             admitted = self.scheduler.admit()
             sp.attrs["admitted"] = st.attrs["admitted"] = len(admitted)
+            drained = False     # a prefill's token read: the device is idle
             for req in admitted:
                 try:
-                    self._run_prefill(req)
+                    drained |= self._run_prefill(req)
                 except Exception as e:
                     # a poison request fails ALONE: evict it with its
                     # pages, keep the engine serving everyone else
@@ -605,10 +652,15 @@ class Engine:
                     self.scheduler.evict(req, "error")
                     self._note_done(req)
                     self._recover_cache("failed prefill")
+                    # a donating backend lost the cache, and with it
+                    # what was in flight
+                    drained, prev = True, self._inflight
             active = [(i, r) for i, r in enumerate(self.scheduler.slots)
                       if r is not None]
         st.attrs["active"] = len(active)
         if not active:
+            # whatever is in flight decodes for requests that have ended
+            self._discard_inflight()
             st.attrs["idle"] = True
             return bool(self.scheduler.queue_depth)
         with _tracing.span("engine.build"):
@@ -624,26 +676,35 @@ class Engine:
             topps = np.ones((S,), np.float32)
             seeds = np.zeros((S, 2), np.uint32)
             steps = np.zeros((S,), np.int32)
-            sampled_n = pages_live = 0
+            batch: dict[int, Request] = {}  # slot -> whom this decode serves
+            pages_live = 0
             for i, r in active:
+                # tokens dispatched for r: those read, and the one of the
+                # decode in flight if it holds r's slot
+                unread = prev is not None and prev.reqs.get(i) is r
+                n = len(r.generated) + unread
+                last = int(r.prompt.size) + n - 1   # position of token n
+                if n >= r.max_new_tokens:
+                    # its last token is in flight: nothing more to decode
+                    pages_live += (last - 1) // self.page_size + 1
+                    continue
+                batch[i] = r
                 # a bootstrap admission (whole prompt cached, prefill
                 # skipped) reaches its first decode with NOTHING
                 # generated: feed the last prompt token at position
                 # prompt_len-1, exactly where prefill would have left it
-                tokens[i] = r.generated[-1] if r.generated \
-                    else int(r.prompt[-1])
-                positions[i] = r.position
+                tokens[i] = _FROM_DEVICE if unread else (
+                    r.generated[-1] if r.generated else int(r.prompt[-1]))
+                positions[i] = last
                 tables[i] = self._row(r)
                 temps[i] = r.temperature
                 topks[i] = r.top_k
                 topps[i] = r.top_p
                 seeds[i] = seed_to_key(r.seed if r.seed is not None
                                        else r.id)
-                steps[i] = len(r.generated)
-                if r.temperature > 0:
-                    sampled_n += 1
+                steps[i] = n
                 # pages that hold a token once this step has written its own
-                pages_live += r.position // self.page_size + 1
+                pages_live += last // self.page_size + 1
             # what the pool has handed out (worst case of every admitted
             # request) against what holds a token: ROADMAP Speed 5
             st.attrs["pages_reserved"] = self.pool.used_pages
@@ -655,76 +716,119 @@ class Engine:
             # must catch while requests keep queueing
             _fi.injector().maybe_stall("serving_decode")
             bucket = f"decode[slots={S},pages={self.max_pages_per_req}]"
-            targs = (self.model.params, self.cache, jnp.asarray(tokens),
-                     jnp.asarray(positions), jnp.asarray(tables),
-                     jnp.asarray(temps), jnp.asarray(topks),
-                     jnp.asarray(topps), jnp.asarray(seeds),
-                     jnp.asarray(steps))
             # as in _run_prefill: read before lower() runs the trace
             pre_compiles = self._compiles.get(bucket, 0)
-            if bucket not in self._compiles:
-                self._register_perf_cost(bucket, self._decode, targs,
-                                         S, self.max_seq_len)
+            if batch:
+                targs = (self.model.params, self.cache, jnp.asarray(tokens),
+                         self._no_tokens if prev is None else prev.tokens,
+                         jnp.asarray(positions), jnp.asarray(tables),
+                         jnp.asarray(temps), jnp.asarray(topks),
+                         jnp.asarray(topps), jnp.asarray(seeds),
+                         jnp.asarray(steps))
+                if bucket not in self._compiles:
+                    self._register_perf_cost(bucket, self._decode, targs,
+                                             S, self.max_seq_len)
+        # the device runs behind the host unless this step's admission
+        # read a prefill's token, which waits for everything dispatched
+        ahead = bool(batch) and prev is not None and not drained
+        next_toks = None
         try:
             t0 = time.perf_counter()
             with _tracing.span("engine.decode", engine=self.engine_id,
-                               active=len(active),
+                               active=len(batch), ahead=ahead,
                                passes=self.model.passes) as sp:
                 with _tracing.span("engine.dispatch"):
-                    self.cache, device_toks = self._decode(*targs)
-                with _tracing.span("engine.wait"):
-                    if sample:
-                        # fenced phase boundaries: dispatch ends when
-                        # the async jit call returns, device when the
-                        # result is ready, transfer when it is host-side
-                        import jax
+                    # with nothing left to decode, this step only reads
+                    fl = None
+                    if batch:
+                        self.cache, device_toks = self._decode(*targs)
+                        fl = _InFlight(self._step_no, device_toks, batch)
+                        self._m_steps.inc()
+                        self._decodes_ahead += ahead
+                        self._note_flops(self._bucket_flops.get(bucket))
+                    self._inflight = fl
+                with _tracing.span(
+                        "engine.wait",
+                        of_step=None if prev is None else prev.step):
+                    if prev is not None:
+                        # a sampled step fences its phase boundaries:
+                        # dispatch ends when the async jit call returns,
+                        # device when the step before's result is ready,
+                        # transfer when it is host-side
                         t1 = time.perf_counter()
-                        jax.block_until_ready(device_toks)
+                        if sample:
+                            jax.block_until_ready(prev.tokens)
                         t2 = time.perf_counter()
-                        next_toks = np.asarray(device_toks)
+                        next_toks = np.asarray(prev.tokens)
                         t3 = time.perf_counter()
-                    else:
-                        next_toks = np.asarray(device_toks)
                 compiled = self._compiles.get(bucket, 0) > pre_compiles
                 if compiled:
                     sp.attrs["compiled"] = True
             dt = time.perf_counter() - t0
             self._m_decode_h.observe(dt)
         except Exception as e:
-            # a decode-step failure poisons the whole slot batch (the
-            # cache buffer may be donated/invalid): fail the in-flight
-            # requests with their pages freed rather than wedging them
+            # a decode-step failure poisons the whole slot batch and the
+            # decode dispatched behind it (the cache buffer may be
+            # donated/invalid): fail the in-flight requests with their
+            # pages freed rather than wedging them
             for _i, r in active:
                 r.error = f"decode failed: {type(e).__name__}: {e}"
                 self.scheduler.evict(r, "error")
                 self._note_done(r)
+            self._discard_inflight()
             self._recover_cache("failed decode")
             raise
         with _tracing.span("engine.emit") as sp:
             if compiled:
                 _perf.note_compile_seconds("engine.decode", dt)
-            elif sample:
+            elif sample and next_toks is not None:
                 # host = batch building (token/position/table arrays);
-                # dispatch = the async jit call returning; device = the
-                # block_until_ready fence; transfer = device->host copy
+                # dispatch = the async jit call returning; device = what
+                # was left of the step before's decode once this one was
+                # dispatched (the block_until_ready fence); transfer =
+                # device->host copy
                 _perf.record_breakdown(self._perf_name, {
                     "host": t0 - t_host0,
                     "dispatch": t1 - t0,
                     "device": t2 - t1,
                     "transfer": t3 - t2,
                 })
-            self._note_tokens(len(active))
-            if sampled_n:
-                self._m_sampling_tokens.inc(sampled_n)
-            self._note_flops(self._bucket_flops.get(bucket))
-            self._m_steps.inc()
-            finished = 0
-            for i, r in active:
+            finished = recorded = sampled_n = 0
+            for i, r in (prev.reqs.items() if prev is not None else ()):
+                if self.scheduler.slots[i] is not r:
+                    # it ended (EOS, cancel, deadline, error) while this
+                    # decode held its slot: the token is past its end
+                    self._tokens_discarded += 1
+                    continue
+                recorded += 1
+                sampled_n += r.temperature > 0
                 if self.scheduler.record_token(r, int(next_toks[i])):
                     self._note_done(r)
                     finished += 1
+            if recorded:
+                self._note_tokens(recorded)
+            if sampled_n:
+                self._m_sampling_tokens.inc(sampled_n)
             sp.attrs["finished"] = finished
+            self._forget_orphaned()
         return True
+
+    def _discard_inflight(self):
+        """Forget the decode in flight: its tokens are nobody's (its
+        requests ended, or the cache it ran on is lost). Its K/V writes
+        landed in pages its requests had reserved, before any program
+        dispatched later can touch them."""
+        if self._inflight is not None:
+            self._tokens_discarded += len(self._inflight.reqs)
+            self._inflight = None
+
+    def _forget_orphaned(self):
+        """Discard the decode in flight once every request of it has
+        ended, so that an idle scheduler means nothing is unread."""
+        fl = self._inflight
+        if fl is not None and not any(self.scheduler.slots[i] is r
+                                      for i, r in fl.reqs.items()):
+            self._discard_inflight()
 
     def _recover_cache(self, why: str):
         """After a failed jitted call on a DONATING backend the cache
@@ -737,6 +841,7 @@ class Engine:
             r.error = f"kv cache lost to a {why} (donated buffer)"
             self.scheduler.evict(r, "error")
             self._note_done(r)
+        self._discard_inflight()
         self.cache = self.model.init_cache(self.num_pages, self.page_size,
                                            self.num_slots)
         self._tally_seen = {}
@@ -760,7 +865,9 @@ class Engine:
         """Abandon a request (frontend timeout, client gone): dequeue or
         preempt it, freeing its pages. False if it already finished."""
         with self._lock:
-            return self.scheduler.cancel(req)
+            cancelled = self.scheduler.cancel(req)
+            self._forget_orphaned()
+            return cancelled
 
     def run_until_idle(self, max_steps: int = 100000):
         for _ in range(max_steps):
@@ -986,6 +1093,8 @@ class Engine:
                 if self.prefix_cache is not None else None,
                 "model_version": self.model_version,
                 "steps": int(self._m_steps.value),
+                "decodes_ahead": self._decodes_ahead,
+                "tokens_discarded": self._tokens_discarded,
                 "tokens_generated": total,
                 "tokens_per_sec": round(tps, 2),
                 "tokens_per_s_per_chip": rates["tokens_per_s_per_chip"],

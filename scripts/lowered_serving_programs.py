@@ -130,7 +130,7 @@ def main(argv=None) -> int:
                 emit(f"engine.{impl}.prefill_tail_{T}", eng._prefill_tail,
                      params, cache, i32(T), i32(), i32(), i32(M), *samp(1))
             emit(f"engine.{impl}.decode", eng._decode, params, cache,
-                 i32(S), i32(S), i32(S, M), *samp(S))
+                 i32(S), i32(S), i32(S), i32(S, M), *samp(S))
             del eng
             continue
         cache = jax.eval_shape(

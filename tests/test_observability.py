@@ -303,7 +303,10 @@ ENGINE_STATS_KEYS = {
     "tokens_per_s_per_chip", "mfu",
     # PR-19 shared-prefix KV reuse: cache stats block (None when the
     # cache is disabled, which is the default)
-    "prefix_cache"}
+    "prefix_cache",
+    # ISSUE 31 the step loop runs a decode ahead of its reads: how often,
+    # and the tokens of requests that ended under a decode in flight
+    "decodes_ahead", "tokens_discarded"}
 POOL_STATS_KEYS = {
     "num_pages", "page_size", "free_pages", "used_pages", "occupancy",
     "alloc_count", "free_count", "alloc_failures",

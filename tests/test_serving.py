@@ -485,6 +485,7 @@ def _engine_programs(family):
     programs = {
         "decode": (eng._decode, (
             *head, jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), jnp.int32),
             jnp.full((S, M), eng.trash_page, jnp.int32), *samp(S)), S),
         "prefill": (eng._prefill, (
             *head, toks, np.int32(T - 3), row, np.int32(1), *samp(1)), 1),
@@ -788,3 +789,355 @@ def test_engine_cancel_queued_and_running(tiny_engine):
     assert eng.pool.used_pages == 0
     assert not eng.cancel(running)           # already finished
     eng.run_until_idle()
+
+
+# ---------------------------------------------------------------------------
+# a decode in flight behind the host (ISSUE 31): `step()` dispatches decode
+# k before it reads decode k-1, whose tokens feed it on the device
+# ---------------------------------------------------------------------------
+
+FAMILIES = ["gpt", "hybrid", "looped"]
+AHEAD_KW = dict(num_slots=3, num_pages=40, page_size=4, max_seq_len=48)
+SAMPLED = dict(temperature=0.8, top_k=12, top_p=0.9)
+
+
+@functools.lru_cache(maxsize=None)
+def _family_model(family):
+    if family == "gpt":
+        return GPTDecodeModel(GPTConfig.tiny(num_layers=2), seed=0)
+    if family == "hybrid":
+        from paddle_tpu.models import lfm2
+        from paddle_tpu.serving import HybridDecodeModel
+        return HybridDecodeModel(lfm2.LFM2Config.tiny(), seed=0)
+    from paddle_tpu.models import ouro
+    from paddle_tpu.serving import LoopedDecodeModel
+    return LoopedDecodeModel(ouro.OuroConfig.tiny(), seed=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_programs(family):
+    """The model's two bodies with the sampler behind them, as a loop
+    that reads every token before it makes the next one calls them."""
+    from paddle_tpu.serving.sampling import sample_tokens
+    model = _family_model(family)
+
+    def prefill(params, cache, tokens, true_len, row, *samp):
+        cache, lg = model.prefill(params, cache, tokens, true_len, row,
+                                  np.int32(0))
+        return cache, sample_tokens(lg[None, :], *samp)[0]
+
+    def decode(params, cache, tokens, positions, tables, *samp):
+        cache, lg = model.decode(params, cache, tokens, positions, tables)
+        return cache, sample_tokens(lg, *samp)
+
+    return jax.jit(prefill), jax.jit(decode)
+
+
+def _step_by_step(family, prompt, max_new, seed, temperature=0.0, top_k=0,
+                  top_p=1.0, eos_id=None):
+    """The reference: one request alone in slot 0, its prefill and then one
+    decode a token, each token on the host before the next call is made."""
+    from paddle_tpu.serving.engine import _bucket_len
+    from paddle_tpu.serving.sampling import seed_to_key
+    model = _family_model(family)
+    S, P, ps = (AHEAD_KW[k] for k in ("num_slots", "num_pages", "page_size"))
+    M = AHEAD_KW["max_seq_len"] // ps
+    prefill, decode = _plain_programs(family)
+    prompt = np.asarray(prompt, np.int32)
+    cache = model.init_cache(P, ps, S)
+    n_pages = pages_needed(prompt.size + max_new, ps)
+    row = np.full((M,), P, np.int32)
+    row[:n_pages] = np.arange(n_pages)
+
+    def samp(n, step):
+        key = np.zeros((n, 2), np.uint32)
+        key[0] = seed_to_key(seed)
+        first = lambda v, dt, rest: np.asarray(     # noqa: E731
+            [v] + [rest] * (n - 1), dt)
+        return (first(temperature, np.float32, 0.0),
+                first(top_k, np.int32, 0), first(top_p, np.float32, 1.0),
+                key, first(step, np.int32, 0))
+
+    T = min(_bucket_len(prompt.size, ps), M * ps)
+    toks = np.zeros((T,), np.int32)
+    toks[:prompt.size] = prompt
+    cache, tok = prefill(model.params, cache, toks, np.int32(prompt.size),
+                         row, *samp(1, 0))
+    out = [int(tok)]
+    tables = np.full((S, M), P, np.int32)
+    tables[0] = row
+    while len(out) < max_new and out[-1] != eos_id:
+        tokens = np.zeros((S,), np.int32)
+        positions = np.zeros((S,), np.int32)
+        tokens[0] = out[-1]
+        positions[0] = prompt.size + len(out) - 1
+        cache, nxt = decode(model.params, cache, tokens, positions, tables,
+                            *samp(S, len(out)))
+        out.append(int(np.asarray(nxt)[0]))
+    return out
+
+
+def _jobs(family, n, seed, max_new=(3, 12)):
+    """`n` requests of mixed lengths, every other one sampled."""
+    cfg = _family_model(family).cfg
+    rng = np.random.RandomState(seed)
+    jobs = []
+    for i in range(n):
+        prompt = rng.randint(0, cfg.vocab_size, int(rng.randint(1, 20)))
+        kw = dict(SAMPLED, seed=900 + i) if i % 2 else dict(seed=900 + i)
+        jobs.append((prompt, int(rng.randint(*max_new)), kw))
+    return jobs
+
+
+def _ahead_engine(family, **kw):
+    return Engine(_family_model(family), **{**AHEAD_KW, **kw})
+
+
+def _held(eng, req):
+    """Whether a decode the host has not read holds `req`'s slot."""
+    fl = eng._inflight
+    return fl is not None and fl.reqs.get(req.slot) is req
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_served_tokens_equal_a_step_by_step_decode(family):
+    """Greedy and sampled requests mixed in one batch, more of them than
+    slots so that every slot is reused: token for token what a loop that
+    reads each token before it makes the next one produces."""
+    eng = _ahead_engine(family)
+    jobs = _jobs(family, 8, seed=5)
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
+    eng.run_until_idle()
+    for (p, n, kw), r in zip(jobs, reqs):
+        assert r.status == "done" and len(r.generated) == n
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+    st = eng.stats()
+    assert st["tokens_discarded"] == 0 and st["pool"]["used_pages"] == 0
+    # a decode ran ahead of the host's reads in most steps, and every
+    # token but the prefills' came out of one
+    assert 0 < st["decodes_ahead"] < st["steps"]
+    assert eng._inflight is None
+    assert all(v == 1 for v in st["compiles"].values()), st["compiles"]
+
+
+@pytest.mark.parametrize("beside", [False, True])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_eos_midstream_discards_the_token_in_flight(family, beside):
+    """The EOS is read while the next decode holds the slot: that decode's
+    token is thrown away, nothing follows the EOS, the pages are back."""
+    # the sampled one: a tiny greedy model soon repeats its first tokens
+    (p2, _n2, kw2), (p, _n, kw) = _jobs(family, 2, seed=6)
+    ref = _step_by_step(family, p, 12, **kw)
+    cut = next(k for k in range(2, 11) if ref.index(ref[k]) == k)
+    eng = _ahead_engine(family)
+    other = eng.submit(p2, 12, **kw2) if beside else None
+    req = eng.submit(p, 12, eos_id=ref[cut], **kw)
+    seen_held = False
+    while not req.done():
+        seen_held |= _held(eng, req)
+        eng.step()
+    assert seen_held and req.status == "done"
+    assert list(req.generated) == ref[:cut + 1]
+    assert req.table is None
+    if beside:
+        # its pages went when the EOS was read; the stray token goes when
+        # the decode that held the slot is read, a step later
+        assert eng.pool.used_pages == len(other.table.pages)
+        assert eng.stats()["tokens_discarded"] == 0
+        eng.step()
+        assert eng.stats()["tokens_discarded"] == 1
+        eng.run_until_idle()
+        assert list(other.generated) == _step_by_step(family, p2, 12, **kw2)
+    # the orphaned decode was forgotten with its last request
+    assert eng.scheduler.idle and eng._inflight is None
+    assert eng.pool.used_pages == 0
+    assert eng.stats()["tokens_discarded"] == 1
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline", "evict"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_request_that_ends_under_a_decode_in_flight(family, how):
+    """Its partial output stands as it was, the token of the decode that
+    held its slot is discarded, its neighbour is not disturbed, and the
+    next tenant of the slot and of the pages decodes as if alone (the
+    stray K/V write landed before the tenant's prefill)."""
+    (pa, _, kwa), (pb, _, kwb), (pc, _, kwc) = _jobs(family, 3, seed=7)
+    eng = _ahead_engine(family)
+    a = eng.submit(pa, 12, **kwa)
+    b = eng.submit(pb, 12, **kwb)
+    for _ in range(4):
+        eng.step()
+    assert _held(eng, a) and _held(eng, b)
+    got, slot = list(a.generated), a.slot
+    if how == "cancel":
+        assert eng.cancel(a) and a.status == "cancelled"
+    elif how == "evict":
+        with eng._lock:
+            a.error = "evicted by the test"
+            assert eng.scheduler.evict(a, "error")
+    else:
+        a.deadline = -1.0
+    c = eng.submit(pc, 9, **kwc)
+    if how != "deadline":
+        assert eng.pool.used_pages == len(b.table.pages)
+    eng.step()                  # expires `a`; discards its token either way
+    assert a.done() and list(a.generated) == got
+    assert eng.stats()["tokens_discarded"] == 1
+    eng.step()
+    assert c.slot == slot       # the freed slot's next tenant
+    eng.run_until_idle()
+    assert a.status == {"cancel": "cancelled", "evict": "error",
+                        "deadline": "deadline"}[how]
+    assert list(a.generated) == got == _step_by_step(
+        family, pa, 12, **kwa)[:len(got)]
+    assert list(b.generated) == _step_by_step(family, pb, 12, **kwb)
+    assert list(c.generated) == _step_by_step(family, pc, 9, **kwc)
+    assert eng.stats()["tokens_discarded"] == 1 and eng.pool.used_pages == 0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_defrag_between_two_steps_with_a_decode_in_flight(family):
+    """`defrag` moves the pages of the cache the decode in flight will
+    hand back (a future of it): it orders itself behind that decode."""
+    jobs = _jobs(family, 3, seed=8, max_new=(10, 12))
+    eng = _ahead_engine(family)
+    first = eng.submit(jobs[0][0], 2, **jobs[0][2])
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs[1:]]
+    for _ in range(4):
+        eng.step()
+    assert first.done() and all(_held(eng, r) for r in reqs)
+    assert eng.defrag()                     # the first request left a hole
+    live = sorted(pg for r in reqs for pg in r.table.pages)
+    assert live == list(range(len(live)))
+    eng.run_until_idle()
+    for (p, n, kw), r in zip(jobs[1:], reqs):
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+
+
+def test_warm_start_between_two_steps_with_a_decode_in_flight(tmp_path):
+    """The flip lands between two dispatches: what was dispatched before
+    it is the old weights', the request goes on under the new ones."""
+    _greedy, (p, _n, kw) = _jobs("gpt", 2, seed=9)     # the sampled one
+    GPTDecodeModel(GPTConfig.tiny(num_layers=2), seed=1).save_checkpoint(
+        str(tmp_path), step=3)
+    eng = Engine(GPTDecodeModel(GPTConfig.tiny(num_layers=2), seed=0),
+                 **AHEAD_KW)
+    req = eng.submit(p, 12, **kw)
+    for _ in range(4):
+        eng.step()
+    assert _held(eng, req)
+    dispatched = len(req.generated) + 1
+    eng.warm_start(str(tmp_path), version=7)
+    assert eng.model_version == 7 and _held(eng, req)
+    eng.run_until_idle()
+    old = _step_by_step("gpt", p, 12, **kw)
+    assert req.status == "done" and len(req.generated) == 12
+    assert list(req.generated)[:dispatched] == old[:dispatched]
+    assert list(req.generated) != old       # the new weights took over
+
+
+def test_a_bootstrap_admission_feeds_a_host_token_beside_device_tokens():
+    """Whole prompt cached: no prefill, so nothing drains the device, and
+    the decode takes this slot's token (the prompt's last) from the host
+    and its neighbour's from the decode before."""
+    from paddle_tpu.observability.tracing import TRACER
+    (pa, _, kwa), (pb, _, kwb) = _jobs("gpt", 2, seed=10)
+    pb = np.resize(pb, 8)                   # two whole pages
+    eng = _ahead_engine("gpt", prefix_cache_pages=16)
+    warm = eng.submit(pb, 4, **kwb)
+    eng.run_until_idle()
+    a = eng.submit(pa, 12, **kwa)
+    for _ in range(3):
+        eng.step()
+    assert _held(eng, a)
+    TRACER.clear()
+    b = eng.submit(pb, 6, **kwb)
+    ahead0 = eng.stats()["decodes_ahead"]
+    eng.step()
+    assert b.status == "running" and b.prefix_match.full
+    assert not [s for s in TRACER.spans() if s.name == "engine.prefill"]
+    dec = next(s for s in TRACER.spans() if s.name == "engine.decode")
+    assert dec.attrs["ahead"] is True and dec.attrs["active"] == 2
+    assert eng.stats()["decodes_ahead"] == ahead0 + 1
+    eng.run_until_idle()
+    assert eng.stats()["prefix_cache"]["cow_copies"] == 1
+    assert list(a.generated) == _step_by_step("gpt", pa, 12, **kwa)
+    assert list(b.generated) == _step_by_step("gpt", pb, 6, **kwb)
+    assert list(b.generated)[:4] == list(warm.generated)
+
+
+@pytest.mark.parametrize("at", [1, 3])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_failed_decode_fails_its_steps_requests_and_the_engine_serves_on(
+        family, at):
+    """The first decode of a batch (nothing in flight) or a later one (its
+    predecessor unread): the step's requests end in error with their pages
+    freed, the unread tokens are discarded, and the next request is
+    served as if nothing had happened."""
+    (pa, _, kwa), (pb, _, kwb), (pc, _, kwc) = _jobs(family, 3, seed=11)
+    eng = _ahead_engine(family)
+    real, calls = eng._decode, []
+
+    def decode(*args):
+        calls.append(1)
+        if len(calls) == at:
+            raise RuntimeError("the device said no")
+        return real(*args)
+    eng._decode = decode
+    a = eng.submit(pa, 12, **kwa)
+    b = eng.submit(pb, 12, **kwb)
+    with pytest.raises(RuntimeError, match="said no"):
+        for _ in range(at):
+            eng.step()
+    for r in (a, b):
+        assert r.status == "error" and "decode failed" in r.error
+        assert len(r.generated) == max(1, at - 1)
+    assert eng._inflight is None and eng.pool.used_pages == 0
+    assert eng.stats()["tokens_discarded"] == (2 if at > 1 else 0)
+    c = eng.submit(pc, 9, **kwc)
+    eng.run_until_idle()
+    assert list(c.generated) == _step_by_step(family, pc, 9, **kwc)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_decode_is_dispatched_before_the_one_before_is_read(family):
+    """The structural guard of ISSUE 31, beside PR 25 / 27 / 29's: in a run
+    of N decode steps that admit nothing, every `engine.dispatch` but the
+    first starts before the `engine.wait` that reads the step before ends,
+    that wait names the step before, and all but the first decode count as
+    ahead. An edit that reads before it dispatches fails here, not only in
+    a cell."""
+    from paddle_tpu.observability.tracing import TRACER
+    N = 9
+    eng = _ahead_engine(family)
+    jobs = _jobs(family, 2, seed=12)
+    TRACER.clear()
+    for p, _n, kw in jobs:
+        eng.submit(p, N + 1, **kw)      # the prefill's token, then N more
+    eng.run_until_idle()
+    spans = TRACER.spans()
+    steps = [s for s in spans if s.name == "engine.step"]
+    assert len(steps) == N + 1          # the last one only reads
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s.parent_id, []).append(s)
+    rows = []
+    for st in steps:
+        dec = next(k for k in by_parent[st.span_id]
+                   if k.name == "engine.decode")
+        dispatch, wait = sorted(by_parent[dec.span_id],
+                                key=lambda s: s.start)
+        assert (dispatch.name, wait.name) == ("engine.dispatch",
+                                              "engine.wait")
+        rows.append((st.attrs["step"], dec, dispatch, wait))
+    assert [w.attrs["of_step"] for _n, _d, _dp, w in rows] == \
+        [None] + [n for n, *_ in rows[:-1]]
+    for (_n, dec, dispatch, wait), (n0, *_rest) in zip(rows[1:], rows):
+        assert wait.attrs["of_step"] == n0 == _n - 1
+        assert dispatch.start <= dispatch.end <= wait.start <= wait.end
+    # the first decode follows a prefill's read, the last step has none
+    assert [d.attrs["ahead"] for _n, d, *_ in rows] == \
+        [False] + [True] * (N - 1) + [False]
+    assert [d.attrs["active"] for _n, d, *_ in rows] == [2] * N + [0]
+    st = eng.stats()
+    assert st["steps"] == N and st["decodes_ahead"] == N - 1

@@ -22,20 +22,35 @@ LOG = []
 
 
 class Spy(HybridDecodeModel):
-    """Hands every program's logits to the host, in order."""
+    """Hands every program's logits to the host, in order; a decode's with
+    the positions it fed, since its tokens are read a `step()` later, when
+    the next decode's logits are already the newest."""
 
     def prefill(self, params, cache, tokens, true_len, page_row, slot):
         cache, lg = super().prefill(params, cache, tokens, true_len,
                                     page_row, slot)
-        jax.debug.callback(lambda x: LOG.append(np.asarray(x)[None]), lg,
-                           ordered=True)
+        jax.debug.callback(
+            lambda x: LOG.append((None, np.asarray(x)[None])), lg,
+            ordered=True)
         return cache, lg
 
     def decode(self, params, cache, tokens, positions, tables):
         cache, lg = super().decode(params, cache, tokens, positions, tables)
-        jax.debug.callback(lambda x: LOG.append(np.asarray(x)), lg,
-                           ordered=True)
+        jax.debug.callback(
+            lambda p, x: LOG.append((np.asarray(p), np.asarray(x))),
+            positions, lg, ordered=True)
         return cache, lg
+
+
+def logits_behind(log, req):
+    """The logits row the token about to be recorded for `req` was drawn
+    from: its prefill's, the newest program, or those of the newest decode
+    that fed its slot the position before the token's."""
+    pos = int(req.prompt.size) + len(req.generated) - 1
+    if not req.generated:
+        return pos, log[-1][1][0]
+    return pos, next(lg[req.slot] for fed, lg in reversed(log)
+                     if fed is not None and fed[req.slot] == pos)
 
 
 @pytest.fixture(scope="module")
@@ -50,9 +65,7 @@ def served():
 
     def record_token(req, token):
         jax.effects_barrier()
-        row = LOG[-1][0 if LOG[-1].shape[0] == 1 else req.slot]
-        seen.setdefault(req.id, []).append(
-            (int(req.prompt.size) + len(req.generated) - 1, row))
+        seen.setdefault(req.id, []).append(logits_behind(LOG, req))
         return inner(req, token)
     eng.scheduler.record_token = record_token
     return cfg, sizes, params, eng, seen

@@ -19,6 +19,7 @@ from paddle_tpu.models import ouro
 from paddle_tpu.serving import Engine, LoopedDecodeModel
 from paddle_tpu.serving import model as serving_model
 from tests.test_ouro_model import sizes_of
+from tests.test_serving_hybrid import logits_behind
 
 ATOL = 1e-4
 LENGTHS = [1, 3, 4, 5, 8, 9, 17, 7, 31]      # round a page (4) and a bucket
@@ -30,18 +31,22 @@ def _serve(model, lengths=LENGTHS, new=6, seed=3):
     log = []
 
     class Spy(type(model)):
-        """Hands every program's logits to the host, in order."""
+        """Hands every program's logits to the host, in order (a decode's
+        with the positions it fed: tests/test_serving_hybrid.py)."""
 
         def prefill(self, params, cache, *a):
             cache, lg = super().prefill(params, cache, *a)
-            jax.debug.callback(lambda x: log.append(np.asarray(x)[None]),
-                               lg, ordered=True)
+            jax.debug.callback(
+                lambda x: log.append((None, np.asarray(x)[None])), lg,
+                ordered=True)
             return cache, lg
 
-        def decode(self, params, cache, *a):
-            cache, lg = super().decode(params, cache, *a)
-            jax.debug.callback(lambda x: log.append(np.asarray(x)), lg,
-                               ordered=True)
+        def decode(self, params, cache, tokens, positions, tables):
+            cache, lg = super().decode(params, cache, tokens, positions,
+                                       tables)
+            jax.debug.callback(
+                lambda p, x: log.append((np.asarray(p), np.asarray(x))),
+                positions, lg, ordered=True)
             return cache, lg
 
     eng = Engine(Spy(model.cfg, params=model.params), num_slots=3,
@@ -51,9 +56,7 @@ def _serve(model, lengths=LENGTHS, new=6, seed=3):
 
     def record_token(req, token):
         jax.effects_barrier()
-        row = log[-1][0 if log[-1].shape[0] == 1 else req.slot]
-        seen.setdefault(req.id, []).append(
-            (int(req.prompt.size) + len(req.generated) - 1, row))
+        seen.setdefault(req.id, []).append(logits_behind(log, req))
         return inner(req, token)
     eng.scheduler.record_token = record_token
     rng = np.random.RandomState(seed)

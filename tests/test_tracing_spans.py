@@ -119,13 +119,15 @@ def test_prefill_names_the_admit_that_ran_it(engine):
 def test_at_most_ten_spans_a_decode_step(engine):
     _reqs, spans = _run(engine, (_prompt(5), 8))
     steps = [s for s in spans if s.name == "engine.step"]
-    assert len(steps) == 7          # the prefill's token, then one a step
+    # the prefill's token and the first decode's dispatch, then a token
+    # read and a decode dispatched a step, then the last token's read
+    assert len(steps) == 8
     # a step that admits nothing: itself, four phases, dispatch and wait
     assert len(spans) - 2 == 7 * len(steps)     # + one prefill, one queue
     # the first step also holds the prefill, and ends the queue span
     inside = [sum(1 for s in spans if st.start <= s.start and s.end <= st.end)
               for st in steps]
-    assert inside == [8] + [7] * 6 and inside[0] + 1 <= 10
+    assert inside == [8] + [7] * 7 and inside[0] + 1 <= 10
 
 
 def test_compiled_marks_the_call_that_compiled():
