@@ -1,0 +1,348 @@
+"""DeepSeek-V3 block, functional core (`model_type: deepseek_v3`; defaults:
+Kakao kanana-2-30b-a3b, the block without the query's low-rank step):
+multi-head LATENT attention and, after `first_k_dense_replace` dense
+layers, routed experts beside shared ones.
+
+    x = E[ids]
+    for l in layers:
+        h = rmsnorm(x, g1)
+        q = h Wq -> [T, H, dn + dr] = [q_nope | q_rope]
+        [c | kr] = h Wkva -> [T, C + dr];  c = rmsnorm(c, gkv)
+        q_rope, kr = rope(q_rope), rope(kr)       # pairs (2i, 2i+1); kr is ONE
+                                                  # key, shared by every head
+        k_nope = c WK_h, v = c WV_h               # [T, H, dn], [T, H, dv]
+        a = softmax((q_nope . k_nope + q_rope . kr) / sqrt(dn + dr), causal) v
+        x = x + a Wo
+        h2 = rmsnorm(x, g2)
+        l < first_k_dense_replace:  f = swiglu(h2; F)
+        else:  s = sigmoid(h2 Wg) (float32);  sel = top_k(s + b)
+               w = scaling * s[sel] / sum(s[sel])
+               f = sum_j w_j swiglu_{sel_j}(h2; Fm) + swiglu_shared(h2; n_shared Fm)
+        x = x + f
+    logits = rmsnorm(x, g_f) W_out                # untied
+
+What a token leaves behind in a layer is the row [c | kr]: C + dr numbers
+(576), not the H (dn + dr + dv) (10,240) of the keys and values it stands
+for. Attention therefore has TWO forms of one function:
+
+  expanded  the equations above: k_nope and v built from c for every head,
+            products dn + dr wide. Cheapest over T^2 pairs: `forward` and
+            the serving prefill.
+  absorbed  WK_h folded into the query, WV_h applied after the sum:
+            q'_h = q_nope_h WK_h^T                          [H, C]
+            score = (q'_h . c_j + q_rope_h . kr_j) / sqrt(dn + dr)
+            o_h = sum_j softmax_j(score) c_j                [H, C]
+            a_h = o_h WV_h
+            A query of C + dr against the cached row, the value the row's
+            own first C numbers: nothing is expanded. The serving decode
+            (`absorb_query`, `expand_value` round the paged kernel).
+
+The layer is written once (`apply_layers`, an unrolled loop: the first
+layer's feed-forward is of another kind) and takes `attend(p, q_nope,
+q_rope, c, kr, state, l) -> (a [B, T, H dv], state)`: what is kept of
+[c | kr] and which form runs. `rmsnorm`, `dense_ffn` and the routed part
+(`routed_ffn` -> parallel/moe.py::dropless_moe_ffn) are models/lfm2.py's;
+the shared experts are one SwiGLU of n_shared x Fm that every token takes,
+added beside the routed part there (`dropless_moe_ffn(shared=...)`).
+
+Weights: `{"embed" [V, D], "head" [D, V], "norm" [D], "layers": [per layer
+{"input_layernorm", "post_attention_layernorm" [D], "attn": {wq [D, H (dn +
+dr)], wkv_a [D, C + dr], kv_a_layernorm [C], wk_b [C, H, dn], wv_b [C, H,
+dv], wo [H dv, D]}, "ffn": {w1, w3 [D, F], w2 [F, D]} | {wg [D, E], bias
+[E], w1, w3 [E, D, Fm], w2 [E, Fm, D], "shared": {w1, w3 [D, n Fm], w2
+[n Fm, D]}}}]}`. `wk_b` / `wv_b` are a checkpoint's `kv_b_proj` split by
+head into its key and value halves: the absorbed form reads each alone.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from .lfm2 import dense_ffn, rmsnorm, routed_ffn
+
+__all__ = ["DeepseekV3Config", "init_params", "forward", "apply_layers",
+           "expanded_attention", "absorb_query", "expand_value",
+           "rope_pairs", "head_logits"]
+
+_ROW_BLOCK = 512        # query rows at a time off the flash kernel
+
+
+@dataclass(frozen=True)
+class DeepseekV3Config:
+    """The published keys of `config.json` (defaults: kanana-2-30b-a3b)."""
+    vocab_size: int = 128256
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    first_k_dense_replace: int = 1
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    n_shared_experts: int = 2
+    num_attention_heads: int = 32
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    q_lora_rank: int | None = None
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.448
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 32768
+    initializer_range: float = 0.02
+    dtype: str = "float32"
+    # global ids of the routed experts held here (None = all), as lfm2's
+    experts_held: tuple | None = None
+
+    def __post_init__(self):
+        if self.q_lora_rank is not None:
+            raise NotImplementedError(
+                f"q_lora_rank {self.q_lora_rank}: the query's low-rank "
+                f"step is not built (one projection D -> H (dn + dr))")
+        if (self.n_group, self.topk_group) != (1, 1):
+            raise NotImplementedError(
+                f"n_group {self.n_group}, topk_group {self.topk_group}: "
+                f"only one group is built (the group-limited step then "
+                f"keeps the only group)")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (pairs)")
+
+    # -- what the shared sub-layers of models/lfm2.py read ------------------
+    @property
+    def num_experts(self) -> int:
+        return self.n_routed_experts
+
+    use_expert_bias = True
+
+    @property
+    def num_moe_layers(self) -> int:
+        return max(0, self.num_hidden_layers - self.first_k_dense_replace)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token leaves in a layer: [c | kr]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return 1.0 / math.sqrt(self.qk_head_dim)
+
+    @classmethod
+    def tiny(cls, **kw):
+        """Every kind of layer at test size: one dense layer, two expert
+        layers of 8 experts (2 a token) beside 2 shared."""
+        base = dict(vocab_size=256, hidden_size=64, intermediate_size=160,
+                    moe_intermediate_size=32, num_hidden_layers=3,
+                    n_routed_experts=8, num_experts_per_tok=2,
+                    num_attention_heads=4, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=24,
+                    max_position_embeddings=512, initializer_range=0.1)
+        base.update(kw)
+        return cls(**base)
+
+
+def layer_shapes(cfg: DeepseekV3Config, l: int) -> dict:
+    D, H, C = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    out = {"input_layernorm": (D,), "post_attention_layernorm": (D,),
+           "attn": {"wq": (D, H * (dn + dr)), "wkv_a": (D, C + dr),
+                    "kv_a_layernorm": (C,), "wk_b": (C, H, dn),
+                    "wv_b": (C, H, dv), "wo": (H * dv, D)}}
+    if l < cfg.first_k_dense_replace:
+        F = cfg.intermediate_size
+        out["ffn"] = {"w1": (D, F), "w3": (D, F), "w2": (F, D)}
+    else:
+        E, F = cfg.n_routed_experts, cfg.moe_intermediate_size
+        Eh = E if cfg.experts_held is None else len(cfg.experts_held)
+        Fs = cfg.n_shared_experts * F
+        out["ffn"] = {"wg": (D, E), "bias": (E,), "w1": (Eh, D, F),
+                      "w3": (Eh, D, F), "w2": (Eh, F, D),
+                      "shared": {"w1": (D, Fs), "w3": (D, Fs),
+                                 "w2": (Fs, D)}}
+    return out
+
+
+def init_params(cfg: DeepseekV3Config, seed: int = 0):
+    """Seeded random weights: matrices normal of `initializer_range`; the
+    norms' gains 1 + 0.1 normal (round one, not AT one: a dropped gain
+    then shows); the experts' bias normal of std 0.1 (a program that
+    weighs by score plus bias, or selects on the score, then disagrees)."""
+    dtype = jnp.dtype(cfg.dtype)
+    key = jax.random.PRNGKey(seed)
+
+    def tree(shapes, k):
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            shapes, is_leaf=lambda s: isinstance(s, tuple))
+        out = []
+        for i, (path, shape) in enumerate(flat):
+            name = path[-1].key
+            z = jax.random.normal(jax.random.fold_in(k, i), shape,
+                                  jnp.float32)
+            if name.endswith("norm"):
+                leaf = 1.0 + 0.1 * z
+            else:
+                leaf = (0.1 if name == "bias" else cfg.initializer_range) * z
+            out.append(leaf.astype(dtype))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    top = tree({"embed": (cfg.vocab_size, cfg.hidden_size),
+                "head": (cfg.hidden_size, cfg.vocab_size),
+                "norm": (cfg.hidden_size,)}, jax.random.fold_in(key, 10_000))
+    top["layers"] = [tree(layer_shapes(cfg, l), jax.random.fold_in(key, l))
+                     for l in range(cfg.num_hidden_layers)]
+    return top
+
+
+# ---------------------------------------------------------------------------
+# sub-layers, each written once
+# ---------------------------------------------------------------------------
+
+def rope_pairs(x, positions, theta):
+    """RoPE over adjacent pairs (2i, 2i+1) of the last axis
+    (`rope_interleave`). x [B, T, ..., d], positions [B, T]."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * inv      # [B, T, d/2]
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def latent_projections(p, h, positions, cfg):
+    """h [B, T, D] -> (q_nope [B, T, H, dn], q_rope [B, T, H, dr] rotated,
+    c [B, T, C] normed, kr [B, T, dr] rotated): the query, and the row a
+    token leaves behind."""
+    B, T, _ = h.shape
+    H, dn, C = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
+        cfg.kv_lora_rank
+    q = (h @ p["wq"]).reshape(B, T, H, cfg.qk_head_dim)
+    ckr = h @ p["wkv_a"]
+    c = rmsnorm(ckr[..., :C], p["kv_a_layernorm"], cfg.rms_norm_eps)
+    return (q[..., :dn], rope_pairs(q[..., dn:], positions, cfg.rope_theta),
+            c, rope_pairs(ckr[..., C:], positions, cfg.rope_theta))
+
+
+def _causal_rows(q, k, v, scale, row0):
+    """Rows row0 .. of q [B, R, H, d] against all of k [B, T, H, d] and v
+    [B, T, H, dv], causal."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    qi = row0 + jnp.arange(q.shape[1])[:, None]
+    s = jnp.where(jnp.arange(k.shape[1])[None, :] <= qi, s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", pr.astype(v.dtype), v)
+
+
+def causal_attention(q, k, v, scale):
+    """q, k [B, T, H, d], v [B, T, H, dv] (another width) -> [B, T, H dv].
+    On a TPU the flash kernel (its forward takes a value width of its
+    own); elsewhere plain softmax, the query rows in blocks once the
+    [H, T, T] scores would not fit (537 MB of float32 at 2,048, 8.6 GB at
+    8,192)."""
+    from ..ops.pallas_attention import can_use_flash, flash_attention
+    B, T, H, _ = q.shape
+    heads_first = [a.transpose(0, 2, 1, 3) for a in (q, k, v)]
+    if can_use_flash(*heads_first, None):
+        o = flash_attention(
+            *heads_first, scale=scale, causal=True,
+            # whole K and V of a head sit in VMEM: at 8,192 positions a
+            # query block of 512 beside them passes Mosaic's 16 MiB
+            block_q=256 if T > 4096 else None)
+        return o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    if T <= 2 * _ROW_BLOCK or T % _ROW_BLOCK:
+        return _causal_rows(q, k, v, scale, 0).reshape(B, T, -1)
+    qb = q.reshape(B, T // _ROW_BLOCK, _ROW_BLOCK, H, -1).swapaxes(0, 1)
+    rows = jnp.arange(T // _ROW_BLOCK) * _ROW_BLOCK
+    o = jax.lax.map(lambda a: _causal_rows(a[0], k, v, scale, a[1]),
+                    (qb, rows))
+    return o.swapaxes(0, 1).reshape(B, T, -1)
+
+
+def expanded_attention(p, q_nope, q_rope, c, kr, scale):
+    """The expanded form over one whole causal sequence: keys and values
+    of every head built from c. Returns [B, T, H dv]."""
+    H = q_nope.shape[2]
+    k_nope = jnp.einsum("btc,chd->bthd", c, p["wk_b"])
+    v = jnp.einsum("btc,chd->bthd", c, p["wv_b"])
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(kr[:, :, None, :],
+                                  kr.shape[:2] + (H, kr.shape[-1]))], -1)
+    return causal_attention(jnp.concatenate([q_nope, q_rope], -1), k, v,
+                            scale)
+
+
+def absorb_query(p, q_nope, q_rope):
+    """The absorbed form's query [..., H, C + dr] = [q_nope WK^T | q_rope]:
+    what meets a cached row [c | kr]."""
+    qc = jnp.einsum("...hd,chd->...hc", q_nope, p["wk_b"])
+    return jnp.concatenate([qc, q_rope.astype(qc.dtype)], -1)
+
+
+def expand_value(p, o):
+    """The absorbed form's output o [..., H, C] (a softmax-weighted sum of
+    cached c) through the value up-projection: [..., H dv]."""
+    a = jnp.einsum("...hc,chd->...hd", o, p["wv_b"])
+    return a.reshape(a.shape[:-2] + (-1,))
+
+
+def head_logits(params, x, cfg):
+    """Final norm and the untied head, float32 logits."""
+    x = rmsnorm(x, params["norm"], cfg.rms_norm_eps)
+    return jnp.einsum("...d,dv->...v", x, params["head"],
+                      preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# the one loop over the layers, and the plain driver
+# ---------------------------------------------------------------------------
+
+def apply_layers(cfg, params, x, positions, attend, state):
+    """x [B, T, D] through every layer. `attend(p["attn"], q_nope, q_rope,
+    c, kr, state, l) -> (a [B, T, H dv], state)`. Returns (x, state, sel
+    [expert layers, B T, k])."""
+    sels = []
+    eps = cfg.rms_norm_eps
+    for l, p in enumerate(params["layers"]):
+        h = rmsnorm(x, p["input_layernorm"], eps)
+        a, state = attend(p["attn"], *latent_projections(
+            p["attn"], h, positions, cfg), state, l)
+        x = x + a @ p["attn"]["wo"]
+        h = rmsnorm(x, p["post_attention_layernorm"], eps)
+        if l < cfg.first_k_dense_replace:
+            f = dense_ffn(p["ffn"], h)
+        else:
+            f, sel = routed_ffn(p["ffn"], h, cfg)
+            sels.append(sel)
+        x = x + f
+    k = cfg.num_experts_per_tok
+    sel = jnp.stack(sels) if sels else jnp.zeros(
+        (0, x.shape[0] * x.shape[1], k), jnp.int32)
+    return x, state, sel
+
+
+def forward(params, ids, cfg: DeepseekV3Config):
+    """ids [B, T] -> logits [B, T, V] float32: the whole sequence at once,
+    no cache, the expanded form."""
+    B, T = ids.shape
+    x = jnp.take(params["embed"], ids, axis=0)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+
+    def attend(p, q_nope, q_rope, c, kr, state, l):
+        return expanded_attention(p, q_nope, q_rope, c, kr,
+                                  cfg.softmax_scale), state
+
+    x, _, _ = apply_layers(cfg, params, x, positions, attend, None)
+    return head_logits(params, x, cfg)

@@ -249,14 +249,18 @@ def dense_ffn(p, h):
 
 
 def routed_ffn(p, h, cfg):
-    """h [B, T, D] -> (f [B, T, D], sel [B T, k] chosen experts)."""
+    """h [B, T, D] -> (f [B, T, D], sel [B T, k] chosen experts). A layer
+    with shared experts (`p["shared"]`: models/deepseek_v3.py) gets their
+    part added."""
     shape = h.shape
+    shared = p.get("shared")
     y, sel = dropless_moe_ffn(
         h.reshape(-1, shape[-1]), p["wg"],
         p["bias"] if cfg.use_expert_bias else None,
         p["w1"], p["w3"], p["w2"], top_k=cfg.num_experts_per_tok,
         norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-        experts_held=cfg.experts_held)
+        experts_held=cfg.experts_held,
+        shared=shared and (shared["w1"], shared["w3"], shared["w2"]))
     return y.reshape(shape), sel
 
 

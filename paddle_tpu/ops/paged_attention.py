@@ -66,7 +66,9 @@ from ..fluid.ops.common import x
 from .pallas_attention import on_tpu
 
 __all__ = ["paged_attention_decode", "paged_attention_xla",
-           "paged_attention_pallas"]
+           "paged_attention_pallas", "paged_latent_attention_decode",
+           "paged_latent_attention_xla", "paged_latent_attention_pallas",
+           "latent_row_width"]
 
 _NEG = -1e30
 
@@ -183,30 +185,20 @@ def _softmax_update(carry, q, k, v, live=None):
     return tuple(out)
 
 
-def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
-                  groups, block):
-    """refs: the pools in HBM ([L, P, ps, Hkv, w]: K and V, or one fused
-    [K | V] pool), o_ref, a VMEM buffer [2, B, ps, Hkv, w] a pool, DMA
-    semaphores [pools, 2] (one a buffer) and, in SMEM, which buffer holds
-    the slot's first block.
-
-    A slot of n = cdiv(ctx, ps) live pages is cdiv(n, B) blocks. The
-    copies of block i + 1 (or, after the last, of the NEXT slot's first
-    block: the grid runs in order on one core and scratch outlives a
-    program) are started before block i is waited for and worked on, so
-    the arithmetic and the start of a slot hide behind copies in flight.
-    A table entry past n is never read, nor is the page it names.
-
-    q and o blocks are [1, H, d] (multi-head, G = 1) or group-major
-    [1, G, Hkv, w]. Fused pool: q arrives with zeros in the V lanes, so
-    q . [K | V] is q . K; the accumulator sums p [K | V] and the wrapper
-    keeps its V lanes. No lane is sliced in the kernel."""
-    ps, B, G = page_size, block, groups
-    n_pools = (len(refs) - 3) // 2
-    pools, o_ref = refs[:n_pools], refs[n_pools]
-    bufs, (sem, first_ref) = refs[n_pools + 1:-2], refs[-2:]
+def _walk_blocks(pt_ref, len_ref, layer, pools, bufs, sem, first_ref,
+                 page_size, block, on_block, carry):
+    """The page walk both kernels share: this program's slot is
+    cdiv(n, B) blocks of its n = cdiv(ctx, ps) live pages. The copies of
+    block i + 1 (or, after the last, of the NEXT slot's first block: the
+    grid runs in order on one core and scratch outlives a program) are
+    started before block i is waited for and handed to `on_block(i, b, n,
+    carry) -> carry` in buffer b, so the arithmetic and the start of a
+    slot hide behind copies in flight. A table entry past n is never
+    read, nor is the page it names. Returns (carry, n, blocks walked, the
+    buffer of the first); the caller stores which buffer the next slot
+    starts in (`first_ref`) when it is done with the last."""
+    ps, B = page_size, block
     s, S = pl.program_id(0), pl.num_programs(0)
-    layer = ly_ref[0]
 
     def n_pages(slot):      # ctx >= 1 is the contract; 0 reads as 1, dead
         return jnp.maximum((len_ref[slot] + ps - 1) // ps, 1)
@@ -216,7 +208,7 @@ def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
         b: one a live page and pool."""
         def page(j, _):
             at = pt_ref[slot, i * B + j]
-            for p in range(n_pools):
+            for p in range(len(pools)):
                 act(pltpu.make_async_copy(pools[p].at[layer, at],
                                           bufs[p].at[b, j], sem.at[p, b]))
             return _
@@ -225,10 +217,6 @@ def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
     def start(slot, i, b):
         copies(slot, i, b, lambda c: c.start())
 
-    def page_of(b, j):
-        k = bufs[0][b, j].astype(jnp.float32)
-        return k, (k if n_pools == 1 else bufs[1][b, j].astype(jnp.float32))
-
     @pl.when(s == 0)
     def _first():
         first_ref[0] = 0
@@ -236,10 +224,6 @@ def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
 
     n, b0 = n_pages(s), first_ref[0]
     n_blocks = (n + B - 1) // B
-    # the block of head group g in q_ref and o_ref: [1, H, d] is group 0
-    at = (lambda g: (0,)) if q_ref.ndim == 3 else (lambda g: (0, g))
-    q = [q_ref[at(g)].astype(jnp.float32) * scale for g in range(G)]
-    Hkv, w = q[0].shape
 
     def one_block(i, carry):
         b = (b0 + i) % 2
@@ -253,14 +237,48 @@ def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
             start(s + 1, 0, 1 - b)
 
         copies(s, i, b, lambda c: c.wait())
+        return on_block(i, b, n, carry)
+
+    carry = jax.lax.fori_loop(0, n_blocks, one_block, carry)
+    return carry, n, n_blocks, b0
+
+
+def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
+                  groups, block):
+    """refs: the pools in HBM ([L, P, ps, Hkv, w]: K and V, or one fused
+    [K | V] pool), o_ref, a VMEM buffer [2, B, ps, Hkv, w] a pool, DMA
+    semaphores [pools, 2] (one a buffer) and, in SMEM, which buffer holds
+    the slot's first block. The pages arrive through `_walk_blocks`.
+
+    q and o blocks are [1, H, d] (multi-head, G = 1) or group-major
+    [1, G, Hkv, w]. Fused pool: q arrives with zeros in the V lanes, so
+    q . [K | V] is q . K; the accumulator sums p [K | V] and the wrapper
+    keeps its V lanes. No lane is sliced in the kernel."""
+    ps, B, G = page_size, block, groups
+    n_pools = (len(refs) - 3) // 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, (sem, first_ref) = refs[n_pools + 1:-2], refs[-2:]
+    s = pl.program_id(0)
+
+    def page_of(b, j):
+        k = bufs[0][b, j].astype(jnp.float32)
+        return k, (k if n_pools == 1 else bufs[1][b, j].astype(jnp.float32))
+
+    # the block of head group g in q_ref and o_ref: [1, H, d] is group 0
+    at = (lambda g: (0,)) if q_ref.ndim == 3 else (lambda g: (0, g))
+    q = [q_ref[at(g)].astype(jnp.float32) * scale for g in range(G)]
+    Hkv, w = q[0].shape
+
+    def whole_pages(i, b, n, carry):
         # every page but the slot's last is whole: no mask
         return jax.lax.fori_loop(
             0, jnp.minimum(B, n - 1 - i * B),
             lambda j, c: _softmax_update(c, q, *page_of(b, j)), carry)
 
     zero = jnp.zeros((Hkv, 1), jnp.float32)
-    carry = jax.lax.fori_loop(
-        0, n_blocks, one_block,
+    carry, n, n_blocks, b0 = _walk_blocks(
+        pt_ref, len_ref, ly_ref[0], pools, bufs, sem, first_ref, ps, B,
+        whole_pages,
         ((zero + _NEG, zero, jnp.zeros((Hkv, w), jnp.float32)),) * G)
     # the page the context ends in, still in the last block's buffer
     last = n_blocks - 1
@@ -331,6 +349,175 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, ctx_lens,
     return call(q, pools, page_table, ctx_lens,
                 scale if scale is not None else 1.0 / math.sqrt(d),
                 (not on_tpu()) if interpret is None else interpret, layer, G)
+
+
+# ---------------------------------------------------------------------------
+# Latent rows (multi-head latent attention, absorbed form). A token's state
+# in a layer is ONE row [c | kr] with no head axis: every query head reads
+# it, as key over its whole width and as value over its first
+# `value_width` lanes. H query heads against one row is a matrix product,
+# so this kernel's arithmetic goes to the MXU, a block of pages at a time.
+# ---------------------------------------------------------------------------
+
+LATENT_LANES = 128      # a pool row is padded to whole registers' lanes
+
+
+def latent_row_width(width: int) -> int:
+    """Lanes of a pool row that holds `width` numbers a token: the next
+    multiple of 128 (576 -> 640). The kernel's page copies move whole
+    128-lane tiles, and the device pads another minor dimension to them
+    anyway (scripts/latent_kernel_step0.py weighs the other layouts)."""
+    return -(-width // LATENT_LANES) * LATENT_LANES
+
+
+def paged_latent_attention_xla(q, rows, page_table, ctx_lens, value_width,
+                               scale, layer):
+    """Gather-based path. q [S, H, w], rows [L, P, ps, W >= w]."""
+    S, H, w = q.shape
+    M, ps = page_table.shape[1], rows.shape[2]
+    kv = rows[layer, page_table].reshape(S, M * ps, rows.shape[3])
+    logits = jnp.einsum("shw,stw->sht", q, kv[..., :w],
+                        preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(M * ps, dtype=jnp.int32)[None, None, :]
+    logits = jnp.where(pos < ctx_lens[:, None, None], logits, _NEG)
+    probs = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("sht,stc->shc", probs.astype(kv.dtype),
+                   kv[..., :value_width])
+    return o.astype(q.dtype)
+
+
+def _latent_kernel(pt_ref, len_ref, ly_ref, q_ref, rows_ref, o_ref, buf, sem,
+                   first_ref, *, page_size, scale, block, value_width):
+    """q block [1, H, W] (zeros past the query's own width), o block [1, H,
+    value_width], buf [2, B, ps, W]. A block of B pages is one [B ps, W]
+    matrix: scores [H, B ps] = q . rows^T and acc += p . rows[:, :value
+    width], both on the MXU in the pool's dtype with float32 results.
+    Only a slot's last block has dead tokens (the tail of its last page,
+    and pages of the buffer it did not fill): there the scores are masked
+    and the rows zeroed (a stale row may hold anything, and 0 x NaN is
+    NaN); every other block runs unmasked."""
+    ps, B = page_size, block
+    s = pl.program_id(0)
+    q = q_ref[0]
+    H, W = q.shape
+    T = B * ps
+    ctx = len_ref[s]
+
+    def on_block(i, b, n, carry):
+        m_prev, l_prev, acc = carry
+        kv = buf[b].reshape(T, W)
+        last = (i + 1) * B >= n
+        at = i * T
+        live_col = at + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0) < ctx
+        kv = jax.lax.cond(last,
+                          lambda r: jnp.where(live_col, r, jnp.zeros_like(r)),
+                          lambda r: r, kv)
+        # the MXU's own precision for the pool's dtype, whatever the
+        # process's default (Mosaic refuses bf16 operands at `highest`)
+        sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.DEFAULT,
+                                 preferred_element_type=jnp.float32) * scale
+        live = at + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) < ctx
+        sc = jnp.where(live, sc, _NEG)      # all true but in the last block
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)     # a walked block has a live token: dead = 0
+        pv = jnp.dot(p.astype(kv.dtype), kv[:, :value_width],
+                     precision=jax.lax.Precision.DEFAULT,
+                     preferred_element_type=jnp.float32)
+        return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + pv)
+
+    zero = jnp.zeros((H, 1), jnp.float32)
+    (_, l, acc), _n, n_blocks, b0 = _walk_blocks(
+        pt_ref, len_ref, ly_ref[0], (rows_ref,), (buf,), sem, first_ref, ps,
+        B, on_block,
+        (zero + _NEG, zero, jnp.zeros((H, value_width), jnp.float32)))
+    first_ref[0] = (b0 + n_blocks) % 2
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def paged_latent_attention_pallas(q, rows, page_table, ctx_lens, value_width,
+                                  scale, layer, interpret=None):
+    """The kernel over q [S, H, w] and rows [L, P, ps, W]; the custom
+    call's output is [S, H, value_width]."""
+    S, H, w = q.shape
+    ps, W = rows.shape[2:]
+    if w < W:
+        q = jnp.concatenate([q, jnp.zeros((S, H, W - w), q.dtype)], axis=-1)
+    B = _block_pages(ps * W * rows.dtype.itemsize, 1, page_table.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, H, W), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, value_width), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, B, ps, W), rows.dtype),
+                        pltpu.SemaphoreType.DMA((1, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    kernel = functools.partial(_latent_kernel, page_size=ps,
+                               scale=float(scale), block=B,
+                               value_width=value_width)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=(not on_tpu()) if interpret is None else interpret,
+    )(page_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(rows.dtype), rows)
+
+
+def _gate_latent(S, H, w, value_width, P, ps, M, W, dtype):
+    """(key, candidates, make_args) of the latent path's gate, as
+    `_gate_paged`: a stack of one layer, the layer an argument."""
+    dtype = jnp.dtype(dtype)
+    key = ("paged_attention", "latent", S, H, w, value_width, P, ps, M, W,
+           str(dtype))
+    scale = 1.0 / math.sqrt(w)
+
+    def make_args():
+        import numpy as np
+        rng = np.random.RandomState(0)
+        kq, kr = jax.random.split(jax.random.PRNGKey(0))
+        return (jax.random.normal(kq, (S, H, w), dtype),
+                jax.random.normal(kr, (1, P, ps, W), dtype),   # on the device
+                jnp.asarray(rng.randint(0, P, (S, M)), jnp.int32),
+                jnp.asarray(rng.randint(1, M * ps + 1, (S,)), jnp.int32),
+                jnp.zeros((), jnp.int32))
+
+    def xla(qq, rr, pt, ln, layer):
+        return paged_latent_attention_xla(qq, rr, pt, ln, value_width, scale,
+                                          layer)
+
+    def pallas(qq, rr, pt, ln, layer):
+        return paged_latent_attention_pallas(qq, rr, pt, ln, value_width,
+                                             scale, layer, interpret=False)
+
+    return key, {"xla": xla, "pallas": pallas}, make_args
+
+
+def paged_latent_attention_decode(q, rows, page_table, ctx_lens, *,
+                                  value_width, scale, layer, impl=None):
+    """Ragged paged attention over latent rows: q [S, H, w] (the absorbed
+    query [q' | q_rope]) against the cached rows [L, P, ps, W] of `layer`
+    (int32 scalar, may be traced; W = `latent_row_width(w)`, lanes past w
+    are never written and stay zero), the value a row's first
+    `value_width` lanes. Returns [S, H, value_width]. impl as
+    `paged_attention_decode`'s."""
+    if impl is None:
+        impl = "xla"
+        if not os.environ.get("PADDLE_TPU_DISABLE_PALLAS") and on_tpu():
+            from . import autobench
+            S, H, w = q.shape
+            key, cands, make_args = _gate_latent(
+                S, H, w, value_width, rows.shape[1], rows.shape[2],
+                page_table.shape[1], rows.shape[3], q.dtype)
+            impl = autobench.prefer(key, cands, make_args, default="xla")
+    fn = paged_latent_attention_pallas if impl == "pallas" \
+        else paged_latent_attention_xla
+    return fn(q, rows, page_table, ctx_lens, value_width, scale, layer)
 
 
 def _gate_paged(S, H, d, P, ps, M, dtype, Hkv=None, fused=False):

@@ -153,7 +153,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
         return acc, m_new, l_new
 
     acc, m_i, l_i = jax.lax.fori_loop(
-        0, hi, body, (jnp.zeros((bq, d), jnp.float32),
+        0, hi, body, (jnp.zeros((bq, v_ref.shape[2]), jnp.float32),
                       jnp.full((bq,), _NEG_INF, jnp.float32),
                       jnp.zeros((bq,), jnp.float32)))
     l_safe = jnp.where(l_i == 0.0, 1.0, l_i)
@@ -277,15 +277,18 @@ def _flash(q3, k3, v3, mask2, seed_arr, scale, causal, block_q, block_k,
 
 def _flash_fwd_impl(q3, k3, v3, mask2, seed_arr, scale, causal, block_q,
                     block_k, dropout_p):
-    """q3,k3,v3: (BH, S, D); mask2: (B, 8, T) additive or None."""
+    """q3,k3,v3: (BH, S, D); mask2: (B, 8, T) additive or None. v3 may
+    be (BH, T, Dv) with another width than q's and k's (latent attention's
+    expanded form: 192-wide products, 128-wide values): the forward only,
+    the backward kernels take one width."""
     bh, s, d = q3.shape
-    t = k3.shape[1]
+    t, dv = k3.shape[1], v3.shape[2]
     heads = bh // mask2.shape[0] if mask2 is not None else 1
     in_specs = [
         _smem_seed_spec(),
         pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
+        pl.BlockSpec((1, t, dv), lambda b, i: (b, 0, 0)),
     ]
     args = [seed_arr, q3, k3, v3]
     if mask2 is not None:
@@ -306,11 +309,11 @@ def _flash_fwd_impl(q3, k3, v3, mask2, seed_arr, scale, causal, block_q,
     o, lse = pl.pallas_call(
         kfn, grid=(bh, s // block_q), in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 128), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, s, 128), jnp.float32),
         ],
         interpret=_interpret())(*args)
@@ -429,11 +432,11 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h, t, d)
-    v3 = v.reshape(b * h, t, d)
+    v3 = v.reshape(b * h, t, v.shape[3])
     mask2 = None
     if mask is not None:
         mask2 = jnp.broadcast_to(mask.reshape(b, 1, t), (b, 8, t))
     seed_arr = jnp.asarray(dropout_seed, jnp.int32).reshape(1)
     o = _flash(q3, k3, v3, mask2, seed_arr, float(scale), bool(causal),
                int(block_q), int(block_k), float(dropout_p))
-    return o.reshape(b, h, s, d)
+    return o.reshape(b, h, s, v.shape[3])

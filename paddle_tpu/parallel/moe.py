@@ -273,11 +273,15 @@ def _gate_grouped(N, k, Eh, D, F, dtype):
     def make_args():
         import numpy as np
         rng = np.random.RandomState(0)
-        mk = lambda *s: jnp.asarray(0.02 * rng.randn(*s), dtype)
+        keys = iter(jax.random.split(jax.random.PRNGKey(0), 4))
+        # drawn on the device: 128 experts' trial weights are 600 M
+        # numbers, a quarter of a minute of numpy a key
+        mk = lambda *s: (0.02 * jax.random.normal(next(keys), s,
+                                                  jnp.float32)).astype(dtype)
         local = jnp.asarray(np.argsort(rng.rand(N, Eh), axis=1)[:, :k],
                             jnp.int32)
         g = jnp.full((N, k), 1.0 / k, jnp.float32)
-        return (jnp.asarray(rng.randn(N, D), dtype), local, g,
+        return (mk(N, D) * 50, local, g,
                 mk(Eh, D, F), mk(Eh, D, F), mk(Eh, F, D))
 
     return key, dict(_GROUPED), make_args
@@ -292,13 +296,20 @@ def _auto_grouped(h, local, w1) -> str:
         return "dense"
     from ..ops import autobench
     (N, k), (Eh, D, F) = local.shape, w1.shape
+    if Eh * N * F * h.dtype.itemsize > 2 ** 30:
+        # every expert on every row holds [Eh, N, F] twice over: past a GiB
+        # each (a prefill bucket of thousands of rows over 128 experts) it
+        # is E/k times the work AND may not fit beside a full cache; it is
+        # not measured
+        return "gmm"
     key, cands, make_args = _gate_grouped(N, k, Eh, D, F, h.dtype)
     return autobench.prefer(key, cands, make_args, default="gmm")
 
 
 def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
                      norm_topk: bool = True, scale: float = 1.0,
-                     experts_held=None, impl: str | None = None):
+                     experts_held=None, impl: str | None = None,
+                     shared=None):
     """Dropless routed SwiGLU layer over the experts held here.
 
     h [N, D]; wg [D, E] and bias [E] are the WHOLE router (it routes over
@@ -307,6 +318,11 @@ def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
     The result is the part of the layer's output that these experts give:
     over the shares of a partition of the experts the parts add up to the
     whole layer. No pair is dropped.
+
+    shared: (w1 [D, Fs], w3 [D, Fs], w2 [Fs, D]) of the SHARED experts
+    (DeepSeek-V3's: one SwiGLU of n_shared x F that every token takes,
+    unrouted and unweighted), added to the routed part. Every share of a
+    partition would compute it alike: give it to one, it counts once.
 
     impl: None = auto (see `_auto_grouped`), "gmm" or "dense".
     Returns (y [N, D] in h's dtype, sel [N, k] int32 global expert ids)."""
@@ -320,4 +336,8 @@ def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
     if impl is None:
         impl = _auto_grouped(h, local, w1)
     y = _GROUPED[impl](h, local, g, w1, w3, w2)
+    if shared is not None:
+        s1, s3, s2 = shared
+        y = y + jnp.dot((jax.nn.silu(h @ s1) * (h @ s3)), s2,
+                        preferred_element_type=jnp.float32)
     return y.astype(h.dtype), sel

@@ -27,7 +27,9 @@ Models whose layers keep other state than K/V (a convolution's last
 inputs, per slot) are served through the same Engine by
 `HybridDecodeModel` (models/lfm2.py), and a looped decoder, whose layers
 run several times a token with K/V of every pass, by `LoopedDecodeModel`
-(models/ouro.py); `DecodeModel` (serving/model.py) is what the engine asks
+(models/ouro.py), and one that caches a latent row a token in place of
+keys and values by `LatentDecodeModel` (models/deepseek_v3.py);
+`DecodeModel` (serving/model.py) is what the engine asks
 of each.
 
 Replicated fleet (serving/router.py, docs/SERVING.md): a Router fronts
@@ -41,7 +43,7 @@ from .sampling import SamplingParams, derive_seed
 from .scheduler import (QueueFull, QuotaExceeded, Request, Scheduler,
                         TokenBucket)
 from .model import (DecodeModel, GPTDecodeModel, HybridDecodeModel,
-                    LoopedDecodeModel)
+                    LatentDecodeModel, LoopedDecodeModel)
 from .engine import Engine
 from .frontend import ServingClient, ServingServer
 from .loadgen import (Arrival, LoadGenerator, LoadResult, TrafficConfig,
@@ -53,7 +55,7 @@ __all__ = [
     "PrefixCache", "PrefixMatch", "SamplingParams", "derive_seed",
     "Request", "Scheduler", "QueueFull", "QuotaExceeded", "TokenBucket",
     "DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
-    "LoopedDecodeModel", "Engine",
+    "LoopedDecodeModel", "LatentDecodeModel", "Engine",
     "ServingServer", "ServingClient",
     "Arrival", "LoadGenerator", "LoadResult", "TrafficConfig",
     "slo_report",

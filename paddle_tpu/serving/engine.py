@@ -564,7 +564,8 @@ class Engine:
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
                            cached_tokens=start, slot=req.slot,
-                           passes=self.model.passes) as sp:
+                           passes=self.model.passes,
+                           **self._attn_form("prefill")) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
             compiled = self._compiles.get(bucket, 0) > pre_compiles
@@ -736,7 +737,8 @@ class Engine:
             t0 = time.perf_counter()
             with _tracing.span("engine.decode", engine=self.engine_id,
                                active=len(batch), ahead=ahead,
-                               passes=self.model.passes) as sp:
+                               passes=self.model.passes,
+                               **self._attn_form("decode")) as sp:
                 with _tracing.span("engine.dispatch"):
                     # with nothing left to decode, this step only reads
                     fl = None
@@ -956,6 +958,13 @@ class Engine:
         if flops:
             with self._stats_lock:
                 self._flops_window.append((time.monotonic(), flops))
+
+    def _attn_form(self, phase: str) -> dict:
+        """Span attribute `attn` of a model whose prefill and decode run
+        different forms of attention (`DecodeModel.attn_forms`); nothing
+        for the others."""
+        form = self.model.attn_forms.get(phase)
+        return {"attn": form} if form else {}
 
     def _kv_cache_bytes(self) -> dict:
         """Bytes of the cache by kind of part: {"paged", "slot",

@@ -2,11 +2,13 @@
 
 `DecodeModel` is what the engine asks of a model; `GPTDecodeModel` (K and V
 in two paged parts, nothing else), `HybridDecodeModel` (paged, per-slot
-and tally parts) and `LoopedDecodeModel` (K and V of every layer of every
-PASS in two paged parts, and tallies) answer it. Each adapter's bodies are
-drivers over its architecture's layer loop (`GPTDecodeModel._layers`;
-`lfm2.apply_layers`; `ouro.apply_passes`): they say how tokens become `x`,
-where K/V land and what attends.
+and tally parts), `LoopedDecodeModel` (K and V of every layer of every
+PASS in two paged parts, and tallies) and `LatentDecodeModel` (ONE latent
+row a token a layer, no keys or values; two forms of attention) answer it.
+Each adapter's bodies are drivers over its architecture's layer loop
+(`GPTDecodeModel._layers`; `lfm2.apply_layers`; `ouro.apply_passes`;
+`deepseek_v3.apply_layers`): they say how tokens become `x`, where the
+attention state lands and what attends.
 
 Trash-page convention: the device pools carry ONE extra page at index
 `num_pages` that absorbs every masked write — padded page-table entries
@@ -27,14 +29,16 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..models import deepseek_v3 as _dsv3
 from ..models import lfm2 as _lfm2
 from ..models import ouro as _ouro
 from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
                           decoder_tail, init_gpt_params)
-from ..ops.paged_attention import paged_attention_decode
+from ..ops.paged_attention import (latent_row_width, paged_attention_decode,
+                                   paged_latent_attention_decode)
 
 __all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
-           "LoopedDecodeModel"]
+           "LoopedDecodeModel", "LatentDecodeModel"]
 
 
 class DecodeModel:
@@ -66,6 +70,10 @@ class DecodeModel:
       passes             how many times a token runs the layers in one
           program: 1 unless the model loops (an attribute of the engine's
           prefill and decode spans)
+      attn_forms         {"prefill": name, "decode": name} for a model
+          whose prefill and decode compute attention by different programs
+          of one function (attribute `attn` of those spans); empty
+          otherwise
 
     `cache'` has the keys, shapes and dtypes of `cache`: the engine donates
     it. The cache is a dict of device arrays, and `cache_kinds` says of each
@@ -86,6 +94,7 @@ class DecodeModel:
     has_prefill_tail = False
     has_routing = False
     passes = 1
+    attn_forms: dict[str, str] = {}
 
     def __init__(self, cfg, params, attn_impl: str | None = None):
         self.cfg = cfg
@@ -408,56 +417,34 @@ class GPTDecodeModel(DecodeModel):
         return cache, _head(params, x, self.cfg)
 
 
-class HybridDecodeModel(DecodeModel):
-    """Serving adapter around `models/lfm2.py`: layers of two kinds, two
-    kinds of state. Attention layers keep K and V per token in ONE fused
-    paged part `kv` [attention layers, P+1, ps, Hkv, 2d] (K | V side by
-    side: a head of 64 alone is a poor minor dimension on the chip, see
-    ops/paged_attention.py). Convolution layers keep the last K-1 gated
-    inputs per slot in `conv` [conv layers, S, K-1, D]. `routing` is
-    paged too: the experts chosen for every cached token, [1, P+1, ps x
-    expert layers x k] (what `Engine.submit(return_routing=True)` hands
-    back; 48 bytes a token here; a page's tokens lie side by side in one
-    minor dimension of 768, because the chip turns a minor dimension of
-    48 round and then copies the whole part twice a step, PR 26).
-    `expert_tokens` and `expert_touched` are tallies [expert layers, E]:
-    token-expert pairs of real tokens, and decode steps in which a live
-    slot's token reached the expert.
+class _ExpertRecords:
+    """What an adapter whose model routes experts keeps of the routing,
+    beside its attention state (mixed into `HybridDecodeModel` and
+    `LatentDecodeModel`; `cfg` gives `num_experts`, `num_experts_per_tok`,
+    `num_moe_layers`). `routing` is a paged part: the experts chosen for
+    every cached token, [1, P+1, ps x expert layers x k] (what
+    `Engine.submit(return_routing=True)` hands back; a page's tokens lie
+    side by side in one minor dimension, because the chip turns a minor
+    dimension of 48 round and then copies the whole part twice a step, PR
+    26); int8 up to 127 experts, int16 beyond. `expert_tokens` and
+    `expert_touched` are tallies [expert layers, E]: token-expert pairs of
+    real tokens, and decode steps in which a live slot's token reached the
+    expert."""
 
-    The three bodies here are drivers over `lfm2.apply_layers`: they
-    differ in what attention does with its state, nothing else."""
-
-    cache_kinds = {"kv": "paged", "routing": "paged", "conv": "slot",
-                   "expert_tokens": "tally", "expert_touched": "tally"}
     has_routing = True
+    _expert_kinds = {"routing": "paged", "expert_tokens": "tally",
+                     "expert_touched": "tally"}
 
-    def __init__(self, cfg: "_lfm2.LFM2Config", params=None, seed: int = 0,
-                 attn_impl: str | None = None):
-        # no position table to run past: RoPE; max_positions is the
-        # config's own ceiling
-        super().__init__(cfg, params if params is not None
-                         else _lfm2.init_params(cfg, seed), attn_impl)
-        self.head_dim = cfg.head_dim
-
-    # -- cache ---------------------------------------------------------
-    def init_cache(self, num_pages: int, page_size: int, num_slots: int):
+    def _expert_parts(self, num_pages: int, page_size: int) -> dict:
         cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
-        La, Lc, Lm = (cfg.layers_of(_lfm2.ATTN), cfg.layers_of(_lfm2.CONV),
-                      cfg.num_moe_layers)
-        E, k = cfg.num_experts, cfg.num_experts_per_tok
+        Lm, E, k = cfg.num_moe_layers, cfg.num_experts, \
+            cfg.num_experts_per_tok
         return {
-            "kv": jnp.zeros((La, num_pages + 1, page_size,
-                             cfg.num_key_value_heads, 2 * cfg.head_dim),
-                            dt),
             # the paged axis second, as in every paged part
             "routing": jnp.zeros((1, num_pages + 1, page_size * Lm * k),
                                  jnp.int8 if E <= 127 else jnp.int16),
-            "conv": jnp.zeros((Lc, num_slots, cfg.conv_L_cache - 1,
-                               cfg.hidden_size), dt),
             "expert_tokens": jnp.zeros((Lm, E), jnp.int32),
-            "expert_touched": jnp.zeros((Lm, E), jnp.int32),
-        }
+            "expert_touched": jnp.zeros((Lm, E), jnp.int32)}
 
     def routing_of(self, cache, pages, length: int):
         """The experts chosen at the first `length` cached positions of a
@@ -500,6 +487,69 @@ class HybridDecodeModel(DecodeModel):
         return jnp.moveaxis(sel, 0, 1).reshape(sel.shape[1], -1) \
             .astype(dtype)
 
+    def _recorded_prefill(self, cache, sel, pages, real) -> dict:
+        """The three parts after a prefill that chose sel [expert layers,
+        T, k] for a bucket whose positions `real` [T] are the prompt's and
+        whose pages are `pages` [T // ps]."""
+        rt = cache["routing"]
+        return {"routing": rt.at[0, pages].set(
+                    self._routes(sel, rt.dtype).reshape(pages.shape[0], -1)),
+                "expert_tokens": cache["expert_tokens"]
+                + self._pairs(sel, real),
+                "expert_touched": cache["expert_touched"]}
+
+    def _recorded_decode(self, cache, sel, page_of, off, live) -> dict:
+        """The three parts after a decode step that chose sel [expert
+        layers, S, k]; slot i's token lies at offset off[i] of page
+        page_of[i], and counts if live[i]."""
+        hit = self._pairs(sel, live)
+        rt = cache["routing"]
+        routes = self._routes(sel, rt.dtype)                    # [S, Lm k]
+        lanes = off[:, None] * routes.shape[1] \
+            + jnp.arange(routes.shape[1], dtype=jnp.int32)
+        return {"routing": rt.at[0, page_of[:, None], lanes].set(routes),
+                "expert_tokens": cache["expert_tokens"] + hit,
+                "expert_touched": cache["expert_touched"]
+                + (hit > 0).astype(jnp.int32)}
+
+
+class HybridDecodeModel(_ExpertRecords, DecodeModel):
+    """Serving adapter around `models/lfm2.py`: layers of two kinds, two
+    kinds of state. Attention layers keep K and V per token in ONE fused
+    paged part `kv` [attention layers, P+1, ps, Hkv, 2d] (K | V side by
+    side: a head of 64 alone is a poor minor dimension on the chip, see
+    ops/paged_attention.py). Convolution layers keep the last K-1 gated
+    inputs per slot in `conv` [conv layers, S, K-1, D]. The routing part
+    (48 bytes a token here, a minor dimension of 768) and the two expert
+    tallies are `_ExpertRecords`'.
+
+    The three bodies here are drivers over `lfm2.apply_layers`: they
+    differ in what attention does with its state, nothing else."""
+
+    cache_kinds = {"kv": "paged", "conv": "slot",
+                   **_ExpertRecords._expert_kinds}
+
+    def __init__(self, cfg: "_lfm2.LFM2Config", params=None, seed: int = 0,
+                 attn_impl: str | None = None):
+        # no position table to run past: RoPE; max_positions is the
+        # config's own ceiling
+        super().__init__(cfg, params if params is not None
+                         else _lfm2.init_params(cfg, seed), attn_impl)
+        self.head_dim = cfg.head_dim
+
+    # -- cache ---------------------------------------------------------
+    def init_cache(self, num_pages: int, page_size: int, num_slots: int):
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        La, Lc = cfg.layers_of(_lfm2.ATTN), cfg.layers_of(_lfm2.CONV)
+        return {
+            "kv": jnp.zeros((La, num_pages + 1, page_size,
+                             cfg.num_key_value_heads, 2 * cfg.head_dim),
+                            dt),
+            "conv": jnp.zeros((Lc, num_slots, cfg.conv_L_cache - 1,
+                               cfg.hidden_size), dt),
+            **self._expert_parts(num_pages, page_size)}
+
     # -- prefill -------------------------------------------------------
     def prefill(self, params, cache, tokens, true_len, page_row, slot):
         """tokens [T] int32 (padded bucket), true_len and slot scalar
@@ -531,17 +581,11 @@ class HybridDecodeModel(DecodeModel):
                                              keepdims=False)
         logits = _lfm2.head_logits(params, xlast, cfg)
         real = jnp.arange(T, dtype=jnp.int32) < true_len
-        rt = cache["routing"]
         return {
             "kv": pool,
-            "routing": rt.at[0, pages].set(
-                self._routes(sel, rt.dtype).reshape(n_pages, -1)),
             "conv": jax.lax.dynamic_update_slice_in_dim(
                 cache["conv"], conv, slot, axis=1),
-            "expert_tokens": cache["expert_tokens"]
-            + self._pairs(sel, real),
-            "expert_touched": cache["expert_touched"],
-        }, logits
+            **self._recorded_prefill(cache, sel, pages, real)}, logits
 
     # -- decode --------------------------------------------------------
     def decode(self, params, cache, tokens, positions, tables):
@@ -571,20 +615,9 @@ class HybridDecodeModel(DecodeModel):
             cfg, params, x, positions[:, None], cache["conv"], attend,
             cache["kv"])
         logits = _lfm2.head_logits(params, x[:, 0], cfg)
-        live = page_of != trash
-        hit = self._pairs(sel, live)
-        rt = cache["routing"]
-        routes = self._routes(sel, rt.dtype)                    # [S, Lm k]
-        lanes = off[:, None] * routes.shape[1] \
-            + jnp.arange(routes.shape[1], dtype=jnp.int32)
-        return {
-            "kv": pool,
-            "routing": rt.at[0, page_of[:, None], lanes].set(routes),
-            "conv": conv,
-            "expert_tokens": cache["expert_tokens"] + hit,
-            "expert_touched": cache["expert_touched"]
-            + (hit > 0).astype(jnp.int32),
-        }, logits
+        return {"kv": pool, "conv": conv,
+                **self._recorded_decode(cache, sel, page_of, off,
+                                        page_of != trash)}, logits
 
 
 class LoopedDecodeModel(DecodeModel):
@@ -708,3 +741,112 @@ class LoopedDecodeModel(DecodeModel):
         live = page_of != trash
         return {"k": ck, "v": cv, **self._tallied(cache, lam[:, 0], live)}, \
             _ouro.head_logits(params, x[0])
+
+
+class LatentDecodeModel(_ExpertRecords, DecodeModel):
+    """Serving adapter around `models/deepseek_v3.py`: multi-head latent
+    attention. A token's state in a layer is ONE row [c | kr] of
+    `cfg.latent_width` numbers (576 at the published sizes, 1,152 bytes in
+    bf16, against 20,480 for the keys and values of its 32 heads), with no
+    head axis: the paged part `latent` [layers, P+1, ps, W], W the width
+    padded to whole 128-lane registers (`ops/paged_attention.py::
+    latent_row_width`: 640; the lanes past the width stay zero). No key and
+    no value is ever stored. The routing part (int16: 128 experts) and the
+    expert tallies are `_ExpertRecords`'. Nothing is kept per slot.
+
+    `prefill` and `decode` are drivers over `deepseek_v3.apply_layers` and
+    run the two FORMS of latent attention (`attn_forms`): prefill the
+    expanded one (keys and values of every head built from c, products 192
+    wide over T^2 pairs) and writes the rows; decode the absorbed one (the
+    key up-projection folded into the query, a query of 576 against the
+    cached row, the value the row's own first 512 numbers: `ops/
+    paged_attention.py::paged_latent_attention_decode`)."""
+
+    cache_kinds = {"latent": "paged", **_ExpertRecords._expert_kinds}
+    attn_forms = {"prefill": "expanded", "decode": "absorbed"}
+
+    def __init__(self, cfg: "_dsv3.DeepseekV3Config", params=None,
+                 seed: int = 0, attn_impl: str | None = None):
+        super().__init__(cfg, params if params is not None
+                         else _dsv3.init_params(cfg, seed), attn_impl)
+
+    def init_cache(self, num_pages: int, page_size: int,
+                   num_slots: int = 0):
+        cfg = self.cfg
+        return {"latent": jnp.zeros(
+                    (cfg.num_hidden_layers, num_pages + 1, page_size,
+                     latent_row_width(cfg.latent_width)),
+                    jnp.dtype(cfg.dtype)),
+                **self._expert_parts(num_pages, page_size)}
+
+    @staticmethod
+    def _rows(c, kr, pool):
+        """[c | kr] of N tokens as rows of the pool: [N, W], zeros in the
+        lanes past the latent width."""
+        row = jnp.concatenate([c, kr], axis=-1)
+        pad = pool.shape[-1] - row.shape[-1]
+        return jnp.pad(row, ((0, 0), (0, pad))).astype(pool.dtype)
+
+    # -- prefill: the expanded form ----------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row,
+                slot=None):
+        """tokens [T] int32 (padded bucket), true_len scalar int32,
+        page_row [M] int32 (fill = trash); `slot` is not used. The rows of
+        every position into the request's pages, causal attention over the
+        bucket in the expanded form. Returns (cache, logits [V]) of the
+        last real position."""
+        cfg = self.cfg
+        T = tokens.shape[0]
+        ps = cache["latent"].shape[2]
+        pages = page_row[:T // ps]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, D]
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+
+        def attend(p, q_nope, q_rope, c, kr, pool, l):
+            rows = self._rows(c[0], kr[0], pool)
+            pool = pool.at[l, pages].set(rows.reshape(T // ps, ps, -1))
+            return _dsv3.expanded_attention(p, q_nope, q_rope, c, kr,
+                                            cfg.softmax_scale), pool
+
+        x, pool, sel = _dsv3.apply_layers(cfg, params, x, positions, attend,
+                                          cache["latent"])
+        xlast = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, 0,
+                                             keepdims=False)
+        real = jnp.arange(T, dtype=jnp.int32) < true_len
+        return {"latent": pool,
+                **self._recorded_prefill(cache, sel, pages, real)}, \
+            _dsv3.head_logits(params, xlast, cfg)
+
+    # -- decode: the absorbed form -----------------------------------------
+    def decode(self, params, cache, tokens, positions, tables):
+        """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
+        inactive slots = all-trash rows with position 0). In each layer:
+        the slot's row to its position's page and offset, then the absorbed
+        query over the slot's cached rows. Returns (cache, logits
+        [S, V])."""
+        cfg = self.cfg
+        ps, trash = cache["latent"].shape[2], cache["latent"].shape[1] - 1
+        # the slot batch as ONE row of S positions, each with its own
+        # position and history: the layers' products are [S, D] x [D, .]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, S, D]
+        page_of = jnp.take_along_axis(
+            tables, (positions // ps)[:, None], axis=1)[:, 0]
+        off = positions % ps
+        ctx = positions + 1
+
+        def attend(p, q_nope, q_rope, c, kr, pool, l):
+            pool = pool.at[l, page_of, off].set(
+                self._rows(c[0], kr[0], pool))
+            q = _dsv3.absorb_query(p, q_nope[0], q_rope[0])   # [S, H, 576]
+            o = paged_latent_attention_decode(
+                q.astype(pool.dtype), pool, tables, ctx,
+                value_width=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+                layer=l, impl=self.attn_impl)
+            return _dsv3.expand_value(p, o)[None], pool
+
+        x, pool, sel = _dsv3.apply_layers(cfg, params, x, positions[None],
+                                          attend, cache["latent"])
+        return {"latent": pool,
+                **self._recorded_decode(cache, sel, page_of, off,
+                                        page_of != trash)}, \
+            _dsv3.head_logits(params, x[0], cfg)

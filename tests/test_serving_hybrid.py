@@ -12,10 +12,12 @@ import pytest
 
 from benchmark.reference import lfm2_moe as ref
 from paddle_tpu.models import lfm2
+from paddle_tpu.models.deepseek_v3 import DeepseekV3Config
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models.ouro import OuroConfig
 from paddle_tpu.serving import (DecodeModel, Engine, GPTDecodeModel,
-                                HybridDecodeModel, LoopedDecodeModel)
+                                HybridDecodeModel, LatentDecodeModel,
+                                LoopedDecodeModel)
 from tests.test_lfm2_model import sizes_of
 
 LOG = []
@@ -226,7 +228,7 @@ def test_defrag_moves_the_pages_and_leaves_the_slots(served):
     assert run(defrag=True) == run(defrag=False)
 
 
-@pytest.mark.parametrize("which", ["gpt", "hybrid", "looped"])
+@pytest.mark.parametrize("which", ["gpt", "hybrid", "looped", "latent"])
 def test_both_decode_models_answer_one_cache_interface(served, which):
     """Everything `Engine` reads of a model, `DecodeModel` declares and
     every model answers; the bodies hand back the cache they were given
@@ -234,8 +236,11 @@ def test_both_decode_models_answer_one_cache_interface(served, which):
     cfg, _sizes, params, _eng, _seen = served
     model = {"gpt": lambda: GPTDecodeModel(GPTConfig.tiny(num_layers=1)),
              "hybrid": lambda: HybridDecodeModel(cfg, params=params),
-             "looped": lambda: LoopedDecodeModel(OuroConfig.tiny())}[which]()
+             "looped": lambda: LoopedDecodeModel(OuroConfig.tiny()),
+             "latent": lambda: LatentDecodeModel(DeepseekV3Config.tiny())
+             }[which]()
     slot = which == "hybrid"
+    routed = which in ("hybrid", "latent")
     assert isinstance(model, DecodeModel)
     assert model.cfg is not None and model.params
     assert model.max_positions == model.cfg.max_position_embeddings
@@ -243,7 +248,10 @@ def test_both_decode_models_answer_one_cache_interface(served, which):
     # the two optional capabilities, and the methods behind them
     assert model.has_prefill_tail is (which == "gpt") \
         is hasattr(model, "prefill_tail")
-    assert model.has_routing is slot is hasattr(model, "routing_of")
+    assert model.has_routing is routed is hasattr(model, "routing_of")
+    # two forms of attention are named, one is not
+    assert model.attn_forms == ({"prefill": "expanded", "decode": "absorbed"}
+                                if which == "latent" else {})
     assert not (model.has_prefill_tail and model.slot_state)
     # a tally has a meaning only through the model
     assert bool(model.parts_of("tally")) is (which != "gpt")

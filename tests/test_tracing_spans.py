@@ -447,3 +447,58 @@ def test_the_experts_stats_are_the_tallies_read_as_before():
     assert st["experts_touched_share"] == pytest.approx(
         touched.sum() / (st["steps"] * touched.size))
     assert eng.stats()["experts_touched_share"] is None
+
+
+def test_a_model_with_two_forms_of_attention_names_them_on_its_spans(engine):
+    """`attn` on `engine.prefill` (expanded) and `engine.decode` (absorbed)
+    for the latent model; absent for a model that has one form (GPT here,
+    the hybrid and the looped model below), whose expert stats and passes
+    are what they were."""
+    from paddle_tpu.models.deepseek_v3 import DeepseekV3Config
+    from paddle_tpu.models.lfm2 import LFM2Config
+    from paddle_tpu.models.ouro import OuroConfig
+    from paddle_tpu.serving import (HybridDecodeModel, LatentDecodeModel,
+                                    LoopedDecodeModel)
+
+    def attn_of(eng):
+        _reqs, spans = _run(eng, (_prompt(5), 3), (_prompt(9, 1), 3))
+        return {name: {s.attrs.get("attn") for s in spans if s.name == name}
+                for name in ("engine.prefill", "engine.decode")}, spans
+
+    kw = dict(num_slots=2, num_pages=16, page_size=8, max_seq_len=32)
+    cfg = DeepseekV3Config.tiny()
+    latent = Engine(LatentDecodeModel(cfg, seed=0), **kw)
+    got, spans = attn_of(latent)
+    assert got == {"engine.prefill": {"expanded"},
+                   "engine.decode": {"absorbed"}}
+    assert {s.attrs["passes"] for s in spans
+            if s.name in ("engine.prefill", "engine.decode")} == {1}
+    # the tallies under the hybrid model's names, through `tally_stats`
+    st = latent.stats()
+    pairs = np.asarray(latent.cache["expert_tokens"]).astype(np.int64)
+    assert st["expert_tokens"] == pairs.tolist()
+    assert pairs.shape == (cfg.num_moe_layers, cfg.n_routed_experts)
+    assert pairs.sum() == (5 + 9 + 2 + 2) * cfg.num_moe_layers \
+        * cfg.num_experts_per_tok
+    assert "expert_load_max_over_mean" in st and "loop_passes" not in st
+    # what the pool really holds a token: 3 rows padded to 128 lanes in
+    # float32, and the routing part (2 expert layers x 2 experts, int8)
+    gauge = registry.REGISTRY.get("paddle_tpu_serving_paged_bytes_per_token")
+    assert gauge.labels(engine=latent.engine_id).value == 3 * 128 * 4 + 4
+    # no span per layer or per form: the phases are the seven they were
+    for stp in (s for s in spans if s.name == "engine.step"
+                and not s.attrs.get("idle")):
+        assert [k.name for k in _children(spans, stp)] == PHASES
+
+    none = {"engine.prefill": {None}, "engine.decode": {None}}
+    assert attn_of(engine)[0] == none
+    hybrid = Engine(HybridDecodeModel(LFM2Config.tiny(), seed=0), **kw)
+    assert attn_of(hybrid)[0] == none
+    hst = hybrid.stats()
+    hp = np.asarray(hybrid.cache["expert_tokens"]).astype(np.int64)
+    assert hst["expert_tokens"] == hp.tolist()
+    mean = hp.mean(axis=1)
+    assert hst["expert_load_max_over_mean"] == (
+        float((hp.max(axis=1) / mean).mean()) if (mean > 0).all() else None)
+    assert attn_of(Engine(LoopedDecodeModel(OuroConfig.tiny(), seed=0),
+                          **kw))[0] == none
