@@ -151,8 +151,8 @@ def test_sample_tokens_greedy_and_determinism():
 def _sample_tokens_parent(logits, temps, topks, topps, seeds, steps):
     """`sample_tokens` as it stood before PR 27, body copied verbatim
     (`argsort`, then the sorted rows fetched back by a `take_along_axis`
-    of S*V single elements): the reference the new spelling must match
-    token for token."""
+    of S*V single elements): the sorting spelling, held to the same rule
+    as the searching one below."""
     import jax
     import jax.numpy as jnp
 
@@ -180,13 +180,91 @@ def _sample_tokens_parent(logits, temps, topks, topps, seeds, steps):
     return jnp.where(temps > 0, sampled, greedy)
 
 
+# A float32 sampler may round a mass to the other side of a level that the
+# float64 spelling sees it within this many float32 ulps (of the kept mass)
+# of. Read over the 45 cases' 11,232 sampled draws, on this CPU: the
+# searching spelling 3 draws, the widest 3.98 ulps; the sorting one 6, 1.36
+_EDGE_ULPS = 8
+# and in no case may more than this share of the sampled draws lean on it
+# (the widest read: 2 of a case's 312)
+_EDGE_SHARE = 0.02
+
+
+class _SortedRow:
+    """The sorted sampler of one row in float64 numpy: the order (scaled
+    logit descending, lower index first among equals, -0.0 == +0.0), the
+    mass up to each place, the kept places."""
+
+    def __init__(self, row, temp, top_k, top_p):
+        V = row.size
+        scaled = row.astype(np.float32) / np.float32(temp)  # as the device
+        self.order = np.lexsort((np.arange(V), -(scaled + np.float32(0))))
+        self.place = np.argsort(self.order)
+        sl = scaled[self.order].astype(np.float64)
+        p = np.exp(sl - sl[0])
+        p /= p.sum()
+        self.cdf = np.cumsum(p)
+        before = self.cdf - p
+        top_p = float(np.float32(top_p))
+        k_eff = min(max(top_k, 1), V) if top_k > 0 else V
+        self.kept = int(((np.arange(V) < k_eff) & (before < top_p)).sum())
+        # a float32 mass may fall on the other side of top_p where the
+        # float64 one lies within the tolerance of it: the kept counts a
+        # float32 spelling may arrive at, never past the k-th place
+        tol = _EDGE_ULPS * float(np.spacing(np.float32(top_p)))
+        self.kept_least = max(1, min(self.kept,
+                                     int((before < top_p - tol).sum())))
+        self.kept_most = max(self.kept, min(
+            k_eff, int((before <= top_p + tol).sum())))
+
+    def draw(self, u):
+        """(the token, the first and the last place a float32 spelling may
+        return): a place is allowed if the target lies within `_EDGE_ULPS`
+        float32 ulps of the kept mass from its stretch of the CDF."""
+        n, cdf = self.kept, self.cdf
+        target = u * cdf[n - 1]
+        exact = min(int((cdf[:n] <= target).sum()), n - 1)
+        tol = _EDGE_ULPS * float(np.spacing(np.float32(cdf[n - 1])))
+        first = np.searchsorted(cdf, u * cdf[self.kept_least - 1] - tol,
+                                side="left")
+        last = np.searchsorted(cdf, u * cdf[self.kept_most - 1] + tol,
+                               side="right")
+        return (int(self.order[exact]), min(int(first), exact),
+                max(exact, min(int(last), self.kept_most - 1)))
+
+
+def _held_to_the_sorted_sampler(got, rows, temps, k, top_p, seeds, steps):
+    """`got` [steps, S] against `_SortedRow`. Greedy slots and top_k = 1
+    are the argmax, exactly; a sampled token is the reference's or, with
+    the target within `_EDGE_ULPS` of an edge of the CDF, the one across
+    it. Returns how many sampled draws there were and how many leaned on
+    the edge rule."""
+    argmax = np.argmax(rows, axis=-1)       # first of equals, -0.0 == 0.0
+    draws = leaned = 0
+    for s in range(rows.shape[0]):
+        if temps[s] == 0 or k == 1:
+            np.testing.assert_array_equal(got[:, s], argmax[s])
+            continue
+        ref = _SortedRow(rows[s], temps[s], k, top_p)
+        us = _uniform(np, np.broadcast_to(seeds[s], (steps.size, 2)), steps)
+        for i, u in enumerate(us):
+            exact, first, last = ref.draw(float(u))
+            draws += 1
+            if got[i, s] != exact:
+                assert first <= ref.place[got[i, s]] <= last, (
+                    s, i, int(got[i, s]), exact, first, last)
+                leaned += 1
+    return draws, leaned
+
+
 _EQ_TEMPS = np.asarray([0.0, 0.3, 0.8, 1.5], np.float32)
 
 
 @pytest.fixture(scope="module")
 def eq_jitted():
-    """New and old spelling, each jitted once over a column of steps: the
-    rows are sorted once a call and drawn from at every step."""
+    """The searching spelling and the sorting one, each jitted once over a
+    column of steps: what depends on the rows alone (the cut; the sort) is
+    done once a call, the draw at every step."""
     import jax
     over_steps = (None,) * 5 + (0,)
     return (jax.jit(jax.vmap(sample_tokens, over_steps)),
@@ -214,35 +292,118 @@ def _eq_rows(rng, V):
 @pytest.mark.parametrize("V", [257, 4096, 50304])
 def test_sample_tokens_matches_the_gathering_spelling(eq_jitted, V, top_k,
                                                       top_p):
-    """PR 27's contract: the sort that carries the values returns what
-    `argsort` + `take_along_axis` returned, token for token, greedy and
-    sampled, ties broken by index. A case is 52 steps: four batches of
-    the eight rows, 13 steps each, the four temperatures mixed in every
-    batch and moved on by one row from batch to batch."""
+    """The search returns what the sorted sampler returns (ISSUE 33):
+    greedy slots and top_k = 1 exactly, the kept set exactly (ties by
+    lower index), a sampled token up to the rounding of a float32 mass at
+    a level it all but touches (`_EDGE_ULPS`), which few draws may need
+    (`_EDGE_SHARE`). The sorting spelling of PR 27's parent is held to the
+    same rule beside it. A case is 52 steps: four batches of the eight
+    rows, 13 steps each, the four temperatures mixed in every batch and
+    moved on by one row from batch to batch."""
     import jax.numpy as jnp
 
-    new_fn, old_fn = eq_jitted
     k = {"V": V, "V+9": V + 9}.get(top_k, top_k)
     rng = np.random.default_rng([V, k, int(top_p * 10)])
     S, per_batch = 8, 13
     topks = jnp.full((S,), k, jnp.int32)
     topps = jnp.full((S,), top_p, jnp.float32)
     picked = set()
+    tally = {"searching": [0, 0], "sorting": [0, 0]}
     for batch in range(4):
         temps = np.roll(np.tile(_EQ_TEMPS, 2), batch)
         seeds = np.stack([seed_to_key(int(x)) for x in
                           rng.integers(0, 2 ** 63, size=S)])
         steps = batch * per_batch + np.arange(per_batch, dtype=np.int32)
-        args = (jnp.asarray(_eq_rows(rng, V)), jnp.asarray(temps), topks,
-                topps, jnp.asarray(seeds),
+        rows = _eq_rows(rng, V)
+        args = (jnp.asarray(rows), jnp.asarray(temps), topks, topps,
+                jnp.asarray(seeds),
                 jnp.asarray(np.repeat(steps[:, None], S, axis=1)))
-        new, old = np.asarray(new_fn(*args)), np.asarray(old_fn(*args))
-        assert new.shape == (per_batch, S)
-        np.testing.assert_array_equal(new, old, err_msg=f"batch {batch}")
-        picked.update(new[:, temps > 0].ravel().tolist())
+        for name, fn in zip(tally, eq_jitted):
+            got = np.asarray(fn(*args))
+            assert got.shape == (per_batch, S)
+            draws, leaned = _held_to_the_sorted_sampler(
+                got, rows, temps, k, top_p, seeds, steps)
+            tally[name][0] += draws
+            tally[name][1] += leaned
+        picked.update(got[:, temps > 0].ravel().tolist())
+    for name, (draws, leaned) in tally.items():
+        assert leaned <= _EDGE_SHARE * max(draws, 1), (name, draws, leaned)
     # the draws moved: a case that kept returning one token shows nothing
     if k != 1:
         assert len(picked) > 8
+
+
+def test_a_rows_draw_does_not_depend_on_its_neighbours():
+    """The search is a row's own: its token is the same whatever rows and
+    parameters stand beside it, and wherever in the batch it stands."""
+    import jax
+
+    rng = np.random.default_rng(33)
+    S, V = 6, 1031
+    rows = _eq_rows(rng, V)[:S]
+    temps = np.asarray([0.8, 0.0, 1.5, 0.3, 0.8, 0.8], np.float32)
+    topks = np.asarray([0, 0, 40, 5, 0, V], np.int32)
+    topps = np.asarray([0.9, 1.0, 0.95, 1.0, 0.1, 0.9], np.float32)
+    seeds = np.stack([seed_to_key(int(x)) for x in
+                      rng.integers(0, 2 ** 63, size=S)])
+    steps = np.arange(S, dtype=np.int32) + 7
+    fn = jax.jit(sample_tokens)
+    together = np.asarray(fn(rows, temps, topks, topps, seeds, steps))
+    for s in range(S):
+        alone = fn(rows[s:s + 1], temps[s:s + 1], topks[s:s + 1],
+                   topps[s:s + 1], seeds[s:s + 1], steps[s:s + 1])
+        assert int(alone[0]) == together[s]
+    # other neighbours, other parameters beside it, another place
+    perm = rng.permutation(S)
+    moved = np.asarray(fn(rows[perm], temps[perm], topks[perm], topps[perm],
+                          seeds[perm], steps[perm]))
+    np.testing.assert_array_equal(moved, together[perm])
+    others = rng.standard_normal((S, V)).astype(np.float32)
+    others[2] = rows[2]
+    mine = np.arange(S) == 2
+    beside = fn(others, np.where(mine, temps, 0.4).astype(np.float32),
+                np.where(mine, topks, 3).astype(np.int32),
+                np.where(mine, topps, 0.5).astype(np.float32),
+                np.where(mine[:, None], seeds, seeds[0]), steps)
+    assert int(beside[2]) == together[2]
+
+
+def test_the_same_seed_and_step_agree_under_jit_and_under_vmap():
+    """Replay within one build: the token of a `(seed, step)` is the same
+    from call to call, eager, jitted, and as one lane of a `vmap` over
+    steps or over batches (docs/SERVING.md, the replay contract)."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(34)
+    S, V, n = 4, 2053, 5
+    rows = jnp.asarray(_eq_rows(rng, V)[[0, 2, 6, 7]])
+    temps = jnp.asarray([0.8, 1.5, 0.8, 0.3], jnp.float32)
+    topks = jnp.asarray([0, 50, 0, 0], jnp.int32)
+    topps = jnp.asarray([0.9, 1.0, 0.7, 0.9], jnp.float32)
+    seeds = jnp.asarray(np.stack([seed_to_key(int(x)) for x in
+                                  rng.integers(0, 2 ** 63, size=S)]))
+    steps = np.arange(n, dtype=np.int32)[:, None] + np.zeros((1, S), np.int32)
+    jitted = jax.jit(sample_tokens)
+    one_by_one = np.stack([np.asarray(jitted(
+        rows, temps, topks, topps, seeds, jnp.asarray(st))) for st in steps])
+    again = np.stack([np.asarray(jitted(
+        rows, temps, topks, topps, seeds, jnp.asarray(st))) for st in steps])
+    np.testing.assert_array_equal(one_by_one, again)
+    eager = np.asarray(sample_tokens(rows, temps, topks, topps, seeds,
+                                     jnp.asarray(steps[2])))
+    np.testing.assert_array_equal(eager, one_by_one[2])
+    over_steps = jax.jit(jax.vmap(sample_tokens, (None,) * 5 + (0,)))
+    np.testing.assert_array_equal(
+        np.asarray(over_steps(rows, temps, topks, topps, seeds,
+                              jnp.asarray(steps))), one_by_one)
+    # a batch of batches: every argument mapped
+    tile = lambda x: jnp.broadcast_to(x, (n,) + x.shape)    # noqa: E731
+    over_all = jax.jit(jax.vmap(sample_tokens))
+    np.testing.assert_array_equal(
+        np.asarray(over_all(tile(rows), tile(temps), tile(topks),
+                            tile(topps), tile(seeds), jnp.asarray(steps))),
+        one_by_one)
 
 
 # ---------------------------------------------------------------------------

@@ -498,31 +498,42 @@ def _engine_programs(family):
 @pytest.mark.parametrize("family,entry", [
     ("gpt", "decode"), ("gpt", "prefill"), ("gpt", "prefill_tail"),
     ("hybrid", "decode"), ("hybrid", "prefill")])
-def test_sampler_sorts_once_and_gathers_no_vocabulary(family, entry):
-    """The structural guard of PR 27: each jitted body of the engine holds
-    exactly one sort over `[S, V]`, of the values WITH their indices and
-    with both results used, and no gather whose result is a vocabulary
-    row a slot (V or S*V elements). `take_along_axis(scaled, order)` was
-    one: on the chip an element-serial fetch of 10 ns an element, 42.8 ms
-    of a 68 ms decode step at `[64, 65536]`."""
+def test_sampler_neither_sorts_nor_scans_a_vocabulary(family, entry):
+    """The structural guard of PR 33 (PR 27's went with the sort it
+    guarded): no jitted body of the engine sorts, prefix-sums or gathers
+    an array of a vocabulary row a slot, operand or result. On a v5e the
+    sort of `[64, 128256]` was 10 ms of a 27 ms decode step, the two
+    prefix sums 1.6, and `take_along_axis(scaled, order)` before them 42.8
+    of 68 at `[64, 65536]`. What the sampler does instead is loop over
+    ONE such array, the one its docstring names, and write no other: its
+    loops take `scaled` and hand back a row's scalars."""
+    from paddle_tpu.serving.sampling import sample_tokens
     programs, V = _engine_programs(family)
     fn, args, S = programs[entry]
     eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
-    sorts = [e for e in eqns if e.primitive.name == "sort"
-             and e.invars[0].aval.shape == (S, V)]
-    assert len(sorts) == 1, sorts
-    sort, = sorts
-    assert sort.params["num_keys"] == 1 and sort.params["is_stable"]
-    assert [v.aval.dtype for v in sort.invars] == [jnp.float32, jnp.int32]
-    assert not any(isinstance(v, jax.core.DropVar) for v in sort.outvars)
-    wide = [(e.primitive.name, v.aval.shape) for e in eqns
-            if e.primitive.name == "gather" for v in e.outvars
-            if v.aval.size and v.aval.size % V == 0]
-    assert not wide, wide
-    # what stays: the one pick a slot out of `order`
-    picks = [e for e in eqns if e.primitive.name == "gather"
-             and e.invars[0].aval.shape == (S, V)]
-    assert [e.outvars[0].aval.size for e in picks] == [S]
+
+    def wide(v):
+        shape = getattr(v.aval, "shape", ())
+        return shape[-1:] == (V,) or v.aval.size == S * V
+
+    def ordering(name):
+        return name in ("sort", "gather") or name.startswith("cum") \
+            or name.startswith("reduce_window")
+    banned = [(e.primitive.name, [v.aval.shape for v in e.invars],
+               [v.aval.shape for v in e.outvars]) for e in eqns
+              if ordering(e.primitive.name)
+              and any(wide(v) for v in (*e.invars, *e.outvars))]
+    assert not banned, banned
+    loops = [e for e in eqns if e.primitive.name in ("scan", "while")
+             and any(wide(v) for v in e.invars)]
+    # the cut, the draw, the index among equals
+    assert len(loops) == 3, [e.primitive.name for e in loops]
+    read = {v for e in loops for v in e.invars if wide(v)}
+    assert len(read) == 1 and "`scaled`" in sample_tokens.__doc__
+    assert next(iter(read)).aval.shape == (S, V)
+    assert not [v.aval.shape for e in loops for v in e.outvars if wide(v)]
+    # a pass is compiled once, not once a bit of the key
+    assert all(e.params.get("unroll", 1) == 1 for e in loops)
 
 
 def test_paged_attention_op_registered_with_infer_shape():
