@@ -12,10 +12,11 @@ Three pieces, all views over the one metrics registry:
   chip's ridge point).
 * **step-time decomposition** — :class:`StepSampler` gates a sampled
   profile of one step in ``PADDLE_TPU_PERFWATCH_EVERY`` (default 50;
-  0 disables).  On a sampled step the caller fences phase boundaries
-  with ``block_until_ready`` and reports host / dispatch / device /
-  transfer seconds via :func:`record_breakdown`; between samples the
-  hot path is untouched, so steady-state overhead stays ~0.
+  0 disables).  On a sampled step the caller reports host / dispatch /
+  device / transfer seconds via :func:`record_breakdown`: the executor
+  fences phase boundaries with ``block_until_ready``, ``Engine.step``
+  reads them off its own spans; between samples the hot path is
+  untouched, so steady-state overhead stays ~0.
 * **MFU accounting** — :func:`chip_peak_flops` resolves the chip's
   peak bf16 FLOP/s from ``jax.devices()[0].device_kind`` (bench.py
   delegates here, so live gauges and bench reports share one peak

@@ -664,9 +664,8 @@ class Engine:
             self._discard_inflight()
             st.attrs["idle"] = True
             return bool(self.scheduler.queue_depth)
-        with _tracing.span("engine.build"):
+        with _tracing.span("engine.build") as build:
             sample = self._perf_sampler.tick()
-            t_host0 = time.perf_counter()
             S = self.num_slots
             tokens = np.zeros((S,), np.int32)
             positions = np.zeros((S,), np.int32)
@@ -719,6 +718,8 @@ class Engine:
             bucket = f"decode[slots={S},pages={self.max_pages_per_req}]"
             # as in _run_prefill: read before lower() runs the trace
             pre_compiles = self._compiles.get(bucket, 0)
+            # the numpy batch is made; what is left is its transfers
+            build.attrs["filled"] = _tracing.TRACER.clock()
             if batch:
                 targs = (self.model.params, self.cache, jnp.asarray(tokens),
                          self._no_tokens if prev is None else prev.tokens,
@@ -739,7 +740,7 @@ class Engine:
                                active=len(batch), ahead=ahead,
                                passes=self.model.passes,
                                **self._attn_form("decode")) as sp:
-                with _tracing.span("engine.dispatch"):
+                with _tracing.span("engine.dispatch") as dispatch:
                     # with nothing left to decode, this step only reads
                     fl = None
                     if batch:
@@ -751,18 +752,13 @@ class Engine:
                     self._inflight = fl
                 with _tracing.span(
                         "engine.wait",
-                        of_step=None if prev is None else prev.step):
+                        of_step=None if prev is None else prev.step) as wait:
                     if prev is not None:
-                        # a sampled step fences its phase boundaries:
-                        # dispatch ends when the async jit call returns,
-                        # device when the step before's result is ready,
-                        # transfer when it is host-side
-                        t1 = time.perf_counter()
-                        if sample:
-                            jax.block_until_ready(prev.tokens)
-                        t2 = time.perf_counter()
+                        # the device finishing decode k-1, then the copy
+                        # of its tokens: `ready` parts the two
+                        jax.block_until_ready(prev.tokens)
+                        wait.attrs["ready"] = _tracing.TRACER.clock()
                         next_toks = np.asarray(prev.tokens)
-                        t3 = time.perf_counter()
                 compiled = self._compiles.get(bucket, 0) > pre_compiles
                 if compiled:
                     sp.attrs["compiled"] = True
@@ -784,16 +780,16 @@ class Engine:
             if compiled:
                 _perf.note_compile_seconds("engine.decode", dt)
             elif sample and next_toks is not None:
-                # host = batch building (token/position/table arrays);
+                # read off this step's spans: host = batch building;
                 # dispatch = the async jit call returning; device = what
                 # was left of the step before's decode once this one was
-                # dispatched (the block_until_ready fence); transfer =
-                # device->host copy
+                # dispatched; transfer = device->host copy
+                ready = wait.attrs["ready"]
                 _perf.record_breakdown(self._perf_name, {
-                    "host": t0 - t_host0,
-                    "dispatch": t1 - t0,
-                    "device": t2 - t1,
-                    "transfer": t3 - t2,
+                    "host": build.duration(),
+                    "dispatch": dispatch.duration(),
+                    "device": ready - wait.start,
+                    "transfer": wait.end - ready,
                 })
             finished = recorded = sampled_n = 0
             for i, r in (prev.reqs.items() if prev is not None else ()):

@@ -130,6 +130,107 @@ def test_at_most_ten_spans_a_decode_step(engine):
     assert inside == [8] + [7] * 7 and inside[0] + 1 <= 10
 
 
+# -- the stamps inside the spans (ISSUE 34) ------------------------------------
+
+def _stamped(spans):
+    """(span, stamp) of every span that carries one of the two stamps."""
+    return [(s, s.attrs[key]) for s in spans
+            for name, key in (("engine.build", "filled"),
+                              ("engine.wait", "ready"))
+            if s.name == name and key in s.attrs]
+
+
+def test_the_stamps_lie_inside_their_spans(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 6), (_prompt(12, 1), 4))
+    by_name = {}
+    for s, stamp in _stamped(spans):
+        assert s.start <= stamp <= s.end
+        by_name.setdefault(s.name, []).append(s)
+    # every build, and every wait that had tokens to read
+    assert by_name["engine.build"] == [s for s in spans
+                                       if s.name == "engine.build"]
+    assert by_name["engine.wait"] == [
+        s for s in spans
+        if s.name == "engine.wait" and s.attrs["of_step"] is not None]
+    assert len(by_name["engine.wait"]) >= 5
+
+
+def test_a_wait_with_no_decode_to_read_has_no_ready(engine):
+    _reqs, spans = _run(engine, (_prompt(5), 4))
+    waits = [s for s in spans if s.name == "engine.wait"]
+    # the first step dispatches decode 1 and has none before it to read
+    assert waits[0].attrs["of_step"] is None
+    assert "ready" not in waits[0].attrs
+    assert all("ready" in w.attrs for w in waits[1:]) and len(waits) > 1
+
+
+def test_the_stamps_are_the_tracers_clock(engine, monkeypatch):
+    ticks = iter(range(10**6))
+    monkeypatch.setattr(TRACER, "clock", lambda: next(ticks))
+    _reqs, spans = _run(engine, (_prompt(5), 4), (_prompt(12, 1), 3))
+    got = _stamped(spans)
+    assert {s.name for s, _ in got} == {"engine.build", "engine.wait"}
+    for s, stamp in got:
+        # a reading of the counter between the span's own two
+        assert isinstance(stamp, int) and s.start < stamp < s.end
+
+
+def test_a_prefill_span_says_what_was_asked_and_what_ran(engine):
+    """What `prefill_padding_share` reads: the positions a prefill was
+    asked for and the bucket its program ran, on the span alone (no
+    counter beside it, and no fence or stamp of the prefill's own: its
+    token is read where it always was)."""
+    _reqs, spans = _run(engine, (_prompt(5), 3), (_prompt(12, 1), 3),
+                        (_prompt(9, 2), 2))
+    prefills = [s for s in spans if s.name == "engine.prefill"]
+    asked = sum(p.attrs["prompt_len"] - p.attrs["cached_tokens"]
+                for p in prefills)
+    ran = sum(p.attrs["bucket"] for p in prefills)
+    assert (asked, ran) == (5 + 12 + 9, 8 + 16 + 16)
+    assert all("ready" not in p.attrs for p in prefills)
+
+
+@pytest.mark.parametrize("recording", [True, False])
+def test_the_sampled_breakdown_is_read_off_the_spans(engine, monkeypatch,
+                                                     recording):
+    """One clock in `Engine.step`: the perf plane's sampled phases are
+    the lengths of that step's spans, with the ring recording or not (a
+    span stamps its start and end either way: PADDLE_TPU_TRACE=0)."""
+    from paddle_tpu.observability import perf
+    name = f"engine:{engine.engine_id}"
+    monkeypatch.setattr(TRACER, "enabled", recording)
+    every = perf.sampling_every()
+    perf.set_every(1)
+    try:
+        seen = (perf.breakdowns().get(name) or {"samples": 0})["samples"]
+        _reqs, spans = _run(engine, (_prompt(5), 5))
+    finally:
+        perf.set_every(every)
+    bd = perf.breakdowns()[name]
+    # every step that read a decode's tokens was sampled: of the five
+    # tokens the prefill made one
+    assert bd["samples"] - seen == 4
+    assert set(bd["phases"]) == {"host", "dispatch", "device", "transfer"}
+    assert all(v >= 0.0 for v in bd["phases"].values())
+    if not recording:
+        assert spans == []
+        return
+    # the last sample is the last step's
+    last = [s for s in spans if s.name == "engine.step"][-1]
+    kids = {k.name: k for k in _children(spans, last)}
+    dec = {k.name: k for k in _children(spans, kids["engine.decode"])}
+    wait = dec["engine.wait"]
+    ph = bd["phases"]
+    assert ph["device"] + ph["transfer"] == pytest.approx(wait.duration(),
+                                                          abs=1e-9)
+    assert ph["device"] == pytest.approx(wait.attrs["ready"] - wait.start,
+                                         abs=1e-9)
+    assert ph["host"] == pytest.approx(kids["engine.build"].duration(),
+                                       abs=1e-9)
+    assert ph["dispatch"] == pytest.approx(
+        dec["engine.dispatch"].duration(), abs=1e-9)
+
+
 def test_compiled_marks_the_call_that_compiled():
     model = GPTDecodeModel(GPTConfig.tiny(num_layers=1), seed=0)
     eng = Engine(model, num_slots=2, num_pages=16, page_size=8,
