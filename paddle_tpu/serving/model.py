@@ -24,6 +24,8 @@ two) — the end-to-end parity test in tests/test_serving.py pins this.
 """
 from __future__ import annotations
 
+import dataclasses
+import logging
 import math
 
 import jax
@@ -39,6 +41,8 @@ from ..ops.paged_attention import (latent_row_width, paged_attention_decode,
 
 __all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
            "LoopedDecodeModel", "LatentDecodeModel"]
+
+logger = logging.getLogger("paddle_tpu.serving.model")
 
 
 class DecodeModel:
@@ -170,6 +174,22 @@ class GPTDecodeModel(DecodeModel):
         super().__init__(cfg, params if params is not None
                          else init_gpt_params(cfg, seed), attn_impl)
         self.head_dim = cfg.hidden_size // cfg.num_heads
+        # The layer loop scans over the stacked blocks, so a layer's `wo`,
+        # `w_up` and `w_down` reach `decoder_tail` as slices of the stack.
+        # XLA reads such a slice in place inside the product it fuses it
+        # with; in front of a Mosaic call it is a copy, the weight stream
+        # itself with the kernel serial behind it (75 MB a layer at GPT-3
+        # XL: 1.8 ms of a 12.2 ms decode step on a v5e, and the chain is
+        # ahead at every prefill bucket too; PERF.md, PR 35). So the
+        # serving bodies take the tail's composed chain, as the trainer
+        # does on a mesh; training on one chip keeps `cfg.fused_blocks`.
+        self._tail_cfg = dataclasses.replace(cfg, fused_blocks=False)
+        if cfg.fused_blocks:
+            logger.info(
+                "fused_blocks is off in the serving bodies: a scan's slice "
+                "of the stacked weights in front of the fused decoder-tail "
+                "kernels (ops/pallas_block.py) is a copy; the composed XLA "
+                "tail runs instead")
 
     # -- checkpoint warm-start (paddle_tpu.checkpoint) ------------------
     def save_checkpoint(self, root: str, step: int | None = None) -> int:
@@ -177,7 +197,6 @@ class GPTDecodeModel(DecodeModel):
         (content-addressed chunks; repeated saves of a mostly-unchanged
         model dedup). Keys are tree paths, structure comes from the
         config at load time — no pickle anywhere."""
-        import dataclasses
         from ..checkpoint import CheckpointStore
         leaves, _treedef = jax.tree_util.tree_flatten_with_path(
             self.params)
@@ -274,8 +293,9 @@ class GPTDecodeModel(DecodeModel):
         cv)` puts the layer's K and V [N, D] into the stacked pools,
         `attend(q, k, v, ck, cv, l) -> [N, D]`, then models.gpt's
         post-attention tail (out-projection + residual + LN2 + FFN: one
-        source of truth with training, gate-chosen fused sub-blocks
-        included). Returns (x, cache)."""
+        source of truth with training; here always its composed chain,
+        which XLA fuses with the scan's slice of the weights, see
+        `__init__`). Returns (x, cache)."""
         cfg = self.cfg
 
         def body(carry, xs):
@@ -286,7 +306,8 @@ class GPTDecodeModel(DecodeModel):
             k = h @ p["wk"] + p["bk"]
             v = h @ p["wv"] + p["bv"]
             ck, cv = write(ck, cv, l, k, v)
-            x = decoder_tail(p, attend(q, k, v, ck, cv, l), x, cfg)
+            x = decoder_tail(p, attend(q, k, v, ck, cv, l), x,
+                             self._tail_cfg)
             return (x, ck, cv), None
 
         (x, ck, cv), _ = jax.lax.scan(

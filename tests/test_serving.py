@@ -458,6 +458,61 @@ def test_gpt_bodies_are_drivers_over_one_layer_loop(entry, monkeypatch):
         else ["GPTDecodeModel._paged.<locals>.attend"])
 
 
+def _weight_operands(eqns, cfg):
+    """The `pallas_call`s among `eqns`, each with those of its operands'
+    shapes that are one layer's `wo`, `w_up` or `w_down`."""
+    D, F = cfg.hidden_size, cfg.intermediate_size
+    return [(e, [v.aval.shape for v in e.invars
+                 if getattr(v.aval, "shape", None) in ((D, D), (D, F), (F, D))])
+            for e in eqns if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("entry", ["prefill", "prefill_tail", "decode"])
+def test_gpt_bodies_put_no_layer_slice_in_front_of_a_kernel(entry,
+                                                            monkeypatch):
+    """The structural guard of PR 35, beside PR 25's: inside the one layer
+    scan no `pallas_call` takes an array of a layer's `wo` / `w_up` /
+    `w_down` shape. There those are the scan's slices of the stacked
+    blocks, and in front of a Mosaic call a slice is a copy: on the chip
+    24 x 75 MB a decode step, the weight stream itself, with the kernel
+    serial behind it (1.8 ms of 12.2). The paged kernel, which takes the
+    WHOLE pool and the layer's index, is the only kernel allowed there.
+    The opt-in makes `can_use_*` and `*_wins` say yes off the chip, as the
+    gate may on it, at widths the fused tail accepts (and that no block
+    of rows, padded to 128, has)."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    cfg = GPTConfig.tiny(num_layers=3, hidden_size=256)
+    assert cfg.fused_blocks         # the tail's kernels were asked for
+    model = GPTDecodeModel(cfg, seed=0, attn_impl="pallas")
+    jaxpr = _gpt_body_jaxpr(model, entry)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    calls = _weight_operands(_eqns(scans[0].params["jaxpr"].jaxpr), cfg)
+    assert not [shapes for _e, shapes in calls if shapes], calls
+    pool = model.init_cache(10, 8)["k"].shape
+    for e, _shapes in calls:
+        assert pool in [v.aval.shape for v in e.invars], e
+    assert bool(calls) == (entry != "prefill")      # the paged kernel ran
+
+
+def test_training_block_keeps_the_fused_tail(monkeypatch):
+    """Serving's choice is not training's: under the same opt-in
+    `gpt_block_fn`, whose weights are arrays of their own (the trainer's
+    layer loop hands each block its leaves), still reaches `fused_out_ln`
+    and `fused_ffn_ln`."""
+    from paddle_tpu.models.gpt import gpt_block_fn, init_gpt_params
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    cfg = GPTConfig.tiny(num_layers=1, hidden_size=256)
+    D, F = cfg.hidden_size, cfg.intermediate_size
+    p = jax.tree_util.tree_map(lambda a: jnp.asarray(a[0]),
+                               init_gpt_params(cfg, 0)["blocks"])
+    jaxpr = jax.make_jaxpr(lambda p, x: gpt_block_fn(p, x, cfg))(
+        p, jnp.zeros((2, 16, D), jnp.float32))
+    took = sorted(tuple(shapes) for _e, shapes
+                  in _weight_operands(_eqns(jaxpr.jaxpr), cfg))
+    assert took == [((D, D),), ((D, F), (F, D))], took
+
+
 def _engine_programs(family):
     """A small engine of one model family and, for each of its jitted
     bodies, the arguments `Engine` calls it with (slot-wide sampling
