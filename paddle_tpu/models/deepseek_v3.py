@@ -63,7 +63,7 @@ import jax.numpy as jnp
 
 from .lfm2 import dense_ffn, rmsnorm, routed_ffn
 
-__all__ = ["DeepseekV3Config", "init_params", "forward", "apply_layers",
+__all__ = ["DeepseekV3Config", "init_params", "seeded_tree", "forward", "apply_layers",
            "expanded_attention", "absorb_query", "expand_value",
            "rope_pairs", "head_logits"]
 
@@ -172,33 +172,38 @@ def layer_shapes(cfg: DeepseekV3Config, l: int) -> dict:
     return out
 
 
+def seeded_tree(shapes, key, std: float, dtype):
+    """A tree of seeded random leaves for a tree of shapes: matrices normal
+    of `std`; a leaf named `*norm` a gain 1 + 0.1 normal (round one, not AT
+    one: a dropped gain then shows); a leaf named `bias` normal of std 0.1
+    (a program that weighs by score plus bias, or selects on the score,
+    then disagrees). models/afmoe.py draws its weights the same way."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    out = []
+    for i, (path, shape) in enumerate(flat):
+        name = path[-1].key
+        z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+        if name.endswith("norm"):
+            leaf = 1.0 + 0.1 * z
+        else:
+            leaf = (0.1 if name == "bias" else std) * z
+        out.append(leaf.astype(dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
 def init_params(cfg: DeepseekV3Config, seed: int = 0):
-    """Seeded random weights: matrices normal of `initializer_range`; the
-    norms' gains 1 + 0.1 normal (round one, not AT one: a dropped gain
-    then shows); the experts' bias normal of std 0.1 (a program that
-    weighs by score plus bias, or selects on the score, then disagrees)."""
+    """Seeded random weights (`seeded_tree`), a key a layer."""
     dtype = jnp.dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
-
-    def tree(shapes, k):
-        flat, treedef = jax.tree_util.tree_flatten_with_path(
-            shapes, is_leaf=lambda s: isinstance(s, tuple))
-        out = []
-        for i, (path, shape) in enumerate(flat):
-            name = path[-1].key
-            z = jax.random.normal(jax.random.fold_in(k, i), shape,
-                                  jnp.float32)
-            if name.endswith("norm"):
-                leaf = 1.0 + 0.1 * z
-            else:
-                leaf = (0.1 if name == "bias" else cfg.initializer_range) * z
-            out.append(leaf.astype(dtype))
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-    top = tree({"embed": (cfg.vocab_size, cfg.hidden_size),
-                "head": (cfg.hidden_size, cfg.vocab_size),
-                "norm": (cfg.hidden_size,)}, jax.random.fold_in(key, 10_000))
-    top["layers"] = [tree(layer_shapes(cfg, l), jax.random.fold_in(key, l))
+    top = seeded_tree({"embed": (cfg.vocab_size, cfg.hidden_size),
+                       "head": (cfg.hidden_size, cfg.vocab_size),
+                       "norm": (cfg.hidden_size,)},
+                      jax.random.fold_in(key, 10_000),
+                      cfg.initializer_range, dtype)
+    top["layers"] = [seeded_tree(layer_shapes(cfg, l),
+                                 jax.random.fold_in(key, l),
+                                 cfg.initializer_range, dtype)
                      for l in range(cfg.num_hidden_layers)]
     return top
 

@@ -29,6 +29,14 @@ serves query heads G*j .. G*j+G-1; G = 1 is plain multi-head attention):
   layer      int32 scalar     which layer's pages to read (may be traced)
   page_table [S, M] int32     pool index of each slot's m-th page
   ctx_lens   [S] int32        valid history length per slot (>= 1)
+  first      [S] int32        optional, the XLA path only: the first live
+             position per slot (a layer that attends to a window: positions
+             first .. ctx - 1); rows below `first` are masked as rows from
+             ctx on are
+  ring       int              optional, with `first`: the table is a RING of
+             `ring` entries, logical page j lies in entry j mod ring (a
+             window layer keeps window / ps + 1 pages a slot, whatever the
+             context; serving/model.py::WindowedDecodeModel)
 Returns     [S, H, d]
 
 A head size under the 128 lanes of a TPU register makes a poor minor
@@ -91,9 +99,35 @@ def _stacked(k_pages, v_pages, layer):
     return k_pages, v_pages, jnp.asarray(layer, jnp.int32)
 
 
+def _live_rows(page_table, ps, ctx_lens, first, ring):
+    """[S, M ps] bool: which gathered rows a slot attends to when its live
+    positions are first .. ctx - 1. Entry e of a ring holds the newest
+    logical page that is congruent to e and not past the context's last;
+    without a ring entry e is page e."""
+    M = page_table.shape[1]
+    e = jnp.arange(M, dtype=jnp.int32)[None, :]
+    if ring is None:
+        page = jnp.broadcast_to(e, page_table.shape)
+    else:
+        last = (ctx_lens[:, None] - 1) // ps
+        page = jnp.where(e < ring, last - (last - e) % ring, -1)
+    pos = page[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
+    pos = pos.reshape(page_table.shape[0], M * ps)
+    return (pos >= first[:, None]) & (pos < ctx_lens[:, None]) & (pos >= 0)
+
+
 def paged_attention_xla(q, k_pages, v_pages, page_table, ctx_lens,
-                        scale=None, layer=None):
-    """Gather-based reference path; fully fused by XLA."""
+                        scale=None, layer=None, first=None, ring=None):
+    """Gather-based reference path; fully fused by XLA. `first`, `ring`:
+    a window (the module says how). This path is a window's only
+    implementation: the kernel walking a ring's live pages lost to it by
+    2.1x at the one shape that asked (PERF.md, PR 40)."""
+    if ring is not None and (first is None
+                             or not 0 < ring <= page_table.shape[1]):
+        raise ValueError(f"a ring of {ring} entries in a table of "
+                         f"{page_table.shape[1]}, first "
+                         f"{'missing' if first is None else 'given'}: a "
+                         f"ring is walked from a slot's first live position")
     S, H, d = q.shape
     k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
     ps = k_pages.shape[2]
@@ -106,6 +140,14 @@ def paged_attention_xla(q, k_pages, v_pages, page_table, ctx_lens,
     else:
         k = k_pages[layer, page_table].reshape(S, M * ps, Hkv, d)
         v = v_pages[layer, page_table].reshape(S, M * ps, Hkv, d)
+    if first is not None:   # a window: rows by their logical position
+        live = _live_rows(page_table, ps, ctx_lens, first, ring)
+        logits = jnp.einsum("skgd,stkd->skgt", q.reshape(S, Hkv, G, d), k,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(live[:, None, None, :], logits, _NEG)
+        probs = jax.nn.softmax(logits, axis=-1)
+        o = jnp.einsum("skgt,stkd->skgd", probs.astype(v.dtype), v)
+        return o.reshape(S, H, d).astype(q.dtype)
     if G == 1:      # multi-head: the program it has always been
         logits = jnp.einsum("shd,sthd->sht", q, k,
                             preferred_element_type=jnp.float32) * scale

@@ -43,7 +43,8 @@ from .sampling import SamplingParams, derive_seed
 from .scheduler import (QueueFull, QuotaExceeded, Request, Scheduler,
                         TokenBucket)
 from .model import (DecodeModel, GPTDecodeModel, HybridDecodeModel,
-                    LatentDecodeModel, LoopedDecodeModel)
+                    LatentDecodeModel, LoopedDecodeModel,
+                    WindowedDecodeModel)
 from .engine import Engine
 from .frontend import ServingClient, ServingServer
 from .loadgen import (Arrival, LoadGenerator, LoadResult, TrafficConfig,
@@ -55,7 +56,8 @@ __all__ = [
     "PrefixCache", "PrefixMatch", "SamplingParams", "derive_seed",
     "Request", "Scheduler", "QueueFull", "QuotaExceeded", "TokenBucket",
     "DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
-    "LoopedDecodeModel", "LatentDecodeModel", "Engine",
+    "LoopedDecodeModel", "LatentDecodeModel", "WindowedDecodeModel",
+    "Engine",
     "ServingServer", "ServingClient",
     "Arrival", "LoadGenerator", "LoadResult", "TrafficConfig",
     "slo_report",

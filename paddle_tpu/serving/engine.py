@@ -188,6 +188,10 @@ class Engine:
             self.scheduler.before_release = \
                 lambda req, status: keep()(req, status)
         self.trash_page = num_pages      # paged parts carry P+1 pages
+        # a model whose window layers keep a ring of pages a SLOT (a slot
+        # part: nothing to allocate, nothing to pass): what the spans and
+        # stats() say of it
+        self.ring_pages = model.ring_pages(page_size) if model.window else 0
         self.cache = model.init_cache(num_pages, page_size, num_slots)
         # shared-prefix KV reuse (serving/prefix_cache.py): 0 pages =
         # disabled (the default — an idle engine then provably holds no
@@ -559,12 +563,17 @@ class Engine:
             # first call of this bucket pays the compile anyway; the
             # abstract lowering for cost analysis rides the same path
             self._register_perf_cost(bucket, fn, targs, T, start + T)
+        # window layers: the prompt's positions their rings never hold
+        window = self.model.window
+        past = {"past_window": max(0, int(req.prompt.size) - window)} \
+            if window else {}
         t0 = time.perf_counter()
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
                            cached_tokens=start, slot=req.slot,
                            passes=self.model.passes,
+                           **past,
                            **self._attn_form("prefill")) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
@@ -678,12 +687,19 @@ class Engine:
             steps = np.zeros((S,), np.int32)
             batch: dict[int, Request] = {}  # slot -> whom this decode serves
             pages_live = 0
+            window = self.model.window
+            ring_live = window_rows = 0
             for i, r in active:
                 # tokens dispatched for r: those read, and the one of the
                 # decode in flight if it holds r's slot
                 unread = prev is not None and prev.reqs.get(i) is r
                 n = len(r.generated) + unread
                 last = int(r.prompt.size) + n - 1   # position of token n
+                if window:  # (a finished request decodes nothing more)
+                    done = n >= r.max_new_tokens
+                    ring_live += min((last - done) // self.page_size + 1,
+                                     self.ring_pages)
+                    window_rows += 0 if done else min(last + 1, window)
                 if n >= r.max_new_tokens:
                     # its last token is in flight: nothing more to decode
                     pages_live += (last - 1) // self.page_size + 1
@@ -709,6 +725,10 @@ class Engine:
             # request) against what holds a token: ROADMAP Speed 5
             st.attrs["pages_reserved"] = self.pool.used_pages
             st.attrs["pages_live"] = pages_live
+            if window:      # a slot's ring is its request's, whole
+                st.attrs["window_pages_reserved"] = \
+                    self.ring_pages * len(active)
+                st.attrs["window_pages_live"] = ring_live
             # hang injection (chaos drills): PADDLE_PS_FAULT_STALL with
             # PADDLE_PS_FAULT_STALL_POINT=serving_decode wedges the
             # step thread here — inside the step lock, exactly like a
@@ -739,6 +759,8 @@ class Engine:
             with _tracing.span("engine.decode", engine=self.engine_id,
                                active=len(batch), ahead=ahead,
                                passes=self.model.passes,
+                               **({"window_rows": window_rows}
+                                  if window else {}),
                                **self._attn_form("decode")) as sp:
                 with _tracing.span("engine.dispatch") as dispatch:
                     # with nothing left to decode, this step only reads
@@ -1092,8 +1114,12 @@ class Engine:
         if len(w) >= 2 and w[-1][0] > w[0][0]:
             tps = sum(n for _, n in w[1:]) / (w[-1][0] - w[0][0])
         rates = self.perf_rates()
+        pool = self.pool.stats()
+        if self.model.window:
+            pool["window"] = {"window": self.model.window,
+                              "ring_pages": self.ring_pages}
         return {**self.scheduler.stats(), **self._tally_stats(),
-                "pool": self.pool.stats(),
+                "pool": pool,
                 "prefix_cache": self.prefix_cache.stats()
                 if self.prefix_cache is not None else None,
                 "model_version": self.model_version,

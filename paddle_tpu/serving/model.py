@@ -3,12 +3,14 @@
 `DecodeModel` is what the engine asks of a model; `GPTDecodeModel` (K and V
 in two paged parts, nothing else), `HybridDecodeModel` (paged, per-slot
 and tally parts), `LoopedDecodeModel` (K and V of every layer of every
-PASS in two paged parts, and tallies) and `LatentDecodeModel` (ONE latent
-row a token a layer, no keys or values; two forms of attention) answer it.
+PASS in two paged parts, and tallies), `LatentDecodeModel` (ONE latent
+row a token a layer, no keys or values; two forms of attention) and
+`WindowedDecodeModel` (the full layers' K and V in paged parts, the window
+layers' in a ring of pages a slot) answer it.
 Each adapter's bodies are drivers over its architecture's layer loop
 (`GPTDecodeModel._layers`; `lfm2.apply_layers`; `ouro.apply_passes`;
-`deepseek_v3.apply_layers`): they say how tokens become `x`, where the
-attention state lands and what attends.
+`deepseek_v3.apply_layers`; `afmoe.apply_layers`): they say how tokens
+become `x`, where the attention state lands and what attends.
 
 Trash-page convention: the device pools carry ONE extra page at index
 `num_pages` that absorbs every masked write — padded page-table entries
@@ -27,20 +29,24 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import os
 
 import jax
 import jax.numpy as jnp
 
+from ..models import afmoe as _afmoe
 from ..models import deepseek_v3 as _dsv3
 from ..models import lfm2 as _lfm2
 from ..models import ouro as _ouro
 from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
                           decoder_tail, init_gpt_params)
 from ..ops.paged_attention import (latent_row_width, paged_attention_decode,
+                                   paged_attention_xla,
                                    paged_latent_attention_decode)
+from ..ops.pallas_attention import on_tpu
 
 __all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
-           "LoopedDecodeModel", "LatentDecodeModel"]
+           "LoopedDecodeModel", "LatentDecodeModel", "WindowedDecodeModel"]
 
 logger = logging.getLogger("paddle_tpu.serving.model")
 
@@ -78,6 +84,10 @@ class DecodeModel:
           whose prefill and decode compute attention by different programs
           of one function (attribute `attn` of those spans); empty
           otherwise
+      window             the positions a model's window layers attend to,
+          kept in a ring of `ring_pages(page_size)` pages a slot (a slot
+          part); None without such layers. The engine reads it for its
+          spans alone (`window_rows`, `past_window`, `window_pages_*`)
 
     `cache'` has the keys, shapes and dtypes of `cache`: the engine donates
     it. The cache is a dict of device arrays, and `cache_kinds` says of each
@@ -89,7 +99,10 @@ class DecodeModel:
       "slot"   [layers of that kind, S, ...]  per SLOT, i.e. per sequence (a
                convolution's or a recurrence's state). Prefill of a request
                writes its slot's row whole, so a slot never reads its last
-               tenant's; decode's row i is slot i.
+               tenant's; decode's row i is slot i. A window layer's ring is
+               one too, [layers, S R + 1, ps, ...]: slot i's R pages are
+               rows i R .. i R + R - 1 (then one trash page), and what the
+               last tenant left there is masked by its position.
       "tally"  counters the programs add to and only `Engine.stats` reads,
                through `tally_stats`: what a tally means is the model's.
     """
@@ -99,6 +112,7 @@ class DecodeModel:
     has_routing = False
     passes = 1
     attn_forms: dict[str, str] = {}
+    window: int | None = None
 
     def __init__(self, cfg, params, attn_impl: str | None = None):
         self.cfg = cfg
@@ -118,6 +132,11 @@ class DecodeModel:
 
     def parts_of(self, kind: str) -> tuple:
         return tuple(n for n, k in self.cache_kinds.items() if k == kind)
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of a slot's ring: the window in pages and one more, so
+        that the page being written never holds a position still read."""
+        return -(-self.window // page_size) + 1
 
     @property
     def slot_state(self) -> bool:
@@ -871,3 +890,155 @@ class LatentDecodeModel(_ExpertRecords, DecodeModel):
                 **self._recorded_decode(cache, sel, page_of, off,
                                         page_of != trash)}, \
             _dsv3.head_logits(params, x[0], cfg)
+
+
+class WindowedDecodeModel(_ExpertRecords, DecodeModel):
+    """Serving adapter around `models/afmoe.py`: grouped-query attention of
+    two kinds, and so two kinds of K/V state. The full layers attend to
+    every cached position: `k_full`, `v_full` [full layers, P+1, ps, Hkv,
+    d], paged under the request's table as every other model's. The sliding
+    layers attend to the last `sliding_window` positions: `k_win`, `v_win`
+    [sliding layers, S R + 1, ps, Hkv, d], a RING of R = window / ps + 1
+    pages a SLOT (position t in page slot R + (t // ps) mod R; the last
+    page is trash). A cached token older than the window holds no bytes in
+    a sliding layer; a slot's ring is its request's whole, so nothing is
+    allocated, passed or moved for it: the scheduler and the page pool know
+    one table a request, as for every model. The routing part lies under
+    that table; the expert tallies are `_ExpertRecords'`.
+
+    `prefill` and `decode` are drivers over `afmoe.apply_layers`. Prefill
+    attends densely, in bands (`afmoe.banded_causal_attention`), writes
+    every position to the full layers' pages and, to the slot's ring, the
+    positions whose page is among the prompt's last R (older ones are never
+    cached there). Decode writes one row to each; the full layers attend
+    through `paged_attention_decode`, the sliding layers through the XLA
+    path from the slot's first live position over the ring."""
+
+    cache_kinds = {"k_full": "paged", "v_full": "paged", "k_win": "slot",
+                   "v_win": "slot", **_ExpertRecords._expert_kinds}
+
+    def __init__(self, cfg: "_afmoe.AfmoeConfig", params=None, seed: int = 0,
+                 attn_impl: str | None = None):
+        # the full layers' table is max_seq_len wide and the XLA path
+        # gathers every entry of every slot, K and V (64 slots of 288
+        # pages of 64 KiB: 2.4 GB a step, held beside the next prefill's
+        # temporaries: 15.3 of 15.75 GiB); the kernel reads live pages in
+        # place, in the same time (PERF.md, PR 40). Not the gate's to draw
+        kernel = on_tpu() and not os.environ.get("PADDLE_TPU_DISABLE_PALLAS")
+        super().__init__(cfg, params if params is not None
+                         else _afmoe.init_params(cfg, seed),
+                         attn_impl or ("pallas" if kernel else "xla"))
+        self.window = cfg.sliding_window
+
+    def init_cache(self, num_pages: int, page_size: int, num_slots: int):
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        tail = (page_size, cfg.num_key_value_heads, cfg.head_dim)
+        full = (cfg.layers_of(_afmoe.FULL), num_pages + 1) + tail
+        win = (cfg.layers_of(_afmoe.SLIDING),
+               num_slots * self.ring_pages(page_size) + 1) + tail
+        return {"k_full": jnp.zeros(full, dt), "v_full": jnp.zeros(full, dt),
+                "k_win": jnp.zeros(win, dt), "v_win": jnp.zeros(win, dt),
+                **self._expert_parts(num_pages, page_size)}
+
+    def _pools(self, cache):
+        return {_afmoe.FULL: (cache["k_full"], cache["v_full"]),
+                _afmoe.SLIDING: (cache["k_win"], cache["v_win"])}
+
+    @staticmethod
+    def _parts(pools) -> dict:
+        (kf, vf), (kw, vw) = pools[_afmoe.FULL], pools[_afmoe.SLIDING]
+        return {"k_full": kf, "v_full": vf, "k_win": kw, "v_win": vw}
+
+    # -- prefill -------------------------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row, slot):
+        """tokens [T] int32 (padded bucket), true_len and slot scalar
+        int32, page_row [M] int32 (fill = trash). Returns (cache, logits
+        [V]) of the last real position."""
+        cfg = self.cfg
+        T = tokens.shape[0]
+        ps = cache["k_full"].shape[2]
+        n = T // ps
+        pages = page_row[:n]
+        # the ring: logical page j of the prompt lies in the slot's page
+        # j mod R, if it is among the prompt's last R pages; older pages,
+        # and the bucket's padding, go to the trash page
+        R = self.ring_pages(ps)
+        trash = cache["k_win"].shape[1] - 1
+        j = jnp.arange(n, dtype=jnp.int32)
+        n_prompt = (true_len + ps - 1) // ps
+        kept = jnp.logical_and(j < n_prompt, j >= n_prompt - R)
+        ring_pages = jnp.where(kept, slot * R + j % R, trash)
+        dest = {_afmoe.FULL: pages, _afmoe.SLIDING: ring_pages}
+        x = _afmoe.embed_tokens(params, tokens, cfg)[None]      # [1, T, D]
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, pools, l):
+            kind, i = cfg.layer_types[l], cfg.index_in_kind(l)
+            ck, cv = pools[kind]
+            shape = (n, ps) + k.shape[2:]
+            pools = {**pools, kind: (
+                ck.at[i, dest[kind]].set(k[0].reshape(shape).astype(ck.dtype)),
+                cv.at[i, dest[kind]].set(v[0].reshape(shape).astype(cv.dtype)))}
+            return _afmoe.banded_causal_attention(
+                q, k, v, scale, _afmoe.window_of(cfg, l)), pools
+
+        x, pools, sel = _afmoe.apply_layers(cfg, params, x, positions, attend,
+                                            self._pools(cache))
+        xlast = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, 0,
+                                             keepdims=False)
+        real = jnp.arange(T, dtype=jnp.int32) < true_len
+        return {**self._parts(pools),
+                **self._recorded_prefill(cache, sel, pages, real)}, \
+            _afmoe.head_logits(params, xlast, cfg)
+
+    # -- decode --------------------------------------------------------
+    def decode(self, params, cache, tokens, positions, tables):
+        """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
+        inactive slots = all-trash rows with position 0). Row i is slot i.
+        In each layer: the slot's K/V to its position's page and offset,
+        under its table or in its ring (an inactive slot's to either's
+        trash page), then ragged paged attention, a sliding layer's from
+        position - window + 1 over the ring. Returns (cache, logits [S,
+        V])."""
+        cfg = self.cfg
+        S = tokens.shape[0]
+        ps, trash = cache["k_full"].shape[2], cache["k_full"].shape[1] - 1
+        R = self.ring_pages(ps)
+        # the slot batch as ONE row of S positions, each with its own
+        # position and history: the layers' products are [S, D] x [D, .]
+        x = _afmoe.embed_tokens(params, tokens, cfg)[None]      # [1, S, D]
+        page = positions // ps
+        page_of = jnp.take_along_axis(tables, page[:, None], axis=1)[:, 0]
+        live = page_of != trash
+        rings = jnp.arange(S * R, dtype=jnp.int32).reshape(S, R)
+        at = {_afmoe.FULL: page_of,
+              _afmoe.SLIDING: jnp.where(
+                  live, jnp.arange(S, dtype=jnp.int32) * R + page % R,
+                  cache["k_win"].shape[1] - 1)}
+        off = positions % ps
+        ctx = positions + 1
+        first = jnp.maximum(ctx - cfg.sliding_window, 0)
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, pools, l):
+            kind, i = cfg.layer_types[l], cfg.index_in_kind(l)
+            ck, cv = pools[kind]
+            ck = ck.at[i, at[kind], off].set(k[0].astype(ck.dtype))
+            cv = cv.at[i, at[kind], off].set(v[0].astype(cv.dtype))
+            if kind == _afmoe.FULL:
+                a = paged_attention_decode(
+                    q[0], ck, cv, tables, ctx, layer=i, scale=scale,
+                    impl=self.attn_impl)
+            else:
+                a = paged_attention_xla(
+                    q[0], ck, cv, rings, ctx, layer=i, scale=scale,
+                    first=first, ring=R)
+            return a.reshape(1, S, -1), {**pools, kind: (ck, cv)}
+
+        x, pools, sel = _afmoe.apply_layers(cfg, params, x, positions[None],
+                                            attend, self._pools(cache))
+        return {**self._parts(pools),
+                **self._recorded_decode(cache, sel, page_of, off, live)}, \
+            _afmoe.head_logits(params, x[0], cfg)

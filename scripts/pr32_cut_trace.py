@@ -9,7 +9,7 @@ straddles two spans and that cutter finds none. Names are cut to 260
 characters, times moved to start at 0. Also prints, by name, the device
 time of every operation of the cut, largest first.
 
-    python3 scripts/pr32_cut_trace.py trace.json two_steps.json
+    python3 scripts/pr32_cut_trace.py trace.json two_steps.json [name length]
 """
 import collections
 import json
@@ -19,7 +19,8 @@ import sys
 NAME = 260
 
 
-def main(src, dst):
+def main(src, dst, name_len=NAME):
+    NAME = int(name_len)    # (PR 40: a call's pool is its sixth operand)
     with open(src) as f:
         planes = json.load(f)["planes"]
     dev = next(p for p in planes if p["name"].startswith("/device:TPU:"))
@@ -58,4 +59,4 @@ def main(src, dst):
 
 
 if __name__ == "__main__":
-    main(*sys.argv[1:3])
+    main(*sys.argv[1:4])
