@@ -12,10 +12,14 @@ implementations behind one function:
     in HBM; the layer, the page table and the contexts are
     scalar-prefetched, and the program copies its slot's own
     cdiv(ctx, ps) pages itself, B pages a block, the next block's copies
-    in flight while this block's pages go through an online softmax in a
-    loop (the per-head mat-vecs on the VPU). What a call costs follows the
-    contexts, not the table's width; a table entry past the context is
-    never read.
+    in flight while this block goes through an online softmax. Where query
+    heads SHARE their keys (G > 1 heads a KV head, or a fused pool) a KV
+    head's block is one matrix and both products go to the MXU, a block
+    and a KV head at a time (`_grouped_kernel`); plain multi-head
+    attention (G = 1) has one query row a head, nothing for the MXU, and
+    does its per-head mat-vecs on the VPU, a page at a time
+    (`_paged_kernel`). What a call costs follows the contexts, not the
+    table's width; a table entry past the context is never read.
 
 Selection runs through ops/autobench.prefer — the same measure-once gate
 that arbitrates Pallas-vs-XLA flash attention — so the hand kernel only
@@ -49,7 +53,9 @@ one gather, or one page DMA, brings both.
 On the chip the kernel's copies move whole 128-lane tiles, so there the
 minor dimension of a pool it is given is a multiple of 128 (d = 128, or a
 fused pool of heads of 64); Mosaic refuses another, the gate keeps the
-refusal with its decision and the XLA path runs.
+refusal with its decision and the XLA path runs. The grouped kernel reads
+a KV head out of a block fast only where that minor dimension IS 128, the
+pool bfloat16 and Hkv even (`_head_rows`): every model served so far.
 
 The two pool ranks are one algorithm: both implementations address
 (layer, page) in the pool they are given, and a rank-4 pool is a stacked
@@ -187,8 +193,10 @@ def _groups(q, k_pages) -> int:
 # VMEM for the page copies: two buffers (one filled by the DMA engine while
 # the other is read) of one block of B pages of each pool. 2 MiB keeps a
 # MiB of copies in flight, enough to cover HBM's latency at its bandwidth;
-# the float32 working set beside them is one page at a time (64 KiB of K,
-# as much of V) and the whole stays far under Mosaic's 16 MiB of scoped VMEM.
+# the working set beside them is one page of K and of V in float32 (64 KiB
+# each; multi-head) or one KV head of a block (the grouped kernel: 128 KiB
+# of K_h, as much of V_h, twice that as the words they are read as) and
+# the whole stays far under Mosaic's 16 MiB of scoped VMEM.
 _PAGE_BUFFER_BYTES = 2 * 2 ** 20
 
 
@@ -199,32 +207,32 @@ def _block_pages(page_bytes: int, n_pools: int, M: int) -> int:
 
 
 def _softmax_update(carry, q, k, v, live=None):
-    """One page into the running softmax of the G head groups that share
-    it. carry: per group (m [Hkv, 1], l [Hkv, 1], acc [Hkv, w]), float32;
-    q: per group [Hkv, w], scaled; k, v: [ps, Hkv, w] float32; live:
-    [ps, Hkv, 1] bool for a page the context ends in, None for a whole one.
+    """One page into the running softmax of heads that each have their own
+    keys (G = 1). carry (m [H, 1], l [H, 1], acc [H, d]), float32; q
+    [H, d], scaled; k, v: [ps, H, d] float32; live: [ps, H, 1] bool for a
+    page the context ends in, None for a whole one.
 
     One query token per head against one page is a batched mat-vec, done
     on the VPU with the head axis kept in place. (The MXU spelling,
     einsum("hd,phd->hp"), puts the batch dimension in the middle of the
-    rhs and leaves the lhs no free dimension; Mosaic refuses it.) Scores
-    stay [ps, Hkv, 1] so that they broadcast over w without a relayout
-    and reduce over the page axis into [Hkv, 1]."""
+    rhs and leaves the lhs no free dimension; Mosaic refuses it, and a
+    head at a time it would be a product of ONE row. Heads that SHARE
+    keys are a matrix against them: `_grouped_kernel`.) Scores stay
+    [ps, H, 1] so that they broadcast over d without a relayout and
+    reduce over the page axis into [H, 1]."""
+    m_prev, l_prev, acc = carry
     if live is not None:
         v = jnp.where(live, v, 0.0)     # rows nobody wrote: 0 x NaN is NaN
-    out = []
-    for (m_prev, l_prev, acc), qg in zip(carry, q):
-        scores = jnp.sum(qg[None] * k, axis=-1, keepdims=True)
-        if live is not None:
-            scores = jnp.where(live, scores, _NEG)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new[None])                     # [ps, Hkv, 1]
-        if live is not None:    # a page all dead: exp(_NEG - _NEG) = 1
-            p = jnp.where(live, p, 0.0)
-        out.append((m_new, alpha * l_prev + jnp.sum(p, axis=0),
-                    acc * alpha + jnp.sum(p * v, axis=0)))
-    return tuple(out)
+    scores = jnp.sum(q[None] * k, axis=-1, keepdims=True)
+    if live is not None:
+        scores = jnp.where(live, scores, _NEG)
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new[None])                         # [ps, H, 1]
+    if live is not None:    # a page all dead: exp(_NEG - _NEG) = 1
+        p = jnp.where(live, p, 0.0)
+    return (m_new, alpha * l_prev + jnp.sum(p, axis=0),
+            acc * alpha + jnp.sum(p * v, axis=0))
 
 
 def _walk_blocks(pt_ref, len_ref, layer, pools, bufs, sem, first_ref,
@@ -285,31 +293,22 @@ def _walk_blocks(pt_ref, len_ref, layer, pools, bufs, sem, first_ref,
     return carry, n, n_blocks, b0
 
 
-def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
-                  groups, block):
-    """refs: the pools in HBM ([L, P, ps, Hkv, w]: K and V, or one fused
-    [K | V] pool), o_ref, a VMEM buffer [2, B, ps, Hkv, w] a pool, DMA
-    semaphores [pools, 2] (one a buffer) and, in SMEM, which buffer holds
-    the slot's first block. The pages arrive through `_walk_blocks`.
-
-    q and o blocks are [1, H, d] (multi-head, G = 1) or group-major
-    [1, G, Hkv, w]. Fused pool: q arrives with zeros in the V lanes, so
-    q . [K | V] is q . K; the accumulator sums p [K | V] and the wrapper
-    keeps its V lanes. No lane is sliced in the kernel."""
-    ps, B, G = page_size, block, groups
-    n_pools = (len(refs) - 3) // 2
-    pools, o_ref = refs[:n_pools], refs[n_pools]
-    bufs, (sem, first_ref) = refs[n_pools + 1:-2], refs[-2:]
+def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, k_pool, v_pool, o_ref,
+                  k_buf, v_buf, sem, first_ref, *, page_size, scale, block):
+    """Multi-head (G = 1): the K and V pools in HBM [L, P, ps, H, d], q
+    and o blocks [1, H, d], a VMEM buffer [2, B, ps, H, d] a pool, DMA
+    semaphores [2, 2] (one a pool and buffer) and, in SMEM, which buffer
+    holds the slot's first block. The pages arrive through `_walk_blocks`
+    and go one at a time through `_softmax_update` on the VPU."""
+    ps, B = page_size, block
     s = pl.program_id(0)
 
     def page_of(b, j):
-        k = bufs[0][b, j].astype(jnp.float32)
-        return k, (k if n_pools == 1 else bufs[1][b, j].astype(jnp.float32))
+        return (k_buf[b, j].astype(jnp.float32),
+                v_buf[b, j].astype(jnp.float32))
 
-    # the block of head group g in q_ref and o_ref: [1, H, d] is group 0
-    at = (lambda g: (0,)) if q_ref.ndim == 3 else (lambda g: (0, g))
-    q = [q_ref[at(g)].astype(jnp.float32) * scale for g in range(G)]
-    Hkv, w = q[0].shape
+    q = q_ref[0].astype(jnp.float32) * scale
+    H, d = q.shape
 
     def whole_pages(i, b, n, carry):
         # every page but the slot's last is whole: no mask
@@ -317,24 +316,115 @@ def _paged_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
             0, jnp.minimum(B, n - 1 - i * B),
             lambda j, c: _softmax_update(c, q, *page_of(b, j)), carry)
 
-    zero = jnp.zeros((Hkv, 1), jnp.float32)
+    zero = jnp.zeros((H, 1), jnp.float32)
     carry, n, n_blocks, b0 = _walk_blocks(
-        pt_ref, len_ref, ly_ref[0], pools, bufs, sem, first_ref, ps, B,
-        whole_pages,
-        ((zero + _NEG, zero, jnp.zeros((Hkv, w), jnp.float32)),) * G)
+        pt_ref, len_ref, ly_ref[0], (k_pool, v_pool), (k_buf, v_buf), sem,
+        first_ref, ps, B, whole_pages,
+        (zero + _NEG, zero, jnp.zeros((H, d), jnp.float32)))
     # the page the context ends in, still in the last block's buffer
     last = n_blocks - 1
-    idx = (n - 1) * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, Hkv, 1), 0)
-    carry = _softmax_update(
+    idx = (n - 1) * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, H, 1), 0)
+    _, l, acc = _softmax_update(
         carry, q, *page_of((b0 + last) % 2, n - 1 - last * B),
         live=idx < len_ref[s])
     first_ref[0] = (b0 + n_blocks) % 2
-    for g, (_, l, acc) in enumerate(carry):
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[at(g)] = (acc / l).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer, G):
+def _head_rows(block, h, words):
+    """KV head h of a block of pages, block [B, ps, Hkv, w] in VMEM, as one
+    matrix [B ps, w]: the rows h, h + Hkv, ... of the block folded to rows.
+    `words`: bfloat16 pages of 128 lanes and an even number of heads, on
+    the chip. Two neighbouring rows of 16 bits share a 32-bit word of a
+    lane, heads 2j and 2j + 1 of a token: the words j, j + Hkv / 2, ...
+    are read with a stride and the head's half is moved to the top of its
+    word, which is that number as a float32: the read hides behind the
+    block's copies. Otherwise the head is indexed, which the interpreters
+    run (they know no folded ref) and Mosaic takes at any shape, a
+    register a token: the whole kernel 23.7 ms where the words give 2.0
+    (Trinity-Mini's call; PERF.md, PR 41)."""
+    B, ps, Hkv, w = block.shape
+    if not words:
+        return block[:, :, h, :].reshape(B * ps, w)
+    rows = block.reshape(B * ps * Hkv, w).bitcast(jnp.uint32)[
+        pl.ds(h // 2, B * ps, stride=Hkv // 2), :]
+    bits = rows & jnp.uint32(0xFFFF0000) if h % 2 else rows << 16
+    return pltpu.bitcast(bits, jnp.float32).astype(block.dtype)
+
+
+def _softmax_block(carry, q, k, v, live, scale):
+    """A block of T cached tokens into the running softmax of the query
+    heads that share them. carry (m [H, 1], l [H, 1], acc [H, c]) float32;
+    q [H, w], k [T, w], v [T, c] in the pool's dtype; live [1, T] bool.
+    Both products on the MXU with float32 results, the probabilities
+    rounded to the pool's dtype as the XLA path rounds them."""
+    m_prev, l_prev, acc = carry
+    # the MXU's own precision for the pool's dtype, whatever the
+    # process's default (Mosaic refuses bf16 operands at `highest`)
+    sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.DEFAULT,
+                             preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(live, sc, _NEG)      # all true but in the last block
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(sc - m_new)     # a walked block has a live token: dead = 0
+    pv = jnp.dot(p.astype(v.dtype), v,
+                 precision=jax.lax.Precision.DEFAULT,
+                 preferred_element_type=jnp.float32)
+    return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
+            acc * alpha + pv)
+
+
+def _grouped_kernel(pt_ref, len_ref, ly_ref, q_ref, *refs, page_size, scale,
+                    block, words):
+    """G query heads a KV head, or a fused pool: refs are the pools in HBM
+    ([L, P, ps, Hkv, w]: K and V, or one fused [K | V] pool), o_ref, a
+    VMEM buffer [2, B, ps, Hkv, w] a pool, DMA semaphores [pools, 2] and
+    the SMEM word of `_paged_kernel`; q and o blocks group-major [1, G,
+    Hkv, w]. KV head h of a block of B pages is one matrix K_h [B ps, w]
+    (`_head_rows`) that the head's G query heads all meet, so scores [G, B
+    ps] = q_h . K_h^T and acc_h += p . V_h go to the MXU, a KV head at a
+    time, the softmax state float32 a KV head. Fused pool: one read brings
+    [K | V]; q has zeros in the V lanes and the wrapper keeps the
+    accumulator's V lanes. Only a slot's last block has dead tokens,
+    masked as `_latent_kernel` masks them."""
+    ps, B = page_size, block
+    n_pools = (len(refs) - 3) // 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, (sem, first_ref) = refs[n_pools + 1:-2], refs[-2:]
+    _, G, Hkv, w = q_ref.shape
+    T = B * ps
+    ctx = len_ref[pl.program_id(0)]
+    q = [q_ref[0, :, h, :] for h in range(Hkv)]
+
+    def on_block(i, b, n, carry):
+        last = (i + 1) * B >= n
+        at = i * T
+        live_col = at + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0) < ctx
+        live = at + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) < ctx
+        out = []
+        for h, state in enumerate(carry):
+            # rows nobody wrote may hold anything, and 0 x NaN is NaN; a
+            # dead key's score is masked, whatever it is
+            v = jax.lax.cond(
+                last, lambda r: jnp.where(live_col, r, jnp.zeros_like(r)),
+                lambda r: r, _head_rows(bufs[-1].at[b], h, words))
+            k = v if n_pools == 1 else _head_rows(bufs[0].at[b], h, words)
+            out.append(_softmax_block(state, q[h], k, v, live, scale))
+        return tuple(out)
+
+    zero = jnp.zeros((G, 1), jnp.float32)
+    carry, _n, n_blocks, b0 = _walk_blocks(
+        pt_ref, len_ref, ly_ref[0], pools, bufs, sem, first_ref, ps, B,
+        on_block,
+        ((zero + _NEG, zero, jnp.zeros((G, w), jnp.float32)),) * Hkv)
+    first_ref[0] = (b0 + n_blocks) % 2
+    for h, (_, l, acc) in enumerate(carry):
+        o_ref[0, :, h, :] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer):
     """The kernel over q [S, H, d] (G = 1) or group-major [S, G, Hkv, w];
     the output has q's shape."""
     ps, Hkv, w = pools[0].shape[2:]
@@ -352,8 +442,15 @@ def _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer, G):
         + [pltpu.SemaphoreType.DMA((len(pools), 2)),
            pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(_paged_kernel, page_size=ps,
-                               scale=float(scale), groups=G, block=B)
+    if q.ndim == 4:
+        kernel = functools.partial(
+            _grouped_kernel,
+            words=(not interpret and w == LATENT_LANES and Hkv % 2 == 0
+                   and pools[0].dtype == jnp.bfloat16))
+    else:
+        kernel = _paged_kernel
+    kernel = functools.partial(kernel, page_size=ps, scale=float(scale),
+                               block=B)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -366,17 +463,17 @@ def _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer, G):
 
 
 def _paged_attention_pallas_gqa(q, pools, page_table, ctx_lens, scale,
-                                interpret, layer, G):
+                                interpret, layer):
     """G query heads a KV head, or a fused pool: q goes in group-major
     [S, G, Hkv, w] (zeros in a fused pool's V lanes) and the custom call's
     output is bf16[S, G, Hkv, w]."""
     S, H, d = q.shape
     Hkv, w = pools[0].shape[3:]
+    G = H // Hkv
     q = q.reshape(S, Hkv, G, d).transpose(0, 2, 1, 3)
     if w != d:
         q = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
-    o = _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer,
-                    G)
+    o = _paged_call(q, pools, page_table, ctx_lens, scale, interpret, layer)
     return o[..., w - d:].transpose(0, 2, 1, 3).reshape(S, H, d)
 
 
@@ -384,13 +481,13 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, ctx_lens,
                            scale=None, interpret=None, layer=None):
     d = q.shape[-1]
     k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
-    G = _groups(q, k_pages)
     pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
-    call = _paged_attention_pallas_gqa if G > 1 or v_pages is None \
+    call = _paged_attention_pallas_gqa \
+        if _groups(q, k_pages) > 1 or v_pages is None \
         else _paged_call        # multi-head: the custom call is bf16[S, H, d]
     return call(q, pools, page_table, ctx_lens,
                 scale if scale is not None else 1.0 / math.sqrt(d),
-                (not on_tpu()) if interpret is None else interpret, layer, G)
+                (not on_tpu()) if interpret is None else interpret, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -446,7 +543,6 @@ def _latent_kernel(pt_ref, len_ref, ly_ref, q_ref, rows_ref, o_ref, buf, sem,
     ctx = len_ref[s]
 
     def on_block(i, b, n, carry):
-        m_prev, l_prev, acc = carry
         kv = buf[b].reshape(T, W)
         last = (i + 1) * B >= n
         at = i * T
@@ -454,21 +550,8 @@ def _latent_kernel(pt_ref, len_ref, ly_ref, q_ref, rows_ref, o_ref, buf, sem,
         kv = jax.lax.cond(last,
                           lambda r: jnp.where(live_col, r, jnp.zeros_like(r)),
                           lambda r: r, kv)
-        # the MXU's own precision for the pool's dtype, whatever the
-        # process's default (Mosaic refuses bf16 operands at `highest`)
-        sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
-                                 precision=jax.lax.Precision.DEFAULT,
-                                 preferred_element_type=jnp.float32) * scale
         live = at + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) < ctx
-        sc = jnp.where(live, sc, _NEG)      # all true but in the last block
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)     # a walked block has a live token: dead = 0
-        pv = jnp.dot(p.astype(kv.dtype), kv[:, :value_width],
-                     precision=jax.lax.Precision.DEFAULT,
-                     preferred_element_type=jnp.float32)
-        return (m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-                acc * alpha + pv)
+        return _softmax_block(carry, q, kv, kv[:, :value_width], live, scale)
 
     zero = jnp.zeros((H, 1), jnp.float32)
     (_, l, acc), _n, n_blocks, b0 = _walk_blocks(
@@ -573,12 +656,15 @@ def _gate_paged(S, H, d, P, ps, M, dtype, Hkv=None, fused=False):
     pool leaves no room for a second one beside it. "live_pages" in the
     key (after "stacked", PR 25) keeps a record measured on the kernel
     of before, whose time was the table's width whatever the contexts,
-    from answering for this one: trees share a machine's gate cache."""
+    from answering for this one: trees share a machine's gate cache.
+    "mxu" does the same for the grouped kernel's keys (PR 41: before it
+    the candidate named `pallas` was the VPU loop, 6 x slower at
+    Trinity-Mini's shape)."""
     dtype = jnp.dtype(dtype)
     key = ("paged_attention", "live_pages", S, H, d, P, ps, M, str(dtype))
     Hkv = H if Hkv is None else Hkv
-    if Hkv != H or fused:   # other kernels, a key of their own
-        key += ("kv_heads", Hkv) + (("fused",) if fused else ())
+    if Hkv != H or fused:   # `_grouped_kernel`, a key of its own
+        key += ("mxu", "kv_heads", Hkv) + (("fused",) if fused else ())
 
     def make_args():
         import numpy as np

@@ -1,10 +1,11 @@
-"""The serving kernels this repo added for the latent model, compiled at
-the benchmark's real widths for a DESCRIBED TPU v5e (no chip; the chip's
-compiler is installed here): what Mosaic refuses shows here and in no
-interpret-mode test (a page row of 576 lanes, more VMEM than a kernel may
-use). Nothing runs: no result, no time. The topology is described inside a
-fixture, and only in this file: one process may load the TPU's library,
-and a worker that is not given this file must not try."""
+"""The serving kernels this repo added (latent rows, grouped query heads),
+compiled at the benchmark's real widths for a DESCRIBED TPU v5e (no chip;
+the chip's compiler is installed here): what Mosaic refuses shows here and
+in no interpret-mode test (a page row of 576 lanes, more VMEM than a
+kernel may use, a read the interpreters spell another way). Nothing runs:
+no result, no time. The topology is described inside a fixture, and only
+in this file: one process may load the TPU's library, and a worker that is
+not given this file must not try."""
 import os
 
 import jax
@@ -62,6 +63,34 @@ def test_the_latent_paged_kernel_compiles_at_the_cells_shapes(one_chip):
                  sds((7, 6401, 64, 576), jnp.bfloat16),
                  sds((64, 160), jnp.int32), sds((64,), jnp.int32),
                  sds((), jnp.int32))
+
+
+@pytest.mark.parametrize("S,H,Hkv,d,L,P,ps,M,fused", [
+    (64, 32, 4, 128, 1, 7681, 64, 288, False),      # Trinity-Mini's full layer
+    (64, 32, 8, 64, 3, 16385, 16, 256, True),       # LFM2's three
+], ids=["trinity_full", "lfm2_fused"])
+def test_the_grouped_paged_kernel_compiles_at_the_cells_shapes(
+        one_chip, S, H, Hkv, d, L, P, ps, M, fused):
+    """`_grouped_kernel` as the chip runs it: a KV head of a block read as
+    32-bit words with a stride through a folded, bitcast ref
+    (`_head_rows(words=True)`, which no interpreted test of the kernel
+    takes), two MXU products a head, the output stored a head at a time
+    into bf16[S, G, Hkv, 128]; the pools read where they lie."""
+    from paddle_tpu.ops import paged_attention as pa
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    pool = sds((L, P, ps, Hkv, 2 * d if fused else d), jnp.bfloat16)
+
+    def call(q, k, v, pt, ctx, layer):
+        return pa.paged_attention_pallas(q, k, v, pt, ctx, layer=layer,
+                                         interpret=False)
+
+    c = _compile(call, sds((S, H, d), jnp.bfloat16), pool,
+                 None if fused else pool, sds((S, M), jnp.int32),
+                 sds((S,), jnp.int32), sds((), jnp.int32))
+    assert f"bf16[{S},{H // Hkv},{Hkv},128]" in c.as_text()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_the_flash_forward_compiles_at_the_longest_prefill_bucket(

@@ -259,6 +259,13 @@ def test_paged_gate_key_tells_grouped_query_and_fused_pools_apart():
                         fused=True)[0]
     assert gqa[-2:] == ("kv_heads", 8) and fused[-1] == "fused"
     assert len({mha, gqa, fused}) == 3
+    # "mxu" (PR 41): a grouped key's `pallas` is the kernel that multiplies
+    # a KV head's block on the MXU; a record timed on the VPU loop of
+    # before, ("paged_attention", "live_pages", ..., "kv_heads", 8,
+    # "fused"), names another kernel and is not found again
+    assert "mxu" in gqa and "mxu" in fused and "mxu" not in mha
+    assert tuple(w for w in fused if w != "mxu") == mha[:2] + (
+        64, 32, 64, 16385, 16, 256, "bfloat16", "kv_heads", 8, "fused")
 
 
 # The kernel's blocks, at a size a test can hold: 16 KiB of page buffers is
@@ -337,6 +344,113 @@ def test_paged_kernel_never_reads_a_dead_page(small_blocks, G, fused):
     want = pa.paged_attention_xla(q, *pools, clean, lens, layer=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
+
+
+# contexts of the grouped kernel's cases, in pages of 8 and blocks of 4: one
+# token; a page's first row and its last; the last page of a block, first
+# row and last; one page past a block, first row and last; the whole table
+_EDGE_LENS = [1, 9, 16, 25, 32, 33, 40, 80]
+
+
+@pytest.mark.parametrize("G,Hkv,fused,dtype,atol", [
+    (G, Hkv, fused, "float32", 1e-5)
+    for G in (2, 4, 8) for Hkv in (2, 4, 8) for fused in (False, True)
+] + [(G, Hkv, fused, "bfloat16", 2e-2)     # the two cells' head counts
+     for G, Hkv in ((8, 4), (4, 8)) for fused in (False, True)])
+def test_grouped_kernel_matches_xla_at_head_counts(monkeypatch, G, Hkv, fused,
+                                                   dtype, atol):
+    """The kernel that multiplies a KV head's block on the MXU, beside the
+    gather path: G query heads over Hkv KV heads, K and V pools and the
+    fused one, the contexts of `_EDGE_LENS` in one batch. Every row no
+    context reaches is NaN: the dead entries of a table name a page of
+    NaN, the tail of a slot's last page is NaN in the pool, and the
+    buffer's pages that a last block does not fill start as NaN. (Each
+    slot has pages of its own, so that one's dead tail is nobody's live
+    row.) bfloat16: both paths round the probabilities to the pool's
+    dtype, the gather path after it has normalised them."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import paged_attention as pa
+    ps, d, M, B, L = 8, 16, 10, 4, 2
+    itemsize = jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(pa, "_PAGE_BUFFER_BYTES",
+                        2 * 2 * B * ps * Hkv * d * itemsize)
+    assert pa._block_pages(ps * Hkv * d * itemsize, 2, M) == B
+    assert pa._block_pages(ps * Hkv * 2 * d * itemsize, 1, M) == B
+    lens = np.asarray(_EDGE_LENS)
+    n = -(-lens // ps)
+    nan_page = int(n.sum())
+    rng = np.random.RandomState(G * 10 + Hkv)
+    kv = rng.randn(2, L, nan_page + 1, ps, Hkv, d).astype(np.float32)
+    kv[:, :, nan_page] = np.nan
+    table = np.full((len(lens), M), nan_page, np.int32)
+    for s, at in enumerate(np.cumsum(n) - n):
+        table[s, :n[s]] = at + rng.permutation(n[s])
+        kv[:, :, table[s, n[s] - 1], lens[s] - (n[s] - 1) * ps:] = np.nan
+    q = jnp.asarray(rng.randn(len(lens), G * Hkv, d), dtype)
+    k, v = jnp.asarray(kv, dtype)
+    pools = (jnp.concatenate([k, v], -1), None) if fused else (k, v)
+    got = pa.paged_attention_pallas(
+        q, *pools, jnp.asarray(table), jnp.asarray(lens, jnp.int32), layer=1,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    clean = tuple(None if p is None else jnp.nan_to_num(p) for p in pools)
+    want = pa.paged_attention_xla(q, *clean, jnp.asarray(table),
+                                  jnp.asarray(lens, jnp.int32), layer=1)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol,
+                               rtol=atol)
+
+
+@pytest.mark.parametrize("Hkv", [2, 4, 8])
+def test_head_rows_as_words_are_the_indexed_head(Hkv):
+    """`_head_rows`' spelling for the chip (bfloat16 pages of 128 lanes:
+    32-bit words read with a stride, a head's half moved to the top of its
+    word) reads what the indexed head reads, bit for bit; the kernel's
+    interpreted runs take the indexed one."""
+    from jax.experimental import pallas as pl
+    from paddle_tpu.ops.paged_attention import _head_rows
+    B, ps, w = 2, 8, 128
+    x = jax.random.normal(jax.random.PRNGKey(Hkv), (B, ps, Hkv, w),
+                          jnp.bfloat16)
+
+    def heads(words):
+        def kernel(x_ref, o_ref):
+            for h in range(Hkv):
+                o_ref[h] = _head_rows(x_ref, h, words)
+        return pl.pallas_call(kernel, interpret=True, out_shape=(
+            jax.ShapeDtypeStruct((Hkv, B * ps, w), x.dtype)))(x)
+
+    want = np.asarray(x.reshape(B * ps, Hkv, w).transpose(1, 0, 2), np.float32)
+    np.testing.assert_array_equal(np.asarray(heads(False), np.float32), want)
+    np.testing.assert_array_equal(np.asarray(heads(True), np.float32), want)
+
+
+@pytest.mark.parametrize("G,fused", [(1, False), (1, True), (4, False),
+                                     (4, True)])
+def test_grouped_paged_kernel_multiplies_on_the_mxu(G, fused):
+    """The structural guard of PR 41: query heads that share their keys
+    (G > 1), or a fused pool, meet a KV head's block as a matrix: the
+    kernel's program holds two `dot_general`s a KV head (scores and
+    values) and makes no float32 copy of a page [ps, Hkv, w], which the
+    VPU loop made once a page and multiplied once a group. Plain
+    multi-head attention (G = 1, K and V pools) has one row a head for the
+    MXU: its kernel holds no `dot_general` and keeps the page loop."""
+    from paddle_tpu.ops import paged_attention as pa
+    Hkv = _WALK["Hkv"]
+    q, pools, table, lens = _walk_args(G, fused, [33, 1, 80, 9])
+    jaxpr = jax.make_jaxpr(lambda *a: pa.paged_attention_pallas(
+        *a, layer=1, interpret=True))(q, *pools, table, lens)
+    call, = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    inside = list(_eqns(call.params["jaxpr"]))
+    dots = [e for e in inside if e.primitive.name == "dot_general"]
+    page = (_WALK["ps"], Hkv, pools[0].shape[-1])
+    pages = [e for e in inside for o in e.outvars
+             if getattr(o.aval, "shape", None) == page
+             and o.aval.dtype == jnp.float32]
+    if G == 1 and not fused:
+        assert not dots and pages
+    else:
+        assert len(dots) == 2 * Hkv and not pages, (len(dots), pages)
 
 
 def test_paged_attention_refuses_heads_that_do_not_divide():
