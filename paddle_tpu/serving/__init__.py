@@ -28,7 +28,9 @@ inputs, per slot) are served through the same Engine by
 `HybridDecodeModel` (models/lfm2.py), and a looped decoder, whose layers
 run several times a token with K/V of every pass, by `LoopedDecodeModel`
 (models/ouro.py), and one that caches a latent row a token in place of
-keys and values by `LatentDecodeModel` (models/deepseek_v3.py);
+keys and values by `LatentDecodeModel` (models/deepseek_v3.py), and one
+whose layers keep a recurrence's state per slot beside a few attention
+layers by `RecurrentDecodeModel` (models/jamba.py);
 `DecodeModel` (serving/model.py) is what the engine asks
 of each.
 
@@ -44,7 +46,7 @@ from .scheduler import (QueueFull, QuotaExceeded, Request, Scheduler,
                         TokenBucket)
 from .model import (DecodeModel, GPTDecodeModel, HybridDecodeModel,
                     LatentDecodeModel, LoopedDecodeModel,
-                    WindowedDecodeModel)
+                    RecurrentDecodeModel, WindowedDecodeModel)
 from .engine import Engine
 from .frontend import ServingClient, ServingServer
 from .loadgen import (Arrival, LoadGenerator, LoadResult, TrafficConfig,
@@ -57,7 +59,7 @@ __all__ = [
     "Request", "Scheduler", "QueueFull", "QuotaExceeded", "TokenBucket",
     "DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
     "LoopedDecodeModel", "LatentDecodeModel", "WindowedDecodeModel",
-    "Engine",
+    "RecurrentDecodeModel", "Engine",
     "ServingServer", "ServingClient",
     "Arrival", "LoadGenerator", "LoadResult", "TrafficConfig",
     "slo_report",

@@ -567,13 +567,17 @@ class Engine:
         window = self.model.window
         past = {"past_window": max(0, int(req.prompt.size) - window)} \
             if window else {}
+        # a recurrence: the bucket its scan runs over, in how many chunks
+        chunk = self.model.scan_chunk
+        scan = {"scan_len": T, "scan_chunks": -(-T // min(chunk, T))} \
+            if chunk else {}
         t0 = time.perf_counter()
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
                            cached_tokens=start, slot=req.slot,
                            passes=self.model.passes,
-                           **past,
+                           **past, **scan,
                            **self._attn_form("prefill")) as sp:
             self.cache, tok = fn(*targs)
             tok = int(tok)
@@ -761,6 +765,9 @@ class Engine:
                                passes=self.model.passes,
                                **({"window_rows": window_rows}
                                   if window else {}),
+                               # live slots whose recurrent state advances
+                               **({"state_rows": len(batch)}
+                                  if self.model.scan_chunk else {}),
                                **self._attn_form("decode")) as sp:
                 with _tracing.span("engine.dispatch") as dispatch:
                     # with nothing left to decode, this step only reads

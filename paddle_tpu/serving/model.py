@@ -6,10 +6,13 @@ and tally parts), `LoopedDecodeModel` (K and V of every layer of every
 PASS in two paged parts, and tallies), `LatentDecodeModel` (ONE latent
 row a token a layer, no keys or values; two forms of attention) and
 `WindowedDecodeModel` (the full layers' K and V in paged parts, the window
-layers' in a ring of pages a slot) answer it.
+layers' in a ring of pages a slot) and `RecurrentDecodeModel` (a
+recurrence's state and its convolution's taps per slot, the few attention
+layers' shared K | V row paged) answer it.
 Each adapter's bodies are drivers over its architecture's layer loop
 (`GPTDecodeModel._layers`; `lfm2.apply_layers`; `ouro.apply_passes`;
-`deepseek_v3.apply_layers`; `afmoe.apply_layers`): they say how tokens
+`deepseek_v3.apply_layers`; `afmoe.apply_layers`; `jamba.apply_layers`):
+they say how tokens
 become `x`, where the attention state lands and what attends.
 
 Trash-page convention: the device pools carry ONE extra page at index
@@ -36,6 +39,7 @@ import jax.numpy as jnp
 
 from ..models import afmoe as _afmoe
 from ..models import deepseek_v3 as _dsv3
+from ..models import jamba as _jamba
 from ..models import lfm2 as _lfm2
 from ..models import ouro as _ouro
 from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
@@ -44,9 +48,11 @@ from ..ops.paged_attention import (latent_row_width, paged_attention_decode,
                                    paged_attention_xla,
                                    paged_latent_attention_decode)
 from ..ops.pallas_attention import on_tpu
+from ..ops.selective_scan import SCAN_CHUNK
 
 __all__ = ["DecodeModel", "GPTDecodeModel", "HybridDecodeModel",
-           "LoopedDecodeModel", "LatentDecodeModel", "WindowedDecodeModel"]
+           "LoopedDecodeModel", "LatentDecodeModel", "WindowedDecodeModel",
+           "RecurrentDecodeModel"]
 
 logger = logging.getLogger("paddle_tpu.serving.model")
 
@@ -88,6 +94,10 @@ class DecodeModel:
           kept in a ring of `ring_pages(page_size)` pages a slot (a slot
           part); None without such layers. The engine reads it for its
           spans alone (`window_rows`, `past_window`, `window_pages_*`)
+      scan_chunk         the positions a chunk of a model's recurrence
+          scans in prefill, its state the carry from chunk to chunk; None
+          without a recurrence. The engine reads it for its spans alone
+          (`scan_len`, `scan_chunks`, `state_rows`)
 
     `cache'` has the keys, shapes and dtypes of `cache`: the engine donates
     it. The cache is a dict of device arrays, and `cache_kinds` says of each
@@ -102,7 +112,11 @@ class DecodeModel:
                tenant's; decode's row i is slot i. A window layer's ring is
                one too, [layers, S R + 1, ps, ...]: slot i's R pages are
                rows i R .. i R + R - 1 (then one trash page), and what the
-               last tenant left there is masked by its position.
+               last tenant left there is masked by its position. Where a
+               slot's state is a few rows (a convolution's three taps),
+               the slot axis may lie further in, [layers, K-1, S, E], so
+               that [S, E] are the tiled dimensions: the engine indexes no
+               slot part, the model's bodies do.
       "tally"  counters the programs add to and only `Engine.stats` reads,
                through `tally_stats`: what a tally means is the model's.
     """
@@ -113,6 +127,7 @@ class DecodeModel:
     passes = 1
     attn_forms: dict[str, str] = {}
     window: int | None = None
+    scan_chunk: int | None = None
 
     def __init__(self, cfg, params, attn_impl: str | None = None):
         self.cfg = cfg
@@ -1042,3 +1057,109 @@ class WindowedDecodeModel(_ExpertRecords, DecodeModel):
         return {**self._parts(pools),
                 **self._recorded_decode(cache, sel, page_of, off, live)}, \
             _afmoe.head_logits(params, x[0], cfg)
+
+
+class RecurrentDecodeModel(DecodeModel):
+    """Serving adapter around `models/jamba.py`: Mamba layers beside a few
+    multi-query attention layers. A Mamba layer's state is per SLOT and
+    does not grow with the sequence: `ssm` [Mamba layers, S, N, E] float32
+    (the recurrence's h) and `conv` [Mamba layers, K-1, S, E] (the
+    convolution's last K-1 inputs); at the published sizes 9,318,400 bytes
+    a slot, read and written whole by every decode step, in place in the
+    donated cache. An attention layer's K and V are ONE head that all the
+    query heads read: a row [v | k] a token in the paged part `kv`
+    [attention layers, P+1, ps, 2d] under the request's table (1 KiB a
+    token over two layers), attended through the latent path (`ops/
+    paged_attention.py::paged_latent_attention_decode`: H query rows
+    against a block of shared rows on the MXU, the query zero in the
+    value's lanes). A pool with a head axis of one would pad it to a tile
+    of sixteen rows.
+
+    `prefill` and `decode` are drivers over `jamba.apply_layers`. Prefill
+    runs the chunked scan from a zero state over the bucket and writes the
+    slot's rows whole from the state AT `true_len` (the padding behind it
+    does not advance the recurrence, and the taps are those before
+    `true_len`), so a slot never reads its last tenant's. Decode advances
+    every slot's rows by one token; a dead slot's stay finite (its input
+    is token 0, its decay under one) and are overwritten at admission."""
+
+    cache_kinds = {"kv": "paged", "ssm": "slot", "conv": "slot"}
+    scan_chunk = SCAN_CHUNK
+
+    def __init__(self, cfg: "_jamba.JambaConfig", params=None, seed: int = 0,
+                 attn_impl: str | None = None):
+        if cfg.num_key_value_heads != 1:
+            raise NotImplementedError(
+                f"num_key_value_heads = {cfg.num_key_value_heads}: the "
+                f"paged part holds one shared [v | k] row a token")
+        super().__init__(cfg, params if params is not None
+                         else _jamba.init_params(cfg, seed), attn_impl)
+
+    def init_cache(self, num_pages: int, page_size: int, num_slots: int):
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        ssm, conv = _jamba.zero_state(cfg, num_slots, dt)
+        return {"kv": jnp.zeros((cfg.layers_of(_jamba.ATTN), num_pages + 1,
+                                 page_size, 2 * cfg.head_dim), dt),
+                "ssm": ssm, "conv": conv}
+
+    # -- prefill -------------------------------------------------------
+    def prefill(self, params, cache, tokens, true_len, page_row, slot):
+        """tokens [T] int32 (padded bucket), true_len and slot scalar
+        int32, page_row [M] int32 (fill = trash). Returns (cache, logits
+        [V]) of the last real position."""
+        cfg = self.cfg
+        T = tokens.shape[0]
+        ps = cache["kv"].shape[2]
+        pages = page_row[:T // ps]
+        x = jnp.take(params["embed"], tokens, axis=0)[None]     # [1, T, D]
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+
+        def attend(q, k, v, pool, i):
+            row = jnp.concatenate([v, k], axis=-1)[0, :, 0]     # [T, 2d]
+            pool = pool.at[i, pages].set(
+                row.reshape(T // ps, ps, -1).astype(pool.dtype))
+            return _lfm2.dense_causal_attention(q, k, v, scale), pool
+
+        x, ssm, conv, pool = _jamba.apply_layers(
+            cfg, params, x, *_jamba.zero_state(cfg, 1, cache["conv"].dtype),
+            attend, cache["kv"], lengths=jnp.reshape(true_len, (1,)))
+        xlast = jax.lax.dynamic_index_in_dim(x[0], true_len - 1, 0,
+                                             keepdims=False)
+        put = jax.lax.dynamic_update_slice_in_dim
+        return {"kv": pool, "ssm": put(cache["ssm"], ssm, slot, axis=1),
+                "conv": put(cache["conv"], conv, slot, axis=2)}, \
+            _jamba.head_logits(params, xlast, cfg)
+
+    # -- decode --------------------------------------------------------
+    def decode(self, params, cache, tokens, positions, tables):
+        """tokens/positions [S] int32, tables [S, M] int32 (fill = trash;
+        inactive slots = all-trash rows with position 0). Row i is slot i.
+        Every Mamba layer advances every slot's state by its token; an
+        attention layer writes the slot's row to its position's page and
+        offset, then attends over the slot's cached rows. Returns (cache,
+        logits [S, V])."""
+        cfg = self.cfg
+        S, d = tokens.shape[0], cfg.head_dim
+        ps = cache["kv"].shape[2]
+        x = jnp.take(params["embed"], tokens, axis=0)[:, None]  # [S, 1, D]
+        page_of = jnp.take_along_axis(
+            tables, (positions // ps)[:, None], axis=1)[:, 0]
+        off = positions % ps
+        ctx = positions + 1
+        scale = 1.0 / math.sqrt(d)
+
+        def attend(q, k, v, pool, i):
+            row = jnp.concatenate([v, k], axis=-1)[:, 0, 0]     # [S, 2d]
+            pool = pool.at[i, page_of, off].set(row.astype(pool.dtype))
+            q = q[:, 0]                                         # [S, H, d]
+            o = paged_latent_attention_decode(
+                jnp.concatenate([jnp.zeros_like(q), q], axis=-1), pool,
+                tables, ctx, value_width=d, scale=scale, layer=i,
+                impl=self.attn_impl)
+            return o.reshape(S, 1, -1), pool
+
+        x, ssm, conv, pool = _jamba.apply_layers(
+            cfg, params, x, cache["ssm"], cache["conv"], attend, cache["kv"])
+        return {"kv": pool, "ssm": ssm, "conv": conv}, \
+            _jamba.head_logits(params, x[:, 0], cfg)

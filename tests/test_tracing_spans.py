@@ -603,3 +603,42 @@ def test_a_model_with_two_forms_of_attention_names_them_on_its_spans(engine):
         float((hp.max(axis=1) / mean).mean()) if (mean > 0).all() else None)
     assert attn_of(Engine(LoopedDecodeModel(OuroConfig.tiny(), seed=0),
                           **kw))[0] == none
+
+
+# -- a model whose layers keep a recurrence's state a slot (ISSUE 42) ---------
+
+@pytest.mark.parametrize("prompt,bucket,chunks", [(5, 8, 1), (17, 32, 1),
+                                                  (300, 512, 2)])
+def test_a_model_with_a_recurrence_names_its_scan_and_its_state_rows(
+        engine, prompt, bucket, chunks):
+    """`scan_len` / `scan_chunks` on `engine.prefill` (the bucket the
+    chunked scan ran over, in chunks of 256 positions), `state_rows`
+    on `engine.decode` (the live slots whose state the step advanced); all
+    absent for a model without a recurrence; the state is a slot part and
+    counts under the slot-state gauge."""
+    from paddle_tpu.models.jamba import JambaConfig
+    from paddle_tpu.serving import RecurrentDecodeModel
+    cfg = JambaConfig.tiny()
+    eng = Engine(RecurrentDecodeModel(cfg, seed=0), num_slots=2,
+                 num_pages=80, page_size=8, max_seq_len=512)
+    reqs, spans = _run(eng, (_prompt(prompt), 3), (_prompt(3, 1), 2))
+    first = next(s for s in spans if s.name == "engine.prefill"
+                 and s.attrs["request"] == reqs[0].id)
+    assert (first.attrs["scan_len"], first.attrs["scan_chunks"]) \
+        == (bucket, chunks)
+    assert first.attrs["scan_len"] == first.attrs["bucket"]
+    decodes = [s for s in spans if s.name == "engine.decode"]
+    assert decodes and all(s.attrs["state_rows"] == s.attrs["active"]
+                           for s in decodes)
+    assert max(s.attrs["state_rows"] for s in decodes) == 2
+    gauge = registry.REGISTRY.get("paddle_tpu_serving_slot_state_bytes")
+    E, N, K = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
+    assert gauge.labels(engine=eng.engine_id).value \
+        == 3 * 2 * (N * E * 4 + (K - 1) * E * 4)
+    # no span per layer or per chunk: the phases are the seven they were
+    for stp in (s for s in spans if s.name == "engine.step"
+                and not s.attrs.get("idle")):
+        assert [k.name for k in _children(spans, stp)] == PHASES
+    _reqs, plain = _run(engine, (_prompt(5), 2))
+    for s in plain:
+        assert not {"scan_len", "scan_chunks", "state_rows"} & set(s.attrs)
