@@ -42,7 +42,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..ops.selective_scan import selective_scan, selective_step
+from ..ops.selective_scan import StackedRow, selective_scan, selective_step
 from .lfm2 import dense_causal_attention, dense_ffn, rmsnorm
 
 __all__ = ["JambaConfig", "init_params", "forward", "apply_layers",
@@ -186,7 +186,9 @@ def init_params(cfg: JambaConfig, seed: int = 0):
 
 def mamba_mixer(p, a, ssm, conv, lengths, cfg):
     """The Mamba mixer of one layer. a [B, T, D] (normed); ssm [B, N, E]
-    float32, the state before a's first position; conv [K-1, B, E], u at
+    float32, the state before a's first position (T == 1: or the layer's
+    `StackedRow` of the stacked state, and then ssm' is one: the one-step
+    update reads the row where it lies); conv [K-1, B, E], u at
     the K-1 positions before it (zeros at a sequence's start; time-major:
     [B, E] are then the minor dimensions, and three taps do not pad to a
     tile of sixteen rows). Returns
@@ -289,11 +291,17 @@ def apply_layers(cfg, params, x, ssm, conv, attend, attn_state,
         x, ssm, conv = carry
         m = l - shift
         lp = _at(params["layers"], l)
+        # one position: the step takes the stack and the index, so that a
+        # decode program reads a layer's state once (ops/selective_scan.py)
+        row = StackedRow(ssm, m)
         y, h, cv = mamba_mixer(
             _at(params["mamba"], m), rmsnorm(x, lp["input_norm"], eps),
-            _at(ssm, m), _at(conv, m), lengths, cfg)
-        put = jax.lax.dynamic_update_index_in_dim
-        return mlp(x + y, lp), put(ssm, h, m, 0), put(conv, cv, m, 0)
+            row if x.shape[1] == 1 else row.row(), _at(conv, m), lengths,
+            cfg)
+        if not isinstance(h, StackedRow):
+            h = row.put(h)
+        return mlp(x + y, lp), h.stack, \
+            jax.lax.dynamic_update_index_in_dim(conv, cv, m, 0)
 
     seen = 0    # attention layers so far
     for kind, lo, hi in _runs(cfg.layer_types):

@@ -10,7 +10,7 @@ chip time. Nothing runs: no time, no result. Run from the repo's root with
 JAX_PLATFORMS=cpu.
 
     python3 scripts/pr42_compile_for_v5e.py [--attn pallas|xla] [--scan pallas|xla]
-        [--buckets 64,2048] [--text <dir>]
+        [--step pallas|xla] [--buckets 64,2048] [--text <dir>]
 """
 import argparse
 import os
@@ -33,6 +33,7 @@ def main():
     ap.add_argument("--slots", type=int, default=256)
     ap.add_argument("--attn", default="pallas")
     ap.add_argument("--scan", default="pallas")
+    ap.add_argument("--step", default="pallas")
     ap.add_argument("--buckets", default="64,2048")
     ap.add_argument("--text", default="")
     args = ap.parse_args()
@@ -45,6 +46,7 @@ def main():
     paged_attention.on_tpu = lambda: True
     selective_scan.on_tpu = lambda: True
     selective_scan._auto_impl = lambda *a: args.scan
+    selective_scan._auto_step_impl = lambda *a: args.step
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

@@ -1,5 +1,6 @@
-"""The serving kernels this repo added (latent rows, grouped query heads),
-compiled at the benchmark's real widths for a DESCRIBED TPU v5e (no chip;
+"""The serving kernels this repo added (latent rows, grouped query heads,
+the one-step update of a recurrent state), compiled at the benchmark's
+real widths for a DESCRIBED TPU v5e (no chip;
 the chip's compiler is installed here): what Mosaic refuses shows here and
 in no interpret-mode test (a page row of 576 lanes, more VMEM than a
 kernel may use, a read the interpreters spell another way). Nothing runs:
@@ -7,6 +8,7 @@ no result, no time. The topology is described inside a fixture, and only
 in this file: one process may load the TPU's library, and a worker that is
 not given this file must not try."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,12 +28,12 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *specs):
+def _compile(fn, *specs, donate=()):
     from jax.experimental.compilation_cache import compilation_cache as cc
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return jax.jit(fn).lower(*specs).compile()
+        return jax.jit(fn, donate_argnums=donate).lower(*specs).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         cc.reset_cache()
@@ -115,3 +117,92 @@ def test_the_flash_forward_compiles_at_the_longest_prefill_bucket(
         with pytest.raises(Exception, match="vmem"):
             _compile(lambda q, k, v: call(q, k, v, 512), sds(192), sds(192),
                      sds(128))
+
+
+def _ops_naming(text, pattern):
+    """The fusions, custom calls and copies of a compiled module whose
+    result, or whose fused computation's parameter, is of a type that
+    `pattern` finds."""
+    reads = {m.group(1) for m in re.finditer(
+        r"^(%fused_computation[\w.\-]*) \(([^)]*)\) ->", text, re.M)
+        if re.search(pattern, m.group(2))}
+    found = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) "
+                     r"(fusion|custom-call|copy)\(", ln)
+        called = re.search(r"calls=(%[\w.\-]+)", ln)
+        if m and (re.search(pattern, m.group(2))
+                  or (called and called.group(1) in reads)):
+            found.append(m.group(1))
+    return found
+
+
+STATE = (26, 256, 16, 5120)     # Jamba2-3B's Mamba layers at 256 slots
+
+
+def test_the_step_kernel_compiles_at_the_cells_shapes(one_chip):
+    """The whole stacked part f32[26,256,16,5120] and a traced layer: the
+    stack one aliased buffer (2.18 GB in, the same 2.18 GB out), no copy
+    of it or of a row among the temporaries."""
+    from paddle_tpu.ops import selective_scan as ss
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    L, S, N, E = STATE
+
+    def call(stack, m, u, dt, A, B, C, D):
+        return ss.selective_step_pallas(u, dt, A, B, C, D, stack, m,
+                                        interpret=False)
+
+    c = _compile(call, sds(STATE), sds((), jnp.int32),
+                 sds((S, E), jnp.bfloat16), sds((S, E)), sds((N, E)),
+                 sds((S, N)), sds((S, N)), sds((E,), jnp.bfloat16),
+                 donate=(0,))
+    m = c.memory_analysis()
+    assert "tpu_custom_call" in c.as_text()
+    assert m.alias_size_in_bytes == 4 * L * S * N * E
+    assert m.temp_size_in_bytes < 32 * 2 ** 20     # streams, B and C
+
+
+@pytest.mark.parametrize("step,ops_a_layer", [("pallas", 1), ("xla", 2)])
+def test_a_jamba_decode_program_touches_a_layers_state_in_one_operation(
+        one_chip, monkeypatch, step, ops_a_layer):
+    """The finding of PR 42 and its guard (PR 43): in the decode program at
+    the cell's size (28 layers, 256 slots, every part donated) the XLA
+    step is TWO fusions a Mamba layer that each read the layer's state
+    out of the stack (y; the update written in place), the kernel ONE
+    custom call; neither copies the stack or a row (temporaries 65 MB,
+    the logits). The three runs of Mamba layers are three loops. If the
+    `xla` case fails because the compiler now fuses the two, the kernel
+    and its gate key can go."""
+    from paddle_tpu.models import jamba
+    from paddle_tpu.ops import paged_attention, selective_scan
+    from paddle_tpu.serving import RecurrentDecodeModel
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(selective_scan, "on_tpu", lambda: True)
+    monkeypatch.setattr(selective_scan, "_auto_step_impl", lambda *a: step)
+    cfg = jamba.JambaConfig(dtype="bfloat16")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s, jnp.bfloat16), jamba.weight_shapes(cfg),
+        is_leaf=lambda s: isinstance(s, tuple))
+    model = RecurrentDecodeModel.__new__(RecurrentDecodeModel)
+    model.cfg, model.attn_impl = cfg, "pallas"
+    S = STATE[1]
+    cache = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init_cache(6144, 64, S)))
+    assert cache["ssm"].shape == STATE
+
+    def decode(cache, params, tokens, positions, tables):
+        return model.decode(params, cache, tokens, positions, tables)
+
+    c = _compile(decode, cache, params, sds((S,), jnp.int32),
+                 sds((S,), jnp.int32), sds((S, 64), jnp.int32), donate=(0,))
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * 26 * S * 16 * 5120
+    assert m.temp_size_in_bytes < 100e6
+    ops = _ops_naming(c.as_text(), r"f32\[(26,)?256,16,5120\]")
+    assert len(ops) == 3 * ops_a_layer, ops
+    if step == "pallas":
+        assert all(o.startswith("%selective_step") for o in ops), ops
