@@ -7,15 +7,18 @@ slot batch. Each scheduler iteration (`step()`):
 
   1. expire deadlines (queued + running; preempted requests free ALL
      their pages back to the pool immediately);
-  2. admit queued requests into free slots (capacity-gated FIFO), run
-     one jitted PREFILL per admission (prompt K/V -> pages, per-slot
-     state -> the request's slot, first token);
+  2. admit queued requests into free slots (capacity-gated FIFO),
+     dispatch one jitted PREFILL per admission (prompt K/V -> pages,
+     per-slot state -> the request's slot, first token) and read
+     nothing back;
   3. dispatch ONE jitted DECODE over the whole slot batch (inactive
      slots ride along pointed at the trash page), then read the tokens
-     of the decode dispatched the step BEFORE and record each slot's
-     token, evicting on EOS / max_new_tokens. One decode is always in
-     flight while the host admits, builds and emits: a running slot's
-     input token is the previous decode's output, taken on the device.
+     of the decode dispatched the step BEFORE and the first tokens of
+     this step's prefills, and record each slot's token, evicting on
+     EOS / max_new_tokens. One decode is always in flight while the
+     host admits, builds and emits: a running slot's input token is the
+     previous decode's output, a freshly prefilled slot's its prefill's,
+     both taken on the device.
 
 Compilation contract: decode is one program per (slots, pages) bucket —
 an Engine has exactly one such bucket, so one compile for its lifetime;
@@ -80,7 +83,8 @@ _DECODE_H = _obs.histogram(
     "wall time of one jitted decode over the slot batch", ["engine"])
 _PREFILL_H = _obs.histogram(
     "paddle_tpu_serving_prefill_seconds",
-    "wall time of one jitted prefill (admission)", ["engine"])
+    "wall time of one jitted prefill's dispatch (admission; its first "
+    "token is read behind the step's decode)", ["engine"])
 _LATENCY_H = _obs.histogram(
     "paddle_tpu_serving_request_latency_seconds",
     "submit-to-finish latency per request", ["engine"])
@@ -116,7 +120,8 @@ def _drop_engine_series(eid: str):
         m.remove_matching(engine=eid)
 
 
-# `tokens` entry of a slot whose input is the decode before's output
+# `tokens` entry of a slot whose input the device holds and the host has
+# not read: the decode before's output, or this step's prefill's
 _FROM_DEVICE = -1
 
 
@@ -297,30 +302,34 @@ class Engine:
         # literal argmax path inside sample_tokens, and no sampling
         # value can ever force a recompile — the one-compile-per-bucket
         # contract is pinned with sampling enabled
-        def prefill(params, cache, tokens, true_len, page_row, slot,
+        # a prefill's first token stays on the device: the program writes
+        # it at the request's slot of `feed`, the [S] vector the step's
+        # decode takes as `prev_tokens` (and the host reads after it)
+        def prefill(params, cache, tokens, true_len, page_row, slot, feed,
                     temps, topks, topps, seeds, steps):
             note_compile(f"prefill[{tokens.shape[0]}]")  # trace-time
             cache, logits = model.prefill(params, cache, tokens,
                                           true_len, page_row, slot)
             tok = sample_tokens(logits[None, :], temps, topks, topps,
                                 seeds, steps)
-            return cache, tok[0]
+            return cache, feed.at[slot].set(tok[0])
 
         def prefill_tail(params, cache, tokens, start, true_len,
-                         page_row, temps, topks, topps, seeds, steps):
+                         page_row, slot, feed, temps, topks, topps, seeds,
+                         steps):
             note_compile(f"prefill_tail[{tokens.shape[0]}]")
             cache, logits = model.prefill_tail(params, cache, tokens,
                                                start, true_len,
                                                page_row)
             tok = sample_tokens(logits[None, :], temps, topks, topps,
                                 seeds, steps)
-            return cache, tok[0]
+            return cache, feed.at[slot].set(tok[0])
 
         def decode(params, cache, tokens, prev_tokens, positions, tables,
                    temps, topks, topps, seeds, steps):
             note_compile(f"decode[slots={S},pages={M}]")  # trace-time
-            # a slot still running from the decode before has no token on
-            # the host yet (_FROM_DEVICE): it is that decode's output
+            # a slot whose token the host has not read (_FROM_DEVICE):
+            # it is the decode before's output, or its prefill's
             tokens = jnp.where(tokens == _FROM_DEVICE, prev_tokens, tokens)
             cache, logits = model.decode(params, cache, tokens,
                                          positions, tables)
@@ -517,10 +526,12 @@ class Engine:
             self.prefix_cache.insert(req.prompt[:n * self.page_size],
                                      req.table.pages[:n])
 
-    def _run_prefill(self, req: Request) -> bool:
-        """Prefill `req` into its slot and record its first token, read
-        from the device here; False for a bootstrap admission, which
-        runs no program and reads nothing."""
+    def _run_prefill(self, req: Request, feed):
+        """Dispatch `req`'s prefill into its slot and read nothing: the
+        program writes the first token at the slot's place in `feed`, the
+        [S] device vector the step's decode takes its unread tokens from,
+        and the new vector is returned. None for a bootstrap admission,
+        which runs no program."""
         import jax.numpy as jnp
         if req.prefix_cow is not None:
             self._apply_cow(req)
@@ -536,7 +547,7 @@ class Engine:
                            trace_id=req.trace_id, engine=self.engine_id,
                            request=req.id,
                            cached_tokens=m.tokens)
-            return False
+            return None
         start = m.tokens if m is not None else 0
         tail = req.prompt[start:] if start else req.prompt
         T = _bucket_len(tail.size, self.page_size)
@@ -549,12 +560,14 @@ class Engine:
             bucket = f"prefill_tail[{T}]"
             fn = self._prefill_tail
             targs = (self.model.params, self.cache, jnp.asarray(toks),
-                     np.int32(start), np.int32(tail.size), row, *samp)
+                     np.int32(start), np.int32(tail.size), row,
+                     np.int32(req.slot), feed, *samp)
         else:
             bucket = f"prefill[{T}]"
             fn = self._prefill
             targs = (self.model.params, self.cache, jnp.asarray(toks),
-                     np.int32(tail.size), row, np.int32(req.slot), *samp)
+                     np.int32(tail.size), row, np.int32(req.slot), feed,
+                     *samp)
         # read BEFORE the cost registration: lower() traces the fn and
         # seeds the jit cache, so the note_compile side effect fires
         # there, not on the timed first call
@@ -579,8 +592,7 @@ class Engine:
                            passes=self.model.passes,
                            **past, **scan,
                            **self._attn_form("prefill")) as sp:
-            self.cache, tok = fn(*targs)
-            tok = int(tok)
+            self.cache, feed = fn(*targs)
             compiled = self._compiles.get(bucket, 0) > pre_compiles
             if compiled:
                 sp.attrs["compiled"] = True
@@ -593,12 +605,7 @@ class Engine:
                        engine=self.engine_id, request=req.id,
                        bucket=T, seconds=round(dt, 6))
         self._cache_insert_prompt(req)
-        self._note_tokens(1)
-        if req.temperature > 0:
-            self._m_sampling_tokens.inc()
-        if self.scheduler.record_token(req, tok):
-            self._note_done(req)
-        return True
+        return feed
 
     def _keep_routing(self, req: Request, status: str):
         """`Scheduler.before_release`: the routing of a flagged request,
@@ -615,27 +622,31 @@ class Engine:
 
         The call is one `engine.step` span whose children are its phases
         in order, one after the other with nothing between them:
-        `engine.admit` (deadlines, admission and the admitted requests'
-        prefills), `engine.build` (the numpy batch and its transfers),
+        `engine.admit` (deadlines, admission and the dispatch of the
+        admitted requests' prefills: nothing is read there),
+        `engine.build` (the numpy batch and its transfers),
         `engine.decode` (`engine.dispatch` of this step's decode, then
         `engine.wait` for the tokens of the decode dispatched the step
-        before) and `engine.emit` (metering and one `record_token` a slot
-        of that earlier decode). An engine with nothing queued and
-        nothing running records nothing.
+        before and the first tokens of this step's prefills) and
+        `engine.emit` (metering and one `record_token` a token read). An
+        engine with nothing queued and nothing running records nothing.
 
-        A decode's tokens are read one call later, so the device runs
-        decode k while the host admits, builds and emits: a slot that is
-        still running takes its input token from decode k-1's output on
-        the device, and its position, sampler counter and pages come from
-        what has been dispatched for it. What a caller may assume: a
-        token appears in `generated` (through `Scheduler.record_token`)
-        no later than the call after the one that dispatched it; a
-        request that ends by `max_new_tokens` is left out of the decode
-        after its last; one that ends any other way (EOS, cancel,
-        deadline, error) while a decode holds its slot has that decode's
-        token discarded, so `generated` never holds a token past the end;
-        and `scheduler.idle` is False while a decode is unread, so a
-        loop that steps until idle has every token."""
+        Nothing the device makes is read before the step's decode is
+        dispatched, so the device runs decode k-1, this step's prefills
+        and decode k while the host admits, builds and emits: a slot that
+        is still running takes its input token from decode k-1's output on
+        the device, a freshly prefilled one from its prefill's, and a
+        request's position, sampler counter and pages come from what has
+        been dispatched for it. What a caller may assume: a request's
+        first token is in `generated` (through `Scheduler.record_token`)
+        when the call that admitted it returns, and a decode's token no
+        later than the call after the one that dispatched it; a request
+        that ends by `max_new_tokens` is left out of the decode after its
+        last; one that ends any other way (EOS, cancel, deadline, error)
+        while a decode holds its slot has that decode's token discarded,
+        so `generated` never holds a token past the end; and
+        `scheduler.idle` is False while a token is unread, so a loop that
+        steps until idle has every token."""
         with self._lock:
             if self.scheduler.idle:
                 return False
@@ -650,25 +661,36 @@ class Engine:
         import jax
         import jax.numpy as jnp
         prev = self._inflight
+        # what the device holds for this step's decode, a slot each: the
+        # decode before's tokens, and over them the first token of each
+        # prefill of this step, which `firsts` (slot -> request) names
+        feed = self._no_tokens if prev is None else prev.tokens
+        firsts: dict[int, Request] = {}
         with _tracing.span("engine.admit") as sp:
             for r in self.scheduler.expire_deadlines():
                 self._note_done(r)
             admitted = self.scheduler.admit()
             sp.attrs["admitted"] = st.attrs["admitted"] = len(admitted)
-            drained = False     # a prefill's token read: the device is idle
             for req in admitted:
+                if req.done():      # it went with a cache lost just below
+                    continue
                 try:
-                    drained |= self._run_prefill(req)
+                    fed = self._run_prefill(req, feed)
                 except Exception as e:
                     # a poison request fails ALONE: evict it with its
                     # pages, keep the engine serving everyone else
                     req.error = f"prefill failed: {type(e).__name__}: {e}"
                     self.scheduler.evict(req, "error")
                     self._note_done(req)
-                    self._recover_cache("failed prefill")
-                    # a donating backend lost the cache, and with it
-                    # what was in flight
-                    drained, prev = True, self._inflight
+                    if self._recover_cache("failed prefill"):
+                        # a donating backend lost the cache, and with it
+                        # what was in flight: the decode before and this
+                        # step's earlier prefills
+                        self._tokens_discarded += len(firsts)
+                        prev, feed, firsts = None, self._no_tokens, {}
+                    continue
+                if fed is not None:
+                    feed, firsts[req.slot] = fed, req
             active = [(i, r) for i, r in enumerate(self.scheduler.slots)
                       if r is not None]
         st.attrs["active"] = len(active)
@@ -694,9 +716,11 @@ class Engine:
             window = self.model.window
             ring_live = window_rows = 0
             for i, r in active:
-                # tokens dispatched for r: those read, and the one of the
-                # decode in flight if it holds r's slot
-                unread = prev is not None and prev.reqs.get(i) is r
+                # tokens dispatched for r: those read, and the one the
+                # device holds for its slot (the decode in flight's, or
+                # its prefill's first)
+                unread = (prev is not None and prev.reqs.get(i) is r) \
+                    or firsts.get(i) is r
                 n = len(r.generated) + unread
                 last = int(r.prompt.size) + n - 1   # position of token n
                 if window:  # (a finished request decodes nothing more)
@@ -705,7 +729,7 @@ class Engine:
                                      self.ring_pages)
                     window_rows += 0 if done else min(last + 1, window)
                 if n >= r.max_new_tokens:
-                    # its last token is in flight: nothing more to decode
+                    # its last token is unread: nothing more to decode
                     pages_live += (last - 1) // self.page_size + 1
                     continue
                 batch[i] = r
@@ -733,12 +757,6 @@ class Engine:
                 st.attrs["window_pages_reserved"] = \
                     self.ring_pages * len(active)
                 st.attrs["window_pages_live"] = ring_live
-            # hang injection (chaos drills): PADDLE_PS_FAULT_STALL with
-            # PADDLE_PS_FAULT_STALL_POINT=serving_decode wedges the
-            # step thread here — inside the step lock, exactly like a
-            # hung jitted decode — which is what the stall watchdog
-            # must catch while requests keep queueing
-            _fi.injector().maybe_stall("serving_decode")
             bucket = f"decode[slots={S},pages={self.max_pages_per_req}]"
             # as in _run_prefill: read before lower() runs the trace
             pre_compiles = self._compiles.get(bucket, 0)
@@ -746,17 +764,17 @@ class Engine:
             build.attrs["filled"] = _tracing.TRACER.clock()
             if batch:
                 targs = (self.model.params, self.cache, jnp.asarray(tokens),
-                         self._no_tokens if prev is None else prev.tokens,
-                         jnp.asarray(positions), jnp.asarray(tables),
+                         feed, jnp.asarray(positions), jnp.asarray(tables),
                          jnp.asarray(temps), jnp.asarray(topks),
                          jnp.asarray(topps), jnp.asarray(seeds),
                          jnp.asarray(steps))
                 if bucket not in self._compiles:
                     self._register_perf_cost(bucket, self._decode, targs,
                                              S, self.max_seq_len)
-        # the device runs behind the host unless this step's admission
-        # read a prefill's token, which waits for everything dispatched
-        ahead = bool(batch) and prev is not None and not drained
+        # the device runs behind the host whenever something this engine
+        # dispatched is unread: the decode before, or this step's prefills
+        pending = prev is not None or bool(firsts)
+        ahead = bool(batch) and pending
         next_toks = None
         try:
             t0 = time.perf_counter()
@@ -781,38 +799,53 @@ class Engine:
                     self._inflight = fl
                 with _tracing.span(
                         "engine.wait",
-                        of_step=None if prev is None else prev.step) as wait:
+                        of_step=None if prev is None else prev.step,
+                        first_tokens=len(firsts)) as wait:
                     if prev is not None:
-                        # the device finishing decode k-1, then the copy
-                        # of its tokens: `ready` parts the two
-                        jax.block_until_ready(prev.tokens)
+                        # hang injection (chaos drills): PADDLE_PS_FAULT_STALL
+                        # with PADDLE_PS_FAULT_STALL_POINT=serving_decode
+                        # wedges the step thread here, inside the step
+                        # lock, where a hung decode would hold it (waiting
+                        # for its tokens; the first tokens of the step that
+                        # dispatched it are out), which is what the stall
+                        # watchdog must catch while requests keep queueing
+                        _fi.injector().maybe_stall("serving_decode")
+                    if pending:
+                        # the device finishing decode k-1 and, behind it,
+                        # this step's prefills (decode k is queued behind
+                        # them), then the copy of their tokens, one [S]
+                        # vector: `ready` parts the two
+                        jax.block_until_ready(feed)
                         wait.attrs["ready"] = _tracing.TRACER.clock()
-                        next_toks = np.asarray(prev.tokens)
+                        next_toks = np.asarray(feed)
                 compiled = self._compiles.get(bucket, 0) > pre_compiles
                 if compiled:
                     sp.attrs["compiled"] = True
             dt = time.perf_counter() - t0
             self._m_decode_h.observe(dt)
         except Exception as e:
-            # a decode-step failure poisons the whole slot batch and the
-            # decode dispatched behind it (the cache buffer may be
+            # a decode-step failure poisons the whole slot batch, the
+            # decode dispatched behind it and the prefills in front of it
+            # whose tokens were not read (the cache buffer may be
             # donated/invalid): fail the in-flight requests with their
             # pages freed rather than wedging them
             for _i, r in active:
                 r.error = f"decode failed: {type(e).__name__}: {e}"
                 self.scheduler.evict(r, "error")
                 self._note_done(r)
+            self._tokens_discarded += len(firsts)
             self._discard_inflight()
             self._recover_cache("failed decode")
             raise
         with _tracing.span("engine.emit") as sp:
             if compiled:
                 _perf.note_compile_seconds("engine.decode", dt)
-            elif sample and next_toks is not None:
+            elif sample and prev is not None:
                 # read off this step's spans: host = batch building;
                 # dispatch = the async jit call returning; device = what
-                # was left of the step before's decode once this one was
-                # dispatched; transfer = device->host copy
+                # was left of the step before's decode (and of this
+                # step's prefills) once this one was dispatched;
+                # transfer = device->host copy
                 ready = wait.attrs["ready"]
                 _perf.record_breakdown(self._perf_name, {
                     "host": build.duration(),
@@ -821,10 +854,14 @@ class Engine:
                     "transfer": wait.end - ready,
                 })
             finished = recorded = sampled_n = 0
-            for i, r in (prev.reqs.items() if prev is not None else ()):
+            # decode k-1's tokens, then the first tokens: a slot in both
+            # changed hands, and its old tenant's token goes
+            for i, r in itertools.chain(
+                    prev.reqs.items() if prev is not None else (),
+                    firsts.items()):
                 if self.scheduler.slots[i] is not r:
-                    # it ended (EOS, cancel, deadline, error) while this
-                    # decode held its slot: the token is past its end
+                    # it ended (EOS, cancel, deadline, error) while the
+                    # program held its slot: the token is past its end
                     self._tokens_discarded += 1
                     continue
                 recorded += 1
@@ -857,13 +894,14 @@ class Engine:
                                       for i, r in fl.reqs.items()):
             self._discard_inflight()
 
-    def _recover_cache(self, why: str):
+    def _recover_cache(self, why: str) -> bool:
         """After a failed jitted call on a DONATING backend the cache
         buffers may already be consumed — rebuild every part and fail
-        whatever in-flight state they held (CPU never donates: old cache stays valid,
-        surviving requests keep decoding)."""
+        whatever in-flight state they held; True if it did (CPU never
+        donates: old cache stays valid, surviving requests keep
+        decoding)."""
         if not self._donate:
-            return
+            return False
         for r in list(self.scheduler.active_requests()):
             r.error = f"kv cache lost to a {why} (donated buffer)"
             self.scheduler.evict(r, "error")
@@ -872,6 +910,7 @@ class Engine:
         self.cache = self.model.init_cache(self.num_pages, self.page_size,
                                            self.num_slots)
         self._tally_seen = {}
+        return True
 
     def drain(self) -> "Engine":
         """Graceful removal from a serving fleet: stop admitting new

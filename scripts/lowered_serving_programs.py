@@ -126,9 +126,10 @@ def main(argv=None) -> int:
                 jax.ShapeDtypeStruct((n, 2), jnp.uint32), i32(n))
             for T in buckets:
                 emit(f"engine.{impl}.prefill_{T}", eng._prefill, params,
-                     cache, i32(T), i32(), i32(M), i32(), *samp(1))
+                     cache, i32(T), i32(), i32(M), i32(), i32(S), *samp(1))
                 emit(f"engine.{impl}.prefill_tail_{T}", eng._prefill_tail,
-                     params, cache, i32(T), i32(), i32(), i32(M), *samp(1))
+                     params, cache, i32(T), i32(), i32(), i32(M), i32(),
+                     i32(S), *samp(1))
             emit(f"engine.{impl}.decode", eng._decode, params, cache,
                  i32(S), i32(S), i32(S), i32(S, M), *samp(S))
             del eng

@@ -403,10 +403,12 @@ def test_wedged_engine_detected_with_trace_keyed_timeline(tmp_path,
         """decode blocks until released — a wedged jitted step, the
         serving tier's watchdog target."""
         release = threading.Event()
+        entered = threading.Event()
 
         def decode(self, *a, **k):
             # block OUTSIDE the trace (fixture engines compile eagerly
             # enough); a hung host callback models a wedged device step
+            self.entered.set()
             self.release.wait()
             return super().decode(*a, **k)
 
@@ -419,12 +421,11 @@ def test_wedged_engine_detected_with_trace_keyed_timeline(tmp_path,
         eng.start()
         req = eng.submit([5, 6, 7], max_new_tokens=8)
         assert req.trace_id           # minted even without a wire hop
-        # wait until prefill COMPLETED (first token recorded) — the
-        # engine thread is then wedged inside the decode step
-        deadline = time.monotonic() + 60
-        while not req.generated and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert req.generated, "prefill never completed"
+        # wait until the prefill is DISPATCHED and the step's decode
+        # entered: the engine thread is then wedged inside the decode
+        # step, in front of the read of the prefill's first token
+        assert model.entered.wait(60), "the decode was never dispatched"
+        assert not req.generated
         assert eng.scheduler.active_requests(), "request not running"
 
         # drive the watchdog the way its poll thread would; detection

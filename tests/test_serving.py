@@ -657,9 +657,11 @@ def _engine_programs(family):
             jnp.zeros((S,), jnp.int32),
             jnp.full((S, M), eng.trash_page, jnp.int32), *samp(S)), S),
         "prefill": (eng._prefill, (
-            *head, toks, np.int32(T - 3), row, np.int32(1), *samp(1)), 1),
+            *head, toks, np.int32(T - 3), row, np.int32(1),
+            jnp.zeros((S,), jnp.int32), *samp(1)), 1),
         "prefill_tail": (eng._prefill_tail, (
-            *head, toks, np.int32(ps), np.int32(T - 3), row, *samp(1)), 1),
+            *head, toks, np.int32(ps), np.int32(T - 3), row, np.int32(1),
+            jnp.zeros((S,), jnp.int32), *samp(1)), 1),
     }
     return programs, V
 
@@ -1093,9 +1095,10 @@ def test_served_tokens_equal_a_step_by_step_decode(family):
         assert list(r.generated) == _step_by_step(family, p, n, **kw)
     st = eng.stats()
     assert st["tokens_discarded"] == 0 and st["pool"]["used_pages"] == 0
-    # a decode ran ahead of the host's reads in most steps, and every
-    # token but the prefills' came out of one
-    assert 0 < st["decodes_ahead"] < st["steps"]
+    # every decode was dispatched with something unread in front of it
+    # (the decode before, or since ISSUE 44 its own step's prefills), and
+    # every token but the prefills' came out of one
+    assert 0 < st["decodes_ahead"] == st["steps"]
     assert eng._inflight is None
     assert all(v == 1 for v in st["compiles"].values()), st["compiles"]
 
@@ -1217,7 +1220,7 @@ def test_warm_start_between_two_steps_with_a_decode_in_flight(tmp_path):
 
 
 def test_a_bootstrap_admission_feeds_a_host_token_beside_device_tokens():
-    """Whole prompt cached: no prefill, so nothing drains the device, and
+    """Whole prompt cached: no prefill and no first token to read, and
     the decode takes this slot's token (the prompt's last) from the host
     and its neighbour's from the decode before."""
     from paddle_tpu.observability.tracing import TRACER
@@ -1250,10 +1253,10 @@ def test_a_bootstrap_admission_feeds_a_host_token_beside_device_tokens():
 @pytest.mark.parametrize("family", FAMILIES)
 def test_a_failed_decode_fails_its_steps_requests_and_the_engine_serves_on(
         family, at):
-    """The first decode of a batch (nothing in flight) or a later one (its
-    predecessor unread): the step's requests end in error with their pages
-    freed, the unread tokens are discarded, and the next request is
-    served as if nothing had happened."""
+    """The first decode of a batch (its step's prefills unread) or a later
+    one (its predecessor unread): the step's requests end in error with
+    their pages freed, the unread tokens are discarded, and the next
+    request is served as if nothing had happened."""
     (pa, _, kwa), (pb, _, kwb), (pc, _, kwc) = _jobs(family, 3, seed=11)
     eng = _ahead_engine(family)
     real, calls = eng._decode, []
@@ -1271,9 +1274,12 @@ def test_a_failed_decode_fails_its_steps_requests_and_the_engine_serves_on(
             eng.step()
     for r in (a, b):
         assert r.status == "error" and "decode failed" in r.error
-        assert len(r.generated) == max(1, at - 1)
+        # what was read: nothing of the step that admitted them (their
+        # first tokens went with the decode dispatched behind them)
+        assert len(r.generated) == at - 1
     assert eng._inflight is None and eng.pool.used_pages == 0
-    assert eng.stats()["tokens_discarded"] == (2 if at > 1 else 0)
+    # the two first tokens, or the two of the decode before
+    assert eng.stats()["tokens_discarded"] == 2
     c = eng.submit(pc, 9, **kwc)
     eng.run_until_idle()
     assert list(c.generated) == _step_by_step(family, pc, 9, **kwc)
@@ -1282,11 +1288,12 @@ def test_a_failed_decode_fails_its_steps_requests_and_the_engine_serves_on(
 @pytest.mark.parametrize("family", FAMILIES)
 def test_every_decode_is_dispatched_before_the_one_before_is_read(family):
     """The structural guard of ISSUE 31, beside PR 25 / 27 / 29's: in a run
-    of N decode steps that admit nothing, every `engine.dispatch` but the
-    first starts before the `engine.wait` that reads the step before ends,
-    that wait names the step before, and all but the first decode count as
-    ahead. An edit that reads before it dispatches fails here, not only in
-    a cell."""
+    of N decode steps of which only the first admits, every
+    `engine.dispatch` but the first starts before the `engine.wait` that
+    reads the step before ends, that wait names the step before, and every
+    decode counts as ahead: the first follows its step's unread prefills
+    (ISSUE 44, whose own guard is below). An edit that reads before it
+    dispatches fails here, not only in a cell."""
     from paddle_tpu.observability.tracing import TRACER
     N = 9
     eng = _ahead_engine(family)
@@ -1315,9 +1322,426 @@ def test_every_decode_is_dispatched_before_the_one_before_is_read(family):
     for (_n, dec, dispatch, wait), (n0, *_rest) in zip(rows[1:], rows):
         assert wait.attrs["of_step"] == n0 == _n - 1
         assert dispatch.start <= dispatch.end <= wait.start <= wait.end
-    # the first decode follows a prefill's read, the last step has none
-    assert [d.attrs["ahead"] for _n, d, *_ in rows] == \
-        [False] + [True] * (N - 1) + [False]
+    # the first decode follows two prefills nobody has read, the last
+    # step has none to dispatch
+    assert [d.attrs["ahead"] for _n, d, *_ in rows] == [True] * N + [False]
     assert [d.attrs["active"] for _n, d, *_ in rows] == [2] * N + [0]
+    assert [w.attrs["first_tokens"] for _n, _d, _dp, w in rows] == \
+        [2] + [0] * N
     st = eng.stats()
-    assert st["steps"] == N and st["decodes_ahead"] == N - 1
+    assert st["steps"] == N and st["decodes_ahead"] == N
+
+
+# ---------------------------------------------------------------------------
+# a prefill's first token behind the step's decode (ISSUE 44): `engine.admit`
+# dispatches the prefills and reads nothing, the decode takes a fresh slot's
+# token on the device, `engine.wait` reads it after the tokens of decode k-1
+# ---------------------------------------------------------------------------
+
+def _phase(spans, step, name):
+    """The span called `name` of the `engine.step` span `step`: a phase of
+    it, or a child of its `engine.decode`."""
+    kids = [s for s in spans if s.parent_id == step.span_id]
+    dec = next((k for k in kids if k.name == "engine.decode"), None)
+    kids += [s for s in spans if dec and s.parent_id == dec.span_id]
+    return next((k for k in kids if k.name == name), None)
+
+
+def _last_step(spans):
+    return [s for s in spans if s.name == "engine.step"][-1]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_requests_admitted_beside_running_ones_decode_as_if_alone(family):
+    """Greedy and sampled requests that arrive while others decode, one or
+    two a step, into slots that others have left: every admission's first
+    token reaches the decode on the device beside the running slots', and
+    every request's tokens are the step-by-step loop's."""
+    eng = _ahead_engine(family)
+    jobs = _jobs(family, 9, seed=21, max_new=(2, 10))
+    reqs, later = [], list(jobs)
+    for k in range(400):
+        # two at once, then one every other step
+        for _ in range(2 if k == 0 else (k % 2 if later else 0)):
+            if later:
+                p, n, kw = later.pop(0)
+                reqs.append(eng.submit(p, n, **kw))
+        eng.step()
+        if not later and eng.scheduler.idle:
+            break
+    assert eng.scheduler.idle and len(reqs) == len(jobs)
+    for (p, n, kw), r in zip(jobs, reqs):
+        assert r.status == "done"
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+    st = eng.stats()
+    assert st["tokens_discarded"] == 0 and st["pool"]["used_pages"] == 0
+    assert st["decodes_ahead"] == st["steps"] and eng._inflight is None
+    assert all(v == 1 for v in st["compiles"].values()), st["compiles"]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_several_admissions_in_one_step_are_read_in_one_wait(family):
+    """Three prefills dispatched one after the other in one `engine.admit`,
+    their first tokens read together behind the decode that already
+    holds all three slots, and recorded in the call that admitted them."""
+    from paddle_tpu.observability.tracing import TRACER
+    eng = _ahead_engine(family)
+    jobs = _jobs(family, 3, seed=22, max_new=(4, 9))
+    TRACER.clear()
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
+    eng.step()
+    spans = TRACER.spans()
+    step = _last_step(spans)
+    assert step.attrs["admitted"] == 3
+    assert len([s for s in spans if s.name == "engine.prefill"]) == 3
+    assert _phase(spans, step, "engine.wait").attrs["first_tokens"] == 3
+    dec = _phase(spans, step, "engine.decode")
+    assert dec.attrs["active"] == 3 and dec.attrs["ahead"] is True
+    assert all(len(r.generated) == 1 and r.first_token_at is not None
+               and _held(eng, r) for r in reqs)
+    eng.run_until_idle()
+    for (p, n, kw), r in zip(jobs, reqs):
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+    assert eng.stats()["tokens_discarded"] == 0
+
+
+@pytest.mark.parametrize("beside", [False, True])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_request_of_one_token_never_enters_a_decode(family, beside):
+    """`max_new_tokens == 1`: its only token is its prefill's, read in the
+    step that admitted it; no decode is dispatched for it, so nothing of
+    it is discarded, and its slot and pages are free when the call ends."""
+    from paddle_tpu.observability.tracing import TRACER
+    (p, _n, kw), (pb, _nb, kwb) = _jobs(family, 2, seed=23)
+    eng = _ahead_engine(family)
+    other = eng.submit(pb, 9, **kwb) if beside else None
+    for _ in range(2 if beside else 0):
+        eng.step()
+    TRACER.clear()
+    req = eng.submit(p, 1, **kw)
+    eng.step()
+    spans = TRACER.spans()
+    step = _last_step(spans)
+    assert req.status == "done" and req.table is None
+    assert list(req.generated) == _step_by_step(family, p, 1, **kw)
+    assert _phase(spans, step, "engine.wait").attrs["first_tokens"] == 1
+    assert _phase(spans, step, "engine.decode").attrs["active"] == beside
+    assert not _held(eng, req)
+    assert eng.pool.used_pages == (len(other.table.pages) if beside else 0)
+    eng.run_until_idle()
+    if beside:
+        assert list(other.generated) == _step_by_step(family, pb, 9, **kwb)
+    assert eng.stats()["tokens_discarded"] == 0
+    assert eng.stats()["steps"] == (8 if beside else 0)
+
+
+@pytest.mark.parametrize("beside", [False, True])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_first_token_that_is_the_eos_discards_the_decode_behind_it(
+        family, beside):
+    """The first token ends the request while decode k, dispatched before
+    the token was read, holds its slot: that decode's token is discarded
+    by PR 31's rule, the pages go back once, and the slot's next tenant
+    decodes as if alone."""
+    (p, _n, kw), (pb, _nb, kwb), (pc, _nc, kwc) = _jobs(family, 3, seed=24)
+    first = _step_by_step(family, p, 1, **kw)[0]
+    eng = _ahead_engine(family)
+    other = eng.submit(pb, 12, **kwb) if beside else None
+    for _ in range(3 if beside else 0):
+        eng.step()
+    req = eng.submit(p, 12, eos_id=first, **kw)
+    eng.step()
+    slot = req.slot
+    assert req.status == "done" and list(req.generated) == [first]
+    assert req.table is None
+    if beside:
+        # the decode behind the prefill still holds the slot for it
+        assert eng._inflight.reqs[slot] is req
+        assert eng.pool.used_pages == len(other.table.pages)
+        assert eng.stats()["tokens_discarded"] == 0
+    else:
+        # nobody else in that decode: forgotten with its only request
+        assert eng.scheduler.idle and eng._inflight is None
+    c = eng.submit(pc, 7, **kwc)
+    eng.step()
+    assert c.slot == slot and eng.stats()["tokens_discarded"] == 1
+    eng.run_until_idle()
+    assert list(c.generated) == _step_by_step(family, pc, 7, **kwc)
+    if beside:
+        assert list(other.generated) == _step_by_step(family, pb, 12, **kwb)
+    assert eng.stats()["tokens_discarded"] == 1 and eng.pool.used_pages == 0
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_request_that_ends_between_its_prefills_dispatch_and_its_read(
+        family, how):
+    """Cancelled while its prefill and the decode behind it are on the
+    device: neither token is its own any more (two discarded), nothing is
+    in `generated`. A deadline that passes there is seen by the next
+    call's `expire_deadlines`: the first token was read and stands, the
+    decode's is discarded. Either way the pages go back once, the
+    neighbour is not disturbed, and the slot's next tenant decodes as if
+    alone."""
+    (pa, _, kwa), (pb, _, kwb), (pc, _, kwc) = _jobs(family, 3, seed=25)
+    eng = _ahead_engine(family)
+    b = eng.submit(pb, 12, **kwb)
+    for _ in range(3):
+        eng.step()
+    a = eng.submit(pa, 12, **kwa)
+    real = eng._decode
+
+    def decode(*args):
+        # the step holds the engine's lock, as `Scheduler.cancel` asks
+        if how == "cancel":
+            assert eng.scheduler.cancel(a)
+        else:
+            a.deadline = -1.0
+        eng._decode = real
+        return real(*args)
+    eng._decode = decode
+    eng.step()
+    slot = a.slot
+    assert eng._decode is real and eng._inflight.reqs[slot] is a
+    if how == "cancel":
+        assert a.status == "cancelled" and a.generated == []
+        assert eng.stats()["tokens_discarded"] == 1     # the first token
+    else:
+        assert a.status == "running" and len(a.generated) == 1
+    c = eng.submit(pc, 8, **kwc)
+    eng.step()                  # expires `a`; its decode's token goes
+    assert a.done() and a.table is None and c.slot == slot
+    assert eng.stats()["tokens_discarded"] == (2 if how == "cancel" else 1)
+    eng.run_until_idle()
+    assert a.status == {"cancel": "cancelled", "deadline": "deadline"}[how]
+    assert list(a.generated) == _step_by_step(
+        family, pa, 12, **kwa)[:how == "deadline"]
+    assert list(b.generated) == _step_by_step(family, pb, 12, **kwb)
+    assert list(c.generated) == _step_by_step(family, pc, 8, **kwc)
+    assert eng.stats()["tokens_discarded"] == (2 if how == "cancel" else 1)
+    assert eng.pool.used_pages == 0
+
+
+@pytest.mark.parametrize("donating", [False, True])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_prefill_that_fails_at_dispatch_fails_alone(family, donating):
+    """Raised where the program is dispatched (trace, compile, shapes):
+    that request ends in error with its pages freed and the step goes on;
+    where nothing is donated the running request, the prefill dispatched
+    before it in the same step and the one after it are untouched. On a
+    backend that donates the cache is lost with everything on it: those
+    end `kv cache lost`, what was unread is discarded, the next request is
+    served."""
+    (pa, _, kwa), (pb, _, kwb), (pc, _, kwc), (pd, _, kwd) = _jobs(
+        family, 4, seed=26)
+    eng = _ahead_engine(family, num_slots=4)
+    eng._donate = donating      # the CPU never donates: force recovery
+    a = eng.submit(pa, 12, **kwa)
+    for _ in range(3):
+        eng.step()
+    real, calls = eng._prefill, []
+
+    def prefill(*args):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("poison prompt")
+        return real(*args)
+    eng._prefill = prefill
+    b = eng.submit(pb, 9, **kwb)
+    bad = eng.submit(pc, 9, **kwc)
+    d = eng.submit(pd, 9, **kwd)
+    eng.step()
+    eng._prefill = real
+    assert bad.status == "error" and "prefill failed" in bad.error
+    assert "poison" in bad.error and bad.table is None
+    if donating:
+        for r in (a, b, d):
+            assert r.status == "error" and "kv cache lost" in r.error
+        assert len(calls) == 2 and b.generated == [] == d.generated
+        # decode k-1's token for `a`, and `b`'s unread first token
+        assert eng.stats()["tokens_discarded"] == 2
+        assert eng._inflight is None and eng.pool.used_pages == 0
+        d = eng.submit(pd, 9, **kwd)
+    else:
+        assert len(calls) == 3
+        assert [len(r.generated) for r in (b, d)] == [1, 1]
+    eng.run_until_idle()
+    assert list(d.generated) == _step_by_step(family, pd, 9, **kwd)
+    if not donating:
+        assert list(a.generated) == _step_by_step(family, pa, 12, **kwa)
+        assert list(b.generated) == _step_by_step(family, pb, 9, **kwb)
+        assert eng.stats()["tokens_discarded"] == 0
+    assert eng.pool.used_pages == 0
+
+
+def test_a_tail_prefill_and_a_bootstrap_admitted_beside_device_tokens():
+    """One step admits a prompt that resumes behind a cached prefix
+    (`prefill_tail`: its first token stays on the device) and one whose
+    whole prompt is cached (no program: the host feeds the prompt's last
+    token), beside a slot that takes the decode before's token."""
+    from paddle_tpu.observability.tracing import TRACER
+    (pa, _, kwa), (pb, _, kwb), (pc, _, kwc) = _jobs("gpt", 3, seed=27)
+    pb = np.resize(pb, 8)                   # two whole pages
+    pc = np.concatenate([pb[:4], np.resize(pc, 7)])    # one of them shared
+    eng = _ahead_engine("gpt", prefix_cache_pages=16)
+    warm = eng.submit(pb, 3, **kwb)
+    eng.run_until_idle()
+    a = eng.submit(pa, 12, **kwa)
+    for _ in range(3):
+        eng.step()
+    assert _held(eng, a)
+    TRACER.clear()
+    b = eng.submit(pb, 6, **kwb)
+    c = eng.submit(pc, 6, **kwc)
+    eng.step()
+    spans = TRACER.spans()
+    step = _last_step(spans)
+    assert b.prefix_match.full and not c.prefix_match.full
+    [tail] = [s for s in spans if s.name == "engine.prefill"]
+    assert tail.attrs["cached_tokens"] == 4 and tail.attrs["slot"] == c.slot
+    assert step.attrs["admitted"] == 2
+    assert _phase(spans, step, "engine.wait").attrs["first_tokens"] == 1
+    dec = _phase(spans, step, "engine.decode")
+    assert dec.attrs["active"] == 3 and dec.attrs["ahead"] is True
+    assert len(c.generated) == 1 and b.generated == []
+    eng.run_until_idle()
+    assert list(a.generated) == _step_by_step("gpt", pa, 12, **kwa)
+    assert list(b.generated) == _step_by_step("gpt", pb, 6, **kwb)
+    assert list(c.generated) == _step_by_step("gpt", pc, 6, **kwc)
+    assert list(b.generated)[:3] == list(warm.generated)
+    assert any(k.startswith("prefill_tail[")
+               for k in eng.stats()["compiles"])
+    assert eng.stats()["tokens_discarded"] == 0
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_defrag_behind_a_step_that_admitted(family):
+    """`defrag` right after the call that admitted: the decode in flight
+    took the fresh slot's token on the device and wrote its K/V through
+    the old page numbers; the move is ordered behind it."""
+    jobs = _jobs(family, 3, seed=28, max_new=(9, 12))
+    eng = _ahead_engine(family)
+    first = eng.submit(jobs[0][0], 2, **jobs[0][2])
+    a = eng.submit(jobs[1][0], jobs[1][1], **jobs[1][2])
+    for _ in range(3):
+        eng.step()
+    assert first.done()
+    b = eng.submit(jobs[2][0], jobs[2][1], **jobs[2][2])
+    eng.step()
+    assert len(b.generated) == 1 and _held(eng, a) and _held(eng, b)
+    assert eng.defrag()                     # the first request left a hole
+    live = sorted(pg for r in (a, b) for pg in r.table.pages)
+    assert live == list(range(len(live)))
+    eng.run_until_idle()
+    for (p, n, kw), r in zip(jobs[1:], (a, b)):
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+
+
+def test_warm_start_behind_a_step_that_admitted(tmp_path):
+    """The flip lands behind the admitting call: the first token and the
+    decode dispatched behind it are the old weights', the rest the new."""
+    _greedy, (p, _n, kw) = _jobs("gpt", 2, seed=9)     # the sampled one
+    GPTDecodeModel(GPTConfig.tiny(num_layers=2), seed=1).save_checkpoint(
+        str(tmp_path), step=3)
+    eng = Engine(GPTDecodeModel(GPTConfig.tiny(num_layers=2), seed=0),
+                 **AHEAD_KW)
+    req = eng.submit(p, 12, **kw)
+    eng.step()
+    assert len(req.generated) == 1 and _held(eng, req)
+    eng.warm_start(str(tmp_path), version=7)
+    eng.run_until_idle()
+    old = _step_by_step("gpt", p, 12, **kw)
+    assert req.status == "done" and len(req.generated) == 12
+    assert list(req.generated)[:2] == old[:2]
+    assert list(req.generated) != old       # the new weights took over
+
+
+class _Unread:
+    """Stands in for a device value the engine has dispatched and not
+    read: notes the tracer's clock whenever the host waits for it or
+    copies it."""
+
+    def __init__(self, value, reads):
+        self.value, self.reads = value, reads
+
+    def _read(self):
+        from paddle_tpu.observability.tracing import TRACER
+        self.reads.append(TRACER.clock())
+        return self.value
+
+    def block_until_ready(self):
+        self._read().block_until_ready()
+        return self
+
+    def __array__(self, *a, **kw):
+        return np.asarray(self._read(), *a, **kw)
+
+    def __int__(self):
+        return int(self._read())
+    __index__ = __int__
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_prefill_token_is_read_before_the_steps_decode_is_dispatched(
+        family):
+    """The structural guard of ISSUE 44, beside PR 31's: in a run that
+    admits on several steps, beside running requests and alone, every
+    `engine.prefill` span ends before its step's `engine.dispatch`
+    starts, the step's `engine.wait` says it read as many first tokens as
+    the step prefilled, its decode counts as ahead, and the host neither
+    waits for nor copies anything a prefill or a decode returned between
+    the start of `engine.admit` and the end of `engine.dispatch`. An edit
+    that reads a first token inside the admission fails here, not only in
+    a cell."""
+    from paddle_tpu.observability.tracing import TRACER
+    eng = _ahead_engine(family)
+    reads = []
+    unwrap = lambda a: a.value if isinstance(a, _Unread) else a  # noqa: E731
+
+    def spied(fn):
+        def call(*args):
+            cache, out = fn(*map(unwrap, args))
+            return cache, _Unread(out, reads)
+        return call
+    eng._prefill, eng._decode = spied(eng._prefill), spied(eng._decode)
+    jobs = _jobs(family, 7, seed=29, max_new=(3, 9))
+    TRACER.clear()
+    reqs, later = [], list(jobs)
+    for k in range(200):
+        for _ in range(2 if k in (0, 5) else k % 3 == 0):
+            if later:
+                p, n, kw = later.pop(0)
+                reqs.append(eng.submit(p, n, **kw))
+        eng.step()
+        if not later and eng.scheduler.idle:
+            break
+    assert eng.scheduler.idle
+    spans = TRACER.spans()
+    steps = [s for s in spans if s.name == "engine.step"]
+    prefills = [s for s in spans if s.name == "engine.prefill"]
+    assert len(prefills) == len(jobs)
+    admitting = 0
+    for st in steps:
+        admit = _phase(spans, st, "engine.admit")
+        dispatch = _phase(spans, st, "engine.dispatch")
+        wait = _phase(spans, st, "engine.wait")
+        dec = _phase(spans, st, "engine.decode")
+        mine = [p for p in prefills if p.caused_by == admit.span_id]
+        assert st.attrs["admitted"] == len(mine)    # no bootstrap here
+        assert wait.attrs["first_tokens"] == len(mine)
+        for p in mine:
+            assert admit.start <= p.start <= p.end <= admit.end \
+                <= dispatch.start
+        if mine:
+            admitting += 1
+            assert dec.attrs["ahead"] is (dec.attrs["active"] > 0)
+        # nothing the device made is read in front of the dispatch
+        early = [t for t in reads if admit.start <= t <= dispatch.end]
+        assert not early, (st.attrs["step"], early)
+        assert all(wait.start <= t <= wait.end for t in reads
+                   if st.start <= t <= st.end)
+    assert admitting >= 4 and len(reads) >= 2 * len(steps) - 2
+    for (p, n, kw), r in zip(jobs, reqs):
+        assert list(r.generated) == _step_by_step(family, p, n, **kw)
+    st = eng.stats()
+    assert st["decodes_ahead"] == st["steps"] and st["tokens_discarded"] == 0

@@ -24,15 +24,16 @@ LOG = []
 
 
 class Spy(HybridDecodeModel):
-    """Hands every program's logits to the host, in order; a decode's with
-    the positions it fed, since its tokens are read a `step()` later, when
-    the next decode's logits are already the newest."""
+    """Hands every program's logits to the host, in order: a prefill's
+    with its slot, a decode's with the positions it fed, since a token is
+    read when later programs' logits are already the newest (a decode's a
+    `step()` later, a prefill's behind its step's decode)."""
 
     def prefill(self, params, cache, tokens, true_len, page_row, slot):
         cache, lg = super().prefill(params, cache, tokens, true_len,
                                     page_row, slot)
         jax.debug.callback(
-            lambda x: LOG.append((None, np.asarray(x)[None])), lg,
+            lambda s, x: LOG.append((int(s), np.asarray(x)[None])), slot, lg,
             ordered=True)
         return cache, lg
 
@@ -46,13 +47,14 @@ class Spy(HybridDecodeModel):
 
 def logits_behind(log, req):
     """The logits row the token about to be recorded for `req` was drawn
-    from: its prefill's, the newest program, or those of the newest decode
+    from: those of the newest prefill into its slot, or of the newest decode
     that fed its slot the position before the token's."""
     pos = int(req.prompt.size) + len(req.generated) - 1
     if not req.generated:
-        return pos, log[-1][1][0]
+        return pos, next(lg[0] for fed, lg in reversed(log)
+                         if isinstance(fed, int) and fed == req.slot)
     return pos, next(lg[req.slot] for fed, lg in reversed(log)
-                     if fed is not None and fed[req.slot] == pos)
+                     if not isinstance(fed, int) and fed[req.slot] == pos)
 
 
 @pytest.fixture(scope="module")

@@ -45,8 +45,8 @@ def _serve(model, lengths=LENGTHS, new=7, seed=3, **engine_kw):
         def prefill(self, params, cache, *a):
             cache, lg = super().prefill(params, cache, *a)
             jax.debug.callback(
-                lambda x: log.append((None, np.asarray(x)[None])), lg,
-                ordered=True)
+                lambda s, x: log.append((int(s), np.asarray(x)[None])),
+                a[-1], lg, ordered=True)
             return cache, lg
 
         def decode(self, params, cache, tokens, positions, tables):

@@ -90,8 +90,12 @@ def test_step_attributes_say_what_the_step_held(engine):
 
 
 def test_a_step_with_no_active_slot_is_idle_with_admit_alone(engine):
-    # the one token of this request is the prefill's: nothing to decode
-    _reqs, spans = _run(engine, (_prompt(5), 1))
+    # the request's deadline has passed by the first step: it never runs
+    TRACER.clear()
+    req = engine.submit(_prompt(5), max_new_tokens=3, deadline=-1.0)
+    engine.run_until_idle()
+    spans = TRACER.spans()
+    assert req.status == "deadline"
     steps = [s for s in spans if s.name == "engine.step"]
     assert len(steps) == 1 and steps[0].attrs["idle"] is True
     assert [k.name for k in _children(spans, steps[0])] == ["engine.admit"]
@@ -99,6 +103,20 @@ def test_a_step_with_no_active_slot_is_idle_with_admit_alone(engine):
     TRACER.clear()
     assert engine.step() is False
     assert TRACER.spans() == []
+
+
+def test_a_request_of_one_token_is_read_in_the_step_that_admitted_it(engine):
+    # the one token of this request is the prefill's: nothing to decode,
+    # but the token is read behind the dispatch like any other (ISSUE 44)
+    reqs, spans = _run(engine, (_prompt(5), 1))
+    steps = [s for s in spans if s.name == "engine.step"]
+    assert len(steps) == 1 and "idle" not in steps[0].attrs
+    kids = _children(spans, steps[0])
+    assert [k.name for k in kids] == PHASES
+    dispatch, wait = _children(spans, kids[2])
+    assert kids[2].attrs["active"] == 0 and kids[2].attrs["ahead"] is False
+    assert wait.attrs["of_step"] is None and wait.attrs["first_tokens"] == 1
+    assert kids[3].attrs["finished"] == 1 and reqs[0].status == "done"
 
 
 def test_prefill_names_the_admit_that_ran_it(engine):
@@ -146,22 +164,35 @@ def test_the_stamps_lie_inside_their_spans(engine):
     for s, stamp in _stamped(spans):
         assert s.start <= stamp <= s.end
         by_name.setdefault(s.name, []).append(s)
-    # every build, and every wait that had tokens to read
+    # every build, and every wait that had tokens to read: a decode's, or
+    # (the first step's) the admitted requests' first
     assert by_name["engine.build"] == [s for s in spans
                                        if s.name == "engine.build"]
     assert by_name["engine.wait"] == [
         s for s in spans
-        if s.name == "engine.wait" and s.attrs["of_step"] is not None]
-    assert len(by_name["engine.wait"]) >= 5
+        if s.name == "engine.wait" and (s.attrs["of_step"] is not None
+                                        or s.attrs["first_tokens"])]
+    assert len(by_name["engine.wait"]) >= 6
 
 
-def test_a_wait_with_no_decode_to_read_has_no_ready(engine):
+def test_a_wait_says_what_it_read_and_is_ready_only_if_it_read(engine):
     _reqs, spans = _run(engine, (_prompt(5), 4))
     waits = [s for s in spans if s.name == "engine.wait"]
-    # the first step dispatches decode 1 and has none before it to read
+    # the first step dispatches decode 1 and has none before it to read,
+    # but its own prefill's first token (ISSUE 44)
     assert waits[0].attrs["of_step"] is None
-    assert "ready" not in waits[0].attrs
-    assert all("ready" in w.attrs for w in waits[1:]) and len(waits) > 1
+    assert [w.attrs["first_tokens"] for w in waits] == [1, 0, 0, 0]
+    assert all("ready" in w.attrs for w in waits) and len(waits) > 1
+    # a whole prompt in the prefix cache: no program, nothing unread
+    model = GPTDecodeModel(GPTConfig.tiny(num_layers=1), seed=0)
+    eng = Engine(model, num_slots=2, num_pages=16, page_size=8,
+                 max_seq_len=32, prefix_cache_pages=8)
+    _run(eng, (np.arange(1, 17), 2))
+    _reqs, spans = _run(eng, (np.arange(1, 17), 2))
+    first = next(s for s in spans if s.name == "engine.wait")
+    assert first.attrs["of_step"] is None and not first.attrs["first_tokens"]
+    assert "ready" not in first.attrs
+    assert not [s for s in spans if s.name == "engine.prefill"]
 
 
 def test_the_stamps_are_the_tracers_clock(engine, monkeypatch):
@@ -178,8 +209,8 @@ def test_the_stamps_are_the_tracers_clock(engine, monkeypatch):
 def test_a_prefill_span_says_what_was_asked_and_what_ran(engine):
     """What `prefill_padding_share` reads: the positions a prefill was
     asked for and the bucket its program ran, on the span alone (no
-    counter beside it, and no fence or stamp of the prefill's own: its
-    token is read where it always was)."""
+    counter beside it, and no fence or stamp of the prefill's own: the
+    span is the dispatch, its token is read in `engine.wait`)."""
     _reqs, spans = _run(engine, (_prompt(5), 3), (_prompt(12, 1), 3),
                         (_prompt(9, 2), 2))
     prefills = [s for s in spans if s.name == "engine.prefill"]
@@ -208,7 +239,7 @@ def test_the_sampled_breakdown_is_read_off_the_spans(engine, monkeypatch,
         perf.set_every(every)
     bd = perf.breakdowns()[name]
     # every step that read a decode's tokens was sampled: of the five
-    # tokens the prefill made one
+    # tokens the prefill made one, read in the first step's wait
     assert bd["samples"] - seen == 4
     assert set(bd["phases"]) == {"host", "dispatch", "device", "transfer"}
     assert all(v >= 0.0 for v in bd["phases"].values())
