@@ -19,9 +19,10 @@ through the entry points a user calls, at the full width of the models
            second pass; one greedy request alone, in the mix and over the wire
   prefix   a second engine with the prefix cache on: a shared 256-token prefix,
            short tails, a whole-prompt hit (prefill_tail and copy-on-write)
-  train    HybridParallelTrainStep, gpt_350m as bench.py::bench_gpt builds it,
-           batch 8 x 1024, four steps on one batch: finite, falling loss, and
-           the attention asked for is the attention that ran
+  train    HybridParallelTrainStep, gpt_350m at the widths of
+           benchmark/configs/gpt_350m_train.json, batch 8 x 1024, four steps
+           on one batch: finite, falling loss, and the attention asked for is
+           the attention that ran
   kernels  the gate's decisions (key, winner, ms and error per candidate) and
            every pallas_call built, none of them in interpret mode on the chip
 
@@ -92,7 +93,7 @@ def real_sizes() -> Sizes:
     from paddle_tpu.models.gpt import GPTConfig
     return Sizes(
         serve_cfg=GPTConfig.gpt3_1p3b(amp_dtype="bfloat16"),
-        # bench.py::bench_gpt's gpt_350m
+        # the widths of benchmark/configs/gpt_350m_train.json
         train_cfg=GPTConfig(hidden_size=1024, num_layers=24, num_heads=16,
                             max_position_embeddings=1024,
                             amp_dtype="bfloat16", attn_impl="flash"),

@@ -19,9 +19,8 @@ import re
 from ..core import FileContext, KeyCounter, Rule, register
 
 __all__ = ["WirePickleRule", "MetricNamesRule", "EnvKnobsRule",
-           "BenchSchemaRule", "REQUIRED_METRICS", "wire_hits",
-           "metric_regs", "knobs_in_tree", "wire_main", "metric_main",
-           "env_main", "bench_schema_main", "bench_result_paths"]
+           "REQUIRED_METRICS", "wire_hits", "metric_regs",
+           "knobs_in_tree", "wire_main", "metric_main", "env_main"]
 
 
 # ---------------------------------------------------------------------------
@@ -225,7 +224,7 @@ REQUIRED_METRICS = {
     # multiplexed RPC transport (docs/PS_WIRE_PROTOCOL.md mux framing):
     # in-flight depth, pool size, zero-copy proof (bytes-copied by
     # path) and reply reordering are the transport's acceptance
-    # contract — the transport bench asserts against these exact names
+    # contract — tests/test_rpc_mux.py asserts against these exact names
     "paddle_tpu_rpc_mux_inflight",
     "paddle_tpu_rpc_mux_channels",
     "paddle_tpu_rpc_mux_bytes_copied_total",
@@ -233,8 +232,8 @@ REQUIRED_METRICS = {
     # online-learning publish pipeline (docs/ONLINE_LEARNING.md):
     # publication/rollback counts, cross-version chunk dedup, hot-swap
     # phase timing and subscriber staleness are the loop's acceptance
-    # contract — the swap-under-load drill and the online bench assert
-    # against these exact names
+    # contract — tests/test_publish.py asserts against these exact
+    # names
     "paddle_tpu_publish_publications_total",
     "paddle_tpu_publish_rollbacks_total",
     "paddle_tpu_publish_dedup_ratio",
@@ -251,7 +250,7 @@ REQUIRED_METRICS = {
     # perf observability plane (docs/OBSERVABILITY.md perf plane): the
     # cost registry, live MFU/breakdown attribution, compile wall-time
     # and memory headroom gauges are the plane's acceptance contract —
-    # the perfwatch sentinel and the `top` perf pane read these exact
+    # the collector's summary and the `top` perf pane read these exact
     # names
     "paddle_tpu_perf_flops",
     "paddle_tpu_perf_bytes",
@@ -274,7 +273,7 @@ REQUIRED_METRICS = {
     # PS high availability (docs/PS_HA.md): role/epoch/fencing state,
     # per-standby replication lag, semi-sync degradation and the
     # promotion/handoff/resync counts are the HA plane's acceptance
-    # contract — the failover drills and the ps_ha bench read these
+    # contract — the failover drills (tests/test_ps_ha.py) read these
     # exact names
     "paddle_tpu_ps_ha_role",
     "paddle_tpu_ps_ha_epoch",
@@ -291,8 +290,8 @@ REQUIRED_METRICS = {
     # tiered embedding store (docs/PS_TIERED.md): per-tier hit/miss
     # and residency, demand-page faults, demotions, cold-read errors
     # and the by-tier pull latency histogram are the tier hierarchy's
-    # acceptance contract — the tiered bench and the collector/top
-    # tier pane read these exact names
+    # acceptance contract — tests/test_tiered_store.py and the
+    # collector/top tier pane read these exact names
     "paddle_tpu_ps_tier_hits_total",
     "paddle_tpu_ps_tier_misses_total",
     "paddle_tpu_ps_tier_resident_rows",
@@ -304,8 +303,8 @@ REQUIRED_METRICS = {
     # fleet time-series plane (docs/OBSERVABILITY.md): TSDB
     # durability/retention accounting, alert lifecycle counts and the
     # per-tenant usage series are the plane's acceptance contract —
-    # the burn-rate chaos drill, `top history/alerts/tenants` and the
-    # tsdb bench read these exact names
+    # the burn-rate chaos drill (tests/test_timeseries.py) and `top
+    # history/alerts/tenants` read these exact names
     "paddle_tpu_tsdb_samples_total",
     "paddle_tpu_tsdb_series",
     "paddle_tpu_tsdb_bytes_on_disk",
@@ -328,8 +327,8 @@ REQUIRED_METRICS = {
     # shared-prefix KV reuse + replayable sampling (docs/SERVING.md):
     # cache effectiveness (hit/miss/tokens-saved), the COW and
     # eviction safety valves, residency gauges, and how much traffic
-    # rides stochastic decode — the prefix bench and the `top` prefix
-    # row read these exact names
+    # rides stochastic decode — the collector's summary and the `top`
+    # prefix row read these exact names
     "paddle_tpu_prefix_lookup_hits_total",
     "paddle_tpu_prefix_lookup_misses_total",
     "paddle_tpu_prefix_prefill_tokens_saved_total",
@@ -606,91 +605,3 @@ class EnvKnobsRule(Rule):
             f"docs/ENV_KNOBS.md (master index)",
             key=f"knob::{name}")
             for name in sorted(set(self._code) - documented)]
-
-
-# ---------------------------------------------------------------------------
-# bench-result schema (perfwatch sentinel inputs)
-# ---------------------------------------------------------------------------
-
-# the repo-root benchmark artifacts the perf-regression sentinel
-# compares across revisions (docs/OBSERVABILITY.md perf plane)
-BENCH_RESULT_RE = re.compile(r"^BENCH_r\d+.*\.json$")
-
-
-def _load_perfwatch():
-    """The perfwatch validator WITHOUT importing the jax-heavy
-    paddle_tpu package (same trick as scripts/_analysis_loader.py):
-    observability/perfwatch.py is stdlib-only at module level by
-    contract, so it loads standalone straight from its file."""
-    import importlib.util
-    import sys
-    if "paddle_tpu.observability.perfwatch" in sys.modules:
-        return sys.modules["paddle_tpu.observability.perfwatch"]
-    if "pt_perfwatch" not in sys.modules:
-        from ..core import repo_root
-        path = os.path.join(repo_root(), "paddle_tpu",
-                            "observability", "perfwatch.py")
-        spec = importlib.util.spec_from_file_location(
-            "pt_perfwatch", path)
-        mod = importlib.util.module_from_spec(spec)
-        sys.modules["pt_perfwatch"] = mod
-        spec.loader.exec_module(mod)
-    return sys.modules["pt_perfwatch"]
-
-
-def bench_result_paths(repo: str) -> list[str]:
-    return [os.path.join(repo, fn) for fn in sorted(os.listdir(repo))
-            if BENCH_RESULT_RE.match(fn)]
-
-
-def bench_schema_main(argv: list[str], repo: str) -> int:
-    """check_bench_schema.py behavior: every benchmark artifact must
-    parse under the perfwatch record schema, or `perfwatch compare`
-    against a future revision silently loses metrics."""
-    paths = argv[1:] or bench_result_paths(repo)
-    pw = _load_perfwatch()
-    bad = []
-    for path in paths:
-        try:
-            problems = pw.validate_file(path)
-        except OSError as e:
-            problems = [f"unreadable: {e}"]
-        bad.extend(f"{path}: {p}" for p in problems)
-    if bad:
-        print("bench result files violate the perfwatch record schema "
-              "(docs/OBSERVABILITY.md perf plane — `perfwatch "
-              "compare` reads these):")
-        print("\n".join(bad))
-        return 1
-    print(f"OK: {len(paths)} bench result file(s) conform to the "
-          f"perfwatch record schema")
-    return 0
-
-
-@register
-class BenchSchemaRule(Rule):
-    name = "bench-schema"
-    description = ("repo-root BENCH_r*.json artifacts parse under the "
-                   "perfwatch record schema (the perf-regression "
-                   "sentinel's input contract)")
-
-    def visit(self, ctx: FileContext):
-        return ()
-
-    def finalize(self, run):
-        if not run.default_scan:  # fixture/subtree scans carry no
-            return ()             # benchmark artifacts
-        from ..core import repo_root
-        out = []
-        dedup = KeyCounter()
-        for path in bench_result_paths(repo_root()):
-            try:
-                problems = _load_perfwatch().validate_file(path)
-            except Exception as e:  # a validator crash must not take
-                problems = [f"validator error: {e}"]  # down the scan
-            rel = os.path.basename(path)
-            out.extend(self.finding(
-                path, 0, f"bench artifact {problem}",
-                key=dedup(f"bench::{rel}::{problem}"))
-                for problem in problems)
-        return out

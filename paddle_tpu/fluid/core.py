@@ -121,7 +121,7 @@ class TPUPlace(Place):
     # jax's default backend: the TPU where JAX found one, else whatever
     # JAX_PLATFORMS / jax's own platform search yields (the CPU in the
     # sandbox and the tests). Nothing here checks that it IS a TPU —
-    # chip_smoke.py and bench.py do, and fail when it is not.
+    # chip_smoke.py does, and fails when it is not.
     backend = "default"
 
     def __init__(self, device_id: int = 0):

@@ -32,7 +32,7 @@ state, l) -> (o [B, T, H d], state)`: what is kept of k and v and what q
 attends over, by `cfg.layer_types[l]`. `rmsnorm`, the rotate-half RoPE,
 `dense_ffn` and the routed part (`routed_ffn` -> parallel/moe.py::
 dropless_moe_ffn, the shared expert added beside it there) are
-models/lfm2.py's.
+models/layers.py's.
 
 Weights: `{"embed" [V, D], "head" [D, V], "norm" [D], "layers": [per layer
 {"input_layernorm", "post_attention_layernorm", "pre_mlp_layernorm",
@@ -49,8 +49,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .deepseek_v3 import seeded_tree
-from .lfm2 import _rope, dense_ffn, rmsnorm, routed_ffn
+from .layers import _rope, dense_ffn, rmsnorm, routed_ffn, seeded_tree
 
 __all__ = ["AfmoeConfig", "SLIDING", "FULL", "init_params", "forward",
            "apply_layers", "banded_causal_attention", "window_of",
@@ -119,7 +118,7 @@ class AfmoeConfig:
         if self.head_dim % 2:
             raise ValueError("head_dim must be even (rotate-half)")
 
-    # -- what the shared sub-layers of models/lfm2.py read ------------------
+    # -- what the shared sub-layers of models/layers.py read ----------------
     use_expert_bias = True
 
     @property
@@ -186,7 +185,7 @@ def layer_shapes(cfg: AfmoeConfig, l: int) -> dict:
 
 def init_params(cfg: AfmoeConfig, seed: int = 0):
     """Seeded random weights, drawn as models/deepseek_v3.py's
-    (`seeded_tree`: gains 1 + 0.1 normal, the experts' bias of std 0.1)."""
+    (`layers.seeded_tree`: gains 1 + 0.1 normal, the experts' bias of std 0.1)."""
     dtype = jnp.dtype(cfg.dtype)
     key = jax.random.PRNGKey(seed)
     top = seeded_tree({"embed": (cfg.vocab_size, cfg.hidden_size),

@@ -3,8 +3,8 @@
 Reference equivalents: PaddleNLP BERT on top of the reference transformer
 stack (python/paddle/nn/layer/transformer.py) with fused attention
 (operators/fused/multihead_matmul_op, fused_embedding_eltwise_layernorm).
-Built on paddle_tpu.nn; runs in eager mode and jits cleanly for the bench
-(whole pretrain step = one XLA computation, bf16 on the MXU via amp).
+Built on paddle_tpu.nn; runs in eager mode and jits cleanly (whole
+pretrain step = one XLA computation, bf16 on the MXU via amp).
 """
 from __future__ import annotations
 

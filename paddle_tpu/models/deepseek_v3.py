@@ -41,7 +41,7 @@ The layer is written once (`apply_layers`, an unrolled loop: the first
 layer's feed-forward is of another kind) and takes `attend(p, q_nope,
 q_rope, c, kr, state, l) -> (a [B, T, H dv], state)`: what is kept of
 [c | kr] and which form runs. `rmsnorm`, `dense_ffn` and the routed part
-(`routed_ffn` -> parallel/moe.py::dropless_moe_ffn) are models/lfm2.py's;
+(`routed_ffn` -> parallel/moe.py::dropless_moe_ffn) are models/layers.py's;
 the shared experts are one SwiGLU of n_shared x Fm that every token takes,
 added beside the routed part there (`dropless_moe_ffn(shared=...)`).
 
@@ -61,9 +61,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .lfm2 import dense_ffn, rmsnorm, routed_ffn
+from .layers import dense_ffn, rmsnorm, routed_ffn, seeded_tree
 
-__all__ = ["DeepseekV3Config", "init_params", "seeded_tree", "forward", "apply_layers",
+__all__ = ["DeepseekV3Config", "init_params", "forward", "apply_layers",
            "expanded_attention", "absorb_query", "expand_value",
            "rope_pairs", "head_logits"]
 
@@ -113,7 +113,7 @@ class DeepseekV3Config:
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even (pairs)")
 
-    # -- what the shared sub-layers of models/lfm2.py read ------------------
+    # -- what the shared sub-layers of models/layers.py read ----------------
     @property
     def num_experts(self) -> int:
         return self.n_routed_experts
@@ -170,26 +170,6 @@ def layer_shapes(cfg: DeepseekV3Config, l: int) -> dict:
                       "shared": {"w1": (D, Fs), "w3": (D, Fs),
                                  "w2": (Fs, D)}}
     return out
-
-
-def seeded_tree(shapes, key, std: float, dtype):
-    """A tree of seeded random leaves for a tree of shapes: matrices normal
-    of `std`; a leaf named `*norm` a gain 1 + 0.1 normal (round one, not AT
-    one: a dropped gain then shows); a leaf named `bias` normal of std 0.1
-    (a program that weighs by score plus bias, or selects on the score,
-    then disagrees). models/afmoe.py draws its weights the same way."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda s: isinstance(s, tuple))
-    out = []
-    for i, (path, shape) in enumerate(flat):
-        name = path[-1].key
-        z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-        if name.endswith("norm"):
-            leaf = 1.0 + 0.1 * z
-        else:
-            leaf = (0.1 if name == "bias" else std) * z
-        out.append(leaf.astype(dtype))
-    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def init_params(cfg: DeepseekV3Config, seed: int = 0):

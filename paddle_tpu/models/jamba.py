@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.selective_scan import StackedRow, selective_scan, selective_step
-from .lfm2 import dense_causal_attention, dense_ffn, rmsnorm
+from .layers import dense_causal_attention, dense_ffn, rmsnorm
 
 __all__ = ["JambaConfig", "init_params", "forward", "apply_layers",
            "mamba_mixer", "zero_state", "head_logits", "MAMBA", "ATTN"]

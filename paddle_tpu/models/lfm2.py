@@ -39,11 +39,11 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..parallel.moe import dropless_moe_ffn
+from .layers import (_rope, dense_causal_attention, dense_ffn, rmsnorm,
+                     routed_ffn)
 
 __all__ = ["LFM2Config", "init_params", "forward", "apply_layers",
-           "conv_operator", "attention_operator", "dense_ffn", "routed_ffn",
-           "rmsnorm", "dense_causal_attention", "head_logits"]
+           "conv_operator", "attention_operator", "head_logits"]
 
 CONV, ATTN = "conv", "full_attention"
 _PUBLISHED_LAYERS = tuple(
@@ -170,26 +170,6 @@ def init_params(cfg: LFM2Config, seed: int = 0):
 # sub-layers, each written once
 # ---------------------------------------------------------------------------
 
-def rmsnorm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, positions, theta):
-    """Rotate-half RoPE over the whole head. x [B, T, H, d], positions
-    [B, T]."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[..., None] * inv     # [B, T, d/2]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
-    rot = jnp.concatenate([-x2, x1], -1)
-    return (xf * cos + rot * sin).astype(x.dtype)
-
-
 def conv_operator(p, h, state, lengths=None):
     """The gated short convolution. h [B, T, D]; state [B, K-1, D]: the
     values of u at the K-1 positions before h's first (zeros at a
@@ -212,20 +192,6 @@ def conv_operator(p, h, state, lengths=None):
     return y, new.astype(state.dtype)
 
 
-def dense_causal_attention(q, k, v, scale):
-    """q [B, T, Hq, d], k and v [B, T, Hkv, d]; KV head j serves query
-    heads G j .. G j + G - 1."""
-    B, T, Hq, d = q.shape
-    Hkv = k.shape[2]
-    qg = q.reshape(B, T, Hkv, Hq // Hkv, d)
-    s = jnp.einsum("btkgd,bskd->bkgts", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    mask = jnp.tril(jnp.ones((T, T), bool))
-    pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
-    a = jnp.einsum("bkgts,bskd->btkgd", pr.astype(v.dtype), v)
-    return a.reshape(B, T, Hq * d)
-
-
 def attention_operator(p, h, positions, attend, state, cfg):
     """Grouped-query attention. `attend(q, k, v, state) -> (a [B, T,
     Hq d], new state)` is the attention-state interface: what is kept of k
@@ -242,26 +208,6 @@ def attention_operator(p, h, positions, attend, state, cfg):
               cfg.rope_theta)
     a, state = attend(q, k, v, state)
     return a @ p["wo"], state
-
-
-def dense_ffn(p, h):
-    return (jax.nn.silu(h @ p["w1"]) * (h @ p["w3"])) @ p["w2"]
-
-
-def routed_ffn(p, h, cfg):
-    """h [B, T, D] -> (f [B, T, D], sel [B T, k] chosen experts). A layer
-    with shared experts (`p["shared"]`: models/deepseek_v3.py) gets their
-    part added."""
-    shape = h.shape
-    shared = p.get("shared")
-    y, sel = dropless_moe_ffn(
-        h.reshape(-1, shape[-1]), p["wg"],
-        p["bias"] if cfg.use_expert_bias else None,
-        p["w1"], p["w3"], p["w2"], top_k=cfg.num_experts_per_tok,
-        norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-        experts_held=cfg.experts_held,
-        shared=shared and (shared["w1"], shared["w3"], shared["w2"]))
-    return y.reshape(shape), sel
 
 
 def head_logits(params, x, cfg):

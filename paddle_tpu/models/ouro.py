@@ -27,7 +27,7 @@ The layer is written once (`apply_passes`: a scan over the passes round a
 scan over the stacked layers); `forward` (dense causal), and the serving
 adapter's prefill and decode (serving/model.py::LoopedDecodeModel) are
 drivers that hand it an `attend` function, as models/lfm2.py's are.
-RMSNorm, rotate-half RoPE and SwiGLU are lfm2's.
+RMSNorm, rotate-half RoPE and SwiGLU are models/layers.py's.
 
 Weights: `{"embed" [V, D], "head" [D, V] (untied), "norm" [D], "gate_w"
 [D], "gate_b" [], "layers": {the four norms [L, D], "wq" [L, H d, D], "wk",
@@ -45,7 +45,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .lfm2 import _rope, dense_causal_attention, dense_ffn, rmsnorm
+from .layers import _rope, dense_causal_attention, dense_ffn, rmsnorm
 
 __all__ = ["OuroConfig", "init_params", "forward", "apply_passes",
            "cache_row", "exit_distribution", "head_logits"]

@@ -40,10 +40,7 @@ Quick use:
     obs.write_bundle("/tmp/debug", reason="manual")
 
 ``obs.set_enabled(False)`` (or ``PADDLE_TPU_TELEMETRY=0``) turns every
-metric write, span record and flight event into a cheap no-op; the
-``BENCH_CONFIG=metrics_overhead`` / ``flight_overhead`` entries in
-bench.py keep the enabled-vs-disabled decode step-time delta honest
-(<2%).
+metric write, span record and flight event into a cheap no-op.
 """
 from __future__ import annotations
 
@@ -52,7 +49,7 @@ import os
 import socket
 
 from . import agent, alerts, collector, debug, flight, meter, perf, \
-    perfwatch, registry, timeseries, tracing, watchdog
+    registry, timeseries, tracing, watchdog
 from .agent import TelemetryAgent, publish_event
 from .alerts import AlertManager, AlertRule
 from .collector import TelemetryCollector, telemetry_dispatch
@@ -70,7 +67,7 @@ from .watchdog import WATCHDOG
 
 __all__ = [
     "registry", "tracing", "flight", "watchdog", "debug",
-    "agent", "collector", "perf", "perfwatch",
+    "agent", "collector", "perf",
     "timeseries", "alerts", "meter",
     "TelemetryAgent", "TelemetryCollector",
     "telemetry_dispatch", "publish_event",

@@ -206,7 +206,7 @@ class TelemetryCollector:
         self._started = time.time()
         # time-series plane: memory-only TSDB unless PADDLE_TPU_TSDB_DIR
         # points at a data dir; PADDLE_TPU_TSDB=0 turns the whole plane
-        # off (the bench A/B toggle)
+        # off
         if tsdb is None \
                 and os.environ.get("PADDLE_TPU_TSDB", "1") != "0":
             tsdb = _ts.TimeSeriesDB()

@@ -21,10 +21,7 @@ Design rules:
     outside it) and NEAR-ZERO when disabled: ``record()`` is one
     attribute check and a return (``PADDLE_TPU_FLIGHT=0`` or
     ``RECORDER.set_enabled(False)``; the master ``obs.set_enabled``
-    switch toggles this recorder too). The
-    ``BENCH_CONFIG=flight_overhead`` microbench holds the enabled cost
-    on the serving decode hot path under the same <2% bar as the
-    metrics registry;
+    switch toggles this recorder too);
   * ``snapshot()`` is JSON-safe by construction (attrs are sanitized at
     export time, not on the hot path) so a ring dump can ride the
     data-only RPC wire (``debug_dump`` verb) and land in a bundle file

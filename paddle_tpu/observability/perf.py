@@ -8,8 +8,7 @@ Three pieces, all views over the one metrics registry:
   ``lower().cost_analysis()`` FLOPs / bytes-accessed at trace time,
   keyed by ``(name, key)`` where ``key`` is the compile bucket or feed
   shape.  Exposed as ``paddle_tpu_perf_flops`` / ``paddle_tpu_perf_bytes``
-  gauges and a :func:`roofline` table (arithmetic intensity vs the
-  chip's ridge point).
+  gauges.
 * **step-time decomposition** — :class:`StepSampler` gates a sampled
   profile of one step in ``PADDLE_TPU_PERFWATCH_EVERY`` (default 50;
   0 disables).  On a sampled step the caller reports host / dispatch /
@@ -18,16 +17,11 @@ Three pieces, all views over the one metrics registry:
   reads them off its own spans; between samples the hot path is
   untouched, so steady-state overhead stays ~0.
 * **MFU accounting** — :func:`chip_peak_flops` resolves the chip's
-  peak bf16 FLOP/s from ``jax.devices()[0].device_kind`` (bench.py
-  delegates here, so live gauges and bench reports share one peak
-  table by construction) and :func:`mfu` converts achieved FLOP/s to
-  model-flops-utilisation. A device kind that is not in the table (the
-  CPU backend, a new chip) has NO peak: no MFU and no roofline rows
-  are reported there, rather than numbers against a guessed chip.
-
-:func:`snapshot` serialises the whole plane (costs, breakdowns, kernel
-margins, HBM stats) into the schema-versioned dict ``perfwatch record``
-writes and ``perfwatch compare`` diffs.
+  peak bf16 FLOP/s from ``jax.devices()[0].device_kind`` and
+  :func:`mfu` converts achieved FLOP/s to model-flops-utilisation. A
+  device kind that is not in the table (the CPU backend, a new chip)
+  has NO peak: no MFU is reported there, rather than a number against
+  a guessed chip.
 """
 from __future__ import annotations
 
@@ -36,13 +30,11 @@ import math
 import os
 import threading
 import time
-import weakref
 
 from . import flight as _flight
 from . import registry as _obs
 
 __all__ = [
-    "SNAPSHOT_SCHEMA",
     "StepSampler",
     "analytic_gpt_flops",
     "chip_peak_bytes_per_s",
@@ -59,23 +51,16 @@ __all__ = [
     "record_breakdown",
     "register_cost",
     "register_jit_cost",
-    "register_provider",
     "reset",
-    "roofline",
     "sampling_every",
     "set_every",
     "set_mfu",
-    "snapshot",
-    "weak_provider",
 ]
-
-SNAPSHOT_SCHEMA = "paddle_tpu.perf/1"
 
 logger = logging.getLogger("paddle_tpu.perf")
 
 # ---------------------------------------------------------------------------
-# Peak tables.  bench.py's chip_peak_flops() delegates here so the live
-# MFU gauges and the bench reports can never disagree on the peak.
+# Peak tables.
 # ---------------------------------------------------------------------------
 
 # (device_kind substring, peak bf16 FLOP/s).  Order matters: first match
@@ -92,8 +77,8 @@ _PEAKS = [
     ("v2", 45e12),
 ]
 
-# (device_kind substring, HBM bandwidth bytes/s) — for the roofline
-# ridge point.  Same shape as _PEAKS; override with TPU_PEAK_GBPS.
+# (device_kind substring, HBM bandwidth bytes/s).  Same shape as
+# _PEAKS; override with TPU_PEAK_GBPS.
 _BWS = [
     ("v6", 1640e9),
     ("v5p", 2765e9),
@@ -156,8 +141,10 @@ def mfu(flops: float, seconds: float) -> float:
 def analytic_gpt_flops(cfg, tokens: int, ctx: int) -> float:
     """Matmul-only forward FLOPs for `tokens` new tokens of a GPT block
     stack at context length `ctx` — the fallback when XLA cost analysis
-    is unavailable.  Matches bench.py's convention (qkv+proj+mlp+attn
-    matmuls + the LM head, no norms/softmax)."""
+    is unavailable.  Same convention as benchmark/lib/peaks.py, which
+    the training cells' `mfu` is made from (qkv+proj+mlp+attn matmuls +
+    the LM head, no norms/softmax); tests/test_perf_plane.py holds the
+    two together."""
     H = int(getattr(cfg, "hidden_size", 0))
     L = int(getattr(cfg, "num_layers", 0))
     F = int(getattr(cfg, "intermediate_size", 4 * H) or 4 * H)
@@ -248,10 +235,6 @@ _LOCK = threading.Lock()
 _COSTS: dict[tuple[str, str], dict] = {}
 _BREAKDOWNS: dict[str, dict] = {}
 _KERNELS: dict[str, dict] = {}
-_MFU_VALUES: dict[str, float] = {}
-# name -> zero-arg callable returning a JSON-safe dict merged into
-# snapshot()["providers"].  Callables must be cheap and must not block.
-_PROVIDERS: dict[str, object] = {}
 
 
 def costs_enabled() -> bool:
@@ -317,34 +300,6 @@ def costs() -> dict[tuple[str, str], dict]:
         return {k: dict(v) for k, v in _COSTS.items()}
 
 
-def roofline() -> list[dict]:
-    """Rows of (name, key, flops, bytes, intensity, bound, frac_of_ridge).
-
-    `bound` says whether the op sits left (memory-bound) or right
-    (compute-bound) of the chip's ridge point peak_flops/peak_bw.
-    No rows on a device without known peaks.
-    """
-    peak, _ = chip_peak_flops()
-    bw, _ = chip_peak_bytes_per_s()
-    if not (peak and bw):
-        return []
-    ridge = peak / bw
-    rows = []
-    for (name, key), c in sorted(costs().items()):
-        fl, by = c.get("flops"), c.get("bytes")
-        inten = (fl / by) if fl and by else None
-        rows.append({
-            "name": name, "key": key,
-            "flops": fl, "bytes": by,
-            "intensity": inten,
-            "ridge": ridge,
-            "bound": (None if inten is None
-                      else ("compute" if inten >= ridge else "memory")),
-            "source": c.get("source"),
-        })
-    return rows
-
-
 # ---------------------------------------------------------------------------
 # Step sampling + breakdown
 # ---------------------------------------------------------------------------
@@ -365,7 +320,7 @@ def sampling_every() -> int:
 
 
 def set_every(n: int) -> None:
-    """Override the sampling cadence at runtime (bench A/B/A, tests)."""
+    """Override the sampling cadence at runtime (tests)."""
     global _EVERY
     _EVERY = max(0, int(n))
 
@@ -420,8 +375,6 @@ def set_mfu(name: str, value: float) -> None:
     v = float(value)
     if not math.isfinite(v):
         v = 0.0
-    with _LOCK:
-        _MFU_VALUES[name] = v
     _MFU.labels(name=name).set(v)
 
 
@@ -459,68 +412,14 @@ def kernels() -> dict[str, dict]:
         return {k: dict(v) for k, v in _KERNELS.items()}
 
 
-# ---------------------------------------------------------------------------
-# Providers + snapshot
-# ---------------------------------------------------------------------------
-
-def register_provider(name: str, fn) -> None:
-    """Register a cheap zero-arg callable contributing a dict to
-    snapshot()["providers"][name] (engines register a weakref-wrapped
-    rates summary).  Re-registering replaces."""
-    with _LOCK:
-        _PROVIDERS[name] = fn
-
-
-def unregister_provider(name: str) -> None:
-    with _LOCK:
-        _PROVIDERS.pop(name, None)
-
-
 def drop_instance(name: str, engine_id: str | None = None) -> None:
     """Drop the per-instance series for a garbage-collected owner."""
-    unregister_provider(name)
     _MFU.remove_matching(name=name)
     _BREAKDOWN.remove_matching(name=name)
     if engine_id is not None:
         _KV_BYTES.remove_matching(engine=engine_id)
     with _LOCK:
         _BREAKDOWNS.pop(name, None)
-        _MFU_VALUES.pop(name, None)
-
-
-def snapshot() -> dict:
-    """Schema-versioned JSON-safe dump of the whole perf plane — the
-    payload of ``perfwatch record`` and the input to ``compare``."""
-    peak, kind = chip_peak_flops()
-    bw, _ = chip_peak_bytes_per_s()
-    with _LOCK:
-        providers = dict(_PROVIDERS)
-        mfus = dict(_MFU_VALUES)
-    prov_out = {}
-    for name, fn in providers.items():  # outside _LOCK: fns may lock
-        try:
-            d = fn()
-            if isinstance(d, dict):
-                prov_out[name] = d
-        except Exception:
-            pass
-    return {
-        "schema": SNAPSHOT_SCHEMA,
-        "created_unix": time.time(),
-        "device_kind": kind,
-        "peak_flops": peak,
-        "peak_bytes_per_s": bw,
-        "costs": [
-            {"name": n, "key": k, **c} for (n, k), c in sorted(costs().items())
-        ],
-        "breakdown": breakdowns(),
-        "mfu": mfus,
-        "kernels": kernels(),
-        "hbm": {k: _hbm_stat(s) for k, s in
-                (("in_use", "bytes_in_use"), ("limit", "bytes_limit"),
-                 ("peak", "peak_bytes_in_use"))},
-        "providers": prov_out,
-    }
 
 
 def reset() -> None:
@@ -529,20 +428,8 @@ def reset() -> None:
         _COSTS.clear()
         _BREAKDOWNS.clear()
         _KERNELS.clear()
-        _MFU_VALUES.clear()
-        _PROVIDERS.clear()
     for g in (_FLOPS, _BYTES):
         g.remove_matching()
     _MFU.remove_matching()
     _BREAKDOWN.remove_matching()
 
-
-def weak_provider(obj, method_name: str):
-    """A provider callable holding only a weakref to `obj`."""
-    ref = weakref.ref(obj)
-    def call():
-        o = ref()
-        if o is None:
-            return {}
-        return getattr(o, method_name)()
-    return call

@@ -21,9 +21,8 @@ Design rules:
     <dir>``.
 
 Disabling (``REGISTRY.set_enabled(False)`` or
-``PADDLE_TPU_TELEMETRY=0``) turns every write into a cheap early return
-— the metrics-overhead microbench (``BENCH_CONFIG=metrics_overhead``)
-measures the enabled-vs-disabled step-time delta.
+``PADDLE_TPU_TELEMETRY=0``) turns every write into a cheap early
+return.
 
 No jax/framework imports here: the registry must be importable from the
 deepest transport modules without cycles.

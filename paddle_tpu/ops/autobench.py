@@ -506,8 +506,7 @@ def decisions() -> dict:
 
 def stats() -> dict:
     """Process-local counters: measures, candidate_errors, cache_hits/
-    misses/stale/corrupt, publishes (tests + bench assert against
-    these)."""
+    misses/stale/corrupt, publishes (tests assert against these)."""
     with _LOCK:
         return dict(_STATS)
 

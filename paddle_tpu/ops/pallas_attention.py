@@ -15,7 +15,7 @@ it — and independently of block-size choices.
 
 Numerical contract: matches `sdpa_reference` (jnp) to bf16 tolerance;
 exercised by tests/test_pallas_kernels.py in interpret mode on CPU and by
-the bench on real TPU.
+the training cells (benchmark/runners/train.py) on the chip.
 """
 from __future__ import annotations
 

@@ -1,9 +1,9 @@
 """Pallas TPU fused transformer FFN: y = act(x @ W1 + b1) @ W2 + b2.
 
-The round-5 BERT traffic audit (bench.py bench_bert docstring) measured
-the FFN activation tier — erf-gelu + its saved branch predicates over
-bf16[B,T,4H] — at ~19% of the train step, VPU-compute-bound and
-materialised to HBM between the two matmuls. This kernel keeps the 4H
+Composed, the FFN activation tier of an encoder's train step (erf-gelu
++ its saved branch predicates over bf16[B,T,4H]) is VPU work that is
+materialised to HBM between the two matmuls; no cell of the benchmark
+measures it. This kernel keeps the 4H
 intermediate in VMEM: per (M-block, I-block) grid cell it computes
 act(x_blk @ W1_blk + b1_blk) on-chip and accumulates the second matmul
 into an f32 scratch, so the intermediate never exists in HBM and the

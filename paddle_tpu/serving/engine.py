@@ -61,8 +61,7 @@ __all__ = ["Engine", "QueueFull"]
 # engine telemetry (labeled per engine instance; the scheduler/pool
 # series share the same label value). Hot-path writes are counter incs
 # and histogram observes around the jitted calls — host-side
-# microseconds against millisecond steps (<2% bar held by the
-# metrics_overhead microbench).
+# microseconds against millisecond steps.
 _REQS = _obs.counter(
     "paddle_tpu_serving_requests_total",
     "requests submitted to the engine", ["engine"])
@@ -371,8 +370,6 @@ class Engine:
         # the model's tallies as last read (stats() reports the change)
         self._tally_seen: dict = {}
         self._steps_seen = 0
-        _perf.register_provider(self._perf_name,
-                                _perf.weak_provider(self, "perf_rates"))
         weakref.finalize(self, _perf.drop_instance, self._perf_name, eid)
 
     # -- submission (any thread) ---------------------------------------

@@ -29,7 +29,7 @@ Design rules:
     into p50/p99 TTFT, p99 inter-token latency, deadline attainment
     and goodput (tokens from requests that met their deadline), and
     mirrors them onto ``paddle_tpu_slo_*`` registry metrics so a
-    scrape sees the same numbers the bench JSON reports.
+    scrape sees the same numbers the report holds.
 
 No jax imports — the generator drives an Engine (in-process), a
 ServingClient (wire) or any submit callable, and is unit-testable
@@ -53,7 +53,7 @@ __all__ = ["TrafficConfig", "Arrival", "LoadGenerator", "LoadResult",
 
 # SLO surface (docs/SERVING.md): the load generator writes what it
 # measured, labeled per generator run, so `/metrics` exposes the same
-# attainment/goodput numbers the bench JSON rows carry
+# attainment/goodput numbers `slo_report` returns
 _TTFT_H = _obs.histogram(
     "paddle_tpu_slo_ttft_seconds",
     "submit-to-first-token latency of generated traffic", ["gen"])

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from ..models import afmoe as _afmoe
 from ..models import deepseek_v3 as _dsv3
 from ..models import jamba as _jamba
+from ..models import layers as _layers
 from ..models import lfm2 as _lfm2
 from ..models import ouro as _ouro
 from ..models.gpt import (GPTConfig, _causal_attention, _head, _ln,
@@ -626,7 +627,7 @@ class HybridDecodeModel(_ExpertRecords, DecodeModel):
             kv = jnp.concatenate([k, v], axis=-1)[0]            # [T, Hkv, 2d]
             pool = pool.at[i, pages].set(
                 kv.reshape((n_pages, ps) + kv.shape[1:]).astype(pool.dtype))
-            return _lfm2.dense_causal_attention(q, k, v, scale), (pool, i)
+            return _layers.dense_causal_attention(q, k, v, scale), (pool, i)
 
         x, conv, pool, sel = _lfm2.apply_layers(
             cfg, params, x, positions,
@@ -752,7 +753,7 @@ class LoopedDecodeModel(DecodeModel):
             shape = (T // ps, ps) + k.shape[2:]
             ck = ck.at[row, pages].set(k[0].reshape(shape).astype(ck.dtype))
             cv = cv.at[row, pages].set(v[0].reshape(shape).astype(cv.dtype))
-            return _lfm2.dense_causal_attention(q, k, v, scale), (ck, cv)
+            return _layers.dense_causal_attention(q, k, v, scale), (ck, cv)
 
         x, lam, (ck, cv) = _ouro.apply_passes(
             cfg, params, x, positions, attend, (cache["k"], cache["v"]))
@@ -1119,7 +1120,7 @@ class RecurrentDecodeModel(DecodeModel):
             row = jnp.concatenate([v, k], axis=-1)[0, :, 0]     # [T, 2d]
             pool = pool.at[i, pages].set(
                 row.reshape(T // ps, ps, -1).astype(pool.dtype))
-            return _lfm2.dense_causal_attention(q, k, v, scale), pool
+            return _layers.dense_causal_attention(q, k, v, scale), pool
 
         x, ssm, conv, pool = _jamba.apply_layers(
             cfg, params, x, *_jamba.zero_state(cfg, 1, cache["conv"].dtype),

@@ -10,7 +10,7 @@ and never from ``tempfile``, a pid or the time:
     (git-ignored), the same from every process of every run.
 
 Entry points call :func:`setup_compile_cache` before their first use of
-jax (``chip_smoke.py``, ``bench.py``); the launcher exports
+jax (``chip_smoke.py``); the launcher exports
 :func:`cache_dir` to its chip-using children as
 ``JAX_COMPILATION_CACHE_DIR`` so that they need no call of their own.
 """

@@ -34,39 +34,6 @@ def _seed():
     yield
 
 
-# -- slowest-test tracker (perf plane) ----------------------------------
-# Every run leaves a per-test duration artifact so
-# `python -m paddle_tpu.observability.perfwatch compare --tests old new`
-# can flag tests that got >2x slower between two runs (the tier-1 wall
-# time ratchet). Path override: PADDLE_TPU_TEST_TIMES.
-_test_durations: dict = {}
-
-
-def pytest_runtest_logreport(report):
-    if report.when == "call":
-        _test_durations[report.nodeid] = \
-            _test_durations.get(report.nodeid, 0.0) + report.duration
-
-
-def pytest_sessionfinish(session, exitstatus):
-    if not _test_durations:
-        return
-    import json
-    path = os.environ.get("PADDLE_TPU_TEST_TIMES") or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".pytest_times.json")
-    try:
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump({"schema": "paddle_tpu.test_times/1",
-                       "tests": {k: round(v, 4)
-                                 for k, v in _test_durations.items()}},
-                      f, indent=0, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError:
-        pass
-
-
 @pytest.fixture()
 def fresh_programs():
     """Fresh main/startup programs + scope for static-graph tests."""

@@ -97,8 +97,14 @@ y = X @ w_true
 
 sched = SampleSchedule(seed=SEED, epoch=0, num_samples=N,
                        global_batch=G)
+# rank 0's merge waits for every rank's part. In a life with a fault to
+# find, a short wait keeps rank 0 stepping beside a rank that will never
+# write; in a fault-free life the only thing it can wait for is a rank
+# the machine's load has held back (four interpreters starting beside a
+# busy test run are seconds apart), and giving up on one fails the life
 ck = ClusterCheckpoint(ROOT, rank=RANK, world=WORLD,
-                       every_steps=SAVE_EVERY, merge_timeout=5.0)
+                       every_steps=SAVE_EVERY,
+                       merge_timeout=5.0 if (KILL_AT or STALL) else 60.0)
 
 base, rem = divmod(ROWS, WORLD)
 row_lo = RANK * base + min(RANK, rem)
@@ -117,6 +123,24 @@ if ClusterCheckpoint.exists(ROOT):
         f"reshard: got {M.shape[0]} rows, own {len(my_rows)}"
 
 elastic.start_heartbeat(interval=0.1)
+
+# the first collective of a real gang: no rank steps before every rank has
+# restored. Without it nothing here holds the ranks together (each
+# recomputes the "allreduce" alone), and a rank that the machine's load
+# started late finds the others steps ahead: rank 0's restore purges the
+# parts past the committed step, a faster rank's fresh one among them, and
+# the merge of that step then waits for a part that will not come. Keyed by
+# where this life starts: a life that committed nothing since the last one
+# meets that one's files and passes as it did before there was a barrier.
+arrived = os.path.join(OUT, f"restored_s{start}_w{WORLD}_r%d")
+with open(arrived % RANK, "w"):
+    pass
+deadline = time.monotonic() + 60.0
+while not all(os.path.exists(arrived % r) for r in range(WORLD)):
+    if time.monotonic() >= deadline:
+        sys.exit(f"rank {RANK}: not every rank of {WORLD} restored")
+    time.sleep(0.01)
+
 losses = open(os.path.join(OUT, f"loss_rank{RANK}.jsonl"), "a")
 
 for step in range(start, STEPS):

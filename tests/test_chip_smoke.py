@@ -71,7 +71,7 @@ def test_compile_cache_is_placed_from_outside_or_fixed(monkeypatch,
 
 
 def test_import_starts_no_backend():
-    code = ("import paddle_tpu, paddle_tpu.distributed.launch, bench, "
+    code = ("import paddle_tpu, paddle_tpu.distributed.launch, "
             "chip_smoke\n"
             "from jax._src import xla_bridge\n"
             "assert not xla_bridge._backends, xla_bridge._backends\n")

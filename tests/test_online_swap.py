@@ -213,7 +213,9 @@ def test_router_publish_watch_rolls_out_automatically(ckpt_root,
             assert _wait_for(
                 lambda: all(r.engine.stats()["model_version"] == 1
                             for r in reps), timeout=60)
-            assert router.rollouts >= 1
+            # counted once the last replica's probe has answered, which
+            # is after its engine says so
+            assert _wait_for(lambda: router.rollouts >= 1, timeout=60)
     finally:
         for r in reps:
             r.stop()

@@ -1,23 +1,15 @@
-"""Perf observability plane: cost registry, step attribution, sentinel.
+"""Perf observability plane: cost registry, step attribution, MFU.
 
 Covers the docs/OBSERVABILITY.md perf-plane acceptance surface: XLA
 FLOPs registered for every jitted engine bucket, sampled step-time
-breakdowns, MFU on `stats()`/`ping`, the shared bench/perf peak table,
-and the perfwatch record/compare/validate regression sentinel.
+breakdowns, MFU on `stats()`/`ping`, and the FLOP convention shared
+with the benchmark's training cells (benchmark/lib/peaks.py).
 """
-import glob
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
-from paddle_tpu.observability import perf, perfwatch
+from paddle_tpu.observability import perf
 from paddle_tpu.observability import registry as obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -56,22 +48,16 @@ def test_cost_registry_covers_every_engine_bucket(perf_engine,
     for bucket in buckets:
         assert (name, bucket) in costs, (bucket, sorted(costs))
         assert costs[(name, bucket)]["flops"] > 0, bucket
-    # the CPU is no chip the peak table knows: no peak, no MFU, no
-    # roofline rows against a guessed device
+    # the CPU is no chip the peak table knows: no peak and no MFU
+    # against a guessed device
     assert perf.chip_peak_flops() == (None, "cpu")
     assert perf.chip_peak_bytes_per_s() == (None, "cpu")
-    assert perf.roofline() == [] and perf.mfu(1e12, 1.0) == 0.0
-    assert perf.snapshot()["peak_flops"] is None
-    # with peaks given, the roofline join places every costed bucket
-    # against the ridge
+    assert perf.mfu(1e12, 1.0) == 0.0
+    # with peaks given, both tables answer for the same device kind
     monkeypatch.setenv("TPU_PEAK_TFLOPS_BF16", "197")
     monkeypatch.setenv("TPU_PEAK_GBPS", "819")
-    rows = {(r["name"], r["key"]): r for r in perf.roofline()}
-    for bucket in buckets:
-        row = rows[(name, bucket)]
-        assert row["ridge"] > 0
-        if row["intensity"] is not None:
-            assert row["bound"] in ("compute", "memory")
+    assert perf.chip_peak_flops() == (197e12, "cpu")
+    assert perf.chip_peak_bytes_per_s() == (819e9, "cpu")
 
 
 def test_engine_stats_and_kv_gauge(perf_engine):
@@ -167,31 +153,34 @@ def test_executor_perf_integration(fresh_programs):
     bd = perf.breakdowns().get("executor")
     assert bd and {"host", "dispatch", "device", "transfer"} \
         <= set(bd["phases"])
-    assert perf.snapshot()["mfu"].get("executor", 0.0) >= 0.0
     dump = {m["name"]: m for m in obs.to_dict()["metrics"]}
+    # a sampled step sets the gauge; the CPU has no peak, so it reads 0
+    assert [s["value"] for s in dump["paddle_tpu_perf_mfu"]["samples"]
+            if s["labels"].get("name") == "executor"] == [0.0]
     sites = {s["labels"]["site"]: s
              for s in dump["paddle_tpu_perf_compile_seconds"]["samples"]}
     assert sites["executor"]["count"] >= 1
 
 
 # ---------------------------------------------------------------------------
-# MFU convention shared with bench.py
+# MFU convention shared with the training cells (benchmark/lib/peaks.py)
 # ---------------------------------------------------------------------------
 
-def test_analytic_flops_and_peak_match_bench(monkeypatch):
-    import bench
+def test_analytic_flops_match_the_training_cells(monkeypatch):
+    from benchmark.lib import peaks
     from paddle_tpu.models.gpt import GPTConfig
     cfg = GPTConfig(hidden_size=1024, num_layers=24, num_heads=16,
                     max_position_embeddings=1024)
+    sizes = {"hidden_size": cfg.hidden_size, "num_layers": cfg.num_layers,
+             "vocab_size": cfg.vocab_size,
+             "intermediate_size": cfg.intermediate_size}
     b, s = 8, 1024
-    bench_fl = bench.gpt_train_flops_per_step(cfg, b, s)
+    cell_fl = peaks.gpt_train_flops_per_step(sizes, b, s)
     plane_fl = 3 * perf.analytic_gpt_flops(cfg, b * s, s)  # fwd + 2x bwd
-    assert abs(bench_fl - plane_fl) / bench_fl < 0.05
-    # one peak table: the bench report and the live gauges agree
+    assert abs(cell_fl - plane_fl) / cell_fl < 0.05
     monkeypatch.setenv("TPU_PEAK_TFLOPS_BF16", "275")
     peak, _ = perf.chip_peak_flops()
     assert peak == 275e12
-    assert bench.chip_peak_flops()[0] == peak
     assert perf.mfu(peak / 2, 1.0) == pytest.approx(0.5)
     assert perf.mfu(0.0, 1.0) == 0.0 and perf.mfu(1.0, 0.0) == 0.0
 
@@ -224,125 +213,7 @@ def test_autobench_decision_feeds_kernel_margins():
     assert k["winner"] == "pallas"
     assert k["margin"] == pytest.approx(1.5)
     assert k["candidates_ms"]["xla"] == pytest.approx(1.5)
-    flat = perfwatch._flatten(perf.snapshot())
-    med, direction = flat["kernel.perfplane_test[s=64].winner_ms"]
-    assert med == pytest.approx(1.0) and direction == "lower"
-
-
-# ---------------------------------------------------------------------------
-# sentinel: record / compare / validate
-# ---------------------------------------------------------------------------
-
-def _snap(mfu_val, device_s):
-    return {"schema": perf.SNAPSHOT_SCHEMA, "created_unix": 0.0,
-            "device_kind": "cpu", "peak_flops": 1.0,
-            "peak_bytes_per_s": 1.0, "costs": [], "kernels": {},
-            "hbm": {}, "providers": {},
-            "mfu": {"engine:e0": mfu_val},
-            "breakdown": {"engine:e0": {"samples": 3,
-                                        "phases": {"device": device_s}}}}
-
-
-def test_compare_identical_exits_zero(tmp_path, capsys):
-    p = tmp_path / "a.json"
-    p.write_text(json.dumps(_snap(0.40, 0.100)))
-    assert perfwatch.main(["compare", str(p), str(p)]) == 0
-    assert "no regressions" in capsys.readouterr().out
-
-
-def test_compare_flags_injected_slowdown(tmp_path, capsys):
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    old.write_text(json.dumps(_snap(0.40, 0.100)))
-    # ~12% slower device phase, beyond the 5% band and the abs floor
-    new.write_text(json.dumps(_snap(0.40, 0.112)))
-    assert perfwatch.main(["compare", str(old), str(new)]) == 1
-    assert "REGRESSION breakdown.engine:e0.device" \
-        in capsys.readouterr().out
-    # an MFU drop regresses in the higher-is-better direction
-    new.write_text(json.dumps(_snap(0.33, 0.100)))
-    assert perfwatch.main(["compare", str(old), str(new)]) == 1
-    assert "REGRESSION mfu.engine:e0" in capsys.readouterr().out
-    # a widened per-metric tolerance band absorbs both
-    new.write_text(json.dumps(_snap(0.33, 0.112)))
-    assert perfwatch.main(
-        ["compare", str(old), str(new), "--tol-pct", "30"]) == 0
-
-
-def test_compare_sub_floor_noise_is_not_a_regression(tmp_path):
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    # 50% relative but 0.05ms absolute: under the breakdown floor
-    old.write_text(json.dumps(_snap(0.40, 0.0001)))
-    new.write_text(json.dumps(_snap(0.40, 0.00015)))
-    assert perfwatch.main(["compare", str(old), str(new)]) == 0
-
-
-def test_compare_tests_flags_2x_slower(tmp_path, capsys):
-    po, pn = tmp_path / "o.json", tmp_path / "n.json"
-    po.write_text(json.dumps({"schema": "paddle_tpu.test_times/1",
-                              "tests": {"t.py::a": 1.0, "t.py::b": 0.5}}))
-    pn.write_text(json.dumps({"schema": "paddle_tpu.test_times/1",
-                              "tests": {"t.py::a": 2.6, "t.py::b": 0.6}}))
-    assert perfwatch.main(["compare", "--tests", str(po), str(pn)]) == 1
-    out = capsys.readouterr().out
-    assert "SLOWER t.py::a" in out and "t.py::b" not in out
-    # identical artifacts pass
-    assert perfwatch.main(["compare", "--tests", str(po), str(po)]) == 0
-
-
-def test_record_snapshot_roundtrip(tmp_path):
-    perf.set_mfu("unit:recorder", 0.25)
-    try:
-        out = tmp_path / "perf.json"
-        assert perfwatch.main(["record", "-o", str(out), "--samples",
-                               "2", "--interval", "0"]) == 0
-        assert perfwatch.validate_file(str(out)) == []
-        flat = perfwatch.load_result(str(out))
-        med, direction = flat["mfu.unit:recorder"]
-        assert med == pytest.approx(0.25) and direction == "higher"
-    finally:
-        perf.drop_instance("unit:recorder")
-
-
-def test_bench_record_writer(tmp_path, monkeypatch):
-    out = tmp_path / "bench.jsonl"
-    monkeypatch.setenv("PADDLE_TPU_BENCH_OUT", str(out))
-    rec = {"metric": "unit_test_ms", "value": 1.5, "unit": "ms"}
-    perfwatch.finalize_record(rec, "unit_test")
-    assert rec["schema"] == perfwatch.BENCH_SCHEMA
-    assert rec["config"] == "unit_test"
-    perfwatch.finalize_record(
-        {"metric": "unit_test_ms", "value": 1.4, "unit": "ms"},
-        "unit_test")
-    assert perfwatch.validate_file(str(out)) == []
-    lines = out.read_text().splitlines()
-    assert len(lines) == 2
-    assert all(json.loads(ln)["schema"] == perfwatch.BENCH_SCHEMA
-               for ln in lines)
-
-
-def test_repo_bench_artifacts_validate():
-    # whatever records the checkout holds (none is fine)
-    for path in sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))):
-        assert perfwatch.validate_file(path) == [], path
-
-
-def test_check_bench_schema_script():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "check_bench_schema.py")],
-        capture_output=True, text=True, cwd=REPO, timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "conform" in r.stdout
-
-
-def test_validate_rejects_malformed(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"schema": "paddle_tpu.bench/1",
-                               "metric": "m", "value": None}))
-    assert perfwatch.validate_file(str(bad))  # null value, no error note
-    unknown = tmp_path / "unknown.json"
-    unknown.write_text(json.dumps({"schema": "paddle_tpu.wat/9"}))
-    assert perfwatch.validate_file(str(unknown))
+    assert k["candidates_ms"]["pallas"] == pytest.approx(1.0)
 
 
 # ---------------------------------------------------------------------------

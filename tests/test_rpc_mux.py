@@ -131,8 +131,7 @@ def test_out_of_order_reply_overtakes_slow_call(stub):
 
 def test_legacy_mode_serializes_one_call_per_channel(stub):
     """mux=False restores the pre-PR-11 shape: with a single exclusive
-    channel the ping queues behind the slow call — the A/B baseline
-    the transport bench compares against."""
+    channel the ping queues behind the slow call."""
     cli = rpc.RpcClient(stub.endpoint, mux=False, pool_size=1)
     try:
         th = threading.Thread(
