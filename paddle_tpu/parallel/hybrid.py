@@ -1,4 +1,6 @@
-"""HybridParallelTrainStep: GPT training over a (dp, pp, tp) mesh.
+"""HybridParallelTrainStep: training over a (dp, pp, tp) mesh of the model
+it is handed (GPT by its GPTConfig, over every axis; any other model by the
+four things docs/TRAINING.md lists, on the axes that model says it runs).
 
 The TPU-native hybrid-parallel engine consumed by
 `fleet.distributed_optimizer` when `DistributedStrategy.pipeline` /
@@ -61,11 +63,52 @@ def make_hybrid_mesh(dp: int = 1, pp: int = 1, tp: int = 1, sp: int = 1,
                 ("pp", "dp", "sp", "ep", "tp"))
 
 
+class _GPTModel:
+    """GPT as the trainer's first model: what `HybridParallelTrainStep`
+    asks of any (docs/TRAINING.md). Its loss is the trainer's own
+    `loss_fn` (the pipeline, ring and expert contexts are the trainer's);
+    its parameters are drawn on the host, as they always were."""
+
+    decay = _DECAY
+    has_aux = False
+
+    def __init__(self, trainer):
+        self._t = trainer
+
+    def param_specs(self):
+        t = self._t
+        return G.gpt_param_specs(pp_stacked=t.pp > 1,
+                                 moe=t.cfg.num_experts > 0)
+
+    def init_params(self, seed, shardings):
+        t = self._t
+        params = jax.tree_util.tree_map(jnp.asarray,
+                                        G.init_gpt_params(t.cfg, seed))
+        if t.pp > 1:
+            lps = t.cfg.num_layers // t.pp
+            params["blocks"] = {
+                k: v.reshape(t.pp, lps, *v.shape[1:])
+                for k, v in params["blocks"].items()}
+        return jax.tree_util.tree_map(jax.device_put, params, shardings)
+
+    def loss(self, params, ids, key=None):
+        return self._t.loss_fn(params, ids, key)
+
+
 class HybridParallelTrainStep:
     """step(ids[B, T]) -> loss; B must divide by dp (and by
-    n_microbatches*dp when pp>1)."""
+    n_microbatches*dp when pp>1).
 
-    def __init__(self, cfg: G.GPTConfig, mesh: Mesh | None = None,
+    `model`: a `GPTConfig`, or an object with `param_specs()`,
+    `init_params(seed, shardings)` (made on the device), `decay` (names of
+    the leaves AdamW decays), `loss(params, ids, key)` and `parallel_axes`
+    (which of pp, tp, sp, ep it runs above 1; `refuse(axis, size)` says
+    what the others lack). A model with `has_aux` returns (loss,
+    {"chosen": int32 [layers, tokens, k]}): the step keeps the last one
+    (`last_chosen`, unread) and a tally of choices by expert
+    (`tally_stats()`)."""
+
+    def __init__(self, model, mesh: Mesh | None = None,
                  dp: int = 1, pp: int = 1, tp: int = 1, sp: int = 1,
                  ep: int = 1, n_microbatches: int | None = None, lr=1e-4,
                  weight_decay: float = 0.01, beta1: float = 0.9,
@@ -75,6 +118,12 @@ class HybridParallelTrainStep:
                  pipeline_schedule: str = "1F1B"):
         if mesh is None:
             mesh = make_hybrid_mesh(dp, pp, tp, sp, ep, devices)
+        if not isinstance(model, G.GPTConfig):
+            self._init_handed_in(model, mesh, (
+                lr, weight_decay, beta1, beta2, epsilon, grad_clip_norm,
+                seed))
+            return
+        cfg = model
         self.sp = mesh.shape.get("sp", 1)
         self.pp = mesh.shape.get("pp", 1)
         self.ep = mesh.shape.get("ep", 1)
@@ -139,33 +188,47 @@ class HybridParallelTrainStep:
         if cfg.num_layers % self.pp:
             raise ValueError(
                 f"num_layers={cfg.num_layers} not divisible by pp={self.pp}")
+        self._take(_GPTModel(self), mesh, sharding, (
+            lr, weight_decay, beta1, beta2, epsilon, grad_clip_norm, seed))
+
+    def _init_handed_in(self, model, mesh, hyper):
+        """A model other than GPT: the axes it does not run are refused by
+        name, the rest is `_take`."""
+        for axis in ("dp", "pp", "tp", "sp", "ep"):
+            n = mesh.shape.get(axis, 1)
+            if n > 1 and axis not in getattr(model, "parallel_axes", ()):
+                if hasattr(model, "refuse"):
+                    model.refuse(axis, n)
+                raise NotImplementedError(
+                    f"{type(model).__name__} does not run {axis}={n}")
+        self.sp = self.pp = self.ep = 1
+        self._schedule = "gpipe"
+        self.cfg = getattr(model, "cfg", None)
+        self.mesh = mesh
+        self.n_micro = 1
+        self._take(model, mesh, False, hyper)
+
+    def _take(self, model, mesh, sharding, hyper):
+        """The seam: parameters, their placement, which leaves decay and
+        the loss all come from `model`."""
+        lr, weight_decay, beta1, beta2, epsilon, grad_clip_norm, seed = hyper
+        self.model = model
         self._lr = lr
         self._seed = seed
         self._hyper = dict(beta1=beta1, beta2=beta2, epsilon=epsilon)
         self._wd = weight_decay
         self._clip = grad_clip_norm
 
-        params = jax.tree_util.tree_map(jnp.asarray,
-                                        G.init_gpt_params(cfg, seed))
-        if self.pp > 1:
-            lps = cfg.num_layers // self.pp
-            params["blocks"] = {
-                k: v.reshape(self.pp, lps, *v.shape[1:])
-                for k, v in params["blocks"].items()}
-        specs = G.gpt_param_specs(pp_stacked=self.pp > 1,
-                                  moe=cfg.num_experts > 0)
         self._specs = jax.tree_util.tree_map(
-            lambda s: _restrict(s, mesh), specs,
+            lambda s: _restrict(s, mesh), model.param_specs(),
             is_leaf=lambda s: isinstance(s, P))
         self._shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), self._specs,
             is_leaf=lambda s: isinstance(s, P))
-        self.params = jax.tree_util.tree_map(jax.device_put, params,
-                                             self._shardings)
-        names = {"wte": "wte", "wpe": "wpe", "lnf_s": "lnf_s",
-                 "lnf_b": "lnf_b",
-                 "blocks": {k: f"blocks.{k}" for k in params["blocks"]}}
-        self._names = names
+        self.params = model.init_params(seed, self._shardings)
+        # a leaf decays by its own name, whatever it is nested in
+        self._decays = [path[-1].key in model.decay for path, _leaf in
+                        jax.tree_util.tree_flatten_with_path(self.params)[0]]
         # ZeRO-1 (strategy.sharding): optimizer moments shard over the dp
         # axis on a free divisible dim — each dp rank owns 1/dp of the
         # Adam state and computes its slice of the update; GSPMD inserts
@@ -200,6 +263,11 @@ class HybridParallelTrainStep:
                       jax.device_put(jnp.ones((1,), jnp.float32), repl))
         self._batch_sharding = NamedSharding(
             mesh, P("dp", "sp") if self.sp > 1 else P("dp"))
+        self.last_chosen = None
+        self._tally = None
+        if getattr(model, "has_aux", False):
+            self._tally = jax.device_put(jnp.zeros(
+                (model.tally_layers, model.num_experts), jnp.int32), repl)
         self._jit_step = self._build(mesh)
 
     # ------------------------------------------------------------------
@@ -346,13 +414,14 @@ class HybridParallelTrainStep:
         hyper = dict(self._hyper)
         opdef.fill_default_attrs(hyper)
         wd, clip = self._wd, self._clip
-        names = self._names
+        decays = self._decays
         use_1f1b = self.pp > 1 and self._schedule == "1F1B"
 
         def grads_1f1b(params, ids, key):
             with self._trace_contexts():
                 return self._loss_and_grads_1f1b(params, ids, key)
 
+        @jax.named_scope("adamw")
         def apply_update(params, opt_state, pows, grads, lr):
             if clip:
                 leaves = jax.tree_util.tree_leaves(grads)
@@ -363,12 +432,12 @@ class HybridParallelTrainStep:
             lr_arr = jnp.asarray([lr], jnp.float32)
             b1p, b2p = pows
 
-            def upd(p, g, st, name):
+            def upd(p, g, st, decay):
                 ins = {"Param": [p], "Grad": [g], "LearningRate": [lr_arr],
                        "Moment1": [st["m1"]], "Moment2": [st["m2"]],
                        "Beta1Pow": [b1p], "Beta2Pow": [b2p]}
                 attrs = dict(hyper)
-                attrs["coeff"] = wd if name.split(".")[-1] in _DECAY else 0.0
+                attrs["coeff"] = wd if decay else 0.0
                 outs = opdef.compute(None, ins, attrs)
                 return (outs["ParamOut"][0],
                         {"m1": outs["Moment1Out"][0],
@@ -378,10 +447,9 @@ class HybridParallelTrainStep:
             flat_p, tdef = jax.tree_util.tree_flatten(params)
             flat_g = jax.tree_util.tree_leaves(grads)
             flat_s = tdef.flatten_up_to(opt_state)
-            flat_n = tdef.flatten_up_to(names)
             new_p, new_s = [], []
-            for p, g, st, n in zip(flat_p, flat_g, flat_s, flat_n):
-                np_, ns_, b1n, b2n = upd(p, g, st, n)
+            for p, g, st, decay in zip(flat_p, flat_g, flat_s, decays):
+                np_, ns_, b1n, b2n = upd(p, g, st, decay)
                 new_p.append(np_)
                 new_s.append(ns_)
             return (jax.tree_util.tree_unflatten(tdef, new_p),
@@ -412,8 +480,26 @@ class HybridParallelTrainStep:
             step2._jit_update = jit_update
             return step2
 
+        model = self.model
+        if self._tally is not None:
+            def step_aux(params, opt_state, pows, tally, ids, lr, key):
+                (loss, aux), grads = jax.value_and_grad(
+                    model.loss, has_aux=True)(params, ids, key)
+                new_p, new_s, new_pows = apply_update(
+                    params, opt_state, pows, grads, lr)
+                chosen = aux["chosen"]              # [layers, tokens, k]
+                hit = jax.vmap(lambda c: jnp.bincount(
+                    c.reshape(-1), length=tally.shape[1]))(chosen)
+                return (loss, new_p, new_s, new_pows,
+                        tally + hit.astype(tally.dtype), chosen)
+
+            return jax.jit(
+                step_aux, donate_argnums=(0, 1, 2, 3),
+                out_shardings=(repl, self._shardings, self._opt_shardings,
+                               (repl, repl), repl, repl))
+
         def step(params, opt_state, pows, ids, lr, key):
-            loss, grads = jax.value_and_grad(self.loss_fn)(
+            loss, grads = jax.value_and_grad(model.loss)(
                 params, ids, key)
             new_p, new_s, new_pows = apply_update(params, opt_state, pows,
                                                   grads, lr)
@@ -439,10 +525,30 @@ class HybridParallelTrainStep:
             key = jax.random.fold_in(jax.random.PRNGKey(self._seed),
                                      self._step_no)
             with _tracing.span("train.dispatch"):
-                loss, self.params, self.opt_state, self._pows = \
-                    self._jit_step(self.params, self.opt_state, self._pows,
-                                   ids, np.float32(lr), key)
+                if self._tally is not None:
+                    (loss, self.params, self.opt_state, self._pows,
+                     self._tally, self.last_chosen) = self._jit_step(
+                        self.params, self.opt_state, self._pows,
+                        self._tally, ids, np.float32(lr), key)
+                else:
+                    loss, self.params, self.opt_state, self._pows = \
+                        self._jit_step(self.params, self.opt_state,
+                                       self._pows, ids, np.float32(lr), key)
         return loss
+
+    def tally_stats(self):
+        """The routed model's counters since the trainer was built, read
+        from the device (it waits for the steps in flight): token-expert
+        pairs routed, pairs whose expert is held here, and each held
+        expert's count by layer. None for a model that routes nothing."""
+        if self._tally is None:
+            return None
+        tally = np.asarray(self._tally)
+        held = list(self.model.experts_held)
+        return {"pairs_routed": int(tally.sum()),
+                "pairs_held": int(tally[:, held].sum()),
+                "experts_held": held,
+                "held_counts": tally[:, held].tolist()}
 
     def unstacked_params(self):
         """Params with block leaves back at [L, ...] (for parity checks /

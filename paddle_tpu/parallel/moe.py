@@ -19,6 +19,7 @@ Design follows GShard/Switch-Transformer, shaped for the MXU:
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 
 import jax
@@ -26,7 +27,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["moe_capacity", "topk_gating", "moe_ffn", "moe_context",
-           "current_moe_mesh", "sigmoid_topk_route", "dropless_moe_ffn"]
+           "current_moe_mesh", "sigmoid_topk_route", "softmax_topk_route",
+           "dropless_moe_ffn"]
 
 _moe_stack: list[tuple[Mesh, str]] = []
 
@@ -181,6 +183,26 @@ def sigmoid_topk_route(h, wg, bias, top_k: int, norm_topk: bool = True,
     return sel.astype(jnp.int32), g * scale
 
 
+def softmax_topk_route(h, wg, bias, top_k: int, norm_topk: bool = True,
+                       scale: float = 1.0):
+    """Router of the softmax-scored kind (Qwen3-MoE's, Mellum's): p =
+    softmax(h wg) over all E in float32, the top_k of p are chosen, and
+    the weights are p over the chosen, normalised over ALL the chosen
+    whether or not their expert is held here (no epsilon: a softmax's top
+    k sum to more than k / E). No bias: the kind has none.
+
+    Returns as `sigmoid_topk_route`."""
+    if bias is not None:
+        raise ValueError("the softmax router has no selection bias")
+    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
+                               wg.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), axis=-1)
+    g, sel = jax.lax.top_k(p, top_k)
+    if norm_topk:
+        g = g / jnp.sum(g, axis=-1, keepdims=True)
+    return sel.astype(jnp.int32), g * scale
+
+
 def _held_index(num_experts: int, experts_held):
     """local[e] = row of expert e in the weights held here, or the count
     held for an expert that lives elsewhere."""
@@ -205,14 +227,51 @@ def _sorted_swiglu(h, local, g, w1, w3, w2, mm):
     order = jnp.argsort(flat, stable=True)
     inverse = jnp.argsort(order)
     sizes = jnp.bincount(flat, length=Eh + 1)[:Eh].astype(jnp.int32)
-    xs = h[order // k]                                       # [N*k, D]
+    # rows of pairs whose expert lives elsewhere belong to no group: the
+    # grouped products leave them unwritten, forward and backward
+    held = (flat[order] < Eh)[:, None]
+    xs = _pair_rows(h, order, inverse, held, k)              # [N*k, D]
     gated = jax.nn.silu(mm(xs, w1, sizes)) * mm(xs, w3, sizes)
     ys = mm(gated.astype(xs.dtype), w2, sizes)
-    # rows of pairs whose expert lives elsewhere belong to no group
-    ys = jnp.where((flat[order] < Eh)[:, None], ys, 0)
-    return jnp.einsum("nkd,nk->nd", ys[inverse].reshape(N, k, -1),
+    ys = jnp.where(held, ys, 0)
+    return jnp.einsum("nkd,nk->nd",
+                      _permute(ys, inverse, order).reshape(N, k, -1),
                       g.astype(jnp.float32),
                       preferred_element_type=jnp.float32)
+
+
+# Under autodiff a gather's transpose is a scatter-add of N*k rows of D,
+# which costs more than the products on a TPU. Both moves of rows are
+# permutations whose inverse is known, so each cotangent is a gather too.
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """x[perm], with inv the inverse permutation."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inv: (x[perm], (perm, inv)),
+                lambda res, ct: (ct[res[1]], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _pair_rows(h, order, inverse, held, k):
+    """Row n of h once for each of its k pairs, in the sorted order."""
+    return h[order // k]
+
+
+def _pair_rows_bwd(k, res, ct):
+    inverse, held = res
+    # a pair no expert here took was never written by the grouped
+    # products' backward either
+    ct = jnp.where(held, ct, 0)[inverse]
+    return (jnp.sum(ct.reshape(-1, k, ct.shape[-1]), axis=1,
+                    dtype=jnp.float32).astype(ct.dtype), None, None, None)
+
+
+_pair_rows.defvjp(
+    lambda h, order, inverse, held, k: (h[order // k], (inverse, held)),
+    _pair_rows_bwd)
 
 
 def _gmm_tiles(m: int, k: int, n: int):
@@ -225,22 +284,58 @@ def _gmm_tiles(m: int, k: int, n: int):
     return tm, k, tn
 
 
+def _tgmm_tiles(m: int, k: int, n: int):
+    """(tm, tk, tn) for `megablox.tgmm` (an expert's [k, n] weight
+    gradient, summed over its rows): the float32 accumulator is a [tk, tn]
+    tile, so the output is cut to a couple of MB where the forward's
+    tiles keep a whole contraction."""
+    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, m) if m % t == 0)
+    cut = lambda x: next((x // c for c in (1, 2, 3, 4, 6, 8)
+                          if x % c == 0 and x // c <= 1024
+                          and (x // c) % 128 == 0), x)
+    return tm, cut(k), cut(n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(x, w, sizes, interpret):
+    """megablox's grouped product with a backward at tiles of its own
+    (jax's own custom_vjp hands the forward's tiling to both backward
+    calls, where `_gmm_tiles`' whole contraction is the other
+    dimension)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return gmm(x, w, sizes, preferred_element_type=x.dtype,
+               tiling=_gmm_tiles(x.shape[0], *w.shape[1:]),
+               interpret=interpret)
+
+
+def _gmm_bwd(interpret, res, ct):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    x, w, sizes = res
+    m, (_, k, n) = x.shape[0], w.shape
+    dx = gmm(ct, w, sizes, preferred_element_type=x.dtype,
+             tiling=_gmm_tiles(m, n, k), transpose_rhs=True,
+             interpret=interpret)
+    dw = tgmm(x.swapaxes(0, 1), ct, sizes, preferred_element_type=w.dtype,
+              tiling=_tgmm_tiles(m, k, n), num_actual_groups=w.shape[0],
+              interpret=interpret)
+    return dx, dw, None
+
+
+_gmm.defvjp(lambda x, w, sizes, interpret:
+            (_gmm(x, w, sizes, interpret), (x, w, sizes)), _gmm_bwd)
+
+
 def grouped_swiglu_gmm(h, local, g, w1, w3, w2, interpret=None):
     """The sorted spelling over jax's own Pallas grouped matmul
     (`pallas.ops.tpu.megablox.gmm`, the kernel `lax.ragged_dot` lowers to
     on a TPU; `ragged_dot` itself runs it at jax's default tiles and lost
     at every row count, docs/KERNELS.md) at tiles chosen for these shapes.
-    Off the TPU the kernel is interpreted."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    Off the TPU the kernel is interpreted. Differentiable (`_gmm`)."""
     from ..ops.pallas_attention import on_tpu
     if interpret is None:
         interpret = not on_tpu()
-
-    def mm(x, w, sizes):
-        return gmm(x, w, sizes, preferred_element_type=x.dtype,
-                   tiling=_gmm_tiles(x.shape[0], *w.shape[1:]),
-                   interpret=interpret)
-    return _sorted_swiglu(h, local, g, w1, w3, w2, mm)
+    return _sorted_swiglu(h, local, g, w1, w3, w2,
+                          lambda x, w, sizes: _gmm(x, w, sizes, interpret))
 
 
 def grouped_swiglu_dense(h, local, g, w1, w3, w2):
@@ -309,7 +404,7 @@ def _auto_grouped(h, local, w1) -> str:
 def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
                      norm_topk: bool = True, scale: float = 1.0,
                      experts_held=None, impl: str | None = None,
-                     shared=None):
+                     shared=None, route: str = "sigmoid"):
     """Dropless routed SwiGLU layer over the experts held here.
 
     h [N, D]; wg [D, E] and bias [E] are the WHOLE router (it routes over
@@ -325,9 +420,21 @@ def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
     partition would compute it alike: give it to one, it counts once.
 
     impl: None = auto (see `_auto_grouped`), "gmm" or "dense".
+    route: "sigmoid" (`sigmoid_topk_route`) or "softmax"
+    (`softmax_topk_route`).
+
+    Differentiable in h, wg and the experts' weights, both spellings: a
+    pair whose expert lives elsewhere adds nothing forward and nothing to
+    any gradient but the router's, whose weights are normalised over all
+    the chosen.
     Returns (y [N, D] in h's dtype, sel [N, k] int32 global expert ids)."""
     E = wg.shape[1]
-    sel, g = sigmoid_topk_route(h, wg, bias, top_k, norm_topk, scale)
+    # by name at call time: the benchmark's fault tools replace a router
+    # as this module's attribute
+    router = {"sigmoid": sigmoid_topk_route,
+              "softmax": softmax_topk_route}[route]
+    with jax.named_scope("moe.route"):
+        sel, g = router(h, wg, bias, top_k, norm_topk, scale)
     table, Eh = _held_index(E, experts_held)
     if w1.shape[0] != Eh:
         raise ValueError(f"{w1.shape[0]} experts' weights for "

@@ -7,7 +7,7 @@ import pytest
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "paddle_tpu", "models")
-CORES = ("lfm2", "ouro", "deepseek_v3", "afmoe", "jamba")
+CORES = ("lfm2", "ouro", "deepseek_v3", "afmoe", "jamba", "mellum")
 
 
 def _sibling_imports(path):

@@ -393,6 +393,39 @@ def test_train_step_has_put_and_dispatch_as_children():
             ["train.put", "train.dispatch"]
 
 
+def test_a_routed_models_step_carries_its_scopes_and_its_tally():
+    """The step's program names its parts (`jax.named_scope`: what a
+    profile groups by) and the trainer keeps the tally `tally_stats()`
+    reads; the spans are the same three a step."""
+    import jax
+    from paddle_tpu.models import mellum
+    from paddle_tpu.parallel.hybrid import HybridParallelTrainStep
+    cfg = mellum.MellumConfig.tiny(experts_held=(0, 1, 2))
+    step = HybridParallelTrainStep(mellum.MellumTrainModel(cfg), seed=0,
+                                   devices=jax.devices()[:1])
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(2, 32))
+    text = step._jit_step.lower(
+        step.params, step.opt_state, step._pows, step._tally,
+        jax.numpy.asarray(ids), np.float32(1e-4),
+        jax.random.PRNGKey(0)).as_text(debug_info=True)
+    for scope in ("attn.band", "attn.full", "moe.route", "moe.experts",
+                  "head.loss", "adamw"):
+        assert scope in text, scope
+    TRACER.clear()
+    for _ in range(2):
+        loss = step(ids)
+    jax.block_until_ready(loss)
+    spans = TRACER.spans()
+    assert [s.name for s in spans if s.name.startswith("train.")].count(
+        "train.step") == 2
+    for st in (s for s in spans if s.name == "train.step"):
+        assert [k.name for k in _children(spans, st)] == \
+            ["train.put", "train.dispatch"]
+    t = step.tally_stats()
+    assert t["pairs_routed"] == 2 * 4 * 64 * 2
+    assert len(t["held_counts"]) == 4 and len(t["held_counts"][0]) == 3
+
+
 # -- Tracer -----------------------------------------------------------------
 
 def test_record_obeys_the_ring_the_sink_and_the_switch():
