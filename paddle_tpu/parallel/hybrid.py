@@ -266,8 +266,13 @@ class HybridParallelTrainStep:
         self.last_chosen = None
         self._tally = None
         if getattr(model, "has_aux", False):
-            self._tally = jax.device_put(jnp.zeros(
-                (model.tally_layers, model.num_experts), jnp.int32), repl)
+            # choices by layer and expert; by layer, the steps whose held
+            # pairs passed the expert layer's bound on its sorted rows
+            self._tally = jax.device_put(
+                {"chosen": jnp.zeros((model.tally_layers, model.num_experts),
+                                     jnp.int32),
+                 "over_bound": jnp.zeros((model.tally_layers,), jnp.int32)},
+                repl)
         self._jit_step = self._build(mesh)
 
     # ------------------------------------------------------------------
@@ -489,9 +494,14 @@ class HybridParallelTrainStep:
                     params, opt_state, pows, grads, lr)
                 chosen = aux["chosen"]              # [layers, tokens, k]
                 hit = jax.vmap(lambda c: jnp.bincount(
-                    c.reshape(-1), length=tally.shape[1]))(chosen)
+                    c.reshape(-1), length=model.num_experts))(
+                        chosen).astype(jnp.int32)
+                over = jnp.sum(hit[:, jnp.asarray(model.experts_held)],
+                               axis=1) > self._rows_bound(chosen.shape)
                 return (loss, new_p, new_s, new_pows,
-                        tally + hit.astype(tally.dtype), chosen)
+                        {"chosen": tally["chosen"] + hit,
+                         "over_bound": tally["over_bound"]
+                         + over.astype(jnp.int32)}, chosen)
 
             return jax.jit(
                 step_aux, donate_argnums=(0, 1, 2, 3),
@@ -536,19 +546,35 @@ class HybridParallelTrainStep:
                                        self._pows, ids, np.float32(lr), key)
         return loss
 
+    def _rows_bound(self, chosen_shape) -> int:
+        """The expert layer's bound on its sorted rows
+        (`moe.held_rows_bound`) at a step's [layers, tokens, k]."""
+        from .moe import held_rows_bound
+        _layers, tokens, k = chosen_shape
+        return held_rows_bound(tokens, k, len(self.model.experts_held),
+                               self.model.num_experts)
+
     def tally_stats(self):
         """The routed model's counters since the trainer was built, read
         from the device (it waits for the steps in flight): token-expert
-        pairs routed, pairs whose expert is held here, and each held
-        expert's count by layer. None for a model that routes nothing."""
+        pairs routed, pairs whose expert is held here, each held expert's
+        count by layer; the steps taken, the expert layer's bound on the
+        rows of its sorted arrays (None before the first step) and, by
+        layer, the steps whose held pairs passed it (which took the
+        whole-size path). None for a model that routes nothing."""
         if self._tally is None:
             return None
-        tally = np.asarray(self._tally)
+        tally = np.asarray(self._tally["chosen"])
         held = list(self.model.experts_held)
         return {"pairs_routed": int(tally.sum()),
                 "pairs_held": int(tally[:, held].sum()),
                 "experts_held": held,
-                "held_counts": tally[:, held].tolist()}
+                "held_counts": tally[:, held].tolist(),
+                "steps": getattr(self, "_step_no", 0),
+                "rows_bound": None if self.last_chosen is None
+                else self._rows_bound(self.last_chosen.shape),
+                "layer_steps_over_bound":
+                    np.asarray(self._tally["over_bound"]).tolist()}
 
     def unstacked_params(self):
         """Params with block leaves back at [L, ...] (for parity checks /

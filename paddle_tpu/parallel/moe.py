@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["moe_capacity", "topk_gating", "moe_ffn", "moe_context",
            "current_moe_mesh", "sigmoid_topk_route", "softmax_topk_route",
-           "dropless_moe_ffn"]
+           "dropless_moe_ffn", "held_rows_bound"]
 
 _moe_stack: list[tuple[Mesh, str]] = []
 
@@ -215,24 +215,60 @@ def _held_index(num_experts: int, experts_held):
     return jnp.asarray(local, jnp.int32), len(held)
 
 
-def _sorted_swiglu(h, local, g, w1, w3, w2, mm):
+# The rows a share's sorted arrays hold, as a multiple of its even part of
+# the pairs (N k Eh / E). 2 from the cell this was built for (PERF.md
+# section 6, PR 46 and 47: 16 of 64 softmax-routed experts held, seeded
+# weights, 131,072 pairs a layer): over a run's steps a layer held up to
+# 30.3% of the pairs against an even 25% (34.2% with a router collapsed at
+# a hundred times the cell's rate), and at 3/2 (37.5%) one layer-step of a
+# traced run's 368 still passed the bound; 2 holds 50%. A routing over the
+# bound takes the whole-size path: the factor buys speed, never
+# correctness, so it is no argument and no option.
+_HELD_ROWS_OVER_EVEN = (2, 1)
+_HELD_ROWS_MULTIPLE = 512       # `_gmm_tiles` / `_tgmm_tiles` keep their rows
+
+
+def held_rows_bound(N: int, k: int, Eh: int, E: int) -> int:
+    """Rows of the arrays in sorted pair order, from shapes alone: all N k
+    where every expert is held, else twice the share's even part of the
+    pairs, rounded up to 512 rows and never more than N k."""
+    pairs = N * k
+    num, den = _HELD_ROWS_OVER_EVEN
+    rows = -(-num * pairs * Eh // (den * E))
+    return min(pairs, -(-rows // _HELD_ROWS_MULTIPLE) * _HELD_ROWS_MULTIPLE)
+
+
+def _sorted_swiglu(h, local, g, w1, w3, w2, interpret, rows=None,
+                   out_dtype=jnp.float32):
     """Sort the token-expert pairs by expert, one grouped product per
-    projection over the sorted rows (`mm(x, w, sizes)`), unsort, weighted
-    sum. Work is N*k rows whatever the routing. Rows move by gathers only
-    (the unsort reads through the inverse permutation): a scatter of N*k
-    rows of D costs more than the products on a TPU."""
+    projection over the sorted rows (`_gmm`), unsort, weighted sum. Rows
+    move by gathers only (the unsort reads through the inverse
+    permutation): a scatter of N*k rows of D costs more than the products
+    on a TPU.
+
+    The sort puts the pairs held here first. `rows` (a static bound on
+    them, `held_rows_bound`) below N*k keeps every sorted array at `rows`
+    rows where the routing fits, and takes all N*k where it does not
+    (`_bounded_rows`): no pair is dropped. Without it, work is N*k rows
+    whatever the routing. The bounded arrays' sum is handed back in
+    `out_dtype`, so that its cotangent arrives, and is gathered, in it:
+    the caller's own dtype where it would cast the float32 sum at once."""
     N, k = local.shape
     Eh = w1.shape[0]
     flat = local.reshape(-1)
     order = jnp.argsort(flat, stable=True)
     inverse = jnp.argsort(order)
     sizes = jnp.bincount(flat, length=Eh + 1)[:Eh].astype(jnp.int32)
+    if rows is not None and rows < N * k:
+        return _bounded_rows(interpret, rows, jnp.dtype(out_dtype), h, order,
+                             inverse, sizes, g, w1, w3, w2)
+    mm = lambda x, w: _gmm(x, w, sizes, interpret)
     # rows of pairs whose expert lives elsewhere belong to no group: the
     # grouped products leave them unwritten, forward and backward
     held = (flat[order] < Eh)[:, None]
     xs = _pair_rows(h, order, inverse, held, k)              # [N*k, D]
-    gated = jax.nn.silu(mm(xs, w1, sizes)) * mm(xs, w3, sizes)
-    ys = mm(gated.astype(xs.dtype), w2, sizes)
+    gated = jax.nn.silu(mm(xs, w1)) * mm(xs, w3)
+    ys = mm(gated.astype(xs.dtype), w2)
     ys = jnp.where(held, ys, 0)
     return jnp.einsum("nkd,nk->nd",
                       _permute(ys, inverse, order).reshape(N, k, -1),
@@ -325,23 +361,130 @@ _gmm.defvjp(lambda x, w, sizes, interpret:
             (_gmm(x, w, sizes, interpret), (x, w, sizes)), _gmm_bwd)
 
 
-def grouped_swiglu_gmm(h, local, g, w1, w3, w2, interpret=None):
+# A strict share's sorted arrays at a bound's rows. The sorted order's
+# first M pairs are all the held ones where `sum(sizes) <= M`; the same
+# products over the same rows in the same groups as the whole-size text
+# above, and the same float32 sums with exact zeros where a pair is held
+# elsewhere. Only two arrays have N*k rows, forward and backward: what
+# fans back out to every pair's slot.
+
+def _fan_out(rows, inverse, total):
+    """[N*k, .]: each pair's row among the first `total` of `rows`, by a
+    gather through the inverse permutation; a pair past `total` has no row
+    (its expert lives elsewhere, or the grouped products left its row
+    unwritten) and reads an exact zero."""
+    at = jnp.minimum(inverse, rows.shape[0] - 1)
+    return jnp.where((inverse < total)[:, None], rows[at], 0)
+
+
+def _swiglu(a, b, dtype):
+    return (jax.nn.silu(a) * b).astype(dtype)
+
+
+def _sorted_products(interpret, M, h, order, sizes, k, w1, w3, w2):
+    """The sorted order's first M pairs through their experts:
+    (xs [M, D], a, b, gated [M, F], ys [M, D])."""
+    xs = h[order[:M] // k]
+    a, b = _gmm(xs, w1, sizes, interpret), _gmm(xs, w3, sizes, interpret)
+    gated = _swiglu(a, b, xs.dtype)
+    return xs, a, b, gated, _gmm(gated, w2, sizes, interpret)
+
+
+def _rows_fwd(interpret, M, out_dtype, h, order, inverse, sizes, g, w1, w3,
+              w2):
+    N, k = g.shape
+    ys = _sorted_products(interpret, M, h, order, sizes, k, w1, w3, w2)[-1]
+    # behind a barrier, or the compiler moves the sum out of the `cond`
+    # and the fan-out's select is written out whole as the branch's result
+    return jax.lax.optimization_barrier(jnp.einsum(
+        "nkd,nk->nd", _fan_out(ys, inverse, jnp.sum(sizes)).reshape(N, k, -1),
+        g.astype(jnp.float32),
+        preferred_element_type=jnp.float32).astype(out_dtype))
+
+
+def _rows_bwd(interpret, M, h, order, inverse, sizes, g, w1, w3, w2, dy):
+    """Cotangents of (h, g, w1, w3, w2) for dy [N, D], the forward
+    recomputed. Each cotangent in sorted order is a gather of M rows (the
+    weighted sum's own would be a broadcast [N, k, D], then a gather of
+    it), and the grouped products' are `_gmm_bwd`'s."""
+    N, k = g.shape
+    total = jnp.sum(sizes)
+    top = order[:M]
+    live = (jnp.arange(M) < total)[:, None]
+    xs, a, b, gated, ys = _sorted_products(interpret, M, h, order, sizes, k,
+                                           w1, w3, w2)
+    dyr = dy[top // k].astype(jnp.float32)                   # [M, D]
+    dys = jnp.where(live, dyr * g.reshape(-1)[top][:, None],
+                    0).astype(ys.dtype)
+    dgs = jnp.sum(dyr * jnp.where(live, ys, 0).astype(jnp.float32), axis=1,
+                  keepdims=True)
+    dg = _fan_out(dgs, inverse, total).reshape(N, k).astype(g.dtype)
+    dgated, dw2, _ = _gmm_bwd(interpret, (gated, w2, sizes), dys)
+    da, db = jax.vjp(functools.partial(_swiglu, dtype=xs.dtype), a, b)[1](
+        dgated)
+    dxa, dw1, _ = _gmm_bwd(interpret, (xs, w1, sizes), da)
+    dxb, dw3, _ = _gmm_bwd(interpret, (xs, w3, sizes), db)
+    dxs = _fan_out(dxa + dxb, inverse, total)
+    dh = jnp.sum(dxs.reshape(N, k, -1), axis=1,
+                 dtype=jnp.float32).astype(dxs.dtype)
+    return dh, dg, dw1, dw3, dw2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_rows(interpret, M, out_dtype, h, order, inverse, sizes, g, w1,
+                  w3, w2):
+    """The sorted arrays at M rows where the held pairs fit them, at all
+    N*k where they do not: one algorithm at two sizes, the choice a
+    `lax.cond` on the routing. One `custom_vjp` over it: differentiated as
+    it stands, a `cond` saves the union of its branches' residuals and
+    fills the untaken branch's with zeros, whole-size arrays written on the
+    short path. The backward holds its own `cond` and recomputes inside it
+    (under `jax.checkpoint` the forward it replaces is dead code), and
+    calls the grouped products as the forward does, so that a profile
+    shows them under the same names."""
+    at = lambda rows: functools.partial(_rows_fwd, interpret, rows, out_dtype)
+    return jax.lax.cond(jnp.sum(sizes) <= M, at(M), at(inverse.shape[0]),
+                        h, order, inverse, sizes, g, w1, w3, w2)
+
+
+def _bounded_rows_bwd(interpret, M, out_dtype, res, dy):
+    at = lambda rows: functools.partial(_rows_bwd, interpret, rows)
+    _h, _order, inverse, sizes = res[:4]
+    # behind a barrier: the compiler otherwise moves what reads the
+    # gradients into both branches, where each then writes the stacked
+    # layers' float32 gradient whole (1.6 GB a branch at Mellum2's sizes)
+    dh, dg, dw1, dw3, dw2 = jax.lax.optimization_barrier(jax.lax.cond(
+        jnp.sum(sizes) <= M, at(M), at(inverse.shape[0]), *res, dy))
+    return dh, None, None, None, dg, dw1, dw3, dw2
+
+
+_bounded_rows.defvjp(
+    lambda interpret, M, out_dtype, *args:
+    (_bounded_rows(interpret, M, out_dtype, *args), args), _bounded_rows_bwd)
+
+
+def grouped_swiglu_gmm(h, local, g, w1, w3, w2, interpret=None, rows=None,
+                       out_dtype=jnp.float32):
     """The sorted spelling over jax's own Pallas grouped matmul
     (`pallas.ops.tpu.megablox.gmm`, the kernel `lax.ragged_dot` lowers to
     on a TPU; `ragged_dot` itself runs it at jax's default tiles and lost
     at every row count, docs/KERNELS.md) at tiles chosen for these shapes.
-    Off the TPU the kernel is interpreted. Differentiable (`_gmm`)."""
+    Off the TPU the kernel is interpreted. Differentiable (`_gmm`).
+    `rows`, `out_dtype`: `_sorted_swiglu`'s bound on the sorted arrays and
+    the dtype of their sum."""
     from ..ops.pallas_attention import on_tpu
     if interpret is None:
         interpret = not on_tpu()
-    return _sorted_swiglu(h, local, g, w1, w3, w2,
-                          lambda x, w, sizes: _gmm(x, w, sizes, interpret))
+    return _sorted_swiglu(h, local, g, w1, w3, w2, interpret, rows,
+                          out_dtype)
 
 
-def grouped_swiglu_dense(h, local, g, w1, w3, w2):
+def grouped_swiglu_dense(h, local, g, w1, w3, w2, rows=None,
+                         out_dtype=jnp.float32):
     """Every expert held on every row, then a masked weighted sum: E/k
-    times the products and no sort. At decode (a few rows an expert) the
-    layer is bound by streaming the experts' weights either way."""
+    times the products and no sort (so nothing for `rows` to bound). At
+    decode (a few rows an expert) the layer is bound by streaming the
+    experts' weights either way."""
     Eh = w1.shape[0]
     a = jnp.einsum("nd,edf->enf", h, w1)
     b = jnp.einsum("nd,edf->enf", h, w3)
@@ -412,7 +555,9 @@ def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
     `experts_held` (global ids, in the order of the rows; default all E).
     The result is the part of the layer's output that these experts give:
     over the shares of a partition of the experts the parts add up to the
-    whole layer. No pair is dropped.
+    whole layer. No pair is dropped: where a strict share is held, the
+    sorted spelling's arrays hold `held_rows_bound` rows and a routing that
+    puts more pairs on the share takes the whole-size path.
 
     shared: (w1 [D, Fs], w3 [D, Fs], w2 [Fs, D]) of the SHARED experts
     (DeepSeek-V3's: one SwiGLU of n_shared x F that every token takes,
@@ -442,7 +587,12 @@ def dropless_moe_ffn(h, wg, bias, w1, w3, w2, *, top_k: int,
     local = sel if table is None else table[sel]
     if impl is None:
         impl = _auto_grouped(h, local, w1)
-    y = _GROUPED[impl](h, local, g, w1, w3, w2)
+    # the sum is cast to h's dtype below; with nothing added to it first,
+    # the bounded arrays may as well hand it back so
+    y = _GROUPED[impl](h, local, g, w1, w3, w2,
+                       rows=held_rows_bound(h.shape[0], top_k, Eh, E),
+                       out_dtype=jnp.float32 if shared is not None
+                       else h.dtype)
     if shared is not None:
         s1, s3, s2 = shared
         y = y + jnp.dot((jax.nn.silu(h @ s1) * (h @ s3)), s2,

@@ -112,7 +112,9 @@ def main():
     t._shardings = jax.tree_util.tree_map(lambda _s: repl, params)
     t._opt_shardings = jax.tree_util.tree_map(
         lambda _s: {"m1": repl, "m2": repl}, params)
-    t._tally = spec((cfg.num_hidden_layers, cfg.num_experts), jnp.int32)
+    t._tally = {"chosen": spec((cfg.num_hidden_layers, cfg.num_experts),
+                               jnp.int32),
+                "over_bound": spec((cfg.num_hidden_layers,), jnp.int32)}
     step = t._build(mesh)
     opt = jax.tree_util.tree_map(lambda p: {"m1": p, "m2": p}, params)
     pows = (spec((1,), jnp.float32), spec((1,), jnp.float32))

@@ -206,3 +206,70 @@ def test_a_jamba_decode_program_touches_a_layers_state_in_one_operation(
     assert len(ops) == 3 * ops_a_layer, ops
     if step == "pallas":
         assert all(o.startswith("%selective_step") for o in ops), ops
+
+
+def _computation(text, name):
+    """The instructions of the computation `name` of a compiled module."""
+    m = re.search(r"\n(?:ENTRY )?" + re.escape(name) + r" \(.*?\n\}\n", text,
+                  re.S)
+    return m.group(0).split("\n")[2:-2]
+
+
+def test_a_ranks_expert_layer_keeps_its_sorted_arrays_at_the_bound(
+        one_chip, monkeypatch):
+    """One expert layer of the Mellum cell (16,384 tokens, 8 of 64 experts
+    a token, 16 held: 131,072 pairs, a bound of 65,536 rows), output and
+    gradients, as the chip's compiler leaves it (PR 47). Two `cond`s, the
+    forward's and the backward's; in the branch that runs where the held
+    pairs fit the bound ONE array of 131,072 rows of 2,304 is written (what
+    fans back out to every pair's slot) and none of 896, against three or
+    more of each in the other branch; outside the `cond`s none at all (a
+    `cond` differentiated as it stands writes the untaken branch's
+    residuals as zeros there). Every Mosaic call is named `gmm` / `tgmm`,
+    which is how the benchmark's readers take the grouped products from a
+    profile (`benchmark/readers/routed_train.py`): traced under `jax.vjp`
+    they come out as `jvp_jit_gmm__`."""
+    from paddle_tpu.ops import pallas_attention
+    from paddle_tpu.parallel import moe
+    monkeypatch.setattr(pallas_attention, "on_tpu", lambda: True)
+    N, k, D, F, E, Eh = 16384, 8, 2304, 896, 64, 16
+    rows = moe.held_rows_bound(N, k, Eh, E)
+    assert (N * k, rows) == (131072, 65536)
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+
+    def layer(h, wg, w1, w3, w2):
+        y, _sel = moe.dropless_moe_ffn(
+            h, wg, None, w1, w3, w2, top_k=k, experts_held=tuple(range(Eh)),
+            impl="gmm", route="softmax")
+        return jnp.sum(y.astype(jnp.float32) ** 2), y
+
+    # tests/conftest.py asks every product for float32 passes, which
+    # Mosaic's bf16 products have not
+    with jax.default_matmul_precision("default"):
+        c = _compile(
+            jax.value_and_grad(layer, (0, 1, 2, 3, 4), has_aux=True),
+            sds((N, D)), sds((D, E), jnp.float32), sds((Eh, D, F)),
+            sds((Eh, D, F)), sds((Eh, F, D)))
+    text = c.as_text()
+    calls = re.findall(r"(%[\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    # forward 3, backward 3 again and 6 of its own, in either branch
+    assert len(calls) == 2 * 12, calls
+    assert all(re.match(r"%t?gmm[.\d]*$", c) for c in calls), calls
+    branches = re.findall(r"branch_computations=\{(%[\w.\-]+), (%[\w.\-]+)\}",
+                          text)
+    assert len(branches) == 2, branches
+    wide = lambda lines, width: [
+        ln for ln in lines
+        if re.match(rf"\s+(ROOT )?%[\w.\-]+ = \(?bf16\[{N * k},{width}\]", ln)
+        and " parameter(" not in ln and " get-tuple-element(" not in ln
+        and " bitcast(" not in ln]
+    for whole, short in branches:           # (false, true)
+        assert len(wide(_computation(text, short), D)) == 1
+        assert wide(_computation(text, short), F) == []
+        assert len(wide(_computation(text, whole), D)) >= 3
+        assert len(wide(_computation(text, whole), F)) >= 3
+    entry = re.search(r"\nENTRY (%[\w.\-]+) ", text).group(1)
+    assert wide(_computation(text, entry), D) == []
+    assert wide(_computation(text, entry), F) == []

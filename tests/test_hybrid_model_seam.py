@@ -70,6 +70,43 @@ def test_a_handed_in_model_trains_and_keeps_its_tally():
         "k_norm"}
 
 
+@pytest.mark.parametrize("router,over", [("seeded", 0), ("onto_held", 3)])
+def test_the_tally_counts_the_steps_over_the_rows_bound(monkeypatch, router,
+                                                        over):
+    """Beside its tally the trainer counts, by layer, the steps whose held
+    pairs passed the expert layer's bound on its sorted rows
+    (`moe.held_rows_bound`, from the same shapes): none on the seeded
+    weights, every layer of every step for a router forced onto the held
+    experts (replaced as the benchmark's fault tools replace one)."""
+    from paddle_tpu.parallel import moe
+    cfg = mellum.MellumConfig.tiny(experts_held=(0, 1))
+    if router == "onto_held":
+        real = moe.softmax_topk_route
+
+        def onto_held(h, wg, bias, top_k, *a, **kw):
+            sel, g = real(h, wg, bias, top_k, *a, **kw)
+            return jax.numpy.broadcast_to(
+                jax.numpy.arange(top_k, dtype=sel.dtype), sel.shape), g
+        monkeypatch.setattr(moe, "softmax_topk_route", onto_held)
+    step = HybridParallelTrainStep(mellum.MellumTrainModel(cfg), seed=3,
+                                   lr=1e-6, devices=jax.devices()[:1])
+    before = step.tally_stats()
+    assert before["steps"] == 0 and before["rows_bound"] is None
+    assert before["layer_steps_over_bound"] == [0] * 4
+    for ids in _batches(cfg.vocab_size, (2, 256), 3):
+        step(ids)
+    t = step.tally_stats()
+    # 1,024 pairs a layer, a quarter of the experts held: twice 256 rows
+    assert t["rows_bound"] == 512 == moe.held_rows_bound(512, 2, 2, 8)
+    assert t["steps"] == 3
+    assert t["layer_steps_over_bound"] == [over] * 4
+    held_a_layer = [sum(row) for row in t["held_counts"]]
+    if over:
+        assert held_a_layer == [3 * 1024] * 4
+    else:
+        assert max(held_a_layer) <= 3 * t["rows_bound"]
+
+
 @pytest.mark.parametrize("axis", ["pp", "tp", "ep", "sp", "dp"])
 def test_the_new_model_refuses_other_axes_by_name(axis):
     cfg = mellum.MellumConfig.tiny()

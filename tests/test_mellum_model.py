@@ -144,3 +144,34 @@ def test_a_band_is_not_the_triangle_and_yarn_is_not_plain():
     a = float(M.loss_and_chosen(params, ids, cfg)[0])
     b = float(M.loss_and_chosen(params, ids, full)[0])
     assert abs(a - b) > 1e-4
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_model_trains_through_the_bounded_sorted_arrays(monkeypatch,
+                                                            remat):
+    """At 2 x 256 tokens a layer has 1,024 pairs and a share of 2 of 8
+    experts a bound of 512 rows (`moe.held_rows_bound`): the sorted
+    spelling of the grouped products, which the chip takes, then runs its
+    bounded arrays inside every rematerialised layer. The loss and every
+    leaf's gradient are the dense spelling's (every expert held on every
+    row: no sort, no bound), which is what a CPU takes."""
+    from paddle_tpu.parallel import moe
+    cfg = M.MellumConfig.tiny(experts_held=(0, 1), remat=remat)
+    sizes = dict(SIZES, experts_held=2)
+    params = R.make_weights(sizes, 7, jnp.float32)
+    ids = _ids(2, 256, seed=2)
+    assert moe.held_rows_bound(512, 2, 2, 8) == 512 < 1024
+
+    def run(impl):
+        monkeypatch.setattr(moe, "_auto_grouped", lambda *a: impl)
+        return jax.value_and_grad(
+            lambda p: M.loss_and_chosen(p, ids, cfg), has_aux=True)(params)
+
+    (loss, chosen), grads = run("gmm")
+    (want, want_chosen), want_grads = run("dense")
+    np.testing.assert_array_equal(chosen, want_chosen)
+    assert abs(float(loss) - float(want)) < 1e-6
+    flat = jax.tree_util.tree_leaves_with_path
+    for (path, a), (_, b) in zip(flat(grads), flat(want_grads)):
+        gap = float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-12))
+        assert gap < 1e-5, (jax.tree_util.keystr(path), gap)
