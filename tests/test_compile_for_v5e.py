@@ -114,9 +114,45 @@ def test_the_flash_forward_compiles_at_the_longest_prefill_bucket(
     with jax.default_matmul_precision("default"):
         c = _compile(call, sds(192), sds(192), sds(128))
         assert "tpu_custom_call" in c.as_text()
+        # the shortest bucket, 1,024: its four q blocks one grid step
+        short = lambda d: jax.ShapeDtypeStruct((1, 32, 1024, d),
+                                               jnp.bfloat16,
+                                               sharding=one_chip)
+        assert "tpu_custom_call" in _compile(
+            call, short(192), short(192), short(128)).as_text()
         with pytest.raises(Exception, match="vmem"):
             _compile(lambda q, k, v: call(q, k, v, 512), sds(192), sds(192),
                      sds(128))
+
+
+@pytest.mark.parametrize("B,H,Hkv,T,d,window", [
+    (16, 16, 16, 1024, 64, None),       # gpt_350m_train.b16s1024
+    (2, 8, 8, 1024, 128, None),         # gpt_1p3b_train_pp2tp2, a shard
+    (2, 32, 4, 8192, 128, 1024),        # Mellum's three banded layers of four
+    (2, 32, 4, 8192, 128, None),        # Mellum's full layer
+], ids=["gpt_350m", "gpt_1p3b_shard", "mellum_band", "mellum_full"])
+def test_the_flash_calls_compile_at_the_training_cells_shapes(
+        one_chip, monkeypatch, B, H, Hkv, T, d, window):
+    """Forward, dq and dk/dv as the training step calls them, tiles of
+    512: three Mosaic calls. At 1,024 positions a head's two q blocks
+    (K blocks) are one grid step with Python's block indices, no loop
+    left; at 8,192 the tiles no edge crosses are a `fori_loop` and a
+    band's three tiles a row block are spelt out, the one before the
+    sequence's start under a traced flag; VMEM within its limit."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    assert pallas_attention._per_step(T, 512, True) == (2 if T == 1024 else 1)
+    sds = lambda h: jax.ShapeDtypeStruct((B, h, T, d), jnp.bfloat16,
+                                         sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(pallas_attention.flash_attention(
+            *a, causal=True, window=window).astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("default"):
+        c = _compile(grads, sds(H), sds(Hkv), sds(Hkv))
+    assert c.as_text().count("tpu_custom_call") == 3
 
 
 def _ops_naming(text, pattern):

@@ -97,17 +97,21 @@ def test_a_band_needs_causal_self_attention():
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_the_plain_call_is_the_program_it_was(causal):
-    """No band and one query head a KV head: the three pallas_calls carry
-    no name and no compiler parameters, the dk/dv call is the
-    whole-sequence kernel, and the lowered text does not change with the
-    new arguments' defaults. Its results equal the grouped path's at G = 1
-    forced through the span kernel, to the last bit of the forward."""
+    """No band and one query head a KV head: the three pallas_calls (and
+    three they stay: the benchmark's roofline takes their mean) carry no
+    name and no compiler parameters, and the dk/dv call is the
+    whole-sequence kernel. Its results equal the grouped path's at G = 1
+    forced through the span kernel, to the last bit: both kernels add a
+    K tile's q blocks in the order of the sequence, and the mask the span
+    kernel puts on the tiles past the diagonal changes no score."""
     q, k, v, w = _inputs(256, 1)
     f = lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=causal, block_q=64, block_k=64) * w)
     text = jax.jit(jax.grad(f, (0, 1, 2))).lower(q, k, v).as_text()
     assert "flash_" not in text
     assert pa._named("fwd", None, 1) == {}
+    assert str(jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, k, v)).count(
+        "pallas_call") == 3
     if causal:
         # the same numbers whichever dk/dv kernel: blocks are summed in
         # the same order
