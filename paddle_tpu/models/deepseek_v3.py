@@ -1,7 +1,9 @@
 """DeepSeek-V3 block, functional core (`model_type: deepseek_v3`; defaults:
 Kakao kanana-2-30b-a3b, the block without the query's low-rank step):
 multi-head LATENT attention and, after `first_k_dense_replace` dense
-layers, routed experts beside shared ones.
+layers, routed experts beside shared ones. Three keys a configuration may
+set beside kanana's (`model_type: xing4_0` sets all three) are at the end
+of this text.
 
     x = E[ids]
     for l in layers:
@@ -37,6 +39,18 @@ for. Attention therefore has TWO forms of one function:
             own first C numbers: nothing is expanded. The serving decode
             (`absorb_query`, `expand_value` round the paged kernel).
 
+With `q_lora_rank` the query takes a low-rank step, q = rmsnorm(h Wq_a,
+gq) Wq_b. With `rope_scaling` (YaRN) both rotations read the blended
+frequency table (`layers.yarn_inv_freq`), cos and sin times mscale(factor,
+mscale) / mscale(factor, mscale_all_dim), and the scores' scale carries
+mscale(factor, mscale_all_dim)^2, in BOTH forms (`softmax_scale`; a YaRN
+dict without both keys, or with an `attention_factor`, is refused). With
+`hc_mult` n the residual is n streams, a tuple of n arrays [B, T, D]:
+every sub-layer reads a mix of them and writes to all, x = hc_write(x,
+res, post, F(hc_read(x, pre))) in place of x = x + F(x)
+(`layers.hc_coefficients`, `hc_read`, `hc_write`; the embedding is copied
+into every stream, and the streams are summed before the final norm).
+
 The layer is written once (`apply_layers`, an unrolled loop: the first
 layer's feed-forward is of another kind) and takes `attend(p, q_nope,
 q_rope, c, kr, state, l) -> (a [B, T, H dv], state)`: what is kept of
@@ -61,13 +75,16 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .layers import dense_ffn, rmsnorm, routed_ffn, seeded_tree
+from .layers import (dense_ffn, hc_coefficients, hc_read, hc_shapes,
+                     hc_write, rmsnorm, routed_ffn, seeded_tree,
+                     yarn_inv_freq)
 
 __all__ = ["DeepseekV3Config", "init_params", "forward", "apply_layers",
            "expanded_attention", "absorb_query", "expand_value",
            "rope_pairs", "head_logits"]
 
 _ROW_BLOCK = 512        # query rows at a time off the flash kernel
+_FFN_ROW_BLOCK = 8192   # positions of the streams' feed-forward sub-layer
 
 
 @dataclass(frozen=True)
@@ -88,6 +105,14 @@ class DeepseekV3Config:
     v_head_dim: int = 128
     kv_lora_rank: int = 512
     q_lora_rank: int | None = None
+    # YaRN, as `config.json` spells it (a dict; kept as sorted pairs)
+    rope_scaling: tuple | None = None
+    # residual streams mixed by hyper-connections (None: one, summed)
+    hc_mult: int | None = None
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     n_group: int = 1
     topk_group: int = 1
     norm_topk_prob: bool = True
@@ -101,10 +126,21 @@ class DeepseekV3Config:
     experts_held: tuple | None = None
 
     def __post_init__(self):
-        if self.q_lora_rank is not None:
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.rope_scaling is not None and self.yarn.get("type") != "yarn":
             raise NotImplementedError(
-                f"q_lora_rank {self.q_lora_rank}: the query's low-rank "
-                f"step is not built (one projection D -> H (dn + dr))")
+                f"rope_scaling {self.yarn}: only YaRN is built")
+        if self.rope_scaling is not None and (
+                "attention_factor" in self.yarn or not (
+                    self.yarn.get("mscale")
+                    and self.yarn.get("mscale_all_dim"))):
+            raise NotImplementedError(
+                f"rope_scaling {self.yarn}: YaRN is built with `mscale` "
+                f"and `mscale_all_dim` both set and no `attention_factor` "
+                f"(without them transformers scales cos and sin by 0.1 "
+                f"ln(factor) + 1)")
         if (self.n_group, self.topk_group) != (1, 1):
             raise NotImplementedError(
                 f"n_group {self.n_group}, topk_group {self.topk_group}: "
@@ -134,8 +170,18 @@ class DeepseekV3Config:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def yarn(self) -> dict:
+        return dict(self.rope_scaling or ())
+
+    @property
     def softmax_scale(self) -> float:
-        return 1.0 / math.sqrt(self.qk_head_dim)
+        """Of the scores, in the expanded and the absorbed form alike:
+        1 / sqrt(dn + dr), times YaRN's mscale(factor, mscale_all_dim)^2."""
+        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        if self.yarn:
+            scale *= _mscale(self.yarn["factor"],
+                             self.yarn["mscale_all_dim"]) ** 2
+        return scale
 
     @classmethod
     def tiny(cls, **kw):
@@ -151,6 +197,11 @@ class DeepseekV3Config:
         return cls(**base)
 
 
+def _mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's `yarn_get_mscale`."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def layer_shapes(cfg: DeepseekV3Config, l: int) -> dict:
     D, H, C = cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -158,6 +209,14 @@ def layer_shapes(cfg: DeepseekV3Config, l: int) -> dict:
            "attn": {"wq": (D, H * (dn + dr)), "wkv_a": (D, C + dr),
                     "kv_a_layernorm": (C,), "wk_b": (C, H, dn),
                     "wv_b": (C, H, dv), "wo": (H * dv, D)}}
+    if cfg.q_lora_rank is not None:
+        r = cfg.q_lora_rank
+        del out["attn"]["wq"]
+        out["attn"].update(wq_a=(D, r), q_a_layernorm=(r,),
+                           wq_b=(r, H * (dn + dr)))
+    if cfg.hc_mult:
+        out["hc_attn"] = hc_shapes(cfg.hc_mult, D)
+        out["hc_ffn"] = hc_shapes(cfg.hc_mult, D)
     if l < cfg.first_k_dense_replace:
         F = cfg.intermediate_size
         out["ffn"] = {"w1": (D, F), "w3": (D, F), "w2": (F, D)}
@@ -192,18 +251,35 @@ def init_params(cfg: DeepseekV3Config, seed: int = 0):
 # sub-layers, each written once
 # ---------------------------------------------------------------------------
 
-def rope_pairs(x, positions, theta):
+def rope_pairs(x, positions, theta, yarn=None):
     """RoPE over adjacent pairs (2i, 2i+1) of the last axis
-    (`rope_interleave`). x [B, T, ..., d], positions [B, T]."""
+    (`rope_interleave`). x [B, T, ..., d], positions [B, T]. `yarn`
+    (`rope_scaling`): the blended table, cos and sin times mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn:
+        inv = yarn_inv_freq(
+            inv, theta, yarn["factor"],
+            yarn["original_max_position_embeddings"],
+            yarn.get("beta_fast", 32), yarn.get("beta_slow", 1))
     ang = positions.astype(jnp.float32)[..., None] * inv      # [B, T, d/2]
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn:
+        factor = _mscale(yarn["factor"], yarn["mscale"]) \
+            / _mscale(yarn["factor"], yarn["mscale_all_dim"])
+        if factor != 1.0:
+            cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., 0::2], xf[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def low_rank_query(p, h, eps):
+    """The query with `q_lora_rank`: rmsnorm(h Wq_a, gq) Wq_b."""
+    return rmsnorm(h @ p["wq_a"], p["q_a_layernorm"], eps) @ p["wq_b"]
 
 
 def latent_projections(p, h, positions, cfg):
@@ -213,11 +289,14 @@ def latent_projections(p, h, positions, cfg):
     B, T, _ = h.shape
     H, dn, C = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
         cfg.kv_lora_rank
-    q = (h @ p["wq"]).reshape(B, T, H, cfg.qk_head_dim)
+    q = h @ p["wq"] if cfg.q_lora_rank is None \
+        else low_rank_query(p, h, cfg.rms_norm_eps)
+    q = q.reshape(B, T, H, cfg.qk_head_dim)
     ckr = h @ p["wkv_a"]
     c = rmsnorm(ckr[..., :C], p["kv_a_layernorm"], cfg.rms_norm_eps)
-    return (q[..., :dn], rope_pairs(q[..., dn:], positions, cfg.rope_theta),
-            c, rope_pairs(ckr[..., C:], positions, cfg.rope_theta))
+    rot = (cfg.rope_theta, cfg.yarn)
+    return (q[..., :dn], rope_pairs(q[..., dn:], positions, *rot),
+            c, rope_pairs(ckr[..., C:], positions, *rot))
 
 
 def _causal_rows(q, k, v, scale, row0):
@@ -294,24 +373,74 @@ def head_logits(params, x, cfg):
 # the one loop over the layers, and the plain driver
 # ---------------------------------------------------------------------------
 
+def residual(cfg, hc, x, branch):
+    """x through one sub-layer, `branch(h) -> (f, aux)`: x + f, or with
+    `hc_mult` streams (x a tuple of them, `hc` the sub-layer's
+    connections) what the branch makes of a mix of them, written to all of
+    them beside their doubly-stochastic carry-over. Returns (x', aux)."""
+    if not cfg.hc_mult:
+        f, aux = branch(x)
+        return x + f, aux
+    pre, post, res = hc_coefficients(
+        hc, x, cfg.hc_sinkhorn_iters, cfg.hc_eps,
+        (cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max))
+    f, aux = branch(hc_read(x, pre))
+    return hc_write(x, res, post, f), aux
+
+
+def _in_row_blocks(fn, x):
+    """`fn(x) -> (x', sel)` over the streams x (a tuple of [B, T, D]) in
+    equal blocks of at most `_FFN_ROW_BLOCK` positions, one after another
+    (a bucket up to that size is one block, and the compiler drops a loop
+    of one turn): a sub-layer in which no position meets another (the
+    feed-forward one with its two mixes) then holds a block's temporaries,
+    not a bucket's (at 16,384 positions the experts' sorted rows, their
+    products and the float32 sum over the k choices are 2 GiB beside four
+    streams)."""
+    B, T, D = x[0].shape
+    nb = -(-T // _FFN_ROW_BLOCK)
+    while T % nb:
+        nb += 1
+    blocks = tuple(a.reshape(B, nb, T // nb, D).swapaxes(0, 1) for a in x)
+    out, sel = jax.lax.map(fn, blocks)
+    if sel is not None:         # [nb, B block, k] -> [B T, k]
+        sel = sel.reshape(nb, B, T // nb, -1).swapaxes(0, 1) \
+            .reshape(B * T, -1)
+    return tuple(a.swapaxes(0, 1).reshape(B, T, D) for a in out), sel
+
+
 def apply_layers(cfg, params, x, positions, attend, state):
     """x [B, T, D] through every layer. `attend(p["attn"], q_nope, q_rope,
     c, kr, state, l) -> (a [B, T, H dv], state)`. Returns (x, state, sel
     [expert layers, B T, k])."""
     sels = []
     eps = cfg.rms_norm_eps
+    if cfg.hc_mult:
+        x = (x,) * cfg.hc_mult
     for l, p in enumerate(params["layers"]):
-        h = rmsnorm(x, p["input_layernorm"], eps)
-        a, state = attend(p["attn"], *latent_projections(
-            p["attn"], h, positions, cfg), state, l)
-        x = x + a @ p["attn"]["wo"]
-        h = rmsnorm(x, p["post_attention_layernorm"], eps)
-        if l < cfg.first_k_dense_replace:
-            f = dense_ffn(p["ffn"], h)
-        else:
-            f, sel = routed_ffn(p["ffn"], h, cfg)
+        def attention(x, p=p, l=l):
+            nonlocal state
+            h = rmsnorm(x, p["input_layernorm"], eps)
+            a, state = attend(p["attn"], *latent_projections(
+                p["attn"], h, positions, cfg), state, l)
+            return a @ p["attn"]["wo"], None
+
+        def feed_forward(x, p=p, l=l):
+            h = rmsnorm(x, p["post_attention_layernorm"], eps)
+            if l < cfg.first_k_dense_replace:
+                return dense_ffn(p["ffn"], h), None
+            return routed_ffn(p["ffn"], h, cfg)
+
+        def ffn_sub_layer(x, p=p, feed_forward=feed_forward):
+            return residual(cfg, p.get("hc_ffn"), x, feed_forward)
+
+        x, _ = residual(cfg, p.get("hc_attn"), x, attention)
+        x, sel = _in_row_blocks(ffn_sub_layer, x) if cfg.hc_mult \
+            else ffn_sub_layer(x)
+        if sel is not None:
             sels.append(sel)
-        x = x + f
+    if cfg.hc_mult:
+        x = sum(x)
     k = cfg.num_experts_per_tok
     sel = jnp.stack(sels) if sels else jnp.zeros(
         (0, x.shape[0] * x.shape[1], k), jnp.int32)

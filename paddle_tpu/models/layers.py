@@ -9,13 +9,17 @@ attribute, which is where the benchmark's fault tools replace it.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..parallel.moe import dropless_moe_ffn
 
-__all__ = ["rmsnorm", "rope", "dense_causal_attention", "dense_ffn",
-           "routed_ffn", "seeded_tree"]
+__all__ = ["rmsnorm", "rope", "yarn_inv_freq", "dense_causal_attention",
+           "dense_ffn", "routed_ffn", "seeded_tree", "hc_shapes",
+           "hc_coefficients", "hc_read", "hc_write"]
 
 
 def rmsnorm(x, w, eps):
@@ -47,6 +51,24 @@ def rope(x, positions, inv_freq, factor: float = 1.0):
     x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
     rot = jnp.concatenate([-x2, x1], -1)
     return (xf * cos + rot * sin).astype(x.dtype)
+
+
+def yarn_inv_freq(f, theta, factor, original, beta_fast, beta_slow):
+    """YaRN's blend of a table f [d/2] of plain frequencies theta^(-2i/d),
+    as transformers' `_compute_yarn_parameters`: frequencies that turn
+    fewer than `beta_slow` times over the `original` context are divided
+    by `factor`, those that turn more than `beta_fast` times are kept, a
+    linear ramp between."""
+    d = 2 * f.shape[0]
+
+    def turns_at(n):        # the index whose frequency turns n times
+        return d * math.log(original / (n * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
 
 
 def dense_causal_attention(q, k, v, scale, window=None):
@@ -89,12 +111,103 @@ def routed_ffn(p, h, cfg):
     return y.reshape(shape), sel
 
 
+# ---------------------------------------------------------------------------
+# a residual that is not a sum: n streams mixed by hyper-connections whose
+# carry-over matrix is made doubly stochastic (manifold-constrained,
+# arXiv:2512.24880 over arXiv:2409.19606). A sub-layer's three steps:
+#
+#     pre, post, res = hc_coefficients(p, X, ...)   # from the streams
+#     h  = hc_read(X, pre)                          # the branch's input
+#     X' = hc_write(X, res, post, F(h))             # carry over and write
+#
+# The streams are a TUPLE of n arrays X_j [..., C], never one array: an
+# axis of n = 4 before C would be the second-minor one, which a TPU pads
+# to the 16 rows of a bfloat16 tile (four times the bytes), and stacked on
+# any other axis every write ends in a concatenation that copies all n
+# streams once more (half of the write's time by the TPU compiler's own
+# cost model; scripts/hyper_step0.py). The coefficients carry their stream
+# axes first, pre [n, ...], res [n, n, ...]: through the Sinkhorn loop the
+# token axis is the minor one.
+# ---------------------------------------------------------------------------
+
+def hc_shapes(n: int, C: int) -> dict:
+    """Parameters of one sub-layer's connections: `phi` [n C, n (n + 2)]
+    (rows j C .. (j + 1) C meet stream j; columns [pre n | post n | res n n
+    (row-major)]), `hc_bias` the same n (n + 2), `hc_scale` [3] = (a_pre,
+    a_post, a_res)."""
+    return {"phi": (n * C, n * (n + 2)), "hc_bias": (n * (n + 2),),
+            "hc_scale": (3,)}
+
+
+def hc_coefficients(p, X, iters: int, eps: float, clamp):
+    """X (n arrays [..., C]) -> (pre [n, ...] = sigmoid, post [n, ...] = 2
+    sigmoid, res [n, n, ...] doubly stochastic after `iters` Sinkhorn
+    iterations: every column divided by its sum + eps, then every row), all
+    float32.
+    The norm over all n C numbers has no gain and scales a token's whole
+    row, so it is applied to the n (n + 2) products and not to X."""
+    n, C = len(X), X[0].shape[-1]
+    r = jax.lax.rsqrt(sum(jnp.mean(jnp.square(x.astype(jnp.float32)),
+                                   axis=-1) for x in X) / n + eps)
+    phi = p["phi"].astype(X[0].dtype).reshape(n, C, -1)
+    z = sum(jnp.einsum("...c,ck->...k", X[j], phi[j],
+                       preferred_element_type=jnp.float32)
+            for j in range(n))
+    z = jnp.moveaxis(z, -1, 0) * r                      # [n (n + 2), ...]
+    # a_pre, a_post, a_res, each over its own columns
+    a = p["hc_scale"].astype(jnp.float32)[
+        np.repeat(np.arange(3), (n, n, n * n))]
+    tail = (1,) * r.ndim
+    z = z * a.reshape((-1,) + tail) \
+        + p["hc_bias"].astype(jnp.float32).reshape((-1,) + tail)
+    pre = jax.nn.sigmoid(z[:n])
+    post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+    m = jnp.exp(jnp.clip(z[2 * n:], clamp[0], clamp[1]))
+
+    # the loop carries n n arrays of the tokens' shape, every sum spelled
+    # as adds: elementwise all through (a `jnp.sum` over a stream axis of
+    # [n, n, ...] whole made a reduction and a division of each half step,
+    # four launches an iteration on a TPU v5e). A `fori_loop`, not 20
+    # copies of the body: a twentieth of the program to compile
+    # (scripts/hyper_step0.py has both readings)
+    def step(_, m):
+        cols = [sum(m[n * i + j] for i in range(n)) + eps for j in range(n)]
+        m = [m[n * i + j] / cols[j] for i in range(n) for j in range(n)]
+        rows = [sum(m[n * i:n * i + n]) + eps for i in range(n)]
+        return tuple(m[n * i + j] / rows[i]
+                     for i in range(n) for j in range(n))
+
+    m = jax.lax.fori_loop(0, iters, step, tuple(m))      # m[n row + col]
+    return pre, post, jnp.stack(m).reshape((n, n) + r.shape)
+
+
+def hc_read(X, pre):
+    """h [..., C] = sum_j pre_j X_j, in X's dtype."""
+    h = sum(pre[j][..., None] * x.astype(jnp.float32)
+            for j, x in enumerate(X))
+    return h.astype(X[0].dtype)
+
+
+def hc_write(X, res, post, f):
+    """X' (n arrays): stream i = sum_j res_ij X_j + post_i f."""
+    n = len(X)
+    xs = [x.astype(jnp.float32) for x in X]
+    ff = f.astype(jnp.float32)
+    return tuple(
+        (sum(res[i, j][..., None] * xs[j] for j in range(n))
+         + post[i][..., None] * ff).astype(X[0].dtype) for i in range(n))
+
+
 def seeded_tree(shapes, key, std: float, dtype):
     """A tree of seeded random leaves for a tree of shapes: matrices normal
     of `std`; a leaf named `*norm` a gain 1 + 0.1 normal (round one, not AT
     one: a dropped gain then shows); a leaf named `bias` normal of std 0.1
     (a program that weighs by score plus bias, or selects on the score,
-    then disagrees). models/afmoe.py draws its weights the same way."""
+    then disagrees); a leaf named `hc_bias` normal of std 1 and one named
+    `hc_scale` 0.5 + 0.05 normal (hyper-connections far from their
+    published start of a near-constant mix: a dropped term or a Sinkhorn
+    loop cut short then shows). models/afmoe.py draws its weights the
+    same way."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
     out = []
@@ -103,7 +216,9 @@ def seeded_tree(shapes, key, std: float, dtype):
         z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
         if name.endswith("norm"):
             leaf = 1.0 + 0.1 * z
+        elif name == "hc_scale":
+            leaf = 0.5 + 0.05 * z
         else:
-            leaf = (0.1 if name == "bias" else std) * z
+            leaf = {"bias": 0.1, "hc_bias": 1.0}.get(name, std) * z
         out.append(leaf.astype(dtype))
     return jax.tree_util.tree_unflatten(treedef, out)

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .layers import (dense_causal_attention, rmsnorm, rope, routed_ffn,
-                     seeded_tree)
+                     seeded_tree, yarn_inv_freq)
 
 __all__ = ["MellumConfig", "MellumTrainModel", "SLIDING", "FULL",
            "init_params", "param_shapes", "rope_table", "loss_and_chosen",
@@ -179,26 +179,16 @@ def init_params(cfg: MellumConfig, seed: int = 0, out_shardings=None):
 
 def rope_table(cfg: MellumConfig, kind: str):
     """(inv_freq [d/2], factor) of a layer kind. Sliding: the plain
-    theta^(-2i/d), factor 1. Full: YaRN as transformers'
-    `_compute_yarn_parameters`: frequencies that turn fewer than
-    `beta_slow` times over the original context are divided by `factor`,
-    those that turn more than `beta_fast` times are kept, a linear ramp
-    between; cos and sin both carry `attention_factor`."""
+    theta^(-2i/d), factor 1. Full: YaRN's blend (`layers.yarn_inv_freq`);
+    cos and sin both carry `attention_factor`."""
     d = cfg.head_dim
     f = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     if kind == SLIDING:
         return f, 1.0
-    orig = cfg.yarn_original_max_position_embeddings
-
-    def turns_at(n):        # the index whose frequency turns n times
-        return d * math.log(orig / (n * 2 * math.pi)) \
-            / (2 * math.log(cfg.rope_theta))
-    low = max(math.floor(turns_at(cfg.yarn_beta_fast)), 0)
-    high = min(math.ceil(turns_at(cfg.yarn_beta_slow)), d - 1)
-    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 0.001), 0.0, 1.0)
-    return f / cfg.yarn_factor * ramp + f * (1.0 - ramp), \
-        cfg.yarn_attention_factor
+    return yarn_inv_freq(
+        f, cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_position_embeddings, cfg.yarn_beta_fast,
+        cfg.yarn_beta_slow), cfg.yarn_attention_factor
 
 
 def _gate_flash(b, hq, hkv, s, d, dtype, window, scale):

@@ -640,14 +640,25 @@ def _kv_index(group):
     return lambda b, i: (b // group, 0, 0)
 
 
-def _named(kind, window, group):
+# what the whole K and V of a head, double-buffered, may take before the
+# plain call asks for more than the compiler's default 16 MiB of scoped
+# VMEM (8,192 positions of 192 + 128 in bf16 take 10 MiB and fit beside a
+# query block of 256; 16,384 take 20 and were refused: PR 49)
+_PLAIN_HELD_BYTES = 12 * 2 ** 20
+
+
+def _named(kind, window, group, held=0):
     """pallas_call arguments of the banded and grouped calls: a name a
     trace tells the band's calls from the full layer's by (the profiler
     shows it as the instruction's), and room for the whole K and V of a
     long sequence. The plain call (no band, one query head a KV head)
-    stays the program it was."""
+    stays the program it was, unless the `held` bytes of its whole K and V
+    pass the default's room: then it gets the room and still no name."""
     if _plain(window, group):
-        return {}
+        if held <= _PLAIN_HELD_BYTES:
+            return {}
+        return {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_SPAN_VMEM_BYTES)}
     return {"name": f"flash_{'band' if window is not None else 'full'}"
                     f"_{kind}",
             "compiler_params": pltpu.CompilerParams(
@@ -700,7 +711,9 @@ def _flash_fwd_impl(q3, k3, v3, mask2, seed_arr, scale, causal, block_q,
             jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, s, 128), jnp.float32),
         ],
-        interpret=_interpret(), **_named("fwd", window, group))(*args)
+        interpret=_interpret(),
+        **_named("fwd", window, group,
+                 held=2 * t * (d + dv) * k3.dtype.itemsize))(*args)
     return o, lse
 
 

@@ -109,13 +109,19 @@ _PAGED_BYTES_PER_TOKEN = _obs.gauge(
     "bytes of the cache's paged parts one cached token holds (every "
     "layer, and every pass of a model that loops)", ["engine"])
 
+_RESIDUAL_STREAMS = _obs.gauge(
+    "paddle_tpu_serving_residual_streams",
+    "residual streams a token carries through the layers (1: a sum; n: "
+    "hyper-connections over n streams)", ["engine"])
+
 _engine_ids = itertools.count()
 
 
 def _drop_engine_series(eid: str):
     for m in (_REQS, _TOKENS, _STEPS, _COMPILES, _DECODE_H, _PREFILL_H,
               _LATENCY_H, _QUEUE_DEPTH, _OCCUPANCY, _SAMPLING_REQS,
-              _SAMPLING_TOKENS, _SLOT_STATE_BYTES, _PAGED_BYTES_PER_TOKEN):
+              _SAMPLING_TOKENS, _SLOT_STATE_BYTES, _PAGED_BYTES_PER_TOKEN,
+              _RESIDUAL_STREAMS):
         m.remove_matching(engine=eid)
 
 
@@ -367,6 +373,7 @@ class Engine:
             lambda: (lambda e: e._kv_cache_bytes()["paged"]
                      / ((e.num_pages + 1) * e.page_size) if e else 0.0)(
                 wr()))
+        _RESIDUAL_STREAMS.labels(engine=eid).set(model.residual_streams)
         # the model's tallies as last read (stats() reports the change)
         self._tally_seen: dict = {}
         self._steps_seen = 0
@@ -1022,10 +1029,14 @@ class Engine:
 
     def _attn_form(self, phase: str) -> dict:
         """Span attribute `attn` of a model whose prefill and decode run
-        different forms of attention (`DecodeModel.attn_forms`); nothing
-        for the others."""
+        different forms of attention (`DecodeModel.attn_forms`), and
+        `residual` of one whose residual is not a sum
+        (`DecodeModel.residual_form`); nothing for the others."""
         form = self.model.attn_forms.get(phase)
-        return {"attn": form} if form else {}
+        out = {"attn": form} if form else {}
+        if self.model.residual_form:
+            out["residual"] = self.model.residual_form
+        return out
 
     def _kv_cache_bytes(self) -> dict:
         """Bytes of the cache by kind of part: {"paged", "slot",

@@ -91,6 +91,13 @@ class DecodeModel:
           whose prefill and decode compute attention by different programs
           of one function (attribute `attn` of those spans); empty
           otherwise
+      residual_form      what carries a token through the layers where
+          that is not one summed vector: `mhc<n>x<iters>` for n streams
+          mixed by hyper-connections whose carry-over takes <iters>
+          Sinkhorn iterations (attribute `residual` of those spans), and
+          `residual_streams` = n (a gauge); "" and 1 otherwise. The
+          streams are activations of the core's `apply_layers`: no part
+          of the cache, nothing the engine moves
       window             the positions a model's window layers attend to,
           kept in a ring of `ring_pages(page_size)` pages a slot (a slot
           part); None without such layers. The engine reads it for its
@@ -127,6 +134,8 @@ class DecodeModel:
     has_routing = False
     passes = 1
     attn_forms: dict[str, str] = {}
+    residual_form = ""
+    residual_streams = 1
     window: int | None = None
     scan_chunk: int | None = None
 
@@ -816,7 +825,12 @@ class LatentDecodeModel(_ExpertRecords, DecodeModel):
     wide over T^2 pairs) and writes the rows; decode the absorbed one (the
     key up-projection folded into the query, a query of 576 against the
     cached row, the value the row's own first 512 numbers: `ops/
-    paged_attention.py::paged_latent_attention_decode`)."""
+    paged_attention.py::paged_latent_attention_decode`).
+
+    A configuration whose residual is several streams (`cfg.hc_mult`,
+    `model_type: xing4_0`) is served by the same two drivers: the streams
+    are activations inside `apply_layers` and leave nothing in the cache;
+    `residual_form` names them on the engine's spans."""
 
     cache_kinds = {"latent": "paged", **_ExpertRecords._expert_kinds}
     attn_forms = {"prefill": "expanded", "decode": "absorbed"}
@@ -825,6 +839,9 @@ class LatentDecodeModel(_ExpertRecords, DecodeModel):
                  seed: int = 0, attn_impl: str | None = None):
         super().__init__(cfg, params if params is not None
                          else _dsv3.init_params(cfg, seed), attn_impl)
+        if cfg.hc_mult:
+            self.residual_streams = cfg.hc_mult
+            self.residual_form = f"mhc{cfg.hc_mult}x{cfg.hc_sinkhorn_iters}"
 
     def init_cache(self, num_pages: int, page_size: int,
                    num_slots: int = 0):

@@ -125,6 +125,59 @@ def test_the_flash_forward_compiles_at_the_longest_prefill_bucket(
                      sds(128))
 
 
+def test_the_flash_forward_compiles_at_sixteen_thousand_positions(
+        one_chip, monkeypatch):
+    """The Xing4.0 cell's longest prefill bucket: a head's whole K and V,
+    double-buffered, are 20 MiB at 16,384 positions of 192 + 128, past the
+    compiler's default 16 MiB of scoped VMEM, which refused the plain call
+    (24.6 MiB asked) until it was given room past `_PLAIN_HELD_BYTES`."""
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    sds = lambda d: jax.ShapeDtypeStruct((1, 32, 16384, d), jnp.bfloat16,
+                                         sharding=one_chip)
+
+    def call(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, scale=192 ** -0.5, causal=True, block_q=256)
+
+    with jax.default_matmul_precision("default"):
+        assert "tpu_custom_call" in _compile(
+            call, sds(192), sds(192), sds(128)).as_text()
+        monkeypatch.setattr(pallas_attention, "_PLAIN_HELD_BYTES", 2 ** 40)
+        with pytest.raises(Exception, match="vmem"):    # traced anew
+            _compile(lambda q, k, v: call(q, k, v), sds(192), sds(192),
+                     sds(128))
+
+
+@pytest.mark.parametrize("T,launches", [(16384, 30), (32, 30)])
+def test_the_residual_streams_steps_hold_no_copy_of_the_streams(
+        one_chip, T, launches):
+    """One sub-layer's hyper-connection steps at the Xing4.0 cell's sizes
+    (four streams of 3,584, a prefill bucket's rows and a decode batch's):
+    the streams are a tuple of arrays, so nothing concatenates them (no
+    temporary the size of a stream), and the Sinkhorn loop is a `while`
+    whose body is a few fusions, not 20 copies of it."""
+    from paddle_tpu.models import layers
+    n, C = 4, 3584
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    p = {"phi": sds((n * C, n * (n + 2))), "hc_bias": sds((n * (n + 2),)),
+         "hc_scale": sds((3,))}
+
+    def both(p, X, f):
+        pre, post, res = layers.hc_coefficients(p, X, 20, 1e-6, (-30., 30.))
+        return layers.hc_write(X, res, post, layers.hc_read(X, pre) + f)
+
+    with jax.default_matmul_precision("default"):
+        c = _compile(both, p, (sds((T, C)),) * n, sds((T, C)))
+    text = c.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r" (?:fusion|custom-call|while)\(", entry)) \
+        < launches
+    assert entry.count(" while(") == 1
+    assert c.memory_analysis().temp_size_in_bytes < T * C * 2
+
+
 @pytest.mark.parametrize("B,H,Hkv,T,d,window", [
     (16, 16, 16, 1024, 64, None),       # gpt_350m_train.b16s1024
     (2, 8, 8, 1024, 128, None),         # gpt_1p3b_train_pp2tp2, a shard

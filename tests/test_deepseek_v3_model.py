@@ -131,8 +131,9 @@ def test_the_shared_and_the_routed_parts_add_up_to_the_references_layer(tiny):
 
 
 def test_what_is_not_built_is_refused():
-    with pytest.raises(NotImplementedError, match="q_lora_rank"):
-        ds.DeepseekV3Config.tiny(q_lora_rank=16)
+    with pytest.raises(NotImplementedError, match="only YaRN"):
+        ds.DeepseekV3Config.tiny(rope_scaling={"type": "linear",
+                                               "factor": 4.0})
     with pytest.raises(NotImplementedError, match="one group"):
         ds.DeepseekV3Config.tiny(n_group=2, topk_group=1)
 

@@ -42,3 +42,12 @@ def test_a_core_imports_layers_and_no_other_core(core):
     assert not siblings & set(CORES)
     # and the library stands under them all
     assert not _sibling_imports(os.path.join(MODELS, "layers.py"))
+
+
+@pytest.mark.parametrize("core", CORES)
+def test_only_the_core_that_was_taught_them_takes_the_residual_streams(core):
+    """`layers.hc_*` are the library's; a core takes them by importing
+    them, and today one does (models/deepseek_v3.py, `hc_mult`)."""
+    with open(os.path.join(MODELS, f"{core}.py")) as f:
+        takes = "hc_coefficients" in f.read()
+    assert takes is (core == "deepseek_v3")

@@ -230,7 +230,8 @@ def test_defrag_moves_the_pages_and_leaves_the_slots(served):
     assert run(defrag=True) == run(defrag=False)
 
 
-@pytest.mark.parametrize("which", ["gpt", "hybrid", "looped", "latent"])
+@pytest.mark.parametrize("which", ["gpt", "hybrid", "looped", "latent",
+                                   "latent_streams"])
 def test_both_decode_models_answer_one_cache_interface(served, which):
     """Everything `Engine` reads of a model, `DecodeModel` declares and
     every model answers; the bodies hand back the cache they were given
@@ -239,10 +240,14 @@ def test_both_decode_models_answer_one_cache_interface(served, which):
     model = {"gpt": lambda: GPTDecodeModel(GPTConfig.tiny(num_layers=1)),
              "hybrid": lambda: HybridDecodeModel(cfg, params=params),
              "looped": lambda: LoopedDecodeModel(OuroConfig.tiny()),
-             "latent": lambda: LatentDecodeModel(DeepseekV3Config.tiny())
+             "latent": lambda: LatentDecodeModel(DeepseekV3Config.tiny()),
+             # four residual streams, a low-rank query: the same parts
+             "latent_streams": lambda: LatentDecodeModel(
+                 DeepseekV3Config.tiny(hc_mult=4, q_lora_rank=12))
              }[which]()
     slot = which == "hybrid"
-    routed = which in ("hybrid", "latent")
+    latent = which.startswith("latent")
+    routed = which == "hybrid" or latent
     assert isinstance(model, DecodeModel)
     assert model.cfg is not None and model.params
     assert model.max_positions == model.cfg.max_position_embeddings
@@ -253,7 +258,10 @@ def test_both_decode_models_answer_one_cache_interface(served, which):
     assert model.has_routing is routed is hasattr(model, "routing_of")
     # two forms of attention are named, one is not
     assert model.attn_forms == ({"prefill": "expanded", "decode": "absorbed"}
-                                if which == "latent" else {})
+                                if latent else {})
+    # a residual that is not a sum is named, a sum is not
+    assert (model.residual_form, model.residual_streams) == (
+        ("mhc4x20", 4) if which == "latent_streams" else ("", 1))
     assert not (model.has_prefill_tail and model.slot_state)
     # a tally has a meaning only through the model
     assert bool(model.parts_of("tally")) is (which != "gpt")

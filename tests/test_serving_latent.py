@@ -73,7 +73,7 @@ def _serve(model, lengths=LENGTHS, new=6, seed=3):
     return eng, [(r, seen[r.id]) for r in reqs]
 
 
-def _widest(params, sizes, served, T=48):
+def _widest(params, sizes, served, T=48, ref=ref):
     """Widest |served logit - reference logit| over every served position
     (prefill's last and every decode's), and that over decode's alone."""
     worst = worst_decode = 0.0
