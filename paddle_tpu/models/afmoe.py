@@ -224,11 +224,20 @@ def _band_rows(q, k, v, scale, row0, key0, window):
 def banded_causal_attention(q, k, v, scale, window=None,
                             row_block: int = _ROW_BLOCK):
     """q [B, T, H, d], k and v [B, T, Hkv, d] -> [B, T, H d]: position i
-    attends to j <= i and, with `window`, to i - j < window. The query
-    rows go in blocks once a whole [T, T] of scores a head is too much; a
-    block of a window layer meets the `window + block` keys that can reach
-    it and no others (O(T window)), a block of a full layer all T (a
-    prompt of 16,384: 256 rows at a time, half a GiB of scores)."""
+    attends to j <= i and, with `window`, to i - j < window. XLA's
+    spelling: float32 scores, a mask, a softmax. The query rows go in
+    blocks once a whole [T, T] of scores a head is too much; a block of a
+    window layer meets the `window + block` keys that can reach it and no
+    others (O(T window)), a block of a full layer all T (a prompt of
+    16,384: 256 rows at a time, half a GiB of scores, three passes over
+    them). What runs it: `forward` below (no cache: tests, the reference's
+    counterpart), and the serving prefill (`serving/model.py::
+    WindowedDecodeModel.prefill`) off a TPU, for a bucket the flash
+    kernel's blocks do not divide, and as the gate's other candidate
+    (`layers.gated_causal_attention`: on a TPU the kernel holds every
+    bucket of 1,024 and over, an eighth of this function's time on the
+    triangle at 16,384 and a quarter on a band: docs/KERNELS.md, "The
+    serving prefill's two calls")."""
     B, T, H, d = q.shape
     R = min(row_block, T)
     if window is None or window + R >= T:

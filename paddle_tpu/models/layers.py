@@ -18,8 +18,9 @@ import numpy as np
 from ..parallel.moe import dropless_moe_ffn
 
 __all__ = ["rmsnorm", "rope", "yarn_inv_freq", "dense_causal_attention",
-           "dense_ffn", "routed_ffn", "seeded_tree", "hc_shapes",
-           "hc_coefficients", "hc_read", "hc_write"]
+           "gated_causal_attention", "dense_ffn", "routed_ffn",
+           "seeded_tree", "hc_shapes", "hc_coefficients", "hc_read",
+           "hc_write"]
 
 
 def rmsnorm(x, w, eps):
@@ -88,6 +89,62 @@ def dense_causal_attention(q, k, v, scale, window=None):
     pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
     a = jnp.einsum("bkgts,bskd->btkgd", pr.astype(v.dtype), v)
     return a.reshape(B, T, Hq * d)
+
+
+def _gate_flash(b, hq, hkv, s, d, dtype, window, scale, xla):
+    """(key, candidates, make_args) for ops/autobench: the banded /
+    grouped flash call against the caller's XLA spelling, each kind under
+    a key of its own (a spelling other than `dense_causal_attention` is
+    named in the key: the draw is between the kernel and THAT). An XLA
+    candidate whose scores do not fit never wins (its error is kept in
+    `perf.kernels()`)."""
+    from ..ops.pallas_attention import flash_attention
+    dtype = jnp.dtype(dtype)
+    key = ("flash_band_gqa" if window is not None else "flash_full_gqa",
+           b, hq, hkv, s, d, str(dtype), window)
+    if xla is not dense_causal_attention:
+        key += (xla.__name__,)
+
+    def make_args():
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        return tuple(jax.random.normal(k, (b, s, h, d), jnp.float32)
+                     .astype(dtype) for k, h in zip(keys, (hq, hkv, hkv)))
+
+    def pallas(q, k, v):
+        t = lambda a: a.transpose(0, 2, 1, 3)
+        return t(flash_attention(t(q), t(k), t(v), scale=scale, causal=True,
+                                 window=window))
+
+    return key, {"pallas": pallas,
+                 "xla": lambda q, k, v: xla(q, k, v, scale, window)}, make_args
+
+
+def gated_causal_attention(q, k, v, scale, window, kernel: bool,
+                           xla=dense_causal_attention):
+    """q [B, T, H, d], k and v [B, T, Hkv, d] -> [B, T, H d]: causal, and
+    inside `window` positions where given. With `kernel` the Pallas flash
+    kernel (ops/pallas_attention.py: a band and grouped heads in its three
+    calls, K and V never repeated) where its blocks divide T and, on a
+    TPU, where the gate measures it faster than `xla(q, k, v, scale,
+    window)`, the caller's XLA spelling of the same sum, at this shape;
+    `xla` everywhere else. A window that every position of T lies inside
+    is no band: both spellings then compute the triangle, under the
+    triangle's key."""
+    B, T, H, d = q.shape
+    if window is not None and window >= T:
+        window = None
+    if kernel:
+        from ..ops.pallas_attention import (_auto_block_k, _auto_block_q,
+                                            on_tpu)
+        if _auto_block_q(T) is not None and _auto_block_k(T) is not None:
+            from ..ops import autobench
+            key, cands, make_args = _gate_flash(
+                B, H, k.shape[2], T, d, q.dtype, window, scale, xla)
+            # interpreted off a TPU: no clock to ask
+            if not on_tpu() or autobench.prefer(
+                    key, cands, make_args, default="pallas") == "pallas":
+                return cands["pallas"](q, k, v).reshape(B, T, H * d)
+    return xla(q, k, v, scale, window)
 
 
 def dense_ffn(p, h):

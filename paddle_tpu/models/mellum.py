@@ -35,8 +35,9 @@ the kinds differ in what attention does, not in what it holds):
 {"input_layernorm", "post_attention_layernorm" [L, D], "attn": {wq [L, D,
 H d], wk, wv [L, D, Hkv d], wo [L, H d, D], q_norm, k_norm [L, d]},
 "ffn": {wg [L, D, E], w1, w3 [L, Eh, D, F], w2 [L, Eh, F, D]}}}`.
-`rmsnorm`, `rope`, the short-sequence attention and `routed_ffn`
-(-> parallel/moe.py::dropless_moe_ffn) are models/layers.py's.
+`rmsnorm`, `rope`, the attention (`gated_causal_attention`: the flash
+kernel or XLA's scores) and `routed_ffn` (-> parallel/moe.py::
+dropless_moe_ffn) are models/layers.py's.
 """
 from __future__ import annotations
 
@@ -48,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .layers import (dense_causal_attention, rmsnorm, rope, routed_ffn,
+from .layers import (gated_causal_attention, rmsnorm, rope, routed_ffn,
                      seeded_tree, yarn_inv_freq)
 
 __all__ = ["MellumConfig", "MellumTrainModel", "SLIDING", "FULL",
@@ -191,55 +192,13 @@ def rope_table(cfg: MellumConfig, kind: str):
         cfg.yarn_beta_slow), cfg.yarn_attention_factor
 
 
-def _gate_flash(b, hq, hkv, s, d, dtype, window, scale):
-    """(key, candidates, make_args) for ops/autobench: the banded /
-    grouped flash call against XLA's scores, each kind under a key of its
-    own. Past a few thousand positions the XLA candidate's scores do not
-    fit and it never wins (its error is kept in `perf.kernels()`)."""
-    from ..ops.pallas_attention import flash_attention
-    dtype = jnp.dtype(dtype)
-    key = ("flash_band_gqa" if window is not None else "flash_full_gqa",
-           b, hq, hkv, s, d, str(dtype), window)
-
-    def make_args():
-        keys = jax.random.split(jax.random.PRNGKey(0), 3)
-        return tuple(jax.random.normal(k, (b, s, h, d), jnp.float32)
-                     .astype(dtype) for k, h in zip(keys, (hq, hkv, hkv)))
-
-    def pallas(q, k, v):
-        t = lambda a: a.transpose(0, 2, 1, 3)
-        return t(flash_attention(t(q), t(k), t(v), scale=scale, causal=True,
-                                 window=window))
-
-    def xla(q, k, v):
-        return dense_causal_attention(q, k, v, scale, window)
-
-    return key, {"pallas": pallas, "xla": xla}, make_args
-
-
 def attend(q, k, v, scale, window, impl):
     """q [B, T, H, d], k and v [B, T, Hkv, d] -> [B, T, H d]: causal, and
     inside `window` positions where given. "flash": the Pallas kernel
-    (ops/pallas_attention.py: a band and grouped heads in its three calls)
-    where the gate measures it faster than XLA's scores, on a TPU."""
-    B, T, H, d = q.shape
-    if impl == "flash":
-        from ..ops.pallas_attention import (_auto_block_k, _auto_block_q,
-                                            flash_attention, on_tpu)
-        fits = _auto_block_q(T) is not None and _auto_block_k(T) is not None
-        wins = fits and not on_tpu()    # interpreted: no clock to ask
-        if fits and on_tpu():
-            from ..ops import autobench
-            key, cands, make_args = _gate_flash(
-                B, H, k.shape[2], T, d, q.dtype, window, scale)
-            wins = autobench.prefer(key, cands, make_args,
-                                    default="pallas") == "pallas"
-        if wins:
-            t = lambda a: a.transpose(0, 2, 1, 3)
-            o = flash_attention(t(q), t(k), t(v), scale=scale, causal=True,
-                                window=window)
-            return t(o).reshape(B, T, H * d)
-    return dense_causal_attention(q, k, v, scale, window)
+    where the gate measures it faster than XLA's whole [T, T] of scores,
+    on a TPU (`layers.gated_causal_attention`, which the serving prefill
+    of models/afmoe.py shares)."""
+    return gated_causal_attention(q, k, v, scale, window, impl == "flash")
 
 
 def layer(p, x, positions, cfg: MellumConfig, kind: str):

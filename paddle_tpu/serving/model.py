@@ -940,12 +940,19 @@ class WindowedDecodeModel(_ExpertRecords, DecodeModel):
     that table; the expert tallies are `_ExpertRecords'`.
 
     `prefill` and `decode` are drivers over `afmoe.apply_layers`. Prefill
-    attends densely, in bands (`afmoe.banded_causal_attention`), writes
-    every position to the full layers' pages and, to the slot's ring, the
-    positions whose page is among the prompt's last R (older ones are never
-    cached there). Decode writes one row to each; the full layers attend
-    through `paged_attention_decode`, the sliding layers through the XLA
-    path from the slot's first live position over the ring."""
+    attends over the bucket itself (`layers.gated_causal_attention`): with
+    `attn_impl` "pallas" (a TPU's default) through the flash kernel, a band
+    of `afmoe.window_of(cfg, l)` on a sliding layer and the triangle on a
+    full one, the 4 KV heads read in place by their 8 query heads each, for
+    every bucket the kernel's blocks divide and the gate measures it
+    faster at (one key a bucket and kind: docs/KERNELS.md); else, and
+    elsewhere, XLA's float32 scores in row blocks
+    (`afmoe.banded_causal_attention`). It writes every position to the
+    full layers' pages and, to the slot's ring, the positions whose page is
+    among the prompt's last R (older ones are never cached there). Decode
+    writes one row to each; the full layers attend through
+    `paged_attention_decode`, the sliding layers through the XLA path from
+    the slot's first live position over the ring."""
 
     cache_kinds = {"k_full": "paged", "v_full": "paged", "k_win": "slot",
                    "v_win": "slot", **_ExpertRecords._expert_kinds}
@@ -1014,8 +1021,10 @@ class WindowedDecodeModel(_ExpertRecords, DecodeModel):
             pools = {**pools, kind: (
                 ck.at[i, dest[kind]].set(k[0].reshape(shape).astype(ck.dtype)),
                 cv.at[i, dest[kind]].set(v[0].reshape(shape).astype(cv.dtype)))}
-            return _afmoe.banded_causal_attention(
-                q, k, v, scale, _afmoe.window_of(cfg, l)), pools
+            return _layers.gated_causal_attention(
+                q, k, v, scale, _afmoe.window_of(cfg, l),
+                self.attn_impl == "pallas",
+                xla=_afmoe.banded_causal_attention), pools
 
         x, pools, sel = _afmoe.apply_layers(cfg, params, x, positions, attend,
                                             self._pools(cache))

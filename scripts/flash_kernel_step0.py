@@ -58,6 +58,18 @@ CELLS = {
         B=1, H=32, Hkv=32, T=1024, d=192, dv=128, block_q=256,
         fwd_only=True),
     "encoder.b16s512": dict(B=16, H=12, Hkv=12, T=512, d=64, causal=False),
+    # the windowed serving prefill's two calls (PR 50; beside XLA's row
+    # blocks: scripts/prefill_flash_step0.py)
+    "trinity_mini_serve/prefill16384/band": dict(
+        B=1, H=32, Hkv=4, T=16384, d=128, window=2048, fwd_only=True),
+    "trinity_mini_serve/prefill16384/full": dict(
+        B=1, H=32, Hkv=4, T=16384, d=128, fwd_only=True),
+    "trinity_mini_serve/prefill4096/band": dict(
+        B=1, H=32, Hkv=4, T=4096, d=128, window=2048, fwd_only=True),
+    "trinity_mini_serve/prefill4096/full": dict(
+        B=1, H=32, Hkv=4, T=4096, d=128, fwd_only=True),
+    "trinity_mini_serve/prefill1024/full": dict(
+        B=1, H=32, Hkv=4, T=1024, d=128, fwd_only=True),
 }
 
 

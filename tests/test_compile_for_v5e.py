@@ -208,6 +208,42 @@ def test_the_flash_calls_compile_at_the_training_cells_shapes(
     assert c.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("T,window,name", [
+    (16384, 2048, "flash_band_fwd"),    # a sliding layer, the longest bucket
+    (16384, None, "flash_full_fwd"),    # the full layer, the longest bucket
+    (1024, 2048, "flash_full_fwd"),     # a bucket inside the window: no band
+], ids=["band_16384", "full_16384", "band_inside_window"])
+def test_the_windowed_prefills_attention_compiles_at_the_cells_buckets(
+        one_chip, monkeypatch, T, window, name):
+    """`layers.gated_causal_attention` as `WindowedDecodeModel.prefill`
+    calls it on a TPU (B 1, 32 query heads over 4 KV heads of 128): ONE
+    Mosaic call under the mask's name, the whole K and V of a KV head in
+    VMEM with no `block_q` handed in, and three times q's bytes kept (a
+    transposed copy and the forward's float32 lse). The gate is off:
+    nothing can be timed on a described chip, and its default, the
+    kernel, holds; without `kernel` the same call is XLA's row blocks."""
+    from paddle_tpu.models import afmoe, layers
+    from paddle_tpu.ops import pallas_attention
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_attention, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_AUTOBENCH", "0")
+    sds = lambda h: jax.ShapeDtypeStruct((1, T, h, 128), jnp.bfloat16,
+                                         sharding=one_chip)
+
+    def attend(kernel):
+        return lambda q, k, v: layers.gated_causal_attention(
+            q, k, v, 128 ** -0.5, window, kernel,
+            xla=afmoe.banded_causal_attention)
+
+    with jax.default_matmul_precision("default"):
+        c = _compile(attend(True), sds(32), sds(4), sds(4))
+        x = _compile(attend(False), sds(32), sds(4), sds(4))
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 1 and name in text
+    assert "tpu_custom_call" not in x.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 3.1 * 32 * T * 128 * 2
+
+
 def _ops_naming(text, pattern):
     """The fusions, custom calls and copies of a compiled module whose
     result, or whose fused computation's parameter, is of a type that

@@ -30,7 +30,8 @@ def _pairs(t, window):
     return w * (w + 1) // 2 + (t - w) * w
 
 
-# the three training cells' calls: (positions, band, query heads a KV head,
+# the three training cells' calls and the served prefill's: (positions,
+# band, query heads a KV head,
 # the most score elements multiplied a pair the mask lets through: edge
 # tiles are multiplied whole, as every tile was, so the tiling's own 1.5,
 # 1.5 and 1.0625: walked in sub-tiles of 128 the backward calls read 1.124,
@@ -38,7 +39,12 @@ def _pairs(t, window):
 # Mellum's, under both cells' bounds, and the sub-tiles went: PERF.md)
 CELLS = {"gpt_350m_t1024_d64": (1024, None, 1, 1.5),
          "mellum_band_t8192_d128": (8192, 1024, 8, 1.5),
-         "mellum_full_t8192_d128": (8192, None, 8, 1.0625)}
+         "mellum_full_t8192_d128": (8192, None, 8, 1.0625),
+         # the served windowed prefill's longest bucket (PR 50; its
+         # forward call alone runs): a band of four tiles has three whole
+         # and two edge tiles a row block
+         "trinity_band_t16384_d128": (16384, 2048, 8, 1.25),
+         "trinity_full_t16384_d128": (16384, None, 8, 1.03125)}
 
 
 @pytest.mark.parametrize("call", ["fwd", "dq", "dkv"])
