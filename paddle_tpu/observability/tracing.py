@@ -23,7 +23,15 @@ numbers; this holds the *timeline*):
     one thread's ``submit`` and ends in another's ``admit``). Every
     stamp of the tracer is ``TRACER.clock()``: a reader that wants the
     spans on another clock reads both back to back and takes the
-    offset.
+    offset;
+  * pause spans — what interrupts a thread from inside the process, on
+    the same clock (``Tracer.install_pause_hooks``): ``host.gc`` for a
+    pass of the collector, ``jit.trace`` / ``jit.lower`` /
+    ``jit.compile`` / ``jit.cache_load`` for jax's own work on any
+    jitted function. Each names the span that was open on its thread in
+    the attribute ``during``, never in ``parent_id`` or ``caused_by``:
+    a step's children stay its phases. Every pause is counted in the
+    registry; one of ``PAUSE_FLOOR`` or longer also leaves a span.
 
 ``PADDLE_TPU_TRACE=0`` disables recording (ids still propagate so
 downstream tiers keep correlating); ``PADDLE_TPU_TRACE_BRIDGE=0``
@@ -31,9 +39,11 @@ disables only the jax annotation bridge.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -52,6 +62,36 @@ _HIGH_WATER = _obs.gauge(
     "paddle_tpu_trace_ring_high_water",
     "max spans ever resident in the trace ring (ring size when the "
     "ring has wrapped)")
+
+
+# every pause is counted here, whatever its length: the spans keep only
+# those of PAUSE_FLOOR or longer
+_GC_SECONDS = _obs.counter(
+    "paddle_tpu_host_gc_seconds_total",
+    "seconds this process spent in the garbage collector, by the "
+    "generation collected", ("generation",))
+_JIT_SECONDS = _obs.counter(
+    "paddle_tpu_jit_seconds_total",
+    "seconds jax spent on any jitted function of this process, by "
+    "stage: trace (to a jaxpr), lower (to MLIR), compile (the backend, "
+    "or the load from the compile cache in its place), cache_load (the "
+    "retrieval alone)", ("stage",))
+
+# A pause shorter than this is counted and leaves no span. Set-up runs
+# hundreds of one-primitive programs and a busy collector makes several
+# passes a second: a ring that drops one span silences every span reader
+# of the run.
+PAUSE_FLOOR = 1e-3
+
+# jax.monitoring's duration events -> the stage of `jit.<stage>`
+# (jax/_src/dispatch.py, jax/_src/compiler.py); the first three carry
+# `fun_name`
+_JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 
 
 def new_trace_id() -> str:
@@ -88,6 +128,7 @@ def _trace_annotation(name):
     if _annotation is None:
         import jax
         _annotation = jax.profiler.TraceAnnotation
+        TRACER._listen_to_jax()
     return _annotation(name)
 
 
@@ -194,6 +235,21 @@ class Tracer:
         # optional per-span tap (the telemetry agent): called OUTSIDE
         # the ring lock with each finished span; must never block
         self._sink = None
+        # pause hooks (install_pause_hooks): None until installed
+        self._pause_floor = None
+        # the trace of the pauses that interrupt no span: one a process,
+        # not one a pause (a collector keeps a ring of traces)
+        self._pause_trace = None
+        self._gc_t0 = None
+        self._gc_seconds = ()
+        self._jit_seconds = {}
+        self._jit_listening = False
+        # collections of the floor or longer, stamped by the gc hook and
+        # made spans by the next `_keep` or `spans()`: the hook runs
+        # wherever the interpreter lets the collector in, also while
+        # this thread holds the ring's lock or the sink's, so it takes
+        # neither
+        self._gc_done: deque = deque()
 
     def set_sink(self, fn):
         """``fn(span)`` is called for every finished span (after ring
@@ -243,7 +299,107 @@ class Tracer:
         self._keep(sp)
         return sp
 
+    # -- pauses -----------------------------------------------------------
+    def install_pause_hooks(self, floor: float = PAUSE_FLOOR) -> bool:
+        """Record what interrupts a thread from inside the process:
+        `host.gc` (a `gc.callbacks` hook) and `jit.trace` / `jit.lower` /
+        `jit.compile` / `jit.cache_load` (one `jax.monitoring` listener,
+        registered now if jax is imported, else where the tracer first
+        imports it). Every pause adds its seconds to
+        `paddle_tpu_host_gc_seconds_total{generation}` or
+        `paddle_tpu_jit_seconds_total{stage}`; one of `floor` seconds or
+        longer is also kept as a span with the attribute `during`, the
+        `span_id` of the span open on its thread. A tracer that is not
+        enabled installs nothing (False). Calling again only sets the
+        floor."""
+        if not self.enabled:
+            return False
+        first = self._pause_floor is None
+        self._pause_floor = float(floor)
+        if first:
+            self._pause_trace = new_trace_id()
+            # (literal label values: the analysis' cardinality rule)
+            self._gc_seconds = (_GC_SECONDS.labels(generation="0"),
+                                _GC_SECONDS.labels(generation="1"),
+                                _GC_SECONDS.labels(generation="2"))
+            self._jit_seconds = {
+                "trace": _JIT_SECONDS.labels(stage="trace"),
+                "lower": _JIT_SECONDS.labels(stage="lower"),
+                "compile": _JIT_SECONDS.labels(stage="compile"),
+                "cache_load": _JIT_SECONDS.labels(stage="cache_load")}
+            gc.callbacks.append(self._on_gc)
+            if "jax" in sys.modules:
+                self._listen_to_jax()
+        return True
+
+    def remove_pause_hooks(self):
+        if self._pause_floor is None:
+            return
+        self._pause_floor = None
+        gc.callbacks.remove(self._on_gc)
+        if self._jit_listening:
+            import jax
+            jax.monitoring.unregister_event_duration_listener(self._on_jit)
+            self._jit_listening = False
+
+    def _listen_to_jax(self):
+        if self._pause_floor is None or self._jit_listening:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(self._on_jit)
+        self._jit_listening = True
+
+    def _on_gc(self, phase, info):
+        # no lock, no span, no ring here: see `_gc_done`
+        if phase == "start":
+            self._gc_t0 = self.clock()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        floor = self._pause_floor
+        if t0 is None or floor is None:     # (un)installed under it
+            return
+        end = self.clock()
+        gen = info.get("generation", 2)
+        self._gc_seconds[gen].inc(end - t0)
+        if self.enabled and end - t0 >= floor:
+            self._gc_done.append((t0, end, gen, info.get("collected", 0),
+                                  self.current_span(),
+                                  threading.get_ident()))
+
+    def _drain_gc(self):
+        while self._gc_done:
+            try:
+                t0, end, gen, collected, amb, tid = self._gc_done.popleft()
+            except IndexError:      # another thread took it
+                return
+            sp = Span("host.gc",
+                      amb.trace_id if amb else self._pause_trace,
+                      _new_span_id(), None, t0, tid,
+                      {"generation": gen, "collected": collected,
+                       **({"during": amb.span_id} if amb else {})})
+            sp.end = end
+            self._keep(sp)
+
+    def _on_jit(self, event, seconds, **kw):
+        stage = _JIT_STAGES.get(event)
+        floor = self._pause_floor
+        if stage is None or floor is None:
+            return
+        now = self.clock()
+        self._jit_seconds[stage].inc(max(0.0, seconds))
+        if seconds < floor:
+            return
+        attrs = {"fun_name": kw["fun_name"]} if "fun_name" in kw else {}
+        amb = self.current_span()
+        if amb is not None:
+            attrs["during"] = amb.span_id
+        self.record(f"jit.{stage}", now - seconds, now,
+                    trace_id=amb.trace_id if amb else self._pause_trace,
+                    **attrs)
+
     def _keep(self, sp: Span):
+        if self._gc_done:
+            self._drain_gc()
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
                 _DROPPED.inc()
@@ -261,10 +417,13 @@ class Tracer:
 
     # -- inspection / export --------------------------------------------
     def spans(self) -> list[Span]:
+        if self._gc_done:
+            self._drain_gc()
         with self._lock:
             return list(self._spans)
 
     def clear(self):
+        self._gc_done.clear()
         with self._lock:
             self._spans.clear()
 
@@ -285,6 +444,7 @@ class Tracer:
 
 
 TRACER = Tracer()
+TRACER.install_pause_hooks()
 span = TRACER.span
 current_trace_id = TRACER.current_trace_id
 export_chrome_trace = TRACER.export_chrome_trace

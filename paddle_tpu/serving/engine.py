@@ -79,11 +79,16 @@ _COMPILES = _obs.counter(
     ["engine", "bucket"])
 _DECODE_H = _obs.histogram(
     "paddle_tpu_serving_decode_step_seconds",
-    "wall time of one jitted decode over the slot batch", ["engine"])
+    "length of one engine.decode span: this step's decode dispatched, "
+    "then the wait for the tokens of the decode before and of this "
+    "step's prefills", ["engine"])
 _PREFILL_H = _obs.histogram(
     "paddle_tpu_serving_prefill_seconds",
-    "wall time of one jitted prefill's dispatch (admission; its first "
-    "token is read behind the step's decode)", ["engine"])
+    "length of one engine.prefill span: since ISSUE 44 the DISPATCH of "
+    "a prefill (transfers and the jitted call returning, a compile "
+    "where the bucket is new), not its device time: that is "
+    "engine.wait up to `ready`, or jit_prefill in a device trace",
+    ["engine"])
 _LATENCY_H = _obs.histogram(
     "paddle_tpu_serving_request_latency_seconds",
     "submit-to-finish latency per request", ["engine"])
@@ -588,7 +593,6 @@ class Engine:
         chunk = self.model.scan_chunk
         scan = {"scan_len": T, "scan_chunks": -(-T // min(chunk, T))} \
             if chunk else {}
-        t0 = time.perf_counter()
         with _tracing.span("engine.prefill", trace_id=req.trace_id,
                            engine=self.engine_id, request=req.id,
                            prompt_len=int(req.prompt.size), bucket=T,
@@ -600,11 +604,15 @@ class Engine:
             compiled = self._compiles.get(bucket, 0) > pre_compiles
             if compiled:
                 sp.attrs["compiled"] = True
-        dt = time.perf_counter() - t0
+        # the span's own stamps: one clock in the step. Since ISSUE 44 this
+        # is the DISPATCH of the prefill (its device time is the step's
+        # `engine.wait` up to `ready`, or `jit_prefill` in a device trace)
+        dt = sp.end - sp.start
         self._m_prefill_h.observe(dt)
         if compiled:
             _perf.note_compile_seconds("engine.prefill", dt)
         self._note_flops(self._bucket_flops.get(bucket))
+        # `seconds`: the dispatch, as the histogram (docs/DEBUGGING.md)
         _flight.record("serving", "prefill", trace_id=req.trace_id,
                        engine=self.engine_id, request=req.id,
                        bucket=T, seconds=round(dt, 6))
@@ -781,7 +789,6 @@ class Engine:
         ahead = bool(batch) and pending
         next_toks = None
         try:
-            t0 = time.perf_counter()
             with _tracing.span("engine.decode", engine=self.engine_id,
                                active=len(batch), ahead=ahead,
                                passes=self.model.passes,
@@ -825,7 +832,7 @@ class Engine:
                 compiled = self._compiles.get(bucket, 0) > pre_compiles
                 if compiled:
                     sp.attrs["compiled"] = True
-            dt = time.perf_counter() - t0
+            dt = sp.end - sp.start
             self._m_decode_h.observe(dt)
         except Exception as e:
             # a decode-step failure poisons the whole slot batch, the

@@ -34,6 +34,18 @@ def _seed():
     yield
 
 
+@pytest.fixture(autouse=True, scope="session")
+def _program_spans_only():
+    """The suite counts the program's own spans (seven a decode step,
+    three a training step), and a pass of the collector takes a
+    millisecond or more every few thousand allocations of a process this
+    size: the tracer's pause hooks (`host.gc`, `jit.*`) are off here, and
+    tests/test_tracing_pauses.py installs them for itself."""
+    from paddle_tpu.observability.tracing import TRACER
+    TRACER.remove_pause_hooks()
+    yield
+
+
 @pytest.fixture()
 def fresh_programs():
     """Fresh main/startup programs + scope for static-graph tests."""

@@ -577,9 +577,17 @@ def test_e2e_fleet_trace_spans_four_processes_subprocess(ckpt_root):
         import PSClient
     from paddle_tpu.serving import ServingClient
 
-    # long linger: the trace must not finalize between two agents'
-    # flush ticks while a tier's spans are still in flight
-    col = TelemetryCollector(sample=1.0, linger_s=3.0)
+    # nothing finalizes by the clock here: a trace closes `linger_s` after
+    # its last span ARRIVED and drops what comes later, and the replica
+    # compiles its first prefill and decode inside this request (on a
+    # machine shared with five other workers that alone passed the 3 s
+    # this used to allow, the trace closed with the replica's queue span
+    # in it and the poll below ran out). The test polls for the four
+    # tiers to a deadline and then closes the trace itself, with every
+    # other trace that is open by then (the router's probes, each step of
+    # the replica): the ring has room for all of them.
+    col = TelemetryCollector(sample=1.0, linger_s=3600.0,
+                             ring_max=1 << 16)
     srv = CollectorServer(collector=col).start()
     base = dict(os.environ)
     base["PYTHONPATH"] = REPO + os.pathsep + base.get("PYTHONPATH", "")
@@ -637,13 +645,20 @@ def test_e2e_fleet_trace_spans_four_processes_subprocess(ckpt_root):
         assert vals.shape == (3, 4)
         ag.flush_once()
 
-        # poll until spans from >= 4 distinct processes landed
-        deadline_t = time.monotonic() + 60
+        # poll until every tier's spans landed (each child flushes on
+        # its own 0.2 s tick), to a deadline and not to a count of ticks
+        want = {"client", "router", "replica", "ps"}
+        deadline_t = time.monotonic() + 120
+        got = None
         while time.monotonic() < deadline_t:
             got = col.trace(tid)
-            if got and len({(p[0], p[1]) for p in got["procs"]}) >= 4:
+            if got and want <= {p[2] for p in got["procs"]}:
                 break
             time.sleep(0.2)
+        else:
+            pytest.fail(f"after 120 s the collector holds spans of "
+                        f"{got and got['procs']} for {tid}, want {want}")
+        assert not got["complete"]      # open until the sweep below
         col.sweep(force=True)
         tr = col.trace(tid)
         assert tr is not None and tr["complete"]
